@@ -270,7 +270,7 @@ class KVPool:
             # already invalidated. The enqueue is async so the lock is
             # held for µs once programs are warm; the first hit in each
             # pow2 k-bucket pays its XLA compile under the lock (once
-            # per process, amortized by LLMC_XLA_CACHE across runs) —
+            # per process, amortized by the persistent XLA cache) —
             # the price of keeping donation + ordering trivially sound.
             try:
                 t_g = time.monotonic()

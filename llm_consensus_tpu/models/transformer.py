@@ -28,7 +28,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from llm_consensus_tpu.utils.jaxcompat import shard_map as _shard_map
+from llm_consensus_tpu.analysis import sanitizer
 from llm_consensus_tpu.models.config import ModelConfig
 from llm_consensus_tpu.ops.attention import attention, make_attention_mask
 from llm_consensus_tpu.ops.mlp import gated_mlp
@@ -37,6 +37,44 @@ from llm_consensus_tpu.ops.quant import (
     is_quantized, kv_layer, kv_read, kv_write_rows, qeinsum)
 from llm_consensus_tpu.ops.norms import rms_norm
 from llm_consensus_tpu.ops.rope import apply_rope, rope_angles, rope_inv_freq
+
+
+class AttentionRoutes:
+    """Trace-time record of the attention path ``forward`` chose.
+
+    Whether a model's prefill and decode run the Pallas kernels or XLA
+    attention is decided inside ``forward`` — from shapes, mesh and the
+    requested impl — and shows in nothing the program returns (dh = 64
+    models, for one, legitimately decode through XLA). Every cached
+    ``forward`` books its choice here while it is traced, so a jitted
+    step counts once per compiled program, not per call. Phase is
+    ``"decode"`` for T = 1 and ``"prefill"`` otherwise; path is
+    ``"pallas"``, ``"xla"`` or ``"ring"``.
+    """
+
+    def __init__(self) -> None:
+        self._lock = sanitizer.make_lock("models.attention_routes")
+        self._seen: dict = {}
+
+    def note(self, model: str, phase: str, path: str) -> None:
+        with self._lock:
+            paths = self._seen.setdefault(model, {}).setdefault(phase, {})
+            paths[path] = paths.get(path, 0) + 1
+
+    def snapshot(self, model: str) -> dict:
+        """``{phase: {path: programs traced}}`` for ``model``."""
+        with self._lock:
+            return {
+                phase: dict(paths)
+                for phase, paths in self._seen.get(model, {}).items()
+            }
+
+    def reset(self) -> None:
+        with self._lock:
+            self._seen.clear()
+
+
+attention_routes = AttentionRoutes()
 
 
 # -- parameter init ----------------------------------------------------------
@@ -323,7 +361,7 @@ def _layer(
             from jax.sharding import PartitionSpec as P
 
             spec = P(None, None, "tp", None)  # [B, S, H, dh], heads on tp
-            fa = _shard_map(
+            fa = jax.shard_map(
                 fa, mesh=flash_mesh,
                 in_specs=(spec, spec, spec), out_specs=spec,
                 check_vma=False,
@@ -362,7 +400,7 @@ def _layer(
                 )
                 if is_quantized(k_att) else spec5
             )
-            da = _shard_map(
+            da = jax.shard_map(
                 da, mesh=flash_mesh,
                 in_specs=(spec, kv_spec, kv_spec, P(), P(), P(None)),
                 out_specs=(spec, P(None, "tp"), P(None, "tp"))
@@ -451,9 +489,11 @@ def forward(
     ``attn_impl="flash"`` routes cache prefill (T > 1, static ``start_pos``)
     through the fused Pallas kernel (ops/pallas/flash_attention.py), which
     never materializes the [B, Hq, T, S] score tensor and bounds work by
-    the causal frontier instead of cache capacity. Shapes the kernel can't
-    tile (or decode steps) silently fall back to the XLA path, so "flash"
-    is always safe to request.
+    the causal frontier instead of cache capacity, and T = 1 steps through
+    the fused decode kernel. Shapes and meshes the kernels can't serve take
+    the XLA path — a decision made here from ``flash_supported`` /
+    ``decode_flash_supported`` and booked in ``attention_routes``, so
+    "flash" is always safe to request and never a guess to read back.
 
     ``mesh``: when the params/cache carry TP NamedShardings, the Pallas
     kernel (a Mosaic custom call with no GSPMD partitioning rule) is wrapped
@@ -471,6 +511,7 @@ def forward(
                 "attn_impl='ring' is a one-shot sequence-parallel prefill: "
                 "it needs a cache, a mesh with an sp axis, and start_pos=0"
             )
+        attention_routes.note(cfg.name, "prefill", "ring")
         return _forward_ring_prefill(
             params, cfg, tokens, cache, mesh, logits_index
         )
@@ -588,6 +629,11 @@ def forward(
     flash_mesh = mesh if (
         (flash_offset is not None or decode_flash) and shard_tp > 1
     ) else None
+    if cache is not None:
+        attention_routes.note(
+            cfg.name, "decode" if t == 1 else "prefill",
+            "pallas" if flash_offset is not None or decode_flash else "xla",
+        )
 
     start = jnp.asarray(start_pos, jnp.int32)
     positions = start + jnp.arange(t, dtype=jnp.int32)[None, :]  # [1, T]

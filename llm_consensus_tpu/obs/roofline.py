@@ -200,33 +200,28 @@ class RooflineLedger:
 
     def _peaks(self) -> "tuple[Optional[float], Optional[float], int]":
         """(peak FLOPs/s, peak HBM bytes/s, device count) per chip from
-        the published-spec tables, or Nones off-accelerator. Resolved
-        once; jax import stays off the dispatch path."""
+        the published-spec tables; Nones on a CPU. A TPU the tables do
+        not list raises (utils/flops.UnknownDeviceError) — it never
+        degrades to the default ridge. Resolved once; jax import stays
+        off the dispatch path."""
         if not self._peaks_resolved:
-            peak_f = peak_b = None
-            n_dev = 1
-            try:
-                import jax
+            import jax
 
-                from llm_consensus_tpu.utils import flops as flops_mod
+            from llm_consensus_tpu.utils import flops as flops_mod
 
-                devices = jax.devices()
-                n_dev = max(1, len(devices))
-                kind = devices[0].device_kind
-                peak_f = flops_mod.device_peak_flops(kind)
-                peak_b = flops_mod.device_peak_hbm_bw(kind)
-            except Exception:  # noqa: BLE001
-                pass
+            devices = jax.devices()
+            kind = devices[0].device_kind
+            peak_f = flops_mod.device_peak_flops(kind)
+            peak_b = flops_mod.device_peak_hbm_bw(kind)
             with self._lock:
                 self._peak_flops, self._peak_bw = peak_f, peak_b
-                self._n_devices = n_dev
+                self._n_devices = max(1, len(devices))
                 self._peaks_resolved = True
         return self._peak_flops, self._peak_bw, self._n_devices
 
     def ridge(self) -> "tuple[float, str]":
         """(FLOPs-per-byte balance point, its provenance): the chip's
-        peak ratio when both peaks are known, the fallback knob off-
-        accelerator."""
+        peak ratio on a TPU, the fallback knob on a CPU."""
         if self.ridge_override is not None:
             return self.ridge_override, "override"
         peak_f, peak_b, _ = self._peaks()

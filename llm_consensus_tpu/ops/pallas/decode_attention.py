@@ -64,12 +64,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from llm_consensus_tpu.utils.jaxcompat import (
-    pallas_tpu_compiler_params as _compiler_params)
 from llm_consensus_tpu.utils import knobs
+from llm_consensus_tpu.utils.backend import pallas_interpret
 
 NEG_INF = -1e30
 _LANES = 128
+_BLOCK_K_CAP = 512
+# Conservative share of the 16 MB scoped VMEM limit left to the K/V code
+# blocks, their scale blocks and the dequant temporaries (see _fits).
+_VMEM_BUDGET = 12 * 1024 * 1024
 
 
 def _pow2_block(width: int, cap: int) -> int:
@@ -80,30 +83,84 @@ def _pow2_block(width: int, cap: int) -> int:
     return bk
 
 
+def _legal_block_ks(width: int, quantized: bool) -> list[int]:
+    """Legal kv block lengths for an attention span of ``width``, largest
+    first. A block must divide the span exactly (the grid covers it with
+    no padding — padding would mean copying the cache), so candidates
+    are the power-of-two divisors. The collapsed (block_k, Hkv·dh) view
+    the kernel matmuls over needs 8 sublanes; int8 KV additionally puts
+    block_k on the LANES of its seq-minor scale block [1, bb, Hkv,
+    block_k], which Mosaic tiles in 128s. One block spanning the whole
+    width is always a legal shape ("equal to the array dim")."""
+    top = _pow2_block(width, _BLOCK_K_CAP)
+    floor = _LANES if quantized else 8
+    out = []
+    bk = top
+    while bk >= floor:
+        out.append(bk)
+        bk //= 2
+    if not out and top == width:
+        out = [width]
+    return out
+
+
+def _fits(b_block: int, block_k: int, hkv: int, dh: int, kv_item: int,
+          quantized: bool) -> bool:
+    """VMEM budget check for one grid iteration's blocks.
+
+    Factor 8 = K+V × up-to-quadruple buffering: the Mosaic pipeline was
+    measured allocating ~2× the naive double-buffer estimate (a
+    4×-factor budget chose blocks that exceeded the 16 MB scoped limit
+    by 4% on v5e at batch 8 bf16). Quantized adds the seq-minor scale
+    blocks (exact-tiling, tiny) and the per-head int8→bf16 code
+    conversions feeding the matmuls."""
+    codes = 8 * b_block * block_k * hkv * dh * kv_item
+    scales = 8 * b_block * hkv * block_k * 2 if quantized else 0
+    temps = 2 * b_block * block_k * dh * 2 if quantized else 0
+    return codes + scales + temps <= _VMEM_BUDGET
+
+
+def _choose_blocks(b: int, width: int, hkv: int, dh: int, kv_item: int,
+                   quantized: bool) -> Optional[tuple[int, int]]:
+    """(b_block, block_k) maximizing bytes per grid iteration —
+    per-iteration overhead (semaphores, DMA issue) dwarfs the tiny
+    per-head matmuls — among legal block shapes that fit VMEM; None when
+    no legal shape fits (the caller's predicate routes to XLA)."""
+    best = None
+    for cand_b in (8, 4, 2, 1):
+        if b % cand_b:
+            continue
+        for cand_k in _legal_block_ks(width, quantized):
+            if _fits(cand_b, cand_k, hkv, dh, kv_item, quantized):
+                if best is None or cand_b * cand_k > best[0] * best[1]:
+                    best = (cand_b, cand_k)
+                break
+    return best
+
+
 def decode_flash_supported(
     n_heads: int, n_kv_heads: int, dh: int, width: Optional[int] = None,
     quantized: bool = False,
 ) -> bool:
-    """True when the kernel's block shapes satisfy Mosaic tiling.
+    """True when the kernel can be built for these shapes — the SAME
+    block chooser ``decode_attention`` runs, so a ``True`` here never
+    turns into a Mosaic rejection at dispatch.
 
     The K/V blocks are (1, b_block, block_k, Hkv, dh) over the stacked
     [L, B, S, Hkv, dh] cache: the lane dim needs dh % 128 == 0 and the
     Hkv sublane dim covers its full array dim (accepted for bf16 and
     int8). ``width`` (the attention span the grid will cover — cache
-    capacity or the caller's bucket) must factor into legal kv blocks:
-    its largest power-of-two divisor serves as block_k, which must be a
-    full-width block or satisfy the (8, 128) / int8 (32, 128) sublane
-    tile on the (block_k, Hkv·dh-ish) DMA granularity. Power-of-two
-    widths (the engine's buckets) always pass.
+    capacity or the caller's bucket) must factor into a legal kv block
+    that fits VMEM at one batch row per iteration (a one-row block
+    divides every batch). bf16 or int8 storage is assumed.
     """
     if n_heads % n_kv_heads or dh % _LANES:
         return False
-    if width is not None:
-        bk = _pow2_block(width, 512)
-        need = 32 if quantized else 8
-        if bk < need and bk != width:
-            return False
-    return True
+    if width is None:
+        return True
+    return _choose_blocks(
+        1, width, n_kv_heads, dh, 1 if quantized else 2, quantized
+    ) is not None
 
 
 def _kernel(
@@ -424,7 +481,6 @@ def decode_attention(
     sliding_window: Optional[int] = None,
     logit_softcap: Optional[float] = None,
     kv_width: Optional[int] = None,  # static attention span bound (≥ pos+1)
-    block_k: int = 512,
     interpret: Optional[bool] = None,
     return_state: bool = False,
 ):
@@ -474,63 +530,29 @@ def decode_attention(
     group = hq // hkv
     scale = dh**-0.5 if scale is None else scale
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
 
     w = s_dim if kv_width is None else min(kv_width, s_dim)
-    # block_k must divide the attention span exactly — the grid covers
-    # it with no padding (padding would mean copying the cache). The
-    # engine's width buckets are 128-multiples, so block_k = 128 always
-    # divides them (odd multiples like 384 factor no higher; pow2
-    # buckets admit larger blocks up to the cap).
-    bk_cap = _pow2_block(w, block_k)
     kv_item = kq.dtype.itemsize
-
-    # (b_block, block_k) jointly maximize bytes per grid iteration —
-    # per-iteration overhead (semaphores, DMA issue) dwarfs the tiny
-    # per-head matmuls — within a conservative VMEM budget covering the
-    # double-buffered K/V code blocks, their scale blocks, and the
-    # per-head dequant temporaries in compute dtype (fp32 k and v).
-    vmem_budget = 12 * 1024 * 1024
-    best = None
-
-    def fits(cand_b, cand_k):
-        # Factor 8 = K+V × up-to-quadruple buffering: the Mosaic pipeline
-        # was measured allocating ~2× the naive double-buffer estimate
-        # (a 4×-factor budget chose blocks that exceeded the 16 MB scoped
-        # limit by 4% on v5e at batch 8 bf16). Quantized adds the
-        # seq-minor scale blocks (exact-tiling, tiny) and the per-head
-        # int8→bf16 code conversions feeding the matmuls.
-        codes = 8 * cand_b * cand_k * hkv * dh * kv_item
-        scales = 8 * cand_b * hkv * cand_k * 2 if quantized else 0
-        temps = 2 * cand_b * cand_k * dh * 2 if quantized else 0
-        return codes + scales + temps <= vmem_budget
-
-    for cand_b in (8, 4, 2, 1):
-        if b % cand_b:
-            continue
-        cand_k = bk_cap
-        while cand_k > 8 and not fits(cand_b, cand_k):
-            cand_k //= 2
-        if not fits(cand_b, cand_k):
-            continue
-        if best is None or cand_b * cand_k > best[0] * best[1]:
-            best = (cand_b, cand_k)
-    # Nothing fits (wide-head bf16 shapes): the smallest legal block —
-    # possibly still over budget, in which case Mosaic's rejection lands
-    # in _flash_guard's XLA fallback rather than silently mis-budgeting.
-    b_block, block_k = best if best is not None else (1, min(8, bk_cap))
+    # forward() only dispatches here when decode_flash_supported — the
+    # same chooser — found a legal block. Direct callers at other spans
+    # (the interpret-mode parity tests at ragged widths) get the
+    # smallest dividing block, which only the interpreter accepts.
+    b_block, block_k = _choose_blocks(
+        b, w, hkv, dh, kv_item, quantized
+    ) or (1, _pow2_block(w, 8))
     forced = knobs.get_str("LLMC_DECODE_BLOCKS")
     if forced:
         # Tuning override "bbxbk" (e.g. "2x512"): bypasses the chooser so
         # block-shape sweeps on real hardware need no code edits. Any
-        # malformed or non-dividing value is ignored (a tuning knob must
-        # never take down the decode hot path).
+        # malformed, non-dividing or Mosaic-illegal value is ignored (a
+        # tuning knob must never take down the decode hot path).
         try:
             fb, _, fk = forced.partition("x")
             fb, fk = int(fb), int(fk)
         except ValueError:
             fb = fk = 0
-        if fb > 0 and fk > 0 and b % fb == 0 and w % fk == 0:
+        if fb > 0 and b % fb == 0 and fk in _legal_block_ks(w, quantized):
             b_block, block_k = fb, fk
     n_kv_blocks = w // block_k
     n_b_blocks = b // b_block
@@ -681,7 +703,7 @@ def decode_attention(
         # block); declaring the grid's batch dim parallel lets Mosaic
         # overlap one iteration's K/V DMAs with its neighbor's compute
         # instead of serializing the whole sweep on DMA latency.
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

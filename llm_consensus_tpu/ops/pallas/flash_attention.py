@@ -39,6 +39,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from llm_consensus_tpu.utils.backend import pallas_interpret
+
 NEG_INF = -1e30  # large-negative f32; exp(NEG_INF - m) underflows to exactly 0
 
 _LANES = 128  # TPU lane width: scratch rows are broadcast across it
@@ -160,7 +162,7 @@ def flash_attention(
         raise ValueError(f"n_heads {hq} not a multiple of n_kv_heads {hkv}")
     scale = dh**-0.5 if scale is None else scale
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
 
     block_q = _pow2_block(t, min(block_q, t))
     # Work is bounded by the causal frontier, not cache capacity.

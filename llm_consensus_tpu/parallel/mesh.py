@@ -29,23 +29,6 @@ from llm_consensus_tpu.models.config import ModelConfig
 from llm_consensus_tpu.utils import knobs
 
 
-def pvary(x, axis_name):
-    """Mark ``x`` as device-varying over ``axis_name`` (str or tuple of
-    names — under a multi-axis shard_map, carries must vary over every
-    bound axis the data they combine with varies over).
-
-    Compat shim: ``lax.pvary`` is deprecated in favor of ``lax.pcast``;
-    older jax only has the former, and jax before the varying-manual-axes
-    type system (< 0.5) has neither — there every shard_map input is
-    already treated as varying, so the marker is correctly a no-op.
-    """
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axis_name, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis_name)
-    return x
-
-
 def make_mesh(
     axis_sizes: dict[str, int],
     devices: Optional[Sequence[jax.Device]] = None,
@@ -306,7 +289,9 @@ def plan_panel(
                 d for m in (p.prefill_mesh, p.mesh) if m is not None
                 for d in m.devices.flat
             ]
-            if taken & {d.id for d in used}:
+            # One chip holds every model by construction (the one-chip
+            # deployment): nothing was mis-planned, nothing to warn of.
+            if n > 1 and taken & {d.id for d in used}:
                 _warn_wrap_sharing(name, used)
             taken |= {d.id for d in used}
             plan.placements.append(p)

@@ -50,12 +50,10 @@ import jax.numpy as jnp
 
 from jax.sharding import Mesh, PartitionSpec as P
 
-from llm_consensus_tpu.utils.jaxcompat import shard_map as _shard_map
 from llm_consensus_tpu.models.config import ModelConfig
 from llm_consensus_tpu.models.transformer import _layer, embed_tokens, unembed
 from llm_consensus_tpu.ops.attention import make_attention_mask
 from llm_consensus_tpu.ops.rope import rope_angles, rope_inv_freq
-from llm_consensus_tpu.parallel.mesh import pvary
 
 
 def _pipeline_body(
@@ -116,7 +114,7 @@ def _pipeline_body(
     init = (
         inq,
         jnp.zeros_like(inq),  # varying by construction (from sharded inq)
-        pvary(zero, axis_name),
+        jax.lax.pcast(zero, axis_name, to="varying"),
     )
     (_, outq, _), _ = jax.lax.scan(step, init, jnp.arange(m + n_stages - 1))
     # Outputs end stage-sharded: stage s holds {g : g ≡ s (mod S)} at
@@ -172,7 +170,7 @@ def pipeline_forward(
     xs = xs.reshape(c, n_stages, mb, t, cfg.d_model).swapaxes(0, 1)
 
     layer_specs = jax.tree.map(lambda _: P(axis_name), params["layers"])
-    body = _shard_map(
+    body = jax.shard_map(
         partial(
             _pipeline_body, cfg=cfg, axis_name=axis_name,
             n_microbatches=microbatches,

@@ -34,9 +34,7 @@ import jax.numpy as jnp
 
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from llm_consensus_tpu.utils.jaxcompat import shard_map as _shard_map
 from llm_consensus_tpu.ops.attention import NEG_INF
-from llm_consensus_tpu.parallel.mesh import pvary
 
 
 def _block_attention(
@@ -109,13 +107,17 @@ def _ring_attention_local(
         kv_pos = jax.lax.ppermute(kv_pos, axis_name, perm)
         return (k_blk, v_blk, kv_pos, out_new, m_new, l_new), None
 
-    # pvary: mark the accumulator inits as device-varying over every bound
+    # pcast: mark the accumulator inits as device-varying over every bound
     # axis so the scan carry types match (they combine with varying data —
     # the ring axis always, plus the head axis when heads are sharded).
     axes = tuple(vary_axes) or (axis_name,)
-    out0 = pvary(jnp.zeros((b, tl, hkv, g, dh), jnp.float32), axes)
-    m0 = pvary(jnp.full((b, hkv, g, tl), NEG_INF, jnp.float32), axes)
-    l0 = pvary(jnp.zeros((b, hkv, g, tl), jnp.float32), axes)
+
+    def varying(x):
+        return jax.lax.pcast(x, axes, to="varying")
+
+    out0 = varying(jnp.zeros((b, tl, hkv, g, dh), jnp.float32))
+    m0 = varying(jnp.full((b, hkv, g, tl), NEG_INF, jnp.float32))
+    l0 = varying(jnp.zeros((b, hkv, g, tl), jnp.float32))
     (_, _, _, out, _, l), _ = jax.lax.scan(
         hop, (k, v, kv_pos0, out0, m0, l0), None, length=axis_size
     )
@@ -162,7 +164,7 @@ def ring_attention(
     scale = q.shape[-1] ** -0.5 if scale is None else scale
     seq_spec = P(None, axis_name, head_axis, None)
     vary_axes = (axis_name,) if head_axis is None else (axis_name, head_axis)
-    fn = _shard_map(
+    fn = jax.shard_map(
         partial(
             _ring_attention_local,
             axis_name=axis_name,

@@ -33,36 +33,57 @@ from llm_consensus_tpu.utils import knobs
 DEFAULT_MAX_NEW_TOKENS = 4096
 SCHEME = "tpu:"
 
-_cache_enabled = False
+# Home of the persistent XLA compilation cache when nobody places it from
+# outside: ONE fixed path inside the checkout. The directory is part of
+# every cache key, so a path that moves with a uid, a pid or a temp dir
+# never hits — and a second process must find what the first compiled.
+DEFAULT_XLA_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".cache", "xla",
+)
 
 
-def _enable_compilation_cache() -> None:
-    """Persist XLA compilations across processes (first-run UX).
+def _place_compilation_cache() -> Optional[str]:
+    """Persist XLA compilations across processes (first-run UX): a fresh
+    process pays a full compile per model×bucket on a real chip; with the
+    on-disk cache every later invocation starts decoding immediately.
 
-    A fresh CLI process pays 20-40s of compile per model×bucket on a real
-    chip; the on-disk cache makes every later invocation start decoding
-    immediately. ``LLMC_XLA_CACHE=0`` disables, ``LLMC_XLA_CACHE=<dir>``
-    relocates. Best-effort: failure to set up the cache never blocks
-    serving.
-    """
-    global _cache_enabled
-    if _cache_enabled:
-        return
-    _cache_enabled = True
-    env = knobs.get_str("LLMC_XLA_CACHE")
-    if env == "0":
-        return
-    cache_dir = env or os.path.join(
-        os.path.expanduser("~"), ".cache", "llm-consensus-tpu", "xla"
-    )
-    try:
-        import jax
+    A cache placed from outside (``JAX_COMPILATION_CACHE_DIR``, or the
+    config option it feeds) is left exactly as given; only when there is
+    none does the cache go to ``DEFAULT_XLA_CACHE_DIR``.
+    ``JAX_ENABLE_COMPILATION_CACHE=0`` turns it off. Returns the
+    directory in use."""
+    import jax
 
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass
+    if jax.config.jax_compilation_cache_dir is None:
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_XLA_CACHE_DIR)
+    return jax.config.jax_compilation_cache_dir
+
+
+def _keep_multichip_programs_out_of_the_cache(n_devices: int) -> None:
+    """A process that places a model across TPU chips runs WITHOUT the
+    persistent compilation cache.
+
+    Found on the chip (PR 21: v5e 2x2 host, jax 0.9.0 / libtpu 0.0.34): a
+    tp=2 engine whose programs were LOADED from the persistent cache
+    halts its slice at first use ("The program continuator has halted
+    unexpectedly") — every warm start, with the Pallas kernels or with
+    XLA attention — while the same programs compiled fresh always ran.
+    One-chip executables load fine. The cache cannot be switched per
+    program, so the whole process pays a compile at every start instead
+    of losing a slice; ``device_stats`` reports the cache as disabled.
+    The CPU backend is not affected and keeps its cache."""
+    import jax
+
+    if (
+        n_devices > 1
+        and jax.default_backend() == "tpu"
+        and jax.config.jax_enable_compilation_cache
+    ):
+        from jax.experimental.compilation_cache import compilation_cache
+
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
 
 
 def _parse_draft_spec(spec: str) -> dict:
@@ -295,6 +316,8 @@ class TPUProvider(Provider):
         ))
         if not panel_presets and judge_preset is None:
             return
+        self._require_accelerator()
+        _place_compilation_cache()
         with self._lock:
             bad = set(self._bad_devices)
         if bad:
@@ -325,6 +348,11 @@ class TPUProvider(Provider):
         prefill_meshes = {
             p.model: p.prefill_mesh for p in plan.placements
         }
+        _keep_multichip_programs_out_of_the_cache(max(
+            m.devices.size
+            for m in (*meshes.values(), *prefill_meshes.values())
+            if m is not None
+        ))
         stale_batchers = []
         stale_handoffs = []
         with self._lock:
@@ -561,15 +589,21 @@ class TPUProvider(Provider):
         import jax
 
         from llm_consensus_tpu.utils.flops import (
-            batched_decode_mbu, decode_mfu)
+            batched_decode_mbu, decode_mfu, device_peak_flops)
 
         now = _time.monotonic()
         out: dict = {}
-        try:
-            device_kind = jax.devices()[0].device_kind
-        except Exception:  # noqa: BLE001 — no backend: no gauges
+        entries = self._batcher_entries()
+        with self._lock:
+            handoffs = dict(self._handoffs)
+        if not entries and not handoffs:
             return out
-        for preset, (eng, batcher) in self._batcher_entries():
+        # Pools exist, so a backend does. Looked up OUTSIDE the per-pool
+        # guards below: an unknown TPU kind raises here (utils/flops)
+        # instead of quietly dropping every gauge.
+        device_kind = jax.devices()[0].device_kind
+        device_peak_flops(device_kind)
+        for preset, (eng, batcher) in entries:
             try:
                 snap = batcher.snapshot()
                 live = sum(
@@ -629,8 +663,6 @@ class TPUProvider(Provider):
         # flops/token ≈ decode flops/token (2·params; the attention
         # quadratic is second-order at serving prompt lengths), so the
         # decode MFU model serves both roles.
-        with self._lock:
-            handoffs = dict(self._handoffs)
         for preset, (_eng, handoff) in handoffs.items():
             if handoff is None:
                 continue
@@ -829,6 +861,73 @@ class TPUProvider(Provider):
         for _, batcher in batchers:
             batcher.close()
 
+    @staticmethod
+    def _require_accelerator() -> None:
+        """A ``tpu:`` model is served from a TPU — or from a backend that
+        was asked for by name (tests and CI pin ``JAX_PLATFORMS=cpu``).
+        Never from the CPU JAX falls back to when it finds no chip: that
+        run would look exactly like success. And on a TPU the chip must
+        be one the peaks table knows (utils/flops raises otherwise), so
+        no MFU/MBU gauge or roofline ridge is ever quietly dropped."""
+        import jax
+
+        from llm_consensus_tpu.utils.backend import checked_backend
+        from llm_consensus_tpu.utils.flops import device_peak_flops
+
+        if checked_backend("a tpu: model") == "tpu":
+            device_peak_flops(jax.devices()[0].device_kind)
+
+    def device_stats(self) -> dict:
+        """Where this provider's engines really run — the /statsz
+        ``device`` block: the backend JAX chose (platform, kind, count),
+        its published peaks, per-device memory, the compile cache, and
+        per engine its devices and attention paths (impl built vs
+        running, guard fallbacks, kernel-or-XLA per phase). Empty until
+        a placement is planned or an engine built: before that this
+        provider has not touched the backend."""
+        import jax
+
+        from llm_consensus_tpu.utils import flops
+
+        with self._lock:
+            engines = dict(self._engines)
+            planned = bool(self._meshes)
+        if not engines and not planned:
+            return {}
+        devices = jax.devices()
+        kind = devices[0].device_kind
+        cache_dir = jax.config.jax_compilation_cache_dir
+        try:
+            n_cached = len(os.listdir(cache_dir)) if cache_dir else 0
+        except OSError:
+            n_cached = 0
+        out: dict = {
+            "platform": devices[0].platform,
+            "kind": kind,
+            "count": len(devices),
+            "peak_flops": flops.device_peak_flops(kind),
+            "peak_hbm_bytes_per_s": flops.device_peak_hbm_bw(kind),
+            "compile_cache": {
+                "dir": cache_dir, "entries": n_cached,
+                "enabled": bool(jax.config.jax_enable_compilation_cache),
+            },
+            "memory": {},
+            "engines": {},
+        }
+        for d in devices:
+            mem = d.memory_stats() or {}
+            out["memory"][str(d.id)] = {
+                k: mem[k]
+                for k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+                if k in mem
+            }
+        for preset, eng in engines.items():
+            leaf = jax.tree.leaves(eng.params)[0]
+            entry = eng.attention_stats()
+            entry["devices"] = sorted(d.id for d in leaf.sharding.device_set)
+            out["engines"][preset] = entry
+        return out
+
     def _engine_for(self, model: str):
         """Get or lazily create the engine serving ``model``.
 
@@ -876,7 +975,10 @@ class TPUProvider(Provider):
             # a failed REBUILD as evidence the placement is suspect.
             fault_plan.check("build", preset=preset)
 
-        _enable_compilation_cache()
+        self._require_accelerator()
+        _place_compilation_cache()
+        if mesh is not None:
+            _keep_multichip_programs_out_of_the_cache(mesh.devices.size)
 
         cfg = get_config(preset)
         params = None

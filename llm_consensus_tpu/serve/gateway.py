@@ -1108,6 +1108,19 @@ class ConsensusGateway:
 
         reg.register("utilization", utilization_block)
 
+        def device_block() -> Optional[dict]:
+            # Where the engines really run (TPUProvider.device_stats):
+            # the backend's platform / kind / count, its peaks, device
+            # memory, the compile cache, and per engine the devices it
+            # lives on and the attention path each phase took — so a
+            # tpu: model answering from the wrong place is readable off
+            # /statsz. Falsy (omitted) for panels with no tpu: model.
+            from llm_consensus_tpu.obs.export import _collect_provider_stats
+
+            return _collect_provider_stats(self.registry, "device_stats") or None
+
+        reg.register("device", device_block)
+
         def disagg_block() -> Optional[dict]:
             # Disaggregated prefill/decode state (engine/handoff.py):
             # per-preset handoff queue depth, waves, transfer bytes/s,

@@ -89,8 +89,6 @@ _k("LLMC_DECODE_QSTRUCT", "bool", True, "ops",
 _k("LLMC_DECODE_W8A8", "bool", False, "ops",
    "1 enables int8*int8 MXU decode scores (experimental)")
 # -- provider ----------------------------------------------------------------
-_k("LLMC_XLA_CACHE", "str", "", "provider",
-   "Persistent XLA compilation-cache dir (default ~/.cache/llmc-xla)")
 _k("LLMC_CHECKPOINT_DIR", "str", "", "provider",
    "Directory of per-model HF safetensors checkpoints")
 _k("LLMC_MAX_BATCH", "int", 0, "provider",

@@ -214,6 +214,29 @@ def test_long_prompt_waits_for_frontier(engine):
         b.close()
 
 
+@pytest.mark.parametrize("order", ["short_first", "long_first"])
+def test_idle_wave_of_capacity_wide_prompts_admits_the_queue_head(engine, order):
+    """Two prompts whose admission bucket saturates cache capacity
+    (bucket(140) = bucket(200) = max_seq = 256) can only sit AT the
+    frontier. Resetting an idle pool's frontier to the LONGER one used to
+    requeue the shorter queue head, then (no leapfrogging) the longer one
+    behind it: nothing admitted, pool still idle, forever — the hang two
+    concurrent long judge prompts hit on the chip. The head must be
+    admitted; the other waits its turn; both come out exact."""
+    b, gate = _gated_batcher(engine, max_batch=4)
+    try:
+        s = SamplingParams(max_new_tokens=12, ignore_eos=True)
+        prompts = ["a" * 140, "b" * 200]
+        if order == "long_first":
+            prompts.reverse()
+        futs = [b.submit(p, s) for p in prompts]
+        gate.set()
+        for p, f in zip(prompts, futs):
+            assert f.result(timeout=120).token_ids == engine.generate(p, s).token_ids
+    finally:
+        b.close()
+
+
 def test_cache_tail_exact_parity(engine):
     """A stream whose window reaches cache capacity must emit every token
     the single-stream engine would (1-step tail dispatches), not retire a

@@ -175,11 +175,6 @@ def test_engine_decode_flash_same_tokens():
 # ---------------------------------------------------------------------------
 
 def _lower_for_tpu(fn, *args):
-    if not hasattr(jax, "export"):
-        # Older jax: the cross-platform export API isn't available, so
-        # the real Mosaic lowering can't run off-TPU — skip rather than
-        # fail the whole numerics file on an API gap.
-        pytest.skip("jax.export unavailable in this jax version")
     jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)
 
 

@@ -1,12 +1,14 @@
 """Engine safety net: a Pallas kernel that fails to lower must degrade
-the engine to the XLA attention path, never kill the run.
+the engine to the XLA attention path, never kill the run — and never
+quietly.
 
 Round 1's decode kernel shipped with a Mosaic-invalid BlockSpec and was
 on by default on TPU backends — every hardware run crashed at first
 dispatch and the bench recorded rc=1. The runner's contract is
 best-effort (reference runner.go:75-83: a model failure is a warning);
-these tests pin the guard that makes a kernel bug a perf regression
-instead of a crash.
+these tests pin the guard that keeps a serving process answering, and
+the count (``flash_fallbacks`` / ``attention_stats``) that lets
+chip_smoke.py and bench.py turn the same event into a failure.
 """
 
 import warnings
@@ -71,6 +73,9 @@ def test_decode_kernel_failure_falls_back_to_xla(monkeypatch):
     assert eng.attn_impl == "xla"
     assert any("falling back to XLA" in str(w.message) for w in caught)
     assert out.token_ids == ref.generate("hello world consensus", sampling).token_ids
+    # Loud, not just logged: built vs running impl and the count.
+    stats = eng.attention_stats()
+    assert (stats["built"], stats["impl"], stats["fallbacks"]) == ("flash", "xla", 1)
 
 
 def test_prefill_kernel_failure_falls_back_to_xla(monkeypatch):
@@ -98,6 +103,7 @@ def test_prefill_kernel_failure_falls_back_to_xla(monkeypatch):
     assert eng.attn_impl == "xla"
     assert any("falling back to XLA" in str(w.message) for w in caught)
     assert out.token_ids == ref.generate(prompt, sampling).token_ids
+    assert eng.flash_fallbacks == 1 and eng.attn_built == "flash"
 
 
 def test_non_pallas_errors_propagate():
@@ -107,3 +113,4 @@ def test_non_pallas_errors_propagate():
     with pytest.raises(ValueError, match="empty prompt"):
         eng.generate_ids([], SamplingParams(max_new_tokens=4))
     assert eng.attn_impl == "flash"  # untouched by unrelated failures
+    assert eng.flash_fallbacks == 0
