@@ -147,6 +147,11 @@ class Judge:
         # exactly the workload prompt lookup wins on). None when the
         # judge's provider ran plain.
         self.last_spec: Optional[dict] = None
+        # The last judge query's clock reads through its engine pool and
+        # its sizes (Response.marks, prompt tokens as the engine counted
+        # them): what serve/scheduler.py builds the result's ``timings``
+        # from. None when the provider reports none.
+        self.last_marks: Optional[dict] = None
 
     @property
     def model(self) -> str:
@@ -186,4 +191,5 @@ class Judge:
             raise RuntimeError(f"judge query failed: {err}") from err
         self.last_truncated = resp.truncated
         self.last_spec = getattr(resp, "spec", None)
+        self.last_marks = getattr(resp, "marks", None)
         return resp.content

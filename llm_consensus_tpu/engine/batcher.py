@@ -138,6 +138,26 @@ class _Stream:
     # version: that is the journal-backed migration path, and greedy
     # byte-identity is promised only to streams that stay resident.
     weight_version: int = -1
+    # Clock reads of this stream's way through the pool, in
+    # ``time.monotonic_ns``, taken from the spans that carried it:
+    # ``admit_ns`` (start of the first pool.admit wave holding it),
+    # ``first_token_ns`` (start of the pool.emit that handed out its
+    # first token) and ``first_chunk_ns`` (its first text pushed to
+    # ``on_text``). They ride GenerateResult.marks to the serving tier's
+    # per-run ``timings``.
+    marks: dict = field(default_factory=dict)
+
+
+def _wave_counts(admit: dict) -> dict:
+    """The counter deltas of one landed admission wave, from its
+    ``pool.admit`` span's arguments: the ``prefill_*`` counters (and
+    ``admit_tokens``) are those arguments summed over waves."""
+    return {
+        "admit_tokens": admit["tokens_real"], "prefill_waves": 1,
+        "prefill_rows_real": admit["rows_real"],
+        "prefill_rows_padded": admit["rows_padded"],
+        "prefill_slot_tokens": admit["slot_tokens"],
+    }
 
 
 @dataclass
@@ -550,6 +570,14 @@ class ContinuousBatcher:
             "impure_s": 0.0, "impure_tokens": 0,
             "establish_s": 0.0, "admit_s": 0.0, "admit_tokens": 0,
             "absorb_s": 0.0, "preemptions": 0,
+            # Counted where the work is dispatched: decode chunks, Σ steps
+            # and Σ steps × live rows (row fill = row_steps ÷ (steps ×
+            # rows)); admission waves, their real and padded rows, and
+            # the token slots their prefill programs covered (padding
+            # share = 1 − admit_tokens ÷ prefill_slot_tokens).
+            "decode_chunks": 0, "decode_steps": 0, "decode_row_steps": 0,
+            "prefill_waves": 0, "prefill_rows_real": 0,
+            "prefill_rows_padded": 0, "prefill_slot_tokens": 0,
         }
         # Priority-aware preemption (pressure/): when a queued stream of
         # a strictly higher class is blocked on a slot, the scheduler
@@ -577,6 +605,12 @@ class ContinuousBatcher:
         # decode/fetch/admit spans land here even with events off, so an
         # engine crash dumps the seconds of timeline that explain it.
         self._bb = _obs.blackbox.ring()
+        # Every span goes through the one emitter (obs/spans.py), once:
+        # to the recorder, the ring and — inside a profiler window — the
+        # profiler's trace, on a row of this pool's own.
+        self._spans = _obs.emitter()
+        self._model = engine.cfg.name
+        self._tid = f"pool:{engine.cfg.name}"
         # Chip-time attribution (obs/attrib): device time per program
         # family from the arrival intervals the fetch worker already
         # measures, the goodput token ledger, and host-gap (bubble)
@@ -854,7 +888,10 @@ class ContinuousBatcher:
             # A wedge abandonment (the supervisor's watchdog) is the
             # FIRST death evidence this pool has: snapshot the ring. A
             # recovery teardown after a crash already dumped.
-            self._bb.instant("engine_abandon", tid="batcher", error=repr(exc))
+            self._spans.instant(
+                "engine_abandon", self._tid, model=self._model,
+                error=repr(exc),
+            )
             self._bb.dump("engine_wedge", extra={"error": repr(exc)})
         wave_streams = [s for _, _, s in wave.batch] if wave is not None else []
         if self._attrib is not None and live:
@@ -1042,17 +1079,12 @@ class ContinuousBatcher:
                 # resume — preemption's recompute cost, booked at the
                 # decision point.
                 self._attrib.token_event("preempt_replay", len(snapshot))
+            self._spans.instant(
+                "preempt", self._tid, model=self._model, slot=slot,
+                priority=s.priority, progress=len(snapshot), trace=s.trace,
+            )
             if self._obs is not None:
-                self._obs.instant(
-                    "preempt", tid="batcher", slot=slot,
-                    priority=s.priority, progress=len(snapshot),
-                )
                 self._obs.count("pressure.preemptions")
-            if self._bb is not None:
-                self._bb.instant(
-                    "preempt", tid="batcher", slot=slot,
-                    priority=s.priority, progress=len(snapshot),
-                )
         if entries:
             self._stat_add(preemptions=len(entries))
         return entries
@@ -1173,7 +1205,7 @@ class ContinuousBatcher:
         self._prefix_weight_version = -1
 
     def _admit_batch(self, batch: list[tuple[int, list, _Stream]],
-                     prefix_p: int = 0) -> Optional[list]:
+                     prefix_p: int, sp) -> Optional[list]:
         """Admit several streams with ONE batched prefill.
 
         A burst of k admissions prefilled row-by-row streams the full
@@ -1187,7 +1219,9 @@ class ContinuousBatcher:
         wave prefill compute scales with the new tokens, not the shared
         prompt. Returns the firsts list entries, or None when the
         batched prefill itself failed (caller falls back to one-by-one
-        admission).
+        admission). ``sp`` is the caller's open ``pool.admit`` span: what
+        the wave dispatched (padded rows, chunks, the token slots they
+        cover) is written on it, and the caller counts from there.
         """
         eng = self.engine
         rows = [ids for _, ids, _ in batch]
@@ -1204,7 +1238,12 @@ class ContinuousBatcher:
             else:
                 last_logits, pcache = eng._prefill_rows(pad_rows)
                 width = eng._rows_bucket(max(len(r) for r in rows))
-        except Exception:  # noqa: BLE001
+            chunks, slot_tokens = eng.last_prefill
+            sp.set(rows_padded=k_pad, chunks=chunks, slot_tokens=slot_tokens)
+        except Exception as exc:  # noqa: BLE001
+            # The fallback below hides the failure from everyone but the
+            # span: say what it was.
+            sp.set(rows_padded=k_pad, error=type(exc).__name__)
             # Batched prefill failed (OOM on the k-row bucket, a bad
             # row) before any state changed: the caller re-admits
             # one-by-one so a failure costs one stream, not the wave.
@@ -1396,12 +1435,6 @@ class ContinuousBatcher:
             batch=batch, wave_p=wave_p, k_pad=k_pad, session=session,
             t_start=time.monotonic(),
         )
-        if self._obs is not None:
-            self._obs.instant(
-                "prefill_interleave_start", tid="batcher",
-                streams=len(batch), prefix=wave_p,
-                tokens=session.remaining_tokens,
-            )
         return True
 
     def _advance_wave(self, pending_firsts: list, exhaust: bool) -> None:
@@ -1410,8 +1443,23 @@ class ContinuousBatcher:
         has nothing live to overlap with), run it to completion. On the
         final credit: splice at the CURRENT frontier, install the
         streams, and attach their first tokens to the next dispatched
-        chunk's fetch."""
+        chunk's fetch. Each credit is one ``pool.admit`` span
+        (``interleaved``); the wave's counters are booked with the credit
+        that installs it."""
         wave = self._pending_wave
+        with self._spans.span(
+            "pool.admit", self._tid, model=self._model, interleaved=True,
+            exhaust=exhaust, rows_real=len(wave.batch),
+            rows_padded=wave.k_pad, prefix=wave.wave_p,
+            traces=[s.trace for _, _, s in wave.batch if s.trace],
+        ) as sp:
+            for _, _, s in wave.batch:
+                s.marks.setdefault("admit_ns", sp.t0_ns)
+            self._advance_wave_credit(wave, pending_firsts, exhaust, sp)
+
+    def _advance_wave_credit(self, wave: "_PendingWave",
+                             pending_firsts: list, exhaust: bool,
+                             sp) -> None:
         eng = self.engine
         t_adm = time.monotonic()
         # lint-ok: GS01 — scheduler-monotone read: only this thread
@@ -1420,7 +1468,6 @@ class ContinuousBatcher:
         adm_drained = self._unfetched == 0  # lint-ok: GS01 monotone read
         if adm_drained:
             self._close_gap(t_adm)
-        t0_obs = self._obs.now() if self._obs is not None else 0
         # Any prefill dispatch makes the next arrival interval impure —
         # the device ran admission work between decode chunks.
         self._nondecode_work = True
@@ -1442,11 +1489,7 @@ class ContinuousBatcher:
             budget = None if exhaust else self._prefill_budget
             with _attrib_tag("prefill"):
                 done = wave.session.step(budget)
-            if self._obs is not None:
-                self._obs.complete(
-                    "prefill_interleave", t0_obs, tid="batcher",
-                    done=done, exhaust=exhaust,
-                )
+            sp.set(done=done)
             if not done:
                 self._stat_add(admit_s=time.monotonic() - t_adm)
                 _book_prefill()
@@ -1499,21 +1542,16 @@ class ContinuousBatcher:
             deltas = {"admit_s": time.monotonic() - t_adm}
             _book_prefill()
             if installed:
-                deltas["admit_tokens"] = sum(
+                tokens_real = sum(
                     len(ids) - wave.wave_p for _, ids, _ in wave.batch
                 )
+                sp.set(ok=True, tokens_real=tokens_real,
+                       chunks=wave.session.chunks,
+                       slot_tokens=wave.session.slot_tokens)
+                deltas.update(_wave_counts(sp.args))
                 self._pending_wave = None
             self._stat_add(**deltas)
         pending_firsts.append(entry)
-        if self._obs is not None:
-            self._obs.complete(
-                "admit", t0_obs, tid="batcher", streams=len(wave.batch),
-                prefix=wave.wave_p, ok=True, interleaved=True,
-            )
-            self._obs.count(
-                "prefill.interleaved_tokens",
-                sum(len(ids) - wave.wave_p for _, ids, _ in wave.batch),
-            )
 
     def _wave_fallback(self, wave: "_PendingWave") -> None:
         """An interleaved wave's prefill failed: requeue its streams and
@@ -1560,6 +1598,7 @@ class ContinuousBatcher:
             latency_ms=(time.monotonic() - s.submitted) * 1000,
             truncated_prompt=s.truncated,
             preempted=s.preempted,
+            marks=s.marks or None,
         )
 
     # -- weight-version pinning (flywheel hot-swap) --------------------------
@@ -1637,8 +1676,13 @@ class ContinuousBatcher:
         if s.on_text is not None:
             text = s.decoder.push(tok)
             if text:
+                first = not s.parts
                 s.parts.append(text)
                 s.on_text(text)
+                if first:
+                    # The first text is with its consumer (the SSE writer
+                    # under serving): the run's first-chunk mark.
+                    s.marks["first_chunk_ns"] = time.monotonic_ns()
         if len(s.out_ids) >= s.max_new:
             self._retire(slot, "length")
 
@@ -2058,8 +2102,9 @@ class ContinuousBatcher:
                 # Blackbox dump at the moment of death: the ring holds
                 # the decode/fetch spans leading up to the crash —
                 # recorded even with --events off.
-                self._bb.instant(
-                    "engine_crash", tid="batcher", error=repr(exc)
+                self._spans.instant(
+                    "engine_crash", self._tid, model=self._model,
+                    error=repr(exc),
                 )
                 self._bb.dump("engine_crash", extra={"error": repr(exc)})
             # Stop the fetch worker BEFORE failing futures: it may still
@@ -2105,13 +2150,11 @@ class ContinuousBatcher:
             self._fetch_q.put(None)
             self._fetch_thread.join(timeout=120)
 
-    def _fetch(self, inflight: tuple, eos: int) -> tuple[int, float]:
-        """Fetch one dispatched chunk's tokens and emit them (plus any
-        prefill-sampled first tokens riding along in the same transfer).
-        Returns ``(live tokens emitted, arrival time)`` — the timestamp
-        is taken when ``device_get`` returns, BEFORE the emit loop, so
-        arrival-to-arrival intervals measure the device/transfer
-        pipeline, not Python emit time.
+    def _fetch_get(self, toks, firsts) -> tuple:
+        """The blocking half of a fetch: one device→host transfer of a
+        dispatched chunk's tokens plus any prefill-sampled first tokens
+        riding along. Returns ``(first_vals, body)`` for ``_fetch_emit``;
+        ``body`` is the fetched payload in its dispatch form.
 
         ``firsts`` entries are per-WAVE: (slot list, samples array,
         owner list) — one device array per admission wave, fetched in
@@ -2120,24 +2163,30 @@ class ContinuousBatcher:
         A spec ROUND GROUP's payload is ``("spec", [(out, a), ...], k)``
         instead of a token matrix: per round, row i emits its accepted
         prefix ``out[i, :a[i]]`` — acceptance is data, fetched with the
-        tokens — and the pool controller observes the mean per-row
-        acceptance while each stream's EMA tracks its own."""
-        toks, owners, firsts = inflight
+        tokens."""
+        samples = [smp for _, smp, _ in firsts]
         if isinstance(toks, tuple) and toks and toks[0] == "spec":
-            return self._fetch_spec(toks, owners, firsts, eos)
-        verdict = None
+            _, rounds, k_used = toks
+            first_vals, fetched = jax.device_get((samples, rounds))
+            return first_vals, ("spec", fetched, k_used)
         if isinstance(toks, tuple) and toks and toks[0] == "sentinel":
             _, toks, verdict = toks
-        if verdict is not None:
-            first_vals, mat, fin = jax.device_get(
-                ([samples for _, samples, _ in firsts], toks, verdict)
+            first_vals, mat, fin = jax.device_get((samples, toks, verdict))
+            return first_vals, ("tokens", mat, fin)
+        first_vals, mat = jax.device_get((samples, toks))
+        return first_vals, ("tokens", mat, None)
+
+    def _fetch_emit(self, first_vals, body: tuple, owners, firsts,
+                    eos: int, t_emit_ns: int) -> int:
+        """The host half of a fetch: emit the fetched tokens to their
+        streams (first tokens first). Returns the live tokens emitted.
+        ``t_emit_ns`` is the enclosing ``pool.emit`` span's start — the
+        clock read a stream's ``first_token_ns`` mark takes."""
+        if body[0] == "spec":
+            return self._emit_spec(
+                first_vals, body[1], body[2], owners, firsts, eos, t_emit_ns,
             )
-        else:
-            first_vals, mat = jax.device_get(
-                ([samples for _, samples, _ in firsts], toks)
-            )
-            fin = None
-        t_arrival = time.monotonic()
+        _, mat, fin = body
         if fin is not None and self._integrity is not None:
             # Finite-logit sentinel verdict: contain BEFORE the emit
             # loop so a poisoned row's garbage tokens never reach its
@@ -2158,7 +2207,7 @@ class ContinuousBatcher:
                     "logits",
                     f"non-finite logits detected in decode row {i}",
                 ))
-        emitted = self._emit_firsts(firsts, first_vals, eos)
+        emitted = self._emit_firsts(firsts, first_vals, eos, t_emit_ns)
         # One bulk ndarray→list conversion: the per-element form
         # (int(mat[step, i]) × chunk × B numpy-scalar extractions) costs
         # tens of host-ms per chunk at serving batch sizes.
@@ -2183,9 +2232,9 @@ class ContinuousBatcher:
             overshoot += len(col) - taken
         if overshoot and self._attrib is not None:
             self._attrib.token_event("overshoot", overshoot)
-        return emitted, t_arrival
+        return emitted
 
-    def _emit_firsts(self, firsts, first_vals, eos) -> int:
+    def _emit_firsts(self, firsts, first_vals, eos, t_emit_ns: int) -> int:
         """Emit prefill-sampled first tokens that rode down with this
         chunk's fetch (owner-checked per wave) — shared by the classic
         and spec fetch paths."""
@@ -2193,18 +2242,17 @@ class ContinuousBatcher:
         for (slots, _, wave_owners), vals in zip(firsts, first_vals):
             for slot, owner, val in zip(slots, wave_owners, vals.tolist()):
                 if self._slots[slot] is owner:
+                    owner.marks.setdefault("first_token_ns", t_emit_ns)
                     self._emit(slot, val, eos)
                     emitted += 1
         return emitted
 
-    def _fetch_spec(self, payload, owners, firsts, eos) -> tuple[int, float]:
-        """Fetch + emit one spec round group (see _fetch)."""
-        _, rounds, k_used = payload
-        first_vals, fetched = jax.device_get(
-            ([samples for _, samples, _ in firsts], rounds)
-        )
-        t_arrival = time.monotonic()
-        emitted = self._emit_firsts(firsts, first_vals, eos)
+    def _emit_spec(self, first_vals, fetched, k_used: int, owners, firsts,
+                   eos, t_emit_ns: int) -> int:
+        """Emit one fetched spec round group (see _fetch_get): the pool
+        controller observes the mean per-row acceptance while each
+        stream's EMA tracks its own."""
+        emitted = self._emit_firsts(firsts, first_vals, eos, t_emit_ns)
         sp = self._spec
         total_acc = 0
         rejected = 0
@@ -2248,7 +2296,7 @@ class ContinuousBatcher:
         if self._obs is not None:
             self._obs.count("spec.rounds", len(fetched))
             self._obs.count("spec.accepted", total_acc)
-        return emitted, t_arrival
+        return emitted
 
     def _fetch_worker(self) -> None:
         """Fetch-side half of the dispatch pipeline (dedicated thread).
@@ -2277,155 +2325,177 @@ class ContinuousBatcher:
                     self._unfetched -= 1
                     self._work.notify_all()
                 continue
-            t0_obs = (
-                time.monotonic_ns()
-                if self._obs is not None or self._bb is not None else 0
-            )
             try:
-                emitted, t_arrival = self._fetch((toks, owners, firsts), eos)
+                # The blocking device→host transfer of one chunk.
+                with self._spans.span(
+                    "pool.fetch", self._tid, model=self._model, pure=pure,
+                ) as sp:
+                    first_vals, body = self._fetch_get(toks, firsts)
+                    sp.set(tokens=int(getattr(body[1], "size", 0)))
             except BaseException as exc:  # noqa: BLE001
-                with self._work:
-                    self._worker_exc = exc
-                    self._unfetched -= 1
-                    self._prev_arrival = None
-                    self._work.notify_all()
+                self._fetch_failed(exc)
                 continue  # keep draining so the scheduler never deadlocks
-            if self._obs is not None:
-                # Transfer + emit wall of one chunk on the fetch worker —
-                # exactly the host time the dispatch pipeline overlaps.
-                self._obs.complete(
-                    "fetch", t0_obs, tid="batcher", tokens=emitted, pure=pure,
-                )
-            if self._bb is not None:
-                self._bb.complete(
-                    "fetch", t0_obs, tid="batcher", tokens=emitted, pure=pure,
-                )
-            # Cancellation/deadlines: after the emit so a cancel never
-            # discards tokens already decoded (it wastes at most the
-            # chunks still in the pipeline).
-            for i, s in enumerate(self._slots):
-                if s is not None and s.ctx.done():
-                    self._retire(
-                        i,
-                        "deadline" if s.ctx.remaining() == 0.0 else "cancelled",
+            # Taken when device_get returns, BEFORE the emit loop, so
+            # arrival-to-arrival intervals measure the device/transfer
+            # pipeline, not Python emit time.
+            t_arrival = time.monotonic()
+            # Host-side handling of the fetched tokens until the scheduler
+            # can issue the next dispatch: EOS, detokenise, stream push,
+            # retirement, the arrival booking and its notify. Fetch + emit
+            # together are the host time the dispatch pipeline overlaps.
+            with self._spans.span(
+                "pool.emit", self._tid, model=self._model,
+            ) as sp:
+                try:
+                    emitted = self._fetch_emit(
+                        first_vals, body, owners, firsts, eos, sp.t0_ns,
                     )
-            with self._work:
-                if pure:
-                    # `emitted` gate: a chunk whose streams all retired
-                    # mid-pipeline (tail overshoot — owners dropped every
-                    # token) is dead stepping, not steady-state decode;
-                    # counting its ~chunk-length interval against zero
-                    # tokens drags the decode-phase rate far below the
-                    # real chunk cadence (measured: 17k reported vs 33k
-                    # traced at B=256). Partially-live chunks still
-                    # count in full — occupancy holes are real serving.
-                    # Zero-emit intervals are accounted as tail_s so the
-                    # bench can bisect the e2e-vs-decode-phase gap.
-                    # ADVICE r5 (batcher.py:963 area): pure chunks with
-                    # no prior arrival (first dispatch after a pipeline
-                    # drain — post-drain decode, or the overshoot gate's
-                    # fall-through dead-step) reference their own
-                    # dispatch time, mirroring the impure branch:
-                    # dispatch→arrival covers exactly that chunk's
-                    # device + transfer wall (nothing but the chunk ran
-                    # since the drain — pure guarantees no admission
-                    # work), so neither post-drain decode nor gate
-                    # dead-stepping is silently dropped from the phase
-                    # accounting.
-                    ref = (
-                        self._prev_arrival
-                        if self._prev_arrival is not None else t_dispatch
-                    )
-                    dt = t_arrival - ref
-                    if emitted:
-                        self._stat_add_locked(
-                            decode_tokens=emitted, decode_s=dt
+                except BaseException as exc:  # noqa: BLE001
+                    self._fetch_failed(exc)
+                    continue
+                sp.set(tokens=emitted)
+                # Cancellation/deadlines: after the emit so a cancel never
+                # discards tokens already decoded (it wastes at most the
+                # chunks still in the pipeline).
+                for i, s in enumerate(self._slots):
+                    if s is not None and s.ctx.done():
+                        self._retire(
+                            i,
+                            "deadline" if s.ctx.remaining() == 0.0 else "cancelled",
                         )
-                    else:
-                        self._stat_add_locked(tail_s=dt)
-                    if self._attrib is not None:
-                        # Chip-time attribution: a PURE arrival interval
-                        # is the device + transfer wall of exactly one
-                        # decode (or spec round-group) dispatch.
-                        self._attrib.observe_device(
-                            "spec_verify" if mode == "spec" else "decode",
-                            dt,
-                        )
-                    sp = self._spec
-                    if (
-                        sp is not None and mode is not None and emitted
-                        and sp.governor.state in ("spec_probe",
-                                                  "plain_probe")
-                        and mode == sp.governor.mode
-                    ):
-                        # Governor A/B: only PURE arrival intervals whose
-                        # chunk ran in the mode being probed count —
-                        # admission/compaction noise and stale pipelined
-                        # chunks from the prior mode would skew the
-                        # drafted-vs-plain rate comparison. The first
-                        # arrival per mode is discarded as compile
-                        # warm-up (see _SpecState.skip_feed).
-                        if sp.skip_feed:
-                            sp.skip_feed = False
-                        elif sp.governor.feed(emitted, dt):
-                            sp.skip_feed = True  # new mode: fresh compile
-                        if sp.governor.disabled_spec and sp.disables == 0:
-                            sp.disables = 1
-                            if self._obs is not None:
-                                self._obs.instant(
-                                    "spec_governor_disable", tid="batcher",
-                                    ema=round(sp.controller.ema, 3),
-                                )
-                else:
-                    # No prev arrival after an idle drain: reference the
-                    # chunk's dispatch time instead — the interval still
-                    # covers the admission prefill the device ran just
-                    # before it (dispatched back-to-back on the host).
-                    ref = (
-                        self._prev_arrival
-                        if self._prev_arrival is not None else t_dispatch
-                    )
+                self._book_arrival(pure, mode, emitted, t_arrival, t_dispatch)
+
+    def _fetch_failed(self, exc: BaseException) -> None:
+        """A chunk's fetch or emit raised: record it for the scheduler
+        (which fails every live stream with it) and release the chunk's
+        pipeline slot."""
+        with self._work:
+            self._worker_exc = exc
+            self._unfetched -= 1
+            self._prev_arrival = None
+            self._work.notify_all()
+
+    def _book_arrival(self, pure: bool, mode, emitted: int,
+                      t_arrival: float, t_dispatch: float) -> None:
+        """Phase accounting for one chunk's arrival, then the notify that
+        lets the scheduler dispatch past the depth gate."""
+        with self._work:
+            if pure:
+                # `emitted` gate: a chunk whose streams all retired
+                # mid-pipeline (tail overshoot — owners dropped every
+                # token) is dead stepping, not steady-state decode;
+                # counting its ~chunk-length interval against zero
+                # tokens drags the decode-phase rate far below the
+                # real chunk cadence (measured: 17k reported vs 33k
+                # traced at B=256). Partially-live chunks still
+                # count in full — occupancy holes are real serving.
+                # Zero-emit intervals are accounted as tail_s so the
+                # bench can bisect the e2e-vs-decode-phase gap.
+                # ADVICE r5 (batcher.py:963 area): pure chunks with
+                # no prior arrival (first dispatch after a pipeline
+                # drain — post-drain decode, or the overshoot gate's
+                # fall-through dead-step) reference their own
+                # dispatch time, mirroring the impure branch:
+                # dispatch→arrival covers exactly that chunk's
+                # device + transfer wall (nothing but the chunk ran
+                # since the drain — pure guarantees no admission
+                # work), so neither post-drain decode nor gate
+                # dead-stepping is silently dropped from the phase
+                # accounting.
+                ref = (
+                    self._prev_arrival
+                    if self._prev_arrival is not None else t_dispatch
+                )
+                dt = t_arrival - ref
+                if emitted:
                     self._stat_add_locked(
-                        impure_s=t_arrival - ref, impure_tokens=emitted
+                        decode_tokens=emitted, decode_s=dt
                     )
-                    if self._attrib is not None:
-                        # Impure interval: the device ran admission
-                        # prefill / compaction work plus the chunk —
-                        # booked against the non-decode family that made
-                        # it impure (the dominant term by construction).
-                        self._attrib.observe_device(
-                            self._impure_kind, t_arrival - ref
+                else:
+                    self._stat_add_locked(tail_s=dt)
+                if self._attrib is not None:
+                    # Chip-time attribution: a PURE arrival interval
+                    # is the device + transfer wall of exactly one
+                    # decode (or spec round-group) dispatch.
+                    self._attrib.observe_device(
+                        "spec_verify" if mode == "spec" else "decode",
+                        dt,
+                    )
+                sp = self._spec
+                if (
+                    sp is not None and mode is not None and emitted
+                    and sp.governor.state in ("spec_probe",
+                                              "plain_probe")
+                    and mode == sp.governor.mode
+                ):
+                    # Governor A/B: only PURE arrival intervals whose
+                    # chunk ran in the mode being probed count —
+                    # admission/compaction noise and stale pipelined
+                    # chunks from the prior mode would skew the
+                    # drafted-vs-plain rate comparison. The first
+                    # arrival per mode is discarded as compile
+                    # warm-up (see _SpecState.skip_feed).
+                    if sp.skip_feed:
+                        sp.skip_feed = False
+                    elif sp.governor.feed(emitted, dt):
+                        sp.skip_feed = True  # new mode: fresh compile
+                    if sp.governor.disabled_spec and sp.disables == 0:
+                        sp.disables = 1
+                        self._spans.instant(
+                            "spec_governor_disable", self._tid,
+                            model=self._model,
+                            ema=round(sp.controller.ema, 3),
                         )
-                self._prev_arrival = t_arrival
-                self._unfetched -= 1
-                if self._unfetched == 0:
-                    # Pipeline drained: the next arrival interval spans
-                    # device idle time, not a chunk — don't count it.
-                    self._prev_arrival = None
-                    if self._attrib is not None and (
-                        any(s is not None for s in self._slots)
-                        or self._queue
-                        or self._pending_wave is not None
-                    ):
-                        # Device idle begins on a batcher that still has
-                        # work: host-gap (bubble) detection arms — the
-                        # next dispatch closes and attributes it.
-                        self._idle_at = t_arrival
-                        self._gap_phase = "schedule"
-                self._work.notify_all()
+            else:
+                # No prev arrival after an idle drain: reference the
+                # chunk's dispatch time instead — the interval still
+                # covers the admission prefill the device ran just
+                # before it (dispatched back-to-back on the host).
+                ref = (
+                    self._prev_arrival
+                    if self._prev_arrival is not None else t_dispatch
+                )
+                self._stat_add_locked(
+                    impure_s=t_arrival - ref, impure_tokens=emitted
+                )
+                if self._attrib is not None:
+                    # Impure interval: the device ran admission
+                    # prefill / compaction work plus the chunk —
+                    # booked against the non-decode family that made
+                    # it impure (the dominant term by construction).
+                    self._attrib.observe_device(
+                        self._impure_kind, t_arrival - ref
+                    )
+            self._prev_arrival = t_arrival
+            self._unfetched -= 1
+            if self._unfetched == 0:
+                # Pipeline drained: the next arrival interval spans
+                # device idle time, not a chunk — don't count it.
+                self._prev_arrival = None
+                if self._attrib is not None and (
+                    any(s is not None for s in self._slots)
+                    or self._queue
+                    or self._pending_wave is not None
+                ):
+                    # Device idle begins on a batcher that still has
+                    # work: host-gap (bubble) detection arms — the
+                    # next dispatch closes and attributes it.
+                    self._idle_at = t_arrival
+                    self._gap_phase = "schedule"
+            self._work.notify_all()
 
     def _drain_fetches(self) -> None:
         """Wait until every dispatched chunk's tokens are emitted — the
         barrier before compaction (full-row retires must not lose
         fetched tokens) and before the scheduler hand-retires slots."""
-        t0_obs = self._obs.now() if self._obs is not None else 0
-        with self._work:
+        with self._spans.span(
+            "pool.drain", self._tid, model=self._model,
+        ) as sp, self._work:
             while self._unfetched > 0 and self._worker_exc is None:
                 self._work.wait(0.1)
+                sp.slice()
             if self._worker_exc is not None:
                 raise self._worker_exc
-        if self._obs is not None:
-            self._obs.complete("drain", t0_obs, tid="batcher")
 
     def _drain_queue_locked(self) -> list:
         """Under ``self._work``: take everything still queued (including
@@ -2435,6 +2505,18 @@ class ContinuousBatcher:
         queued = list(self._queue)
         self._queue.clear()
         return queued
+
+    def _idle_locked(self) -> bool:
+        """Under ``self._work``: nothing to admit, dispatch or interleave
+        (and not closing)."""
+        sanitizer.assert_held(self._work)
+        return (
+            self._worker_exc is None
+            and not self._queue
+            and not any(s is not None for s in self._slots)
+            and self._pending_wave is None
+            and not (self._closed and self._unfetched == 0)
+        )
 
     def _loop(self) -> None:
         eng = self.engine
@@ -2466,18 +2548,23 @@ class ContinuousBatcher:
                 # through the worker (their tokens emit without scheduler
                 # help); the close path below additionally requires the
                 # drain to finish.
-                while (
-                    self._worker_exc is None
-                    and not self._queue
-                    and not any(s is not None for s in self._slots)
-                    and self._pending_wave is None
-                    and not (self._closed and self._unfetched == 0)
-                ):
-                    # Truly idle (the armed work expired/cancelled away):
-                    # a gap armed at the last drain must not span client
-                    # think time into the next request's first dispatch.
-                    self._idle_at = None
-                    self._work.wait()
+                if self._idle_locked():
+                    # No live row, no queued stream: the pool has no work.
+                    with self._spans.span(
+                        "pool.wait", self._tid, model=self._model,
+                    ) as sp:
+                        while self._idle_locked():
+                            # Truly idle (the armed work expired/cancelled
+                            # away): a gap armed at the last drain must
+                            # not span client think time into the next
+                            # request's first dispatch.
+                            self._idle_at = None
+                            # Bounded, so that a wait that straddles a
+                            # profiler window's edge is in the trace up
+                            # to its last slice (obs/spans.py): at most
+                            # this much of it is lost at either edge.
+                            self._work.wait(0.05)
+                            sp.slice()
                 if self._worker_exc is not None:
                     raise self._worker_exc
                 if (
@@ -2520,7 +2607,10 @@ class ContinuousBatcher:
                 # 155+101, and the 101-row wave's padded-size variant
                 # cost a fresh ~7 s program compile mid-measurement); a
                 # lone request pays ~20 ms.
-                with self._work:
+                with self._spans.span(
+                    "pool.absorb", self._tid, model=self._model,
+                    queued=len(pending),
+                ) as sp, self._work:
                     t_abs = time.monotonic()
                     deadline = t_abs + 0.25
                     seen = -1
@@ -2534,6 +2624,7 @@ class ContinuousBatcher:
                         quiet = quiet + 1 if n == seen else 0
                         seen = n
                         self._work.wait(timeout=0.01)
+                        sp.slice()  # a window's edge costs one 10 ms piece
                     pending += list(self._queue)
                     self._queue.clear()
                     self._stat_add_locked(
@@ -2547,20 +2638,18 @@ class ContinuousBatcher:
                 self._nondecode_work = True  # compaction breaks steadiness
                 self._impure_kind = "compact"
                 self._gap_phase = "compact"
-                t0_obs = self._obs.now() if self._obs is not None else 0
                 t_cpt = time.monotonic()
                 self._close_gap(t_cpt)  # compaction runs pipeline-drained
-                with _attrib_tag("compact"):
+                with self._spans.span(
+                    "pool.compact", self._tid, model=self._model,
+                ) as sp, _attrib_tag("compact"):
                     self._compact()
+                    sp.set(pos=self._pos)
                 if self._attrib is not None:
                     # Host dispatch wall of the roll (the pipeline is
                     # drained, so nothing else is on the device clock).
                     self._attrib.observe_device(
                         "compact", time.monotonic() - t_cpt
-                    )
-                if self._obs is not None:
-                    self._obs.complete(
-                        "compact", t0_obs, tid="batcher", pos=self._pos
                     )
                 if self._pos >= eng.max_seq:
                     # Compaction could not make room (unreachable by
@@ -2708,25 +2797,20 @@ class ContinuousBatcher:
                             if est_drained:
                                 self._close_gap(t_est)
                             self._gap_phase = "establish"
-                            t0_obs = (
-                                self._obs.now()
-                                if self._obs is not None else 0
-                            )
-                            with _attrib_tag("prefill"):
+                            with self._spans.span(
+                                "pool.establish", self._tid,
+                                model=self._model, prefix=est_p,
+                            ) as sp, _attrib_tag("prefill"):
                                 est_ok = self._establish_prefix(
                                     list(candidates[0][:est_p])
                                 )
+                                sp.set(ok=est_ok)
                             self._stat_add(
                                 establish_s=time.monotonic() - t_est
                             )
                             if self._attrib is not None and est_drained:
                                 self._attrib.observe_device(
                                     "prefill", time.monotonic() - t_est
-                                )
-                            if self._obs is not None:
-                                self._obs.complete(
-                                    "establish", t0_obs, tid="batcher",
-                                    prefix=est_p, ok=est_ok,
                                 )
                             if est_ok:
                                 wave_p = est_p
@@ -2845,36 +2929,42 @@ class ContinuousBatcher:
                             # The armed bubble ends where this drained
                             # admission's DEVICE window begins.
                             self._close_gap(t_adm)
-                        t0_obs = (
-                            self._obs.now() if self._obs is not None else 0
-                        )
                         admitted = None
-                        try:
-                            with _attrib_tag("prefill"):
-                                admitted = self._admit_batch(batch, wave_p)
-                        finally:
-                            self._stat_add(
-                                admit_s=time.monotonic() - t_adm,
-                                admit_tokens=(
-                                    0 if admitted is None else
-                                    sum(len(i2) - wave_p for _, i2, _ in batch)
-                                ),
-                            )
-                            if self._attrib is not None and adm_drained:
-                                # Drained pipeline: nothing else was on
-                                # the device clock, so the admission host
-                                # wall IS this dispatch's device window
-                                # (busy-pipeline admissions book through
-                                # the impure arrival interval instead).
-                                self._attrib.observe_device(
-                                    "prefill", time.monotonic() - t_adm
-                                )
-                        if self._obs is not None:
-                            self._obs.complete(
-                                "admit", t0_obs, tid="batcher",
-                                streams=len(batch), prefix=wave_p,
-                                ok=admitted is not None,
-                            )
+                        tokens_real = sum(
+                            len(i2) - wave_p for _, i2, _ in batch
+                        )
+                        # One admission wave, from its dispatch to its
+                        # last chunk dispatched (the device runs on).
+                        with self._spans.span(
+                            "pool.admit", self._tid, model=self._model,
+                            rows_real=len(batch), tokens_real=tokens_real,
+                            prefix=wave_p,
+                            traces=[s.trace for _, _, s in batch if s.trace],
+                        ) as sp:
+                            for _, _, s in batch:
+                                s.marks.setdefault("admit_ns", sp.t0_ns)
+                            try:
+                                with _attrib_tag("prefill"):
+                                    admitted = self._admit_batch(
+                                        batch, wave_p, sp
+                                    )
+                            finally:
+                                sp.set(ok=admitted is not None)
+                                deltas = {"admit_s": time.monotonic() - t_adm}
+                                if admitted is not None:
+                                    deltas.update(_wave_counts(sp.args))
+                                self._stat_add(**deltas)
+                                if self._attrib is not None and adm_drained:
+                                    # Drained pipeline: nothing else was
+                                    # on the device clock, so the
+                                    # admission host wall IS this
+                                    # dispatch's device window
+                                    # (busy-pipeline admissions book
+                                    # through the impure arrival interval
+                                    # instead).
+                                    self._attrib.observe_device(
+                                        "prefill", time.monotonic() - t_adm
+                                    )
                         if admitted is None:
                             batch_singles = batch
                             if wave_p:
@@ -2924,36 +3014,40 @@ class ContinuousBatcher:
                     adm_drained = self._unfetched == 0  # lint-ok: GS01 monotone read
                     if adm_drained:
                         self._close_gap(t_adm)
-                    t0_obs = self._obs.now() if self._obs is not None else 0
                     tok = None
                     admit_ok = False
-                    try:
-                        with _attrib_tag("prefill"):
-                            tok = self._admit(slot, ids, stream)
-                        admit_ok = True
-                    except Exception as exc:  # noqa: BLE001
-                        # A failed prefill (bad prompt, OOM on a new
-                        # bucket) fails THIS stream; the pool keeps
-                        # serving others.
-                        stream.future.set_exception(exc)
-                        if stream.jentry is not None:
-                            # Terminal for this stream on a HEALTHY pool:
-                            # not a replay candidate.
-                            stream.jentry.close("failed")
-                    finally:
-                        deltas = {"admit_s": time.monotonic() - t_adm}
-                        if admit_ok:
-                            deltas["admit_tokens"] = len(ids)
-                        self._stat_add(**deltas)
-                        if self._attrib is not None and adm_drained:
-                            self._attrib.observe_device(
-                                "prefill", time.monotonic() - t_adm
-                            )
-                        if self._obs is not None:
-                            self._obs.complete(
-                                "admit", t0_obs, tid="batcher",
-                                streams=1, prefix=0, ok=admit_ok,
-                            )
+                    with self._spans.span(
+                        "pool.admit", self._tid, model=self._model,
+                        rows_real=1, rows_padded=1, tokens_real=len(ids),
+                        prefix=0,
+                        traces=[stream.trace] if stream.trace else [],
+                    ) as sp:
+                        stream.marks.setdefault("admit_ns", sp.t0_ns)
+                        try:
+                            with _attrib_tag("prefill"):
+                                tok = self._admit(slot, ids, stream)
+                            admit_ok = True
+                        except Exception as exc:  # noqa: BLE001
+                            # A failed prefill (bad prompt, OOM on a new
+                            # bucket) fails THIS stream; the pool keeps
+                            # serving others.
+                            stream.future.set_exception(exc)
+                            if stream.jentry is not None:
+                                # Terminal for this stream on a HEALTHY
+                                # pool: not a replay candidate.
+                                stream.jentry.close("failed")
+                        finally:
+                            sp.set(ok=admit_ok)
+                            deltas = {"admit_s": time.monotonic() - t_adm}
+                            if admit_ok:
+                                chunks, slot_tokens = eng.last_prefill
+                                sp.set(chunks=chunks, slot_tokens=slot_tokens)
+                                deltas.update(_wave_counts(sp.args))
+                            self._stat_add(**deltas)
+                            if self._attrib is not None and adm_drained:
+                                self._attrib.observe_device(
+                                    "prefill", time.monotonic() - t_adm
+                                )
                     if admit_ok and tok is not None:
                         firsts.append(([slot], tok, [self._slots[slot]]))
                 if requeue or not batch:
@@ -2979,13 +3073,18 @@ class ContinuousBatcher:
                         t_abs = time.monotonic()
                         deadline = t_abs + 0.12
                         seen = -1
-                        while (
-                            not self._closed
-                            and len(self._queue) != seen
-                            and time.monotonic() < deadline
-                        ):
-                            seen = len(self._queue)
-                            self._work.wait(timeout=0.01)
+                        with self._spans.span(
+                            "pool.absorb", self._tid, model=self._model,
+                            queued=len(self._queue),
+                        ) as sp:
+                            while (
+                                not self._closed
+                                and len(self._queue) != seen
+                                and time.monotonic() < deadline
+                            ):
+                                seen = len(self._queue)
+                                self._work.wait(timeout=0.01)
+                                sp.slice()
                         self._stat_add_locked(
                             absorb_s=time.monotonic() - t_abs
                         )
@@ -3133,9 +3232,11 @@ class ContinuousBatcher:
                         )
                         if fs is not None and fs.kind == "canary_regress":
                             time.sleep(float(fs.param("s", 0.05)))
-                t0_obs = (
-                    time.monotonic_ns()
-                    if self._obs is not None or self._bb is not None else 0
+                # One decode-chunk dispatch: the host wall of the async
+                # enqueue (device time surfaces as fetch arrivals). Live
+                # rows are counted here, where the chunk is issued.
+                rows_live = sum(
+                    1 for s in self._slots[:self._rows_cap] if s is not None
                 )
                 if self._spec is not None and sampling.temperature == 0.0:
                     # Speculative decode mode: the dispatch becomes a
@@ -3143,20 +3244,15 @@ class ContinuousBatcher:
                     # while the governor probes/locks plain). Greedy
                     # gating is per-template — a sampled-template pool
                     # keeps the classic path below untouched.
-                    with _attrib_tag("spec_verify"):
+                    with self._spans.span(
+                        "pool.decode", self._tid, model=self._model,
+                        rows_live=rows_live, rows=self._rows_cap,
+                    ) as sp, _attrib_tag("spec_verify"):
                         payload, covered, mode = self._dispatch_spec(chunk)
-                    if self._obs is not None:
-                        self._obs.complete(
-                            "decode", t0_obs, tid="batcher",
-                            steps=covered, pos=self._pos, spec=mode,
-                        )
-                    if self._bb is not None:
-                        self._bb.complete(
-                            "decode", t0_obs, tid="batcher",
-                            steps=covered, pos=self._pos, spec=mode,
-                        )
+                        sp.set(steps=covered, pos=self._pos, spec=mode)
                 else:
                     n_steps = self._plan_steps(chunk)
+                    kv_width = eng._decode_width(self._pos + n_steps)
                     sentinel = self._integrity is not None
                     poison = None
                     if sentinel and eng._faults is not None:
@@ -3171,7 +3267,12 @@ class ContinuousBatcher:
                             poison = jnp.asarray(
                                 int(fs.param("row", 0)), jnp.int32
                             )
-                    with _attrib_tag("decode"):
+                    with self._spans.span(
+                        "pool.decode", self._tid, model=self._model,
+                        steps=n_steps, kv_width=kv_width or 0,
+                        rows_live=rows_live, rows=self._rows_cap,
+                        pos=self._pos + n_steps,
+                    ), _attrib_tag("decode"):
                         out = eng._flash_guard(
                             lambda impl: _decode_chunk(
                                 eng.params, eng.cfg, self._token, self._pos,
@@ -3179,9 +3280,7 @@ class ContinuousBatcher:
                                 sampling.temperature,
                                 sampling.top_k, sampling.top_p,
                                 row_start=self._row_start,
-                                kv_width=eng._decode_width(
-                                    self._pos + n_steps
-                                ),
+                                kv_width=kv_width,
                                 attn_impl=impl, mesh=eng.mesh,
                                 # Shared-prefix merge: participating rows
                                 # attend the pool's one prefix KV copy +
@@ -3206,19 +3305,6 @@ class ContinuousBatcher:
                         payload = toks
                     covered, mode = n_steps, None
                     self._pos += n_steps
-                    if self._obs is not None:
-                        # Host dispatch wall of one decode chunk (the
-                        # async enqueue — device time surfaces as fetch
-                        # arrivals).
-                        self._obs.complete(
-                            "decode", t0_obs, tid="batcher",
-                            steps=n_steps, pos=self._pos,
-                        )
-                    if self._bb is not None:
-                        self._bb.complete(
-                            "decode", t0_obs, tid="batcher",
-                            steps=n_steps, pos=self._pos,
-                        )
                 # Pure decode interval iff nothing but the previous
                 # chunk ran on the device since the last dispatch — no
                 # admission prefills (even failed ones), no compaction.
@@ -3245,6 +3331,10 @@ class ContinuousBatcher:
                 self._nondecode_work = False
                 with self._work:
                     self._unfetched += 1
+                    self._stat_add_locked(
+                        decode_chunks=1, decode_steps=covered,
+                        decode_row_steps=covered * rows_live,
+                    )
                     # Host gap closed: the device sat idle from the
                     # drain to this dispatch while the batcher was busy
                     # — attribute the bubble to the scheduler phase that

@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import threading
 import time
+import types
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Callable, Optional
@@ -85,6 +86,12 @@ class GenerateResult:
     # least once — rides the Response so the live-metrics plane can
     # label the request's latency outcome honestly.
     preempted: bool = False
+    # Clock reads (``time.monotonic_ns``) of a POOLED stream's way through
+    # its batcher, taken from the spans that carried it: ``admit_ns``,
+    # ``first_token_ns``, ``first_chunk_ns`` (engine/batcher.py _Stream).
+    # None on the single-stream paths. The serving tier turns the judge's
+    # into the result's ``timings``.
+    marks: Optional[dict] = None
 
 
 @partial(
@@ -195,10 +202,6 @@ def _extract_row0(template, pcache, width: int):
     return jax.tree.map(copy, template, pcache)
 
 
-@partial(
-    jax.jit, static_argnames=("cfg", "kv_width", "w8a8"),
-    donate_argnames=("cache",),
-)
 def _prefill_chunk(params, cfg: ModelConfig, tokens, start_pos, last_index,
                    cache, kv_width: int, row_start=None, prefix=None,
                    prefix_len=None, w8a8: bool = False):
@@ -223,11 +226,6 @@ def _prefill_chunk(params, cfg: ModelConfig, tokens, start_pos, last_index,
     return logits[:, 0], cache
 
 
-@partial(
-    jax.jit,
-    static_argnames=("cfg", "max_chunks", "kv_width", "w8a8"),
-    donate_argnames=("cache",),
-)
 def _prefill_chunks_loop(params, cfg: ModelConfig, tokens, base, n_real,
                          last_index, cache, max_chunks: int, kv_width: int,
                          w8a8: bool = False):
@@ -268,12 +266,6 @@ def _prefill_chunks_loop(params, cfg: ModelConfig, tokens, base, n_real,
     return last_logits, cache
 
 
-@partial(
-    jax.jit,
-    static_argnames=("cfg", "n_steps", "temperature", "top_k", "top_p",
-                     "kv_width", "attn_impl", "mesh", "w8a8", "sentinel"),
-    donate_argnames=("cache",),
-)
 def _decode_chunk(params, cfg: ModelConfig, token, pos, cache, key,
                   n_steps, temperature, top_k, top_p, row_start=None,
                   kv_width=None, attn_impl="xla", mesh=None,
@@ -372,19 +364,110 @@ _sp_prefill_step = _roofline.instrument(
     key=lambda a, k: _roofline.shape_of(a[2]),
     tokens=lambda a, k: _nrows(a[2]),
 )
-_prefill_chunk = _roofline.instrument(
-    _prefill_chunk, family="prefill",
+
+
+class _NamedPrograms:
+    """One hot program family, jitted under names that say what runs.
+
+    A device trace names a program by the function that was jitted, so one
+    ``jax.jit`` per family made every model's decode chunk at every width
+    the same ``jit__decode_chunk(<id>)``. Here the plain function is
+    jitted once per (model, kv_width[, steps]) under
+    ``<stem>__<model>__kv<width>[__s<steps>]`` (``kv0`` = the whole
+    cache) — values that were static arguments already, so there is the
+    same number of compiles as before, only findable by name. The other
+    static arguments (sampling, attention impl, mesh, ...) still key the
+    named program's own jit cache.
+
+    The cache is per family and process-wide, not per engine: two engines
+    of one model share the named program exactly as they shared the one
+    jit. Each named program is wrapped by ``_roofline.instrument`` like
+    the family's single jit was; ``lower`` / ``_cache_size`` keep the
+    jit-like surface the tests and chip_smoke.py introspect.
+    """
+
+    def __init__(self, fn, stem: str, static: tuple, name_key: Callable,
+                 **instrument):
+        self._fn = fn
+        self._stem = stem
+        self._static = static
+        # (args, kwargs) -> (model, kv_width[, steps]): what the name says.
+        self._key = name_key
+        self._instrument = instrument
+        self._programs: dict = {}
+        self._lock = sanitizer.make_lock(f"engine.programs.{stem}")
+        self.__name__ = stem
+        self.__doc__ = fn.__doc__
+
+    def name_of(self, key: tuple) -> str:
+        model, kv_width, *steps = key
+        safe = "".join(c if c.isalnum() else "_" for c in model)
+        name = f"{self._stem}__{safe}__kv{kv_width or 0}"
+        return f"{name}__s{steps[0]}" if steps else name
+
+    def program(self, *args, **kwargs):
+        """The named, instrumented jit these arguments run under."""
+        key = self._key(args, kwargs)
+        prog = self._programs.get(key)
+        if prog is None:
+            with self._lock:
+                prog = self._programs.get(key)
+                if prog is None:
+                    prog = self._programs[key] = self._build(key)
+        return prog
+
+    def _build(self, key: tuple):
+        fn, name = self._fn, self.name_of(key)
+        named = types.FunctionType(
+            fn.__code__, fn.__globals__, name, fn.__defaults__,
+            fn.__closure__,
+        )
+        named.__kwdefaults__ = fn.__kwdefaults__
+        named.__qualname__ = name
+        named.__doc__ = fn.__doc__
+        return _roofline.instrument(
+            jax.jit(named, static_argnames=self._static,
+                    donate_argnames=("cache",)),
+            **self._instrument,
+        )
+
+    def __call__(self, *args, **kwargs):
+        return self.program(*args, **kwargs)(*args, **kwargs)
+
+    def lower(self, *args, **kwargs):
+        return self.program(*args, **kwargs).lower(*args, **kwargs)
+
+    def _cache_size(self) -> int:
+        with self._lock:
+            programs = list(self._programs.values())
+        return sum(p._cache_size() for p in programs)
+
+
+_prefill_chunk = _NamedPrograms(
+    _prefill_chunk, "prefill_chunk", ("cfg", "kv_width", "w8a8"),
+    lambda a, k: (a[1].name, _kvw(a, k, 6)),
+    family="prefill",
     key=lambda a, k: (_roofline.shape_of(a[2]), _kvw(a, k, 6)),
     tokens=lambda a, k: _nrows(a[2]),
 )
-_prefill_chunks_loop = _roofline.instrument(
-    _prefill_chunks_loop, family="prefill",
+_prefill_chunks_loop = _NamedPrograms(
+    _prefill_chunks_loop, "prefill_chunks_loop",
+    ("cfg", "max_chunks", "kv_width", "w8a8"),
+    lambda a, k: (a[1].name, _kvw(a, k, 8)),
+    family="prefill",
     key=lambda a, k: (_roofline.shape_of(a[2]), _kvw(a, k, 8)),
     tokens=lambda a, k: int(a[4]) * int(a[2].shape[-1]),
     steps=lambda a, k: int(a[4]),
 )
-_decode_chunk = _roofline.instrument(
-    _decode_chunk, family="decode",
+_decode_chunk = _NamedPrograms(
+    _decode_chunk, "decode_chunk",
+    ("cfg", "n_steps", "temperature", "top_k", "top_p", "kv_width",
+     "attn_impl", "mesh", "w8a8", "sentinel"),
+    lambda a, k: (
+        a[1].name, _kvw(a, k, 11),
+        int(k["n_steps"] if "n_steps" in k else a[6]),
+    ),
+    family="decode",
     key=lambda a, k: (_roofline.shape_of(a[2]), int(a[6]), _kvw(a, k, 11)),
     tokens=lambda a, k: int(a[6]) * _nrows(a[2]),
     steps=lambda a, k: int(a[6]),
@@ -658,6 +741,13 @@ class Engine:
         from llm_consensus_tpu import obs as _obs
 
         self._obs = _obs.recorder()
+        # Spans go through the one emitter (obs/spans.py): recorder, flight
+        # ring and — inside a profiler window — the device trace's clock.
+        self._spans = _obs.emitter()
+        # (chunks dispatched, token slots covered) by the last prefill
+        # (``_prefill_ids`` or an admission wave), padding included: the
+        # pool's admission counts from it.
+        self.last_prefill = (0, 0)
         # Chip-time attribution (obs/attrib): single-stream prefill and
         # decode walls book here; the weights register as a modeled
         # resident-HBM component for the watermark sentinel.
@@ -1065,32 +1155,34 @@ class Engine:
             max_chunks >= n_tail
             and knobs.get_bool("LLMC_PREFILL_SCAN")
         )
-        with jax.profiler.TraceAnnotation("llmc.prefill"):
-            if use_scan:
-                toks = self._place(
-                    jnp.asarray(
-                        padded + [0] * ((max_chunks - n_tail) * chunk),
-                        jnp.int32,
-                    ).reshape(max_chunks, 1, chunk)
-                )
-                last_logits, cache = _prefill_chunks_loop(
+        if use_scan:
+            toks = self._place(
+                jnp.asarray(
+                    padded + [0] * ((max_chunks - n_tail) * chunk),
+                    jnp.int32,
+                ).reshape(max_chunks, 1, chunk)
+            )
+            last_logits, cache = _prefill_chunks_loop(
+                self.params, self.cfg, toks,
+                self._place(jnp.asarray(base, jnp.int32)),
+                self._place(jnp.asarray(n_tail, jnp.int32)),
+                last_in_chunk, cache, max_chunks=max_chunks,
+                kv_width=kv_width, w8a8=self.w8a8,
+            )
+        else:
+            for i in range(n_tail):
+                toks = self._place(jnp.asarray(
+                    padded[i * chunk:(i + 1) * chunk], jnp.int32
+                )[None, :])
+                last_logits, cache = _prefill_chunk(
                     self.params, self.cfg, toks,
-                    self._place(jnp.asarray(base, jnp.int32)),
-                    self._place(jnp.asarray(n_tail, jnp.int32)),
-                    last_in_chunk, cache, max_chunks=max_chunks,
-                    kv_width=kv_width, w8a8=self.w8a8,
+                    self._place(jnp.asarray(base + i * chunk, jnp.int32)),
+                    last_in_chunk, cache, kv_width=kv_width,
+                    w8a8=self.w8a8,
                 )
-            else:
-                for i in range(n_tail):
-                    toks = self._place(jnp.asarray(
-                        padded[i * chunk:(i + 1) * chunk], jnp.int32
-                    )[None, :])
-                    last_logits, cache = _prefill_chunk(
-                        self.params, self.cfg, toks,
-                        self._place(jnp.asarray(base + i * chunk, jnp.int32)),
-                        last_in_chunk, cache, kv_width=kv_width,
-                        w8a8=self.w8a8,
-                    )
+        # What was prefilled, for the caller's accounting: chunks
+        # dispatched and the token slots they covered (padding included).
+        self.last_prefill = (n_tail, n_tail * chunk)
         return last_logits, cache
 
     def _prefill_ids(self, prompt_ids: list[int]):
@@ -1100,10 +1192,19 @@ class Engine:
         reuse, sequence-parallel (ring) prefill, chunked prefill, and
         one-shot per-bucket prefill — shared by the single-stream decode
         loop and the continuous batcher's admission path.
+        ``self.last_prefill`` is then (chunks dispatched, token slots they
+        covered), padding included.
         """
         if self._faults is not None:
             self._faults.check("prefill")  # injected device OOM / loss
-        t0_obs = self._obs.now() if self._obs is not None else 0
+        with self._spans.span(
+            "prefill", "engine", model=self.cfg.name, tokens=len(prompt_ids),
+        ) as sp:
+            last_logits, cache, reused = self._prefill_ids_body(prompt_ids)
+            sp.set(reused=reused)
+        return last_logits, cache
+
+    def _prefill_ids_body(self, prompt_ids: list[int]):
         cfg = self.cfg
         n_prompt = len(prompt_ids)
         sp = 1 if self.mesh is None else dict(self.mesh.shape).get("sp", 1)
@@ -1153,12 +1254,12 @@ class Engine:
             bucket = sp_bucket
             padded = prompt_ids + [0] * (bucket - n_prompt)
             tokens = self._place(jnp.asarray(padded, jnp.int32)[None, :])
-            with jax.profiler.TraceAnnotation("llmc.prefill"):
-                last_logits, cache = _sp_prefill_step(
-                    self.params, cfg, tokens,
-                    self._place(jnp.asarray([n_prompt - 1])),
-                    cache, mesh=self.mesh,
-                )
+            last_logits, cache = _sp_prefill_step(
+                self.params, cfg, tokens,
+                self._place(jnp.asarray([n_prompt - 1])),
+                cache, mesh=self.mesh,
+            )
+            self.last_prefill = (1, bucket)
         elif chunk_len and n_prompt > chunk_len and n_chunks * chunk_len <= self.max_seq:
             # Chunked prefill: the same compiled program dispatched per
             # chunk, dynamic start offset. Dispatches pipeline (no fetch
@@ -1174,18 +1275,13 @@ class Engine:
             bucket = _bucket(n_prompt, self.max_seq)
             padded = prompt_ids + [0] * (bucket - n_prompt)
             tokens = self._place(jnp.asarray(padded, jnp.int32)[None, :])
-            with jax.profiler.TraceAnnotation("llmc.prefill"):
-                last_logits, cache = self._flash_guard(lambda impl: _prefill_step(
-                    self.params, cfg, tokens,
-                    self._place(jnp.asarray([n_prompt - 1])),
-                    cache, attn_impl=impl, mesh=self.mesh, w8a8=self.w8a8,
-                ))
-        if self._obs is not None:
-            self._obs.complete(
-                "prefill", t0_obs, tid="engine",
-                tokens=n_prompt, reused=reuse_len if reuse_ok else 0,
-            )
-        return last_logits, cache
+            last_logits, cache = self._flash_guard(lambda impl: _prefill_step(
+                self.params, cfg, tokens,
+                self._place(jnp.asarray([n_prompt - 1])),
+                cache, attn_impl=impl, mesh=self.mesh, w8a8=self.w8a8,
+            ))
+            self.last_prefill = (1, bucket)
+        return last_logits, cache, reuse_len if reuse_ok else 0
 
     def _rows_bucket(self, n_max: int) -> int:
         """Cache capacity ``_prefill_rows`` will allocate for a wave whose
@@ -1385,10 +1481,10 @@ class Engine:
             else:
                 t_last_fetch = now
                 n_at_last_fetch = len(out_ids)
-        # Telemetry: bound at engine construction (obs/__init__.py), so a
-        # disabled run's decode loop consults only this None — per chunk,
-        # one check at dispatch and one at fetch, no recorder state.
-        obs_r = self._obs
+        # Telemetry: the emitter was bound at engine construction
+        # (obs/__init__.py); per chunk, one span at dispatch and one at
+        # fetch, each written once to the sinks that were on then.
+        spans = self._spans
         # Chip-time attribution: fetch-to-fetch intervals are the
         # single-stream decode wall (the batcher's arrival-interval twin).
         attrib = self._attrib
@@ -1398,20 +1494,18 @@ class Engine:
             """Fetch one dispatched chunk's token ids and emit them; the
             prefill-sampled token rides down with the first fetch."""
             nonlocal first, stopped
-            t0_obs = obs_r.now() if obs_r is not None else 0
-            if first is not None:
-                first_id, tok_mat = jax.device_get((first, toks))
-                fetched = [int(first_id[0])] + [int(t) for t in tok_mat[:, 0]]
-                first = None
-            else:
-                fetched = [int(t) for t in jax.device_get(toks)[:, 0]]
-            stopped = emit(fetched)
-            if obs_r is not None:
-                # After the emit: the span covers transfer + emit, like
-                # the batcher's fetch span (the documented span names).
-                obs_r.complete(
-                    "fetch", t0_obs, tid="engine", tokens=len(fetched)
-                )
+            # The span covers transfer + emit (the documented span names).
+            with spans.span("fetch", "engine", model=cfg.name) as sp:
+                if first is not None:
+                    first_id, tok_mat = jax.device_get((first, toks))
+                    fetched = (
+                        [int(first_id[0])] + [int(t) for t in tok_mat[:, 0]]
+                    )
+                    first = None
+                else:
+                    fetched = [int(t) for t in jax.device_get(toks)[:, 0]]
+                stopped = emit(fetched)
+                sp.set(tokens=len(fetched))
             if attrib is not None:
                 nonlocal t_attr
                 now = time.monotonic()
@@ -1456,9 +1550,11 @@ class Engine:
                         if fs is not None and fs.kind == "canary_regress":
                             time.sleep(float(fs.param("s", 0.05)))
                 n_steps = chunk if pos + chunk <= self.max_seq else 1
-                t0_obs = obs_r.now() if obs_r is not None else 0
-                with jax.profiler.TraceAnnotation("llmc.decode_chunk"), \
-                        _attrib_tag("decode"):
+                # Host dispatch wall (the async enqueue, not device
+                # time — the ~40%-host-on-dispatch finding's signal).
+                with spans.span(
+                    "decode", "engine", model=cfg.name, steps=n_steps,
+                ), _attrib_tag("decode"):
                     token, toks, cache = self._flash_guard(
                         lambda impl: _decode_chunk(
                             self.params, cfg, token, pos, cache, key, n_steps,
@@ -1466,12 +1562,6 @@ class Engine:
                             kv_width=self._decode_width(pos + n_steps),
                             attn_impl=impl, mesh=self.mesh, w8a8=self.w8a8,
                         )
-                    )
-                if obs_r is not None:
-                    # Host dispatch wall (the async enqueue, not device
-                    # time — the ~40%-host-on-dispatch finding's signal).
-                    obs_r.complete(
-                        "decode", t0_obs, tid="engine", steps=n_steps
                     )
                 pos += n_steps
             if inflight is not None:
@@ -1588,30 +1678,29 @@ class Engine:
         )
         if self._shard_fn is not None:
             cache = self._shard_fn(cache)
-        with jax.profiler.TraceAnnotation("llmc.batch_prefill"):
-            if use_chunks:
-                n_chunks = bucket // chunk_len
-                last_in_chunk = self._place(
-                    jnp.full((b,), (bucket - 1) % chunk_len, jnp.int32)
+        if use_chunks:
+            n_chunks = bucket // chunk_len
+            last_in_chunk = self._place(
+                jnp.full((b,), (bucket - 1) % chunk_len, jnp.int32)
+            )
+            for i in range(n_chunks):
+                toks = self._place(jnp.asarray(
+                    [r[i * chunk_len:(i + 1) * chunk_len] for r in padded],
+                    jnp.int32,
+                ))
+                last_logits, cache = _prefill_chunk(
+                    self.params, cfg, toks,
+                    self._place(jnp.asarray(i * chunk_len, jnp.int32)),
+                    last_in_chunk, cache, kv_width=bucket,
+                    row_start=row_start, w8a8=self.w8a8,
                 )
-                for i in range(n_chunks):
-                    toks = self._place(jnp.asarray(
-                        [r[i * chunk_len:(i + 1) * chunk_len] for r in padded],
-                        jnp.int32,
-                    ))
-                    last_logits, cache = _prefill_chunk(
-                        self.params, cfg, toks,
-                        self._place(jnp.asarray(i * chunk_len, jnp.int32)),
-                        last_in_chunk, cache, kv_width=bucket,
-                        row_start=row_start, w8a8=self.w8a8,
-                    )
-            else:
-                tokens = self._place(jnp.asarray(padded, jnp.int32))
-                last_logits, cache = _prefill_step(
-                    self.params, cfg, tokens, last_index, cache,
-                    attn_impl="xla", mesh=None, row_start=row_start,
-                    kv_width=bucket, w8a8=self.w8a8,
-                )
+        else:
+            tokens = self._place(jnp.asarray(padded, jnp.int32))
+            last_logits, cache = _prefill_step(
+                self.params, cfg, tokens, last_index, cache,
+                attn_impl="xla", mesh=None, row_start=row_start,
+                kv_width=bucket, w8a8=self.w8a8,
+            )
         key = self._place(jax.random.PRNGKey(sampling.seed))
         token = sample_token(
             last_logits, jax.random.fold_in(key, bucket - 1),
@@ -1671,15 +1760,14 @@ class Engine:
             toks = None
             if steps_dispatched < steps_needed and pos < self.max_seq:
                 n_steps = chunk if pos + chunk <= self.max_seq else 1
-                with jax.profiler.TraceAnnotation("llmc.batch_decode"):
-                    token, toks, cache = self._flash_guard(
-                        lambda impl: _decode_chunk(
-                            self.params, cfg, token, pos, cache, key, n_steps,
-                            *sample_args, row_start=row_start,
-                            kv_width=self._decode_width(pos + n_steps),
-                            attn_impl=impl, mesh=self.mesh, w8a8=self.w8a8,
-                        )
+                token, toks, cache = self._flash_guard(
+                    lambda impl: _decode_chunk(
+                        self.params, cfg, token, pos, cache, key, n_steps,
+                        *sample_args, row_start=row_start,
+                        kv_width=self._decode_width(pos + n_steps),
+                        attn_impl=impl, mesh=self.mesh, w8a8=self.w8a8,
                     )
+                )
                 steps_dispatched += n_steps
                 pos += n_steps
             if inflight is not None:
@@ -1792,7 +1880,6 @@ class AdmissionPrefill:
         if engine._faults is not None:
             engine._faults.check("prefill")  # injected device OOM / loss
         self._eng = engine
-        self._t0_obs = engine._obs.now() if engine._obs is not None else 0
         self.rows = rows
         self.k = len(rows)
         self._prefix_cache = prefix_cache
@@ -1866,6 +1953,22 @@ class AdmissionPrefill:
         self._done = False
 
     @property
+    def chunks(self) -> int:
+        """Chunk programs this wave dispatches (1 for a one-shot bucket);
+        chunks forked from a retained prefix are not dispatched."""
+        return self._n_chunks - self._first_chunk
+
+    @property
+    def slot_tokens(self) -> int:
+        """Token slots the wave's dispatches cover: rows × chunks × chunk
+        length (rows × bucket for a one-shot) — padding rows, padding
+        inside rows and all. The real tokens are the caller's to count."""
+        per_row = (
+            self.chunks * self._chunk_len if self._use_chunks else self.width
+        )
+        return self.k * per_row
+
+    @property
     def remaining_tokens(self) -> int:
         """Total prompt tokens (rows × chunk length) not yet dispatched —
         the batcher's credit ledger sizes its interleave pacing off this."""
@@ -1886,59 +1989,58 @@ class AdmissionPrefill:
         eng = self._eng
         place = eng._place
         cfg = eng.cfg
-        with jax.profiler.TraceAnnotation("llmc.admit_prefill"):
-            if not self._use_chunks:
-                # One-shot per-bucket program: indivisible by construction.
-                tokens = place(jnp.asarray(self._padded, jnp.int32))
-                last_index = place(
-                    jnp.asarray([len(r) - 1 for r in self.rows], jnp.int32)
-                )
-                if self._suffix:
-                    self._last_logits, self._cache = _prefill_step(
-                        eng.params, cfg, tokens, last_index, self._cache,
-                        attn_impl="xla", mesh=eng.mesh,
-                        prefix=self._prefix_cache, prefix_len=self._plen_dev,
-                        w8a8=eng.w8a8,
-                    )
-                else:
-                    self._last_logits, self._cache = eng._flash_guard(
-                        lambda impl: _prefill_step(
-                            eng.params, cfg, tokens, last_index, self._cache,
-                            attn_impl=impl, mesh=eng.mesh, w8a8=eng.w8a8,
-                        )
-                    )
-                self._done = True
-                return True
-            chunk_len = self._chunk_len
-            spent = 0
-            while self._next_chunk < self._n_chunks:
-                c = self._next_chunk
-                toks = place(jnp.asarray(
-                    [p[c * chunk_len:(c + 1) * chunk_len]
-                     for p in self._padded],
-                    jnp.int32,
-                ))
-                # Per-row "last token in THIS chunk" index, clamped: rows
-                # whose last token lies elsewhere produce a logit nobody
-                # reads; the gather in finish() selects each row's real
-                # chunk.
-                idx = place(jnp.asarray(
-                    [min(max(len(r) - 1 - c * chunk_len, 0), chunk_len - 1)
-                     for r in self.rows],
-                    jnp.int32,
-                ))
-                lg, self._cache = _prefill_chunk(
-                    eng.params, cfg, toks,
-                    place(jnp.asarray(c * chunk_len, jnp.int32)),
-                    idx, self._cache, kv_width=self.width,
+        if not self._use_chunks:
+            # One-shot per-bucket program: indivisible by construction.
+            tokens = place(jnp.asarray(self._padded, jnp.int32))
+            last_index = place(
+                jnp.asarray([len(r) - 1 for r in self.rows], jnp.int32)
+            )
+            if self._suffix:
+                self._last_logits, self._cache = _prefill_step(
+                    eng.params, cfg, tokens, last_index, self._cache,
+                    attn_impl="xla", mesh=eng.mesh,
                     prefix=self._prefix_cache, prefix_len=self._plen_dev,
                     w8a8=eng.w8a8,
                 )
-                self._per_chunk.append(lg)
-                self._next_chunk += 1
-                spent += self.k * chunk_len
-                if token_budget is not None and spent >= token_budget:
-                    break
+            else:
+                self._last_logits, self._cache = eng._flash_guard(
+                    lambda impl: _prefill_step(
+                        eng.params, cfg, tokens, last_index, self._cache,
+                        attn_impl=impl, mesh=eng.mesh, w8a8=eng.w8a8,
+                    )
+                )
+            self._done = True
+            return True
+        chunk_len = self._chunk_len
+        spent = 0
+        while self._next_chunk < self._n_chunks:
+            c = self._next_chunk
+            toks = place(jnp.asarray(
+                [p[c * chunk_len:(c + 1) * chunk_len]
+                 for p in self._padded],
+                jnp.int32,
+            ))
+            # Per-row "last token in THIS chunk" index, clamped: rows
+            # whose last token lies elsewhere produce a logit nobody
+            # reads; the gather in finish() selects each row's real
+            # chunk.
+            idx = place(jnp.asarray(
+                [min(max(len(r) - 1 - c * chunk_len, 0), chunk_len - 1)
+                 for r in self.rows],
+                jnp.int32,
+            ))
+            lg, self._cache = _prefill_chunk(
+                eng.params, cfg, toks,
+                place(jnp.asarray(c * chunk_len, jnp.int32)),
+                idx, self._cache, kv_width=self.width,
+                prefix=self._prefix_cache, prefix_len=self._plen_dev,
+                w8a8=eng.w8a8,
+            )
+            self._per_chunk.append(lg)
+            self._next_chunk += 1
+            spent += self.k * chunk_len
+            if token_budget is not None and spent >= token_budget:
+                break
         if self._next_chunk >= self._n_chunks:
             self._done = True
         return self._done
@@ -1946,7 +2048,7 @@ class AdmissionPrefill:
     def finish(self):
         """(last_logits [k, V], cache, width): gather each row's real
         last-token logits, retain the wave snapshot (full-prompt waves
-        whose rows share a chunk-sized prefix), close the obs span."""
+        whose rows share a chunk-sized prefix)."""
         eng = self._eng
         if self._use_chunks:
             if len(self._per_chunk) == 1:
@@ -2003,13 +2105,9 @@ class AdmissionPrefill:
             eng._retain_prefix(
                 self.rows[0], _extract_row0(template, cache, self.width)
             )
-        if eng._obs is not None:
-            args = {"rows": self.k, "width": self.width}
-            if self._suffix:
-                args["prefix"] = self._plen
-            eng._obs.complete(
-                "admit_prefill", self._t0_obs, tid="engine", **args
-            )
+        # What the wave dispatched: the pool's ``pool.admit`` span and its
+        # counters read it (there is no span of the session's own).
+        eng.last_prefill = (self.chunks, self.slot_tokens)
         return last_logits, cache, self.width
 
 
@@ -2129,19 +2227,18 @@ class PrefillSession:
             self.overflowed = True
         if self.overflowed:
             return
-        with jax.profiler.TraceAnnotation("llmc.prefill"):
-            while len(self._ids) - self._base >= chunk:
-                toks = eng._place(jnp.asarray(
-                    self._ids[self._base:self._base + chunk], jnp.int32,
-                )[None, :])
-                kv_width = _bucket(self._base + chunk, eng.max_seq)
-                self._last_logits, self._cache = _prefill_chunk(
-                    eng.params, eng.cfg, toks,
-                    eng._place(jnp.asarray(self._base, jnp.int32)),
-                    eng._place(jnp.asarray([chunk - 1], jnp.int32)),
-                    self._cache, kv_width=kv_width, w8a8=eng.w8a8,
-                )
-                self._base += chunk
+        while len(self._ids) - self._base >= chunk:
+            toks = eng._place(jnp.asarray(
+                self._ids[self._base:self._base + chunk], jnp.int32,
+            )[None, :])
+            kv_width = _bucket(self._base + chunk, eng.max_seq)
+            self._last_logits, self._cache = _prefill_chunk(
+                eng.params, eng.cfg, toks,
+                eng._place(jnp.asarray(self._base, jnp.int32)),
+                eng._place(jnp.asarray([chunk - 1], jnp.int32)),
+                self._cache, kv_width=kv_width, w8a8=eng.w8a8,
+            )
+            self._base += chunk
 
     def sync(self) -> None:
         """Block until every dispatched prefill chunk has completed on
@@ -2227,14 +2324,13 @@ class PrefillSession:
                     )
                 padded = self._ids[self._base:] + [0] * (chunk - residue)
                 kv_width = _bucket(self._base + chunk, eng.max_seq)
-                with jax.profiler.TraceAnnotation("llmc.prefill"):
-                    self._last_logits, self._cache = _prefill_chunk(
-                        eng.params, eng.cfg,
-                        eng._place(jnp.asarray(padded, jnp.int32)[None, :]),
-                        eng._place(jnp.asarray(self._base, jnp.int32)),
-                        eng._place(jnp.asarray([residue - 1], jnp.int32)),
-                        self._cache, kv_width=kv_width, w8a8=eng.w8a8,
-                    )
+                self._last_logits, self._cache = _prefill_chunk(
+                    eng.params, eng.cfg,
+                    eng._place(jnp.asarray(padded, jnp.int32)[None, :]),
+                    eng._place(jnp.asarray(self._base, jnp.int32)),
+                    eng._place(jnp.asarray([residue - 1], jnp.int32)),
+                    self._cache, kv_width=kv_width, w8a8=eng.w8a8,
+                )
                 self._base = n
             ids = list(self._ids)
             last_logits, cache = self._last_logits, self._cache

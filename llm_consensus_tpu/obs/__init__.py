@@ -7,6 +7,11 @@ Consumers bind the recorder at construction time
 ``is not None`` check on the hot dispatch/fetch paths; the enable decision
 is made at recorder-resolution time, never per-event.
 
+``emitter()`` is how spans are written: one call per span, fanned out
+to the recorder, the flight ring and — while a profiler window is open —
+the profiler's own trace (obs/spans.py), bound at construction like the
+recorder.
+
 ``install()`` / ``reset()`` exist for tests, the CLI's ``--events`` flag,
 and the events dryrun lane, which enable telemetry mid-process (before any
 engine/batcher/runner is constructed); production resolves from the
@@ -38,11 +43,12 @@ from llm_consensus_tpu.obs import (  # noqa: F401 — public API
     attrib, blackbox, live, profiler, roofline)
 from llm_consensus_tpu.obs.recorder import (  # noqa: F401 — public API
     Event, Recorder, resolve_max_events)
+from llm_consensus_tpu.obs.spans import Emitter, Span  # noqa: F401
 from llm_consensus_tpu.utils import knobs
 
 __all__ = [
-    "Event", "Recorder", "attrib", "blackbox", "live", "profiler",
-    "roofline", "recorder", "install", "reset",
+    "Emitter", "Event", "Recorder", "Span", "attrib", "blackbox", "emitter",
+    "live", "profiler", "roofline", "recorder", "install", "reset",
 ]
 
 _lock = sanitizer.make_lock("obs.registry")
@@ -61,6 +67,17 @@ def recorder() -> Optional[Recorder]:
                     _recorder = Recorder(max_events=resolve_max_events())
                 _resolved = True
     return _recorder
+
+
+def emitter() -> Emitter:
+    """A span emitter over the sinks that are on NOW: the recorder, the
+    flight ring, and the installed profiler's window (obs/spans.py).
+    Consumers call this once, at construction, like ``recorder()``."""
+    prof = profiler.profiler()
+    return Emitter(
+        recorder(), blackbox.ring(),
+        prof.window if prof is not None else None,
+    )
 
 
 def install(r: Optional[Recorder]) -> None:

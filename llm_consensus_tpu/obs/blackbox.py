@@ -77,6 +77,10 @@ class FlightRecorder:
     def now() -> int:
         return time.monotonic_ns()
 
+    def append(self, ev: Event) -> None:
+        """Append one finished event (the span emitter's sink form)."""
+        self._ring.append(ev)
+
     def complete(self, name: str, t0_ns: int, tid: str = "main",
                  **args) -> None:
         """Record a span that started at ``t0_ns`` and ends now — the

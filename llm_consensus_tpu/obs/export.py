@@ -119,6 +119,12 @@ def local_trace(recorder: Recorder, pid: int = 0) -> dict:
     return trace_document(events)
 
 
+# Spans that bound the decode activity window: the single-stream engine's
+# dispatch and fetch, and the pooled batcher's (the last fetch ends the
+# window, so its emit half is not needed).
+_DECODE_ACTIVITY = ("decode", "fetch", "pool.decode", "pool.fetch")
+
+
 def aggregate_throughput(
     recorder: Recorder, events: Optional[list[Event]] = None
 ) -> Optional[dict]:
@@ -142,7 +148,7 @@ def aggregate_throughput(
     if events is None:
         events = recorder.events()
     spans = [
-        e for e in events if e.ph == "X" and e.name in ("decode", "fetch")
+        e for e in events if e.ph == "X" and e.name in _DECODE_ACTIVITY
     ]
     if spans:
         window_s = (

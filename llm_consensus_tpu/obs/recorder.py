@@ -90,7 +90,8 @@ class Recorder:
 
     # -- recording -----------------------------------------------------------
 
-    def _append(self, ev: Event) -> None:
+    def append(self, ev: Event) -> None:
+        """Append one finished event (the span emitter's sink form)."""
         with self._lock:
             if len(self._events) >= self._max_events:
                 # Dropped, not silently: the counter exports as
@@ -118,7 +119,7 @@ class Recorder:
         ends now — the hot-path form: the caller pays one clock read up
         front and one append here, nothing else."""
         t1 = time.monotonic_ns()
-        self._append(Event(
+        self.append(Event(
             name=name, ph="X", ts_ns=t0_ns, tid=tid,
             dur_ns=max(t1 - t0_ns, 0), args=args,
         ))
@@ -135,7 +136,7 @@ class Recorder:
             self.complete(name, t0, tid=tid, **args)
 
     def instant(self, name: str, tid: str = "main", **args) -> None:
-        self._append(Event(
+        self.append(Event(
             name=name, ph="i", ts_ns=time.monotonic_ns(), tid=tid, args=args,
         ))
 

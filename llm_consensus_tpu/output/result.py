@@ -30,6 +30,11 @@ class Result:
     # LLM-graded confidence in the consensus (roadmap §2.4, --confidence):
     # {score: 0-100 | null, controversy: [str]}.
     confidence: "dict | None" = None
+    # Where a SERVED run's time went (serve/scheduler.py run_timings:
+    # queue, panel, judge queue / prefill / first chunk / decode, total,
+    # judge prompt and answer tokens). Last, and omitted on paths that do
+    # not have it, so the reference shape and field order are unchanged.
+    timings: "dict | None" = None
 
     def to_dict(self) -> dict:
         out = {
@@ -48,6 +53,8 @@ class Result:
             out["agreement"] = self.agreement
         if self.confidence is not None:
             out["confidence"] = self.confidence
+        if self.timings is not None:
+            out["timings"] = self.timings
         return out
 
     def to_json(self, indent: int = 2) -> str:

@@ -91,6 +91,11 @@ class Response:
     # once by the pressure scheduler (engine/batcher.preempt) — the
     # live-metrics plane labels the request's latency outcome with it.
     preempted: bool = False
+    # Clock reads of this query's way through its engine pool
+    # (``time.monotonic_ns``: admit_ns, first_token_ns, first_chunk_ns —
+    # engine/batcher.py); never serialized. The serving tier turns the
+    # judge's into the result's ``timings``.
+    marks: Optional[dict] = None
 
     def to_dict(self) -> dict:
         """JSON shape parity with the reference's Response tags."""

@@ -224,7 +224,7 @@ class TPUProvider(Provider):
         # engine-stream spans (with the request trace id) into the
         # always-on flight recorder ring.
         self._live = obs.live.metrics()
-        self._bb = obs.blackbox.ring()
+        self._spans = obs.emitter()
         # Chip-time attribution (obs/attrib): the provider computes LIVE
         # per-pool MFU/MBU gauges from scrape-to-scrape batcher deltas
         # (utilization_stats); the per-site attribution itself lives in
@@ -1690,10 +1690,7 @@ class TPUProvider(Provider):
             ctx.raise_if_done()
             engine = self._engine_for(req.model)
         start = time.monotonic()
-        t0_ns = (
-            time.monotonic_ns()
-            if self._obs is not None or self._bb is not None else 0
-        )
+        t0_ns = time.monotonic_ns()
         sampling = SamplingParams(
             max_new_tokens=(
                 req.max_tokens if req.max_tokens is not None else DEFAULT_MAX_NEW_TOKENS
@@ -1819,19 +1816,14 @@ class TPUProvider(Provider):
                 weight_bytes={"int8": 1, "int4": 0.5}.get(engine.quant, 2),
                 kv_bytes=1 if engine.kv_quant == "int8" else 2,
             )
-        if self._obs is not None:
-            # Engine-level trace span: the request trace id's innermost
-            # hop (router → gateway → runner → HERE), so one id recovers
-            # the on-device half of any slow request's path.
-            self._obs.complete(
-                "engine_stream", t0_ns, tid="engine", model=req.model,
-                trace=req.trace_id, tokens=len(result.token_ids),
-            )
-        if self._bb is not None:
-            self._bb.complete(
-                "engine_stream", t0_ns, tid="engine", model=req.model,
-                trace=req.trace_id, tokens=len(result.token_ids),
-            )
+        # Engine-level trace span: the request trace id's innermost hop
+        # (router → gateway → runner → HERE), so one id recovers the
+        # on-device half of any slow request's path. After the fact: the
+        # retry ladder above may have run it more than once.
+        self._spans.complete(
+            "engine_stream", t0_ns, "engine", model=req.model,
+            trace=req.trace_id, tokens=len(result.token_ids),
+        )
         if self._live is not None and result.token_ids:
             from llm_consensus_tpu.obs.live import class_label
 
@@ -1889,4 +1881,9 @@ class TPUProvider(Provider):
                 if getattr(result, "kv_truncated", False) else None
             ),
             preempted=getattr(result, "preempted", False),
+            marks=(
+                dict(result.marks, prompt_tokens=result.prompt_tokens,
+                     tokens=len(result.token_ids))
+                if getattr(result, "marks", None) else None
+            ),
         )

@@ -123,9 +123,9 @@ class Runner:
         from llm_consensus_tpu import obs
 
         self._obs = obs.recorder()
-        # Flight recorder (obs/blackbox): worker spans land in the
-        # always-on ring so a crash snapshot shows the fan-out shape.
-        self._bb = obs.blackbox.ring()
+        # Worker spans go through the one emitter: the always-on ring gets
+        # them too, so a crash snapshot shows the fan-out shape.
+        self._spans = obs.emitter()
 
     def with_callbacks(self, callbacks: Callbacks) -> "Runner":
         self._callbacks = callbacks
@@ -187,34 +187,22 @@ class Runner:
             # Workers never raise: failures — including ones thrown by the
             # caller's own callbacks — become warnings so siblings always run
             # to completion (runner.go:75-83, 100-111).
-            t0_obs = (
-                time.monotonic_ns()
-                if self._obs is not None or self._bb is not None else 0
-            )
-            try:
-                query_one(model, wid)
-            except Exception as err:
-                with lock:
-                    accounted = wid in done or wid in abandoned
-                if not accounted:
-                    record_failure(wid, model, err)
-                    if cb.on_model_error:
-                        try:
-                            cb.on_model_error(model, err)
-                        except Exception:
-                            pass  # the error hook itself may be the broken one
-            finally:
-                targs = {"trace": self._trace} if self._trace else {}
-                if self._obs is not None:
-                    self._obs.complete(
-                        "worker", t0_obs, tid="runner", model=model, wid=wid,
-                        **targs,
-                    )
-                if self._bb is not None:
-                    self._bb.complete(
-                        "worker", t0_obs, tid="runner", model=model, wid=wid,
-                        **targs,
-                    )
+            with self._spans.span(
+                "worker", "runner", model=model, role="panel", wid=wid,
+                trace=self._trace,
+            ):
+                try:
+                    query_one(model, wid)
+                except Exception as err:
+                    with lock:
+                        accounted = wid in done or wid in abandoned
+                    if not accounted:
+                        record_failure(wid, model, err)
+                        if cb.on_model_error:
+                            try:
+                                cb.on_model_error(model, err)
+                            except Exception:
+                                pass  # the error hook may be the broken one
 
         def query_one(model: str, wid: int) -> None:
             model_ctx = ctx.with_timeout(self._timeout)

@@ -86,16 +86,18 @@ class ClientGone(Exception):
 class Ticket:
     """One granted admission slot; release exactly once."""
 
-    def __init__(self, controller: "AdmissionController", t0_ns: int):
+    def __init__(self, controller: "AdmissionController", t0_ns: int,
+                 trace: Optional[str] = None):
         self._controller = controller
         self._t0_ns = t0_ns
+        self._trace = trace
         self._released = False
 
     def release(self) -> None:
         if self._released:
             return
         self._released = True
-        self._controller._release(self._t0_ns)
+        self._controller._release(self._t0_ns, self._trace)
 
     def __enter__(self) -> "Ticket":
         return self
@@ -169,6 +171,7 @@ class AdmissionController:
 
         self._faults = faults.plan()
         self._obs = obs.recorder()
+        self._spans = obs.emitter()
 
     # -- admission -----------------------------------------------------------
 
@@ -224,7 +227,8 @@ class AdmissionController:
         return victim
 
     def admit(self, ctx: Optional[Context] = None, probe=None,
-              priority: int = PRIORITY_NORMAL) -> Ticket:
+              priority: int = PRIORITY_NORMAL,
+              trace: Optional[str] = None) -> Ticket:
         """Block until an execution slot is granted; returns its Ticket.
 
         Raises :class:`QueueFull` / :class:`Draining` for shed load, or
@@ -235,6 +239,8 @@ class AdmissionController:
         coalesced followers riding it) and :class:`ClientGone` is raised
         instead of granting a slot the answer can never reach.
         ``priority`` orders the dequeue (see the module docstring).
+        ``trace`` is the request's id, carried on its ``queue_wait`` and
+        ``admit`` spans.
         """
         t0 = time.monotonic_ns()
         if self._faults is not None:
@@ -328,15 +334,17 @@ class AdmissionController:
                 self._cond.notify_all()
             self._active += 1
             self.admitted += 1
+        # The slot-hold span starts on the read that ends the wait.
+        t_granted = self._spans.complete(
+            "queue_wait", t0, "serve", trace=trace, priority=priority,
+        )
         if self._obs is not None:
-            self._obs.complete("queue_wait", t0, tid="serve")
             self._obs.count("serve.admitted")
-        return Ticket(self, time.monotonic_ns())
+        return Ticket(self, t_granted, trace)
 
-    def _release(self, admit_t0_ns: int) -> None:
-        if self._obs is not None:
-            # The slot-hold interval: concurrent occupancy on the timeline.
-            self._obs.complete("admit", admit_t0_ns, tid="serve")
+    def _release(self, admit_t0_ns: int, trace: Optional[str] = None) -> None:
+        # The slot-hold interval: concurrent occupancy on the timeline.
+        self._spans.complete("admit", admit_t0_ns, "serve", trace=trace)
         with self._cond:
             self._active -= 1
             self._cond.notify_all()

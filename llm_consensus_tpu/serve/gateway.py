@@ -220,6 +220,7 @@ class ConsensusGateway:
         # Flight recorder (obs/blackbox): request spans in the always-on
         # ring; the SLO-burn watcher dumps it.
         self._bb = obs.blackbox.ring()
+        self._spans = obs.emitter()
         # Chip-time attribution (obs/attrib): the /statsz ``attrib``
         # block + the labeled device-time/goodput/compile counters on
         # /metricsz come from this ledger.
@@ -1174,11 +1175,10 @@ class ConsensusGateway:
         """SLO-burn anomaly (p99 TTFT over threshold for N windows):
         snapshot the flight recorder — the tail regression's timeline is
         in the ring RIGHT NOW and gone in a minute."""
+        self._spans.instant("slo_burn", "serve", **info)
         if self._obs is not None:
-            self._obs.instant("slo_burn", tid="serve", **info)
             self._obs.count("obs.slo_burns")
         if self._bb is not None:
-            self._bb.instant("slo_burn", tid="serve", **info)
             self._bb.dump("slo_burn", extra=info)
         self.log(f"SLO burn: {info}")
 
@@ -1434,7 +1434,8 @@ class ConsensusGateway:
             with self._open_cond:
                 self._open_requests += 1
             try:
-                outcome = self._serve_consensus(req, respond, t0, probe)
+                outcome = self._serve_consensus(
+                    req, respond, t0, probe, t0_ns)
             except RetryLater:
                 outcome = "shed"
                 raise
@@ -1458,11 +1459,10 @@ class ConsensusGateway:
                 # histogram should show. (A vanished client has no
                 # latency anyone experienced; skip it.)
                 self._observe("e2e", req, time.monotonic() - t0, outcome)
-            if self._bb is not None:
-                self._bb.complete(
-                    "request", t0_ns, tid="serve", trace=req.trace_id,
-                    outcome=outcome, priority=req.priority,
-                )
+            self._spans.complete(
+                "request", t0_ns, "serve", trace=req.trace_id,
+                outcome=outcome, priority=req.priority,
+            )
 
     @staticmethod
     def _result_outcome(out, degraded: Optional[str]) -> str:
@@ -1478,7 +1478,8 @@ class ConsensusGateway:
         return "ok"
 
     def _serve_consensus(self, req: ServeRequest, respond: "_Responder",
-                         t0: float, probe=None) -> str:
+                         t0: float, probe=None,
+                         t0_ns: Optional[int] = None) -> str:
         """The per-request core; returns the outcome label for the e2e
         histogram (``ok`` / ``degraded`` / ``preempted``)."""
         degraded: Optional[str] = None
@@ -1538,7 +1539,8 @@ class ConsensusGateway:
             t_q = time.monotonic()
             try:
                 ticket = self.admission.admit(
-                    ctx, probe=leader_probe, priority=req.priority
+                    ctx, probe=leader_probe, priority=req.priority,
+                    trace=req.trace_id,
                 )
             except ClientGone:
                 # Dropped at dequeue. A follower racing in between the
@@ -1586,7 +1588,8 @@ class ConsensusGateway:
                         flight.publish(kind, model, text)
                         respond.chunk(kind, model, text)
 
-                    out = self.scheduler.execute(session, req, emit=emit)
+                    out = self.scheduler.execute(
+                        session, req, emit=emit, arrival_ns=t0_ns)
             except BaseException as err:
                 if resident is not None and resident.migrated:
                     # The failure is retire() shipping this stream out —
@@ -1713,6 +1716,10 @@ class _Responder:
     def _envelope(self, out, run_id: str, cached: bool, coalesced: bool,
                   degraded=None) -> dict:
         doc = out.to_dict()
+        if cached or coalesced:
+            # The timings are the executed run's own; a reply that did not
+            # execute has none.
+            doc.pop("timings", None)
         doc["run_id"] = run_id
         doc["cached"] = cached
         doc["coalesced"] = coalesced

@@ -98,6 +98,53 @@ class RunSession:
     ctx: Context
 
 
+def run_timings(arrival_ns: int, run_ns: int, judge_ns: int, end_ns: int,
+                marks: Optional[dict]) -> Optional[dict]:
+    """Where one served run's time went, from the clock reads its spans
+    made (``time.monotonic_ns``): the request's arrival, the start of
+    ``consensus_run``, the start of the judge's ``worker`` span, the end
+    of ``consensus_run``, and the judge stream's marks through its pool
+    (engine/batcher.py). Six consecutive stretches that share their
+    boundaries, so they sum to ``total_ms``:
+
+      queue_ms              arrival → the run starts (cache, coalescing,
+                            admission queue, run dir)
+      panel_ms              → the judge's worker starts (fan-out, every
+                            panel answer, agreement, judge prompt render)
+      judge_queue_ms        → the first ``pool.admit`` wave that carries
+                            this run's judge prompt is dispatched
+      judge_prefill_ms      → its first token is on the host (prefill and
+                            the first decode chunk it rides down with)
+      judge_first_chunk_ms  → its first text is handed to the stream
+      judge_decode_ms       → the run ends
+
+    None when the judge reported no marks (a single-response passthrough,
+    a remote or unpooled judge): the block is then left out."""
+    if not marks:
+        return None
+    admit = marks.get("admit_ns")
+    first = marks.get("first_token_ns")
+    if admit is None or first is None:
+        return None
+    edges = [
+        arrival_ns, run_ns, judge_ns, admit, first,
+        marks.get("first_chunk_ns", first), end_ns,
+    ]
+    # A boundary never precedes the one before it: a prompt already
+    # admitted by an overlapping judge prefill clamps to the worker start.
+    for i in range(1, len(edges)):
+        edges[i] = max(edges[i], edges[i - 1])
+    names = ("queue_ms", "panel_ms", "judge_queue_ms", "judge_prefill_ms",
+             "judge_first_chunk_ms", "judge_decode_ms")
+    out = {
+        name: (b - a) / 1e6 for name, a, b in zip(names, edges, edges[1:])
+    }
+    out["total_ms"] = (edges[-1] - edges[0]) / 1e6
+    out["judge_prompt_tokens"] = marks.get("prompt_tokens")
+    out["judge_tokens"] = marks.get("tokens")
+    return out
+
+
 class Scheduler:
     """Executes consensus runs over a shared registry of warm providers."""
 
@@ -126,7 +173,7 @@ class Scheduler:
         # keeps multi-gateway tests per-replica; production binds the
         # process singleton.
         self._live = live if live is not None else obs.live.metrics()
-        self._bb = obs.blackbox.ring()
+        self._spans = obs.emitter()
 
     # -- sessions ------------------------------------------------------------
 
@@ -177,12 +224,15 @@ class Scheduler:
         session: RunSession,
         req: ServeRequest,
         emit: Optional[EmitFn] = None,
+        arrival_ns: Optional[int] = None,
     ) -> output_mod.Result:
         """Run panel fan-out + judge synthesis for one request.
 
         Streams through ``emit``; persists into the session's run dir;
         returns the finished Result. Raises on total failure (all panel
-        models failed, judge failed, deadline expired)."""
+        models failed, judge failed, deadline expired). ``arrival_ns``
+        is when the request reached the gateway (the ``request`` span's
+        start): the result's ``timings`` count from there."""
         ctx = session.ctx
         import time as _time
 
@@ -243,10 +293,13 @@ class Scheduler:
             judge_cb = None
             if emit is not None:
                 judge_cb = lambda c: emit("judge_chunk", req.judge, c)  # noqa: E731
-            t0_judge = _time.monotonic()
-            consensus = judge.synthesize_stream(
-                ctx, req.prompt, result.responses, judge_cb
-            )
+            with self._spans.span(
+                "worker", "runner", model=req.judge, role="judge",
+                trace=req.trace_id,
+            ) as judge_span:
+                consensus = judge.synthesize_stream(
+                    ctx, req.prompt, result.responses, judge_cb
+                )
             if self._live is not None:
                 from llm_consensus_tpu.obs.live import class_label
 
@@ -255,7 +308,8 @@ class Scheduler:
                 # request's own panel class, the same derivation the
                 # Judge itself runs under).
                 self._live.observe(
-                    "judge_synthesis", _time.monotonic() - t0_judge,
+                    "judge_synthesis",
+                    (judge_span.t1_ns - judge_span.t0_ns) / 1e9,
                     outcome="ok",
                     **{"class": class_label(max(0, req.priority - 1))},
                 )
@@ -277,15 +331,15 @@ class Scheduler:
                 self.runs_executed += 1
             if self._obs is not None:
                 self._obs.count("serve.runs")
-                self._obs.complete(
-                    "consensus_run", t0_run, tid="serve",
-                    trace=req.trace_id, run_id=session.run_id,
-                )
-            if self._bb is not None:
-                self._bb.complete(
-                    "consensus_run", t0_run, tid="serve",
-                    trace=req.trace_id, run_id=session.run_id,
-                )
+            t1_run = self._spans.complete(
+                "consensus_run", t0_run, "serve",
+                trace=req.trace_id, run_id=session.run_id,
+            )
+            out.timings = run_timings(
+                arrival_ns if arrival_ns is not None else t0_run, t0_run,
+                judge_span.t0_ns, t1_run,
+                getattr(judge, "last_marks", None),
+            )
             self.persist(session, out, telemetry=True)
             return out
         finally:

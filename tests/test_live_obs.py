@@ -701,7 +701,7 @@ def test_engine_crash_dumps_blackbox_with_events_off(tmp_path):
         doc = obs_export.load_trace(fr.last_path)
         names = obs_export.trace_span_names(doc)
         # The dump holds decode spans from BEFORE the crash.
-        assert "decode" in names, names
+        assert "pool.decode" in names, names
         instants = {
             e["name"] for e in doc["traceEvents"]
             if isinstance(e, dict) and e.get("ph") == "i"

@@ -93,8 +93,13 @@ def test_interleaved_admission_byte_identical(engine):
     for got, ref in zip(classic, refs):
         assert got.token_ids == ref.token_ids
     # The wave really was paced between decode chunks, not admitted
-    # classically (the classic span set has no prefill_interleave).
-    assert "prefill_interleave" in rec.span_names()
+    # classically (a classic wave's pool.admit is not ``interleaved``).
+    credits = [
+        e for e in rec.events()
+        if e.name == "pool.admit" and e.args.get("interleaved")
+    ]
+    assert credits and credits[-1].args["done"] is True
+    assert credits[-1].args["slot_tokens"] >= credits[-1].args["tokens_real"]
 
 
 def test_admission_session_paced_equals_one_shot(engine):

@@ -378,7 +378,7 @@ class TestBatchedSpec:
             assert results[1].token_ids == rb.token_ids
             # Deterministic given fixed weights: stream B outlives A and
             # drives the frontier to capacity, so the slide really ran.
-            assert "compact" in obs.recorder().span_names()
+            assert "pool.compact" in obs.recorder().span_names()
         finally:
             obs.reset()
 
