@@ -1295,10 +1295,33 @@ class ContinuousBatcher:
         fused splice), and nondeterministic burst splits otherwise keep
         discovering new sizes — a fresh full-model compile landing
         inside serving traffic. The floor caps the variant set at 3 per
-        pool; padding rows repeat row 0 (idempotent), costing only
-        amortized admission-prefill FLOPs."""
+        pool; padding rows repeat row 0 (idempotent). They are real
+        rows to the device: nearly free while a row is at most one
+        prefill chunk (the wave is weights-bound), full price beyond it
+        (measured on a v5e: a lone 1.7k-token prompt padded to six rows
+        took six times one row's prefill), which is why
+        ``_singles_cover_fewer`` sends such waves row by row."""
         k_pad = 1 << (k - 1).bit_length()
         return min(max(k_pad, self.max_batch // 4, 8), self.max_batch)
+
+    def _singles_cover_fewer(self, lens: list[int]) -> bool:
+        """Whether a full-prompt wave of rows ``lens`` long should be
+        admitted row by row (``_admit``) instead of as one padded wave
+        (``_admit_batch``): the path that dispatches fewer token slots.
+
+        Only for waves whose rows are EACH longer than one prefill
+        chunk: a chunk already streams the weights once per
+        ``prefill_chunk`` tokens, so such a wave is compute-bound and
+        batching its rows buys nothing, while every padding row and
+        every slot a short row is padded to the longest's bucket costs
+        what a real one does. Shorter rows are weights-bound: one padded
+        wave streams the weights once for all of them, and stays."""
+        eng = self.engine
+        chunk = eng.prefill_chunk
+        if not chunk or min(lens) <= chunk:
+            return False
+        batched = self._wave_k_pad(len(lens)) * eng._rows_bucket(max(lens))
+        return sum(-(-n // chunk) * chunk for n in lens) < batched
 
     def _install_wave(self, batch, prefix_p: int, k_pad: int,
                       last_logits, pcache, width: int) -> tuple:
@@ -1448,8 +1471,8 @@ class ContinuousBatcher:
         that installs it."""
         wave = self._pending_wave
         with self._spans.span(
-            "pool.admit", self._tid, model=self._model, interleaved=True,
-            exhaust=exhaust, rows_real=len(wave.batch),
+            "pool.admit", self._tid, model=self._model, route="rows",
+            interleaved=True, exhaust=exhaust, rows_real=len(wave.batch),
             rows_padded=wave.k_pad, prefix=wave.wave_p,
             traces=[s.trace for _, _, s in wave.batch if s.trace],
         ) as sp:
@@ -2912,7 +2935,15 @@ class ContinuousBatcher:
                             # the loop below): one wave at a time, later
                             # arrivals queue until it installs.
                             batch = []
-                    if batch:
+                    if batch and not wave_p and self._singles_cover_fewer(
+                        [len(i2) for _, i2, _ in batch]
+                    ):
+                        # Long rows, few of them: the one-row path
+                        # covers fewer token slots than the padded wave.
+                        # (Suffix waves stay batched: a single row
+                        # cannot join the pool's prefix.)
+                        batch_singles = batch
+                    elif batch:
                         # Any admission work makes the next arrival
                         # interval impure for decode-phase accounting,
                         # even if the prefill fails and emits no firsts.
@@ -2937,8 +2968,8 @@ class ContinuousBatcher:
                         # last chunk dispatched (the device runs on).
                         with self._spans.span(
                             "pool.admit", self._tid, model=self._model,
-                            rows_real=len(batch), tokens_real=tokens_real,
-                            prefix=wave_p,
+                            route="rows", rows_real=len(batch),
+                            tokens_real=tokens_real, prefix=wave_p,
                             traces=[s.trace for _, _, s in batch if s.trace],
                         ) as sp:
                             for _, _, s in batch:
@@ -2991,10 +3022,11 @@ class ContinuousBatcher:
                         else:
                             firsts += admitted
                 for slot, ids, stream in batch_singles:
-                    # The single-stream fallback splices the FULL prompt
-                    # (it never joins the shared prefix), so a row that
-                    # was admitted under suffix accounting must re-check
-                    # the full-window fit before _admit can misalign it.
+                    # The one-row path (chosen above, or the fallback of
+                    # a failed wave) splices the FULL prompt (it never
+                    # joins the shared prefix), so a row that was
+                    # admitted under suffix accounting must re-check the
+                    # full-window fit before _admit can misalign it.
                     n = len(ids)
                     if n > self._pos or (
                         (self._pos - n) + _bucket(n, eng.max_seq)
@@ -3018,8 +3050,8 @@ class ContinuousBatcher:
                     admit_ok = False
                     with self._spans.span(
                         "pool.admit", self._tid, model=self._model,
-                        rows_real=1, rows_padded=1, tokens_real=len(ids),
-                        prefix=0,
+                        route="single", rows_real=1, rows_padded=1,
+                        tokens_real=len(ids), prefix=0,
                         traces=[stream.trace] if stream.trace else [],
                     ) as sp:
                         stream.marks.setdefault("admit_ns", sp.t0_ns)

@@ -534,9 +534,15 @@ def test_wave_prefix_reuse_across_bursts():
 
     cfg = get_config("tiny-llama")
     params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    eng = Engine(cfg, params=params, dtype=jnp.float32, max_seq=256,
+    eng = Engine(cfg, params=params, dtype=jnp.float32, max_seq=512,
                  stream_interval=8, prefill_chunk=16)
+    # 245-247 tokens a row: the two rows of a two-row pool fill their
+    # 256-slot bucket, so the full wave stays batched (a wave of long
+    # rows that row by row would cover fewer slots goes through _admit).
+    # The rows part after ~136 tokens, under LLMC_POOL_PREFIX_MIN: the
+    # reuse under test is the engine snapshot's, not the pool's prefix.
     shared = "shared panel prompt prefix " * 5  # ~135 tokens, ~8 chunks
+    tail = "y " * 45
     s = SamplingParams(max_new_tokens=6, ignore_eos=True)
     chunk_calls = []
     real_chunk = eng_mod._prefill_chunk
@@ -548,13 +554,13 @@ def test_wave_prefix_reuse_across_bursts():
     eng_mod._prefill_chunk = spy
     b, gate = _gated_batcher(eng, max_batch=2)
     try:
-        w1 = [shared + f"wave one tail {i}" for i in range(2)]
+        w1 = [shared + f"{i} first wave tail " + tail for i in range(2)]
         futs = [b.submit(p, s) for p in w1]
         gate.set()
         r1 = [f.result(timeout=300) for f in futs]
         wave1_chunks = len(chunk_calls)
         chunk_calls.clear()
-        w2 = [shared + f"second wave tail {i}" for i in range(2)]
+        w2 = [shared + f"{i} second wave tail " + tail for i in range(2)]
         futs = [b.submit(p, s) for p in w2]
         r2 = [f.result(timeout=300) for f in futs]
         wave2_chunks = len(chunk_calls)
@@ -564,9 +570,92 @@ def test_wave_prefix_reuse_across_bursts():
         b.close()
     assert wave2_chunks < wave1_chunks, (wave1_chunks, wave2_chunks)
     for p, r in zip(w1 + w2, r1 + r2):
-        ref = Engine(cfg, params=params, dtype=jnp.float32, max_seq=256,
+        ref = Engine(cfg, params=params, dtype=jnp.float32, max_seq=512,
                      stream_interval=8, prefill_chunk=16).generate(p, s)
         assert r.token_ids == ref.token_ids, p
+
+
+def _rows(lens, shared=0):
+    """Token ids for rows ``lens`` long that part at token ``shared``
+    (0: no two rows share even their first token)."""
+    head = [3 + j % 190 for j in range(shared)]
+    return [head + [5 + (11 * i + 7 * j) % 190 for j in range(n - shared)]
+            for i, n in enumerate(lens)]
+
+
+# chunk 16, so a "long" row is over 16 tokens. Each case: the wave's row
+# lengths, the pool's rows, the tokens the wave's rows share, and the
+# (route, rows_real, rows_padded, slot_tokens) of every pool.admit span
+# the wave must leave, in order.
+_ROUTE_CASES = {
+    # a lone long row: one row, not six copies of it
+    "lone-long": ([40], 6, 0, [("single", 1, 1, 48)]),
+    # three long rows of six: 48 + 48 + 64 slots, against 6 x 64 batched
+    "three-long-of-six": (
+        [40, 37, 50], 6, 0,
+        [("single", 1, 1, 48), ("single", 1, 1, 48), ("single", 1, 1, 64)],
+    ),
+    # two long rows of four: 48 + 64 slots, against 4 x 64 batched
+    "two-long-of-four": ([40, 50], 4, 0,
+                         [("single", 1, 1, 48), ("single", 1, 1, 64)]),
+    # a full wave of long rows that fill their bucket: 6 x 64 either way,
+    # so one wave (fewer dispatches)
+    "full-wave-long": ([60, 64, 58, 61, 63, 59], 6, 0,
+                       [("rows", 6, 6, 6 * 64)]),
+    # a lone short row: weights-bound, one padded one-shot wave as ever
+    "lone-short": ([10], 6, 0, [("rows", 1, 6, 6 * 16)]),
+    # one row within a chunk keeps the whole wave batched
+    "short-beside-long": ([12, 40], 6, 0, [("rows", 2, 6, 6 * 64)]),
+    # a shared-prefix suffix wave (row by row would cover 2 x 240 slots
+    # against 6 x 32): the one-row path cannot join the pool's prefix
+    "suffix-wave": ([230, 225], 6, 200, [("rows", 2, 6, 6 * 32)]),
+}
+
+
+@pytest.mark.parametrize("case", list(_ROUTE_CASES))
+def test_admission_wave_takes_the_route_that_covers_fewer_slots(case):
+    """ISSUE 24: a wave whose rows are each longer than a prefill chunk
+    goes row by row when that dispatches fewer token slots than the
+    padded wave; every other wave is admitted as before. Whatever the
+    route, no attempt fails and the greedy tokens are ``generate_ids``'s."""
+    from llm_consensus_tpu.obs import blackbox
+    from llm_consensus_tpu.obs.blackbox import FlightRecorder
+
+    lens, max_batch, shared, want = _ROUTE_CASES[case]
+    cfg = get_config("tiny-llama")
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    ring = FlightRecorder(capacity=1024)
+    blackbox.install(ring)  # before the pool: emitters bind at construction
+    try:
+        eng = Engine(cfg, params=params, dtype=jnp.float32, max_seq=512,
+                     stream_interval=8, prefill_chunk=16)
+        b, gate = _gated_batcher(eng, max_batch=max_batch)
+        s = SamplingParams(max_new_tokens=6, ignore_eos=True)
+        rows = _rows(lens, shared)
+        try:
+            futs = [b.submit_ids(ids, s) for ids in rows]
+            gate.set()
+            results = [f.result(timeout=300) for f in futs]
+            st = b.snapshot()
+        finally:
+            gate.set()
+            b.close()
+    finally:
+        blackbox.reset()
+    admits = [e.args for e in ring.snapshot()
+              if e.name == "pool.admit" and e.tid == "pool:tiny-llama"]
+    assert all(a["ok"] for a in admits), admits
+    assert [(a["route"], a["rows_real"], a["rows_padded"], a["slot_tokens"])
+            for a in admits] == want
+    assert [a["prefix"] for a in admits] == [shared] * len(want)
+    assert st["prefill_waves"] == len(want)
+    assert st["prefill_rows_real"] == len(lens)
+    assert st["prefill_rows_padded"] == sum(w[2] for w in want)
+    assert st["prefill_slot_tokens"] == sum(w[3] for w in want)
+    assert st["admit_tokens"] == sum(lens) - shared * len(lens)
+    # after the pool's results: generate_ids retains its prompt's KV
+    for ids, r in zip(rows, results):
+        assert r.token_ids == eng.generate_ids(ids, s).token_ids
 
 
 def test_large_seed_admission_not_pool_fatal(engine):
@@ -596,7 +685,12 @@ def test_wave_admission_non_chunk_multiple_capacity():
     assert eng._rows_bucket(150) % 16 != 0  # the hazard shape
     b, gate = _gated_batcher(eng, max_batch=2)
     s = SamplingParams(max_new_tokens=6, ignore_eos=True)
-    prompts = ["x " * 70 + "one", "x " * 70 + "two"]  # ~140+ tokens each
+    # 193 tokens each: a full wave whose rows fill the 200-slot bucket
+    # (padded to chunks they would cover 208), so it stays one wave;
+    # shorter rows would be admitted one by one.
+    prompts = ["x " * 94 + "yone", "x " * 94 + "ytwo"]
+    assert not b._singles_cover_fewer(
+        [len(eng.tokenizer.encode(p)) for p in prompts])
     try:
         futs = [b.submit(p, s) for p in prompts]
         gate.set()
