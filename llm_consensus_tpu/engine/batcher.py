@@ -509,15 +509,7 @@ class ContinuousBatcher:
         self._prefix_weight_version = -1  # engine version that built it
         self._plen = place(jnp.zeros((), jnp.int32))
         self._prefix_rows = place(jnp.zeros((max_batch,), jnp.bool_))
-        from llm_consensus_tpu.models import init_kv_cache
-
-        cache = init_kv_cache(
-            engine.cfg, batch=max_batch, max_seq=engine.max_seq,
-            dtype=engine._dtype, quant=engine.kv_quant,
-        )
-        if engine._shard_fn is not None:
-            cache = engine._shard_fn(cache)
-        self._cache = cache
+        self._cache = engine.new_cache(max_batch)
         # Occupancy row-bucketing (the dead-slot-stepping fix): the pool
         # cache starts at full capacity, but when occupancy falls below
         # half the CURRENT row capacity for a few consecutive chunks,

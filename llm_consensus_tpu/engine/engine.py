@@ -543,8 +543,10 @@ class Engine:
         kv_quant: Optional[str] = None,
         kv_pool: bool = True,
     ):
+        t_build_ns = time.monotonic_ns()
         self.cfg = cfg
         self.mesh = mesh
+        caller_shard_fn = shard_fn is not None
         if mesh is not None and shard_fn is None:
             from llm_consensus_tpu.parallel.sharding import make_shard_fn
 
@@ -660,41 +662,39 @@ class Engine:
         # _dtype/kv_quant/_shard_fn are set so the arena shards like a
         # working cache.
         self._kv_pool = None
+        self._cache_makers: dict = {}  # (rows, slots) -> new_cache's program
         caller_params = params is not None
-        streamed_init = False
-        if params is None:
-            # The provider's planner pins even 1-chip engines to a mesh,
-            # which sets shard_fn — but on a one-device mesh "sharding"
-            # is plain replication, so the streamed path serves it too
-            # (the round-4 8B ladder OOM'd exactly here: the full bf16
-            # tree materialized before quantization).
-            one_dev = mesh is not None and mesh.devices.size == 1
-            if quant in ("int8", "int4") and (shard_fn is None or one_dev):
-                # Streamed init-quantization: each weight quantizes as it
-                # is created, so peak HBM is the quantized tree + one
-                # bf16 leaf — an 8B-class random init fits one 16 GB
-                # chip, where init-then-quantize OOMs at the bf16 tree.
-                # (Multi-device engines keep init→shard→quantize: the
-                # bf16 tree is split across the slice's chips.)
+        if params is None and caller_shard_fn:
+            # A caller's own placement function can only be handed a
+            # whole tree; quantization follows it (the spec tree matches
+            # the unquantized structure).
+            params = shard_fn(
+                init_params(cfg, jax.random.PRNGKey(seed), dtype=dtype)
+            )
+        elif params is None:
+            # Each leaf is made under its own sharding on the engine's
+            # mesh (one-chip meshes included: the planner pins those too,
+            # and replication on one device is the device itself), and
+            # quantized there as it is made: no chip ever holds more than
+            # its share of the stored tree plus one leaf, and none outside
+            # the mesh holds anything. Init-then-shard built the whole
+            # tree on the default device first: an 8B ladder OOM'd there
+            # before quantization (round 4), and a 14.5 GB bf16 tree for a
+            # tp=2 slice did beside another engine's weights (PR 22).
+            shardings = None
+            if mesh is not None:
+                from llm_consensus_tpu.parallel.sharding import param_shardings
+
+                shardings = param_shardings(cfg, mesh)
+            make = init_params
+            if quant in ("int8", "int4"):
                 from llm_consensus_tpu.ops.quant import init_params_quantized
 
-                params = init_params_quantized(
-                    cfg, jax.random.PRNGKey(seed), dtype=dtype, mode=quant
-                )
-                if one_dev:
-                    from jax.sharding import NamedSharding, PartitionSpec
-
-                    # Physically identical to what shard_fn would build
-                    # on a 1-device mesh; shard_fn itself can't run on
-                    # the quantized tree (its spec tree matches the
-                    # unquantized structure).
-                    params = jax.device_put(
-                        params, NamedSharding(mesh, PartitionSpec())
-                    )
-                streamed_init = True
-            else:
-                params = init_params(cfg, jax.random.PRNGKey(seed), dtype=dtype)
-        if shard_fn is not None and not streamed_init:
+                make = partial(init_params_quantized, mode=quant)
+            params = make(
+                cfg, jax.random.PRNGKey(seed), dtype=dtype, shardings=shardings
+            )
+        elif shard_fn is not None:
             params = shard_fn(params)
         if quant in ("int8", "int4"):
             from llm_consensus_tpu.ops.quant import quantize_params
@@ -702,11 +702,14 @@ class Engine:
             # Donate only params we created: device_put in shard_fn can
             # alias (not copy) when shardings already match, so even
             # post-shard trees may share buffers with a caller's arrays.
-            # Idempotent for the streamed-init path above (is_quantized
+            # Idempotent for the leaf-by-leaf init above (is_quantized
             # leaves pass through).
             params = quantize_params(params, donate=not caller_params, mode=quant)
         self.params = params
         self._shard_fn = shard_fn
+        # Dispatch is asynchronous: the tree is there when this returns.
+        jax.block_until_ready(params)
+        init_s = (time.monotonic_ns() - t_build_ns) / 1e9
         # Live weight hot-swap (flywheel): double-buffered checkpoint
         # flip. ``swap_weights`` prepares the incoming version to the
         # side (shard + quantize, never under a lock), then flips
@@ -794,6 +797,25 @@ class Engine:
         # component key. Classic single-snapshot prefix reuse still
         # applies, so shared-prefix handoff waves keep their fork reuse.
         self._kv_pool = pool_for(self) if kv_pool else None
+        # What the build cost and where the tree lives (/statsz
+        # ``device.engines.<model>``, and the ``engine.build`` span).
+        per_chip: dict = {}
+        for leaf in jax.tree.leaves(params):
+            for shard in leaf.addressable_shards:
+                per_chip[shard.device.id] = (
+                    per_chip.get(shard.device.id, 0) + shard.data.nbytes
+                )
+        self.build_stats = {
+            "tp": int(dict(mesh.shape).get("tp", 1)) if mesh is not None else 1,
+            "param_bytes_per_chip": max(per_chip.values(), default=0),
+            "build_s": round((time.monotonic_ns() - t_build_ns) / 1e9, 3),
+        }
+        self._spans.complete(
+            "engine.build", t_build_ns, "engine", model=cfg.name,
+            devices=sorted(per_chip), tp=self.build_stats["tp"],
+            param_bytes_per_chip=self.build_stats["param_bytes_per_chip"],
+            init_s=round(init_s, 3),
+        )
 
     def _flash_guard(self, dispatch: Callable[[str], tuple]):
         """Run a jitted dispatch parameterized on attention impl; if the
@@ -829,6 +851,33 @@ class Engine:
             self.attn_impl = "xla"
             self.flash_fallbacks += 1
             return dispatch("xla")
+
+    def new_cache(self, batch: int, max_seq: Optional[int] = None) -> dict:
+        """A zeroed key/value cache of ``batch`` rows (``max_seq``: the
+        engine's capacity unless given), made where it will live: on an
+        engine with a mesh one jitted program per shape writes each
+        chip's shard in place (``out_shardings``), so a pool's or a wave's
+        cache never exists on the default device first — six rows of a 7B
+        tp=2 pool were 3.2 GB made on chip 0 and then moved, and every
+        judge admission half a gigabyte more (PR 25, on the chip)."""
+        key = (batch, max_seq or self.max_seq)
+        make = self._cache_makers.get(key)
+        if make is None:
+            make = partial(
+                init_kv_cache, self.cfg, batch=batch, max_seq=key[1],
+                dtype=self._dtype, quant=self.kv_quant,
+            )
+            if self.mesh is not None:
+                from llm_consensus_tpu.parallel.sharding import cache_shardings
+
+                make = jax.jit(make, out_shardings=cache_shardings(
+                    self.cfg, self.mesh, jax.eval_shape(make)
+                ))
+            elif self._shard_fn is not None:
+                whole, place = make, self._shard_fn
+                make = lambda: place(whole())  # noqa: E731 — a caller's own placement
+            self._cache_makers[key] = make
+        return make()
 
     def attention_stats(self) -> dict:
         """The attention impl this engine was built with and runs now,
@@ -1223,12 +1272,7 @@ class Engine:
             and reuse_len + n_tail * chunk_len <= self.max_seq
         )
         if not reuse_ok:
-            cache = init_kv_cache(
-                cfg, batch=1, max_seq=self.max_seq, dtype=self._dtype,
-                quant=self.kv_quant,
-            )
-            if self._shard_fn is not None:
-                cache = self._shard_fn(cache)
+            cache = self.new_cache(1)
         # Ring attention shards the bucket over sp; a bucket clamped to a
         # non-divisible max_seq can't, so it falls through to the
         # replicated-over-sp paths below (correct, just not seq-sharded).
@@ -1672,12 +1716,7 @@ class Engine:
         padded = [[0] * s + r for s, r in zip(row_start_list, rows)]
         row_start = self._place(jnp.asarray(row_start_list, jnp.int32))
         last_index = self._place(jnp.full((b,), bucket - 1, jnp.int32))
-        cache = init_kv_cache(
-            cfg, batch=b, max_seq=self.max_seq, dtype=self._dtype,
-            quant=self.kv_quant,
-        )
-        if self._shard_fn is not None:
-            cache = self._shard_fn(cache)
+        cache = self.new_cache(b)
         if use_chunks:
             n_chunks = bucket // chunk_len
             last_in_chunk = self._place(
@@ -1932,13 +1971,10 @@ class AdmissionPrefill:
                 engine._place(jnp.asarray(reuse_base, jnp.int32)),
                 self.k, self.width,
             )
+            if engine._shard_fn is not None:
+                cache = engine._shard_fn(cache)
         else:
-            cache = init_kv_cache(
-                engine.cfg, batch=self.k, max_seq=self.width,
-                dtype=engine._dtype, quant=engine.kv_quant,
-            )
-        if engine._shard_fn is not None:
-            cache = engine._shard_fn(cache)
+            cache = engine.new_cache(self.k, self.width)
         self._cache = cache
         self._padded = [r + [0] * (self.width - len(r)) for r in rows]
         self._plen_dev = (
@@ -2096,12 +2132,7 @@ class AdmissionPrefill:
                 else eng._prefix_ids != tuple(self.rows[0])
             )
         ):
-            template = init_kv_cache(
-                eng.cfg, batch=1, max_seq=eng.max_seq, dtype=eng._dtype,
-                quant=eng.kv_quant,
-            )
-            if eng._shard_fn is not None:
-                template = eng._shard_fn(template)
+            template = eng.new_cache(1)
             eng._retain_prefix(
                 self.rows[0], _extract_row0(template, cache, self.width)
             )
@@ -2160,13 +2191,7 @@ class PrefillSession:
         # a swap landed between appends, so the cache never mixes KV
         # from two weight versions.
         self._weight_version = engine.weight_version
-        cache = init_kv_cache(
-            engine.cfg, batch=1, max_seq=engine.max_seq,
-            dtype=engine._dtype, quant=engine.kv_quant,
-        )
-        if engine._shard_fn is not None:
-            cache = engine._shard_fn(cache)
-        self._cache = cache
+        self._cache = engine.new_cache(1)
 
     @property
     def tokens(self) -> int:
@@ -2300,13 +2325,7 @@ class PrefillSession:
                 # prompt pass, never correctness.
                 self._base = 0
                 self._last_logits = None
-                cache = init_kv_cache(
-                    eng.cfg, batch=1, max_seq=eng.max_seq,
-                    dtype=eng._dtype, quant=eng.kv_quant,
-                )
-                if eng._shard_fn is not None:
-                    cache = eng._shard_fn(cache)
-                self._cache = cache
+                self._cache = eng.new_cache(1)
                 self._weight_version = eng.weight_version
                 pending = self._ids
                 self._ids = []

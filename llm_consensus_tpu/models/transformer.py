@@ -81,10 +81,21 @@ attention_routes = AttentionRoutes()
 
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
-                leaf_hook=None) -> dict:
+                leaf_hook=None, shardings: Optional[dict] = None) -> dict:
     """Random-init parameter pytree (layers stacked on axis 0).
 
-    ``leaf_hook(name, array) -> array`` transforms each weight AS it is
+    ``shardings`` (a tree of ``jax.sharding.Sharding`` shaped like the
+    result: ``parallel.sharding.param_shardings``) makes every leaf
+    directly under its sharding, one jitted program a leaf with that
+    ``out_shardings``: each device generates its own shard, so no device
+    ever holds a leaf whole unless its spec says so, and nothing lands on
+    a device outside the shardings' mesh. A 14.5 GB bf16 tree for a tp=2
+    slice then costs each of its chips half, where init-then-shard built
+    all of it on the default device first. The values do not depend on the
+    sharding (the threefry generator is partitionable and nothing here
+    reduces), nor on the hook below.
+
+    ``leaf_hook(name, array) -> array`` transforms each leaf AS it is
     created — ops/quant.init_params_quantized uses it to quantize
     leaf-by-leaf so peak HBM is the quantized tree plus ONE bf16 leaf,
     not the full bf16 tree (the difference between an 8B random init
@@ -93,41 +104,48 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
     quantization produce identical values.
     """
     keys = iter(jax.random.split(key, 16))
+    # Leaf names are unique across the two levels of the tree.
+    sharding_of = {**shardings, **shardings["layers"]} if shardings else {}
 
-    def normal(k, shape, std, name=""):
+    def make(name, fn, *args):
         # Jitted so XLA fuses normal→scale→astype into one kernel that
         # writes ``dtype`` directly: the eager form materializes the
         # float32 intermediate, and on an 8B model that is a 7.5 GB
         # transient PER STACKED LEAF — the difference between the
         # streamed-quantized init fitting one 16 GB chip or not.
         # Values are identical (same op chain, same key).
-        w = jax.jit(
+        w = jax.jit(fn, out_shardings=sharding_of.get(name))(*args)
+        return leaf_hook(name, w) if leaf_hook is not None else w
+
+    def normal(k, shape, std, name):
+        return make(
+            name,
             lambda kk: (
                 jax.random.normal(kk, shape, jnp.float32) * std
-            ).astype(dtype)
-        )(k)
-        return leaf_hook(name, w) if leaf_hook is not None else w
+            ).astype(dtype),
+            k,
+        )
+
+    def norm(shape, name):
+        # offset parameterization: stored weights are (w - offset), init 0
+        fill = jnp.zeros if cfg.norm_offset else jnp.ones
+        return make(name, lambda: fill(shape, dtype))
 
     d, dh, hq, hkv, f, l = (
         cfg.d_model, cfg.head_dim, cfg.n_heads, cfg.n_kv_heads, cfg.d_ff, cfg.n_layers,
     )
     proj_std = d ** -0.5
     layers: dict = {
-        "attn_norm": jnp.ones((l, d), dtype),
-        "mlp_norm": jnp.ones((l, d), dtype),
+        "attn_norm": norm((l, d), "attn_norm"),
+        "mlp_norm": norm((l, d), "mlp_norm"),
         "wq": normal(next(keys), (l, d, hq * dh), proj_std, "wq"),
         "wk": normal(next(keys), (l, d, hkv * dh), proj_std, "wk"),
         "wv": normal(next(keys), (l, d, hkv * dh), proj_std, "wv"),
         "wo": normal(next(keys), (l, hq * dh, d), (hq * dh) ** -0.5, "wo"),
     }
-    if cfg.norm_offset:
-        # offset parameterization: stored weights are (w - offset), init 0
-        layers["attn_norm"] = jnp.zeros((l, d), dtype)
-        layers["mlp_norm"] = jnp.zeros((l, d), dtype)
     if cfg.qkv_bias:
-        layers["bq"] = jnp.zeros((l, hq * dh), dtype)
-        layers["bk"] = jnp.zeros((l, hkv * dh), dtype)
-        layers["bv"] = jnp.zeros((l, hkv * dh), dtype)
+        for name, width in (("bq", hq * dh), ("bk", hkv * dh), ("bv", hkv * dh)):
+            layers[name] = make(name, lambda w=width: jnp.zeros((l, w), dtype))
     if cfg.is_moe:
         e = cfg.n_experts
         layers["w_router"] = normal(next(keys), (l, d, e), proj_std, "w_router")
@@ -141,7 +159,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
 
     params = {
         "embed": normal(next(keys), (cfg.vocab_size, d), 0.02, "embed"),
-        "final_norm": (jnp.zeros if cfg.norm_offset else jnp.ones)((d,), dtype),
+        "final_norm": norm((d,), "final_norm"),
         "layers": layers,
     }
     if not cfg.tie_embeddings:

@@ -139,7 +139,7 @@ _quantize4_leaf = jax.jit(_quantize4, static_argnames=("group",))
 
 
 def init_params_quantized(cfg, key, dtype=jnp.bfloat16,
-                          mode: str = "int8") -> dict:
+                          mode: str = "int8", shardings=None) -> dict:
     """Random-init a parameter tree with every matmul weight quantized
     AS it is created (models/transformer.py init_params leaf_hook).
 
@@ -149,7 +149,9 @@ def init_params_quantized(cfg, key, dtype=jnp.bfloat16,
     3.8 GB largest leaf) and OOMing at init (16 GB bf16). Values are
     IDENTICAL to quantize_params(init_params(...), donate=True): the
     key sequence doesn't depend on the hook and the same per-leaf
-    quantizer runs either way.
+    quantizer runs either way. With ``shardings`` (init_params) each
+    leaf is made under its sharding and quantized there, as
+    quantize_params does to a tree that was sharded whole.
     """
     from llm_consensus_tpu.models.transformer import init_params
 
@@ -164,7 +166,9 @@ def init_params_quantized(cfg, key, dtype=jnp.bfloat16,
             )
             return leaf(w)
 
-    return init_params(cfg, key, dtype=dtype, leaf_hook=hook)
+    return init_params(
+        cfg, key, dtype=dtype, leaf_hook=hook, shardings=shardings
+    )
 
 
 def quantize_params(params: dict, donate: bool = False,

@@ -28,6 +28,7 @@ from llm_consensus_tpu.parallel.ring import ring_attention
 from llm_consensus_tpu.parallel.sharding import (
     cache_specs,
     make_shard_fn,
+    param_shardings,
     param_specs,
     shard_pytree,
 )
@@ -42,6 +43,7 @@ __all__ = [
     "plan_panel",
     "cache_specs",
     "make_shard_fn",
+    "param_shardings",
     "param_specs",
     "pipeline_forward",
     "ring_attention",

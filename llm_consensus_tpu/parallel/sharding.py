@@ -181,10 +181,42 @@ def abstract_param_bytes(cfg: ModelConfig, mesh: Mesh) -> tuple[int, int]:
     return acc["total"], acc["sharded"]
 
 
+def param_shardings(cfg: ModelConfig, mesh: Mesh) -> dict:
+    """``param_specs`` as ``NamedSharding``s on ``mesh``: what
+    ``init_params(shardings=...)`` makes each leaf under, so a tree larger
+    than one chip never exists whole on any chip."""
+    return jax.tree.map(
+        lambda s: NamedSharding(mesh, s), param_specs(cfg, mesh),
+        is_leaf=lambda s: isinstance(s, P),
+    )
+
+
 def shard_pytree(tree, specs, mesh: Mesh):
     """Place ``tree`` on ``mesh`` according to a matching spec pytree."""
     return jax.tree.map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), tree, specs
+    )
+
+
+def cache_shardings(cfg: ModelConfig, mesh: Mesh, cache) -> dict:
+    """``NamedSharding``s for a tree shaped like ``init_kv_cache``'s
+    (arrays or their shapes): what ``Engine.new_cache`` makes a cache
+    under, and what ``make_shard_fn`` moves one to.
+
+    int8 caches nest {"q8", "s"} under k/v: codes keep the
+    [L, B, S, Hkv, dh] layout; scales are seq-minor [L, B, Hkv, S] (heads
+    on axis 2), so their tp split moves with the head axis. Layout
+    discrimination routes through ops.quant.kv_seq_axis, the rule's
+    single owner."""
+    from llm_consensus_tpu.ops.quant import kv_seq_axis
+
+    k_spec = cache_specs(cfg, mesh)["k"]
+    s_spec = P(k_spec[0], k_spec[1], k_spec[3], k_spec[2])
+    return jax.tree.map(
+        lambda leaf: NamedSharding(
+            mesh, k_spec if kv_seq_axis(leaf) == 2 else s_spec
+        ),
+        cache,
     )
 
 
@@ -199,24 +231,8 @@ def make_shard_fn(cfg: ModelConfig, mesh: Mesh) -> Callable:
         if isinstance(tree, dict) and "embed" in tree:
             return shard_pytree(tree, param_specs(cfg, mesh), mesh)
         if isinstance(tree, dict) and set(tree) == {"k", "v"}:
-            # int8 caches nest {"q8", "s"} under k/v: codes keep the
-            # [L, B, S, Hkv, dh] layout; scales are seq-minor
-            # [L, B, Hkv, S] (heads on axis 2), so their tp split moves
-            # with the head axis. Layout discrimination routes through
-            # ops.quant.kv_seq_axis, the rule's single owner.
-            from llm_consensus_tpu.ops.quant import kv_seq_axis
-
-            k_spec = cache_specs(cfg, mesh)["k"]
-            s_spec = P(k_spec[0], k_spec[1], k_spec[3], k_spec[2])
-            return shard_pytree(
-                tree,
-                jax.tree.map(
-                    lambda leaf: (
-                        k_spec if kv_seq_axis(leaf) == 2 else s_spec
-                    ),
-                    tree,
-                ),
-                mesh,
+            return jax.tree.map(
+                jax.device_put, tree, cache_shardings(cfg, mesh, tree)
             )
         raise ValueError(f"unrecognized pytree with keys {list(tree)}")
 

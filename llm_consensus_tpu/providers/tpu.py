@@ -881,10 +881,11 @@ class TPUProvider(Provider):
         """Where this provider's engines really run — the /statsz
         ``device`` block: the backend JAX chose (platform, kind, count),
         its published peaks, per-device memory, the compile cache, and
-        per engine its devices and attention paths (impl built vs
-        running, guard fallbacks, kernel-or-XLA per phase). Empty until
-        a placement is planned or an engine built: before that this
-        provider has not touched the backend."""
+        per engine its devices, what its build cost (``tp``,
+        ``param_bytes_per_chip``, ``build_s``) and attention paths (impl
+        built vs running, guard fallbacks, kernel-or-XLA per phase).
+        Empty until a placement is planned or an engine built: before
+        that this provider has not touched the backend."""
         import jax
 
         from llm_consensus_tpu.utils import flops
@@ -923,7 +924,7 @@ class TPUProvider(Provider):
             }
         for preset, eng in engines.items():
             leaf = jax.tree.leaves(eng.params)[0]
-            entry = eng.attention_stats()
+            entry = {**eng.attention_stats(), **eng.build_stats}
             entry["devices"] = sorted(d.id for d in leaf.sharding.device_set)
             out["engines"][preset] = entry
         return out
