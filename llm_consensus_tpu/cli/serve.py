@@ -7,13 +7,20 @@ continuous batcher stay resident, and many concurrent consensus runs
 multiplex onto them.
 
 Capacity model: each concurrent run sends one stream per panel model to
-that preset's continuous batcher (``max_batch`` slots per preset), so
+that preset's continuous batcher (``max_batch`` rows per preset), so
 the admission concurrency cap and the batcher depth are the SAME budget
-viewed from two layers. ``--max-batch`` (or ``LLMC_MAX_BATCH``) sets the
-batcher depth; the default admission cap is derived from it, and an
-explicit ``--max-concurrency`` that oversubscribes the batcher is
-rejected at startup — a misconfigured server must fail fast, not queue
-inside the submit path where nothing can shed load.
+viewed from two layers. A run's phases are sequential — the scheduler
+runs the panel to the end and only then the judge (serve/scheduler.py)
+— so a judge that is also a panelist reuses the row its panel answer
+has vacated: a run holds at most one row of that pool at any time, and
+as many runs are admitted as the pool has rows. Only judge overlap
+(``--judge-overlap`` / ``LLMC_JUDGE_OVERLAP=1``) opens the judge's
+stream while the panel still decodes, and then it counts as one more.
+``--max-batch`` (or ``LLMC_MAX_BATCH``) sets the batcher depth; the
+default admission cap is derived from it, and an explicit
+``--max-concurrency`` that oversubscribes the batcher is rejected at
+startup — a misconfigured server must fail fast, not queue inside the
+submit path where nothing can shed load.
 
 SIGTERM/SIGINT drain gracefully: stop admitting (new requests get 503 +
 ``Retry-After``), finish in-flight runs, flush every ``data/<run-id>/``,
@@ -235,27 +242,40 @@ def parse_serve_args(argv: list[str]) -> ServeConfig:
     )
 
 
-def _tpu_multiplicity(models: list[str], judge: str) -> int:
-    """Peak concurrent streams one tpu preset sees from ONE run.
+def _tpu_multiplicity(models: list[str], judge: str,
+                      judge_overlap: bool = False) -> int:
+    """Peak streams ONE run holds on one tpu preset at the same time.
 
-    A preset asked for N times in the panel contributes N concurrent
-    streams; a judge sharing a panel preset can overlap another run's
-    panel query on that preset, so it counts too."""
+    A preset asked for N times in the panel holds N rows while the
+    panel decodes. The judge runs after every panel answer is complete,
+    so its stream takes a row the run's panel phase has already given
+    back: a judge that is also a panelist adds nothing, and a judge on a
+    preset of its own holds one. With judge overlap the judge's session
+    opens while the panel still decodes, so it is one stream more."""
     from llm_consensus_tpu.providers.tpu import SCHEME, parse_model_name
 
     counts: dict[str, int] = {}
-    for m in models + [judge]:
+    for m in models:
         if m.startswith(SCHEME):
             preset = parse_model_name(m)
             counts[preset] = counts.get(preset, 0) + 1
+    if judge.startswith(SCHEME):
+        preset = parse_model_name(judge)
+        panel = counts.get(preset, 0)
+        counts[preset] = panel + 1 if judge_overlap else max(panel, 1)
     return max(counts.values(), default=0)
 
 
 def resolve_concurrency(cfg: ServeConfig) -> int:
-    """Derive (or validate) the admission cap against batcher capacity."""
+    """Derive (or validate) the admission cap against batcher capacity:
+    the number of runs whose streams fit a pool's rows, counted as the
+    scheduler issues them (``_tpu_multiplicity``)."""
     from llm_consensus_tpu.cli.main import CLIError
+    from llm_consensus_tpu.consensus.overlap import overlap_enabled
 
-    mult = _tpu_multiplicity(cfg.models, cfg.judge)
+    mult = _tpu_multiplicity(
+        cfg.models, cfg.judge, overlap_enabled(cfg.judge_overlap or None)
+    )
     if cfg.max_concurrency is None:
         if mult == 0:
             return DEFAULT_HTTP_CONCURRENCY  # HTTP-only: no device budget

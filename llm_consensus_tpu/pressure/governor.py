@@ -5,7 +5,9 @@ One :class:`PressureGovernor` per gateway watches three signal families —
   * **admission** — queue-depth fraction and slot occupancy (latency
     already committed to clients);
   * **batcher headroom** — live + queued streams against pool capacity,
-    the worst pool across presets;
+    the worst pool across presets: rows in use alone stay under the
+    high-water mark (a full pool with nothing waiting is throughput),
+    streams queued for want of a row count in full;
   * **KV-pool pressure** — arena occupancy plus exhaustion/eviction
     *deltas* since the last sample (an exhausted publish means reuse is
     already being truncated — the silent-degradation signal operators
@@ -61,6 +63,10 @@ from llm_consensus_tpu.analysis import sanitizer
 from llm_consensus_tpu.utils import knobs
 
 LADDER = ("ok", "evict", "preempt", "brownout", "shed")
+# What capacity IN USE weighs in a signal (admission slots, a pool's
+# rows): under the default high-water mark, so a fully-utilized server
+# with nothing waiting never walks the ladder on occupancy alone.
+_IN_USE_WEIGHT = 0.7
 _RUNG = {name: i for i, name in enumerate(LADDER)}
 
 
@@ -328,7 +334,7 @@ class PressureGovernor:
                 # throughput, not overload — full slots alone must never
                 # walk the ladder; they only corroborate queue/KV/
                 # batcher pressure (pressure = max of the signals).
-                signals["slots"] = 0.7 * min(
+                signals["slots"] = _IN_USE_WEIGHT * min(
                     1.0, adm.get("active", 0)
                     / max(1, adm.get("max_concurrency", 1))
                 )
@@ -340,11 +346,18 @@ class PressureGovernor:
             if stats_fn is not None:
                 try:
                     for snap in stats_fn().values():
+                        # The slots rule above, for a pool's rows: rows
+                        # in use (or about to be seated: queued streams
+                        # a free row waits for) are throughput and stay
+                        # under the high-water mark however many; every
+                        # stream queued because no row is free is
+                        # latency already committed and counts in full.
                         cap = max(1, snap.get("cap", 1))
+                        want = snap.get("live", 0) + snap.get("queued", 0)
                         signals["batcher"] = max(
                             signals["batcher"],
-                            min(1.0, (snap.get("live", 0)
-                                      + snap.get("queued", 0)) / cap),
+                            min(1.0, (_IN_USE_WEIGHT * min(want, cap)
+                                      + max(0, want - cap)) / cap),
                         )
                 except Exception:  # noqa: BLE001
                     pass

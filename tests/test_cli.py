@@ -646,37 +646,200 @@ def test_serve_max_batch_env_alias(monkeypatch):
     assert cfg.max_batch == 3
 
 
-def test_serve_concurrency_validated_against_max_batch():
+_LLAMA, _GEMMA = "tpu:tiny-llama", "tpu:tiny-gemma"
+
+# (panel, judge, further flags, LLMC_JUDGE_OVERLAP, the cap or the error)
+_CAP_CASES = {
+    # one stream per preset per run, the judge on a preset of its own
+    "distinct-presets": (f"{_LLAMA},{_GEMMA}", "tpu:tiny-mistral",
+                         ["--max-batch", "8"], None, 8),
+    # the same preset twice in the panel doubles its per-run streams
+    "preset-twice": (f"{_LLAMA},{_LLAMA}", _GEMMA,
+                     ["--max-batch", "8"], None, 4),
+    # ISSUE 26: the judge runs after the panel, in the row its panel
+    # answer gave back, so a judge that is a panelist costs no row
+    "judge-a-panelist": (f"{_LLAMA},{_GEMMA}", _LLAMA,
+                         ["--max-batch", "8"], None, 8),
+    "judge-a-panelist-six-rows": (f"{_LLAMA},{_GEMMA}", _LLAMA,
+                                  ["--max-batch", "6"], None, 6),
+    "judge-the-preset-asked-twice": (f"{_LLAMA},{_LLAMA}", _LLAMA,
+                                     ["--max-batch", "8"], None, 4),
+    # ... unless judge overlap opens its session while the panel decodes
+    "judge-a-panelist-overlap-flag": (
+        f"{_LLAMA},{_GEMMA}", _LLAMA,
+        ["--max-batch", "8", "--judge-overlap"], None, 4),
+    "judge-a-panelist-overlap-env": (f"{_LLAMA},{_GEMMA}", _LLAMA,
+                                     ["--max-batch", "8"], "1", 4),
+    "judge-a-panelist-overlap-env-off": (f"{_LLAMA},{_GEMMA}", _LLAMA,
+                                         ["--max-batch", "8"], "0", 8),
+    "judge-apart-overlap": (_LLAMA, _GEMMA,
+                            ["--max-batch", "8", "--judge-overlap"], None, 8),
+    "judge-the-preset-asked-twice-overlap": (
+        f"{_LLAMA},{_LLAMA}", _LLAMA,
+        ["--max-batch", "8", "--judge-overlap"], None, 2),
+    # an explicit cap is held to the same arithmetic
+    "explicit-cap-fills-the-pool": (
+        f"{_LLAMA},{_GEMMA}", _LLAMA,
+        ["--max-batch", "6", "--max-concurrency", "6"], None, 6),
+    "explicit-cap-refused-with-overlap-flag": (
+        f"{_LLAMA},{_GEMMA}", _LLAMA,
+        ["--max-batch", "6", "--max-concurrency", "6", "--judge-overlap"],
+        None, "needing 12 slots > --max-batch 6"),
+    "explicit-cap-refused-with-overlap-env": (
+        f"{_LLAMA},{_GEMMA}", _LLAMA,
+        ["--max-batch", "6", "--max-concurrency", "6"], "1",
+        "needing 12 slots > --max-batch 6"),
+    "explicit-cap-half-with-overlap": (
+        f"{_LLAMA},{_GEMMA}", _LLAMA,
+        ["--max-batch", "6", "--max-concurrency", "3", "--judge-overlap"],
+        None, 3),
+    # an explicit cap that oversubscribes the batcher fails at startup
+    "explicit-cap-oversubscribes": (
+        _LLAMA, _GEMMA, ["--max-batch", "4", "--max-concurrency", "8"],
+        None, "oversubscribes"),
+    # HTTP-only panels have no device budget to validate against
+    "http-only": ("m1,m2", "j",
+                  ["--max-batch", "1", "--max-concurrency", "32"], None, 32),
+    "http-only-default": ("m1,m2", "j", ["--max-batch", "1"], None, 8),
+}
+
+
+@pytest.mark.parametrize("case", list(_CAP_CASES))
+def test_serve_concurrency_validated_against_max_batch(case, monkeypatch):
+    """The admission cap is the number of runs whose streams fit a pool's
+    rows, counted as the scheduler issues them: panel, then judge."""
     from llm_consensus_tpu.cli.serve import parse_serve_args, resolve_concurrency
 
-    # tpu panel: the cap derives from batcher slots / streams-per-run.
-    cfg = parse_serve_args([
-        "--models", "tpu:tiny-llama,tpu:tiny-gemma",
-        "--judge", "tpu:tiny-mistral", "--max-batch", "8",
-    ])
-    assert resolve_concurrency(cfg) == 8  # 1 stream per preset per run
+    models, judge, flags, env, want = _CAP_CASES[case]
+    monkeypatch.delenv("LLMC_JUDGE_OVERLAP", raising=False)
+    if env is not None:
+        monkeypatch.setenv("LLMC_JUDGE_OVERLAP", env)
+    cfg = parse_serve_args(["--models", models, "--judge", judge] + flags)
+    if isinstance(want, str):
+        with pytest.raises(CLIError, match=want):
+            resolve_concurrency(cfg)
+    else:
+        assert resolve_concurrency(cfg) == want
 
-    # The same preset twice in the panel doubles its per-run streams.
-    cfg = parse_serve_args([
-        "--models", "tpu:tiny-llama,tpu:tiny-llama",
-        "--judge", "tpu:tiny-gemma", "--max-batch", "8",
-    ])
-    assert resolve_concurrency(cfg) == 4
 
-    # An explicit cap that oversubscribes the batcher fails at startup.
-    cfg = parse_serve_args([
-        "--models", "tpu:tiny-llama", "--judge", "tpu:tiny-gemma",
-        "--max-batch", "4", "--max-concurrency", "8",
-    ])
-    with pytest.raises(CLIError, match="oversubscribes"):
-        resolve_concurrency(cfg)
+def _max_overlap(spans):
+    """The most of ``spans`` ((start, end) pairs) open at one instant."""
+    edges = sorted([(t0, 1) for t0, _ in spans] + [(t1, -1) for _, t1 in spans],
+                   key=lambda e: (e[0], e[1]))
+    live = peak = 0
+    for _, d in edges:
+        live += d
+        peak = max(peak, live)
+    return peak
 
-    # HTTP-only panels have no device budget to validate against.
-    cfg = parse_serve_args([
-        "--models", "m1,m2", "--judge", "j",
-        "--max-batch", "1", "--max-concurrency", "32",
-    ])
-    assert resolve_concurrency(cfg) == 32
+
+def test_serve_seats_as_many_runs_as_a_pool_has_rows(tmp_path, monkeypatch):
+    """ISSUE 26, the row arithmetic proved and not asserted: ``serve``
+    with four rows a pool and a judge that is also a panelist admits
+    four runs at once; all four complete, and no pool ever holds more
+    streams (live or queued) than it has rows — a run's judge stream
+    starts only after its panel stream on that pool has ended."""
+    import http.client
+    import re
+    import threading
+    import time
+
+    from llm_consensus_tpu.cli.serve import serve_main
+    from llm_consensus_tpu.obs import blackbox as bb_mod
+    from llm_consensus_tpu.obs.blackbox import FlightRecorder
+
+    monkeypatch.delenv("LLMC_JUDGE_OVERLAP", raising=False)
+    ring = FlightRecorder(capacity=16384)
+    bb_mod.install(ring)  # before the server: emitters bind at construction
+    stderr, stop, rc = io.StringIO(), threading.Event(), []
+    server = threading.Thread(target=lambda: rc.append(serve_main(
+        ["--models", "tpu:tiny-llama,tpu:tiny-qwen2",
+         "--judge", "tpu:tiny-llama", "--max-batch", "4", "--port", "0",
+         "--max-tokens", "48", "--cache-size", "0", "--timeout", "300",
+         "--data-dir", str(tmp_path / "data"), "--no-save"],
+        stdout=io.StringIO(), stderr=stderr,
+        install_signal_handlers=False, shutdown=stop,
+    )))
+    server.start()
+
+    def call(method, path, body=None):
+        conn = http.client.HTTPConnection("127.0.0.1", port, timeout=300)
+        try:
+            conn.request(method, path, body and json.dumps(body),
+                         {"Content-Type": "application/json"})
+            r = conn.getresponse()
+            return r.status, json.loads(r.read())
+        finally:
+            conn.close()
+
+    samples, docs, done = [], [None] * 4, threading.Event()
+    try:
+        deadline = time.monotonic() + 120
+        while not (m := re.search(r"http://127\.0\.0\.1:(\d+)/", stderr.getvalue())):
+            assert server.is_alive() and time.monotonic() < deadline, (
+                stderr.getvalue())
+            time.sleep(0.05)
+        port = int(m.group(1))
+        # one run alone first: every program the four meet is compiled
+        # at some width, so the four overlap for most of their length
+        assert call("POST", "/v1/consensus", {"prompt": "warm the pools"})[0] == 200
+        t_four = time.monotonic_ns()
+
+        def sample():
+            while not done.is_set():
+                samples.append(call("GET", "/statsz")[1])
+                time.sleep(0.005)
+
+        gate = threading.Barrier(4)
+
+        def post(i):
+            gate.wait()
+            docs[i] = call("POST", "/v1/consensus",
+                           {"prompt": f"question number {i}: why {i}?"})
+
+        sampler = threading.Thread(target=sample)
+        sampler.start()
+        posts = [threading.Thread(target=post, args=(i,)) for i in range(4)]
+        for t in posts:
+            t.start()
+        for t in posts:
+            t.join(timeout=300)
+        done.set()
+        sampler.join(timeout=30)
+        last = call("GET", "/statsz")[1]
+    finally:
+        done.set()
+        stop.set()
+        server.join(timeout=120)
+        bb_mod.reset()
+    assert rc == [0], stderr.getvalue()
+    assert all(d is not None and d[0] == 200 for d in docs), docs
+    for _, d in docs:
+        assert len(d["responses"]) == 2 and d["consensus"], d
+        assert not d.get("failed_models") and "degraded" not in d, d
+    assert last["admission"]["max_concurrency"] == 4
+    # the four were in flight together, so the pools were asked for
+    # everything four runs ask at once
+    events = [e for e in ring.snapshot() if e.ts_ns >= t_four]
+    runs = [(e.ts_ns, e.ts_ns + e.dur_ns) for e in events
+            if e.name == "consensus_run"]
+    assert len(runs) == 4 and _max_overlap(runs) == 4, runs
+    # ... and no pool was ever asked for a fifth row: by the streams'
+    # own spans (exact), and by the governor's samples of the pools
+    for model, n in (("tpu:tiny-llama", 8), ("tpu:tiny-qwen2", 4)):
+        streams = [(e.ts_ns, e.ts_ns + e.dur_ns) for e in events
+                   if e.name == "engine_stream" and e.args["model"] == model]
+        assert len(streams) == n, (model, streams)
+        assert _max_overlap(streams) <= 4, (model, streams)
+    pools = [p for s in samples
+             for p in s["pressure"].get("pools", {}).values()]
+    assert pools and max(p["live"] for p in pools) >= 2
+    assert all(p["cap"] == 4 and p["live"] + p["queued"] <= 4
+               for p in pools), [p for p in pools
+                                 if p["live"] + p["queued"] > 4]
+    # full pools with nothing waiting are throughput: the ladder stays put
+    assert {s["pressure"]["state"] for s in samples + [last]} == {"ok"}
+    assert last["pressure"]["escalations"] == 0
 
 
 def test_tpu_provider_reads_llmc_max_batch(monkeypatch):

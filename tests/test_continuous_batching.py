@@ -537,13 +537,18 @@ def test_wave_prefix_reuse_across_bursts():
     eng = Engine(cfg, params=params, dtype=jnp.float32, max_seq=512,
                  stream_interval=8, prefill_chunk=16)
     # 245-247 tokens a row: the two rows of a two-row pool fill their
-    # 256-slot bucket, so the full wave stays batched (a wave of long
-    # rows that row by row would cover fewer slots goes through _admit).
+    # 256-slot bucket. Since ISSUE 26 such a tie goes row by row, so the
+    # pool no longer takes this wave through the batched prefill: the
+    # reuse is asserted on the batched prefill itself (what mixed waves,
+    # suffix waves and interleaved admission still run), the exactness
+    # across bursts through the pool.
     # The rows part after ~136 tokens, under LLMC_POOL_PREFIX_MIN: the
     # reuse under test is the engine snapshot's, not the pool's prefix.
     shared = "shared panel prompt prefix " * 5  # ~135 tokens, ~8 chunks
     tail = "y " * 45
     s = SamplingParams(max_new_tokens=6, ignore_eos=True)
+    w1 = [shared + f"{i} first wave tail " + tail for i in range(2)]
+    w2 = [shared + f"{i} second wave tail " + tail for i in range(2)]
     chunk_calls = []
     real_chunk = eng_mod._prefill_chunk
 
@@ -552,23 +557,27 @@ def test_wave_prefix_reuse_across_bursts():
         return real_chunk(*a, **k)
 
     eng_mod._prefill_chunk = spy
-    b, gate = _gated_batcher(eng, max_batch=2)
     try:
-        w1 = [shared + f"{i} first wave tail " + tail for i in range(2)]
-        futs = [b.submit(p, s) for p in w1]
-        gate.set()
-        r1 = [f.result(timeout=300) for f in futs]
+        eng._prefill_rows([eng.tokenizer.encode(p) for p in w1])
         wave1_chunks = len(chunk_calls)
         chunk_calls.clear()
-        w2 = [shared + f"{i} second wave tail " + tail for i in range(2)]
-        futs = [b.submit(p, s) for p in w2]
-        r2 = [f.result(timeout=300) for f in futs]
+        eng._prefill_rows([eng.tokenizer.encode(p) for p in w2])
         wave2_chunks = len(chunk_calls)
     finally:
         eng_mod._prefill_chunk = real_chunk
+    assert 0 < wave2_chunks < wave1_chunks, (wave1_chunks, wave2_chunks)
+    b, gate = _gated_batcher(eng, max_batch=2)
+    try:
+        assert b._singles_cover_fewer(
+            [len(eng.tokenizer.encode(p)) for p in w1])  # the tie
+        futs = [b.submit(p, s) for p in w1]
+        gate.set()
+        r1 = [f.result(timeout=300) for f in futs]
+        futs = [b.submit(p, s) for p in w2]
+        r2 = [f.result(timeout=300) for f in futs]
+    finally:
         gate.set()
         b.close()
-    assert wave2_chunks < wave1_chunks, (wave1_chunks, wave2_chunks)
     for p, r in zip(w1 + w2, r1 + r2):
         ref = Engine(cfg, params=params, dtype=jnp.float32, max_seq=512,
                      stream_interval=8, prefill_chunk=16).generate(p, s)
@@ -599,9 +608,15 @@ _ROUTE_CASES = {
     "two-long-of-four": ([40, 50], 4, 0,
                          [("single", 1, 1, 48), ("single", 1, 1, 64)]),
     # a full wave of long rows that fill their bucket: 6 x 64 either way,
-    # so one wave (fewer dispatches)
+    # and a tie goes row by row (ISSUE 26: the six-row chunk program is
+    # the slower one per token, and the one a full pool would now meet)
     "full-wave-long": ([60, 64, 58, 61, 63, 59], 6, 0,
-                       [("rows", 6, 6, 6 * 64)]),
+                       [("single", 1, 1, 64)] * 6),
+    # the same wave one token over a chunk boundary on one row: 6 x 80
+    # batched against 5 x 64 + 80, row by row as before
+    "full-wave-long-ragged": ([60, 64, 58, 61, 65, 59], 6, 0,
+                              [("single", 1, 1, 64)] * 4
+                              + [("single", 1, 1, 80), ("single", 1, 1, 64)]),
     # a lone short row: weights-bound, one padded one-shot wave as ever
     "lone-short": ([10], 6, 0, [("rows", 1, 6, 6 * 16)]),
     # one row within a chunk keeps the whole wave batched

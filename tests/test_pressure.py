@@ -166,6 +166,56 @@ def test_governor_kv_signal_reads_deltas():
     assert g.pressure_signals()["kv"] <= 0.2
 
 
+# (live, queued, rows) of the fullest pool, with every admission slot
+# taken and nobody waiting at the front door → the batcher signal, and
+# the ladder's state after ten samples at the default marks.
+_POOL_CASES = {
+    # ISSUE 26: serve seats as many runs as a pool has rows, so a full
+    # pool with nothing waiting is the healthy steady state, not overload
+    "every-row-live": (6, 0, 6, 0.7, "ok"),
+    "every-row-live-eight": (8, 0, 8, 0.7, "ok"),
+    # a wave landing at an idle pool: each stream has a row to go to
+    "a-wave-arriving": (0, 6, 6, 0.7, "ok"),
+    "half-live": (3, 0, 6, 0.35, "ok"),
+    "one-live": (1, 0, 6, 0.7 / 6, "ok"),
+    # streams queued for want of a row count in full
+    "one-waits-for-a-row": (6, 1, 6, 0.7 + 1 / 6, "shed"),
+    "three-wait-for-a-row": (6, 3, 6, 1.0, "shed"),
+    "a-wave-waits-behind-half-a-pool": (3, 6, 6, 1.0, "shed"),
+}
+
+
+@pytest.mark.parametrize("case", list(_POOL_CASES))
+def test_full_rows_alone_never_walk_the_ladder(case):
+    """The batcher signal follows the slots rule: rows in use stay under
+    the high-water mark however many; streams queued because no row is
+    free escalate (two samples a rung, so ten reach the top)."""
+    live, queued, rows, signal, state = _POOL_CASES[case]
+
+    class P:
+        def pressure_stats(self):
+            return {"tiny": {"live": live, "cap": rows, "queued": queued,
+                             "preemptions": 0}}
+
+    g = PressureGovernor(
+        admission_snapshot=lambda: {
+            "active": rows, "max_concurrency": rows, "waiting": 0,
+            "max_queue": 16,
+        },
+        provider_iter=lambda: [P()],
+    )
+    assert (g.high_water, g.up_patience) == (0.75, 2)
+    assert g.pressure_signals()["batcher"] == pytest.approx(signal)
+    states = [g.sample() for _ in range(10)]
+    assert states[-1] == state, states
+    snap = g.snapshot()
+    if state == "ok":
+        assert set(states) == {"ok"}
+        assert snap["escalations"] == 0 and snap["brownouts"] == 0
+    else:
+        assert states[1] == "evict" and snap["brownouts"] == 1
+
+
 # -- admission: priority dequeue, aging, bump, retry-after -------------------
 
 
