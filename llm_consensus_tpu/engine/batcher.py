@@ -63,9 +63,10 @@ import threading
 import time
 import warnings
 from concurrent.futures import Future, InvalidStateError
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -160,6 +161,299 @@ def _wave_counts(admit: dict) -> dict:
     }
 
 
+def fits(n: int, pos: int, width: int, max_seq: int) -> bool:
+    """THE fit rule — the one safety condition of admission, written
+    once: a row of ``n`` tokens admitted at frontier ``pos`` splices a
+    ``width``-wide bucket at ``pos - n``, so its window
+    ``[pos - n, pos - n + width)`` must lie inside ``[0, max_seq]``. A
+    prompt longer than the frontier (``n > pos``) waits for the frontier
+    to pass it; an overrun past capacity makes ``dynamic_update_slice``
+    CLAMP, which silently misaligns the row. ``width`` and ``pos`` are
+    those of the splice the caller is about to make."""
+    return n <= pos and (pos - n) + width <= max_seq
+
+
+def wave_k_pad(k: int, max_batch: int) -> int:
+    """Pad the wave to a power of two, FLOORED at max_batch/4: every
+    distinct padded size is a compiled program (admission prefill +
+    fused splice), and nondeterministic burst splits otherwise keep
+    discovering new sizes — a fresh full-model compile landing
+    inside serving traffic. The floor caps the variant set at 3 per
+    pool; padding rows repeat row 0 (idempotent). They are real
+    rows to the device: nearly free while a row is at most one
+    prefill chunk (the wave is weights-bound), full price beyond it
+    (measured on a v5e: a lone 1.7k-token prompt padded to six rows
+    took six times one row's prefill), which is why
+    ``singles_cover_fewer`` sends such waves row by row."""
+    k_pad = 1 << (k - 1).bit_length()
+    return min(max(k_pad, max_batch // 4, 8), max_batch)
+
+
+def singles_cover_fewer(lens: list, max_batch: int, chunk: int,
+                        rows_bucket: Callable[[int], int]) -> bool:
+    """Whether a full-prompt wave of rows ``lens`` long should be
+    admitted row by row (``_admit``) instead of as one padded wave
+    (``_admit_batch``): the path that dispatches fewer token slots,
+    and on a tie row by row — a full wave of long rows that fill
+    their bucket covers the same slots either way, and the batched
+    chunk program is the slower one per token (measured on a v5e:
+    six 2k rows 0.84 s batched, 0.51 s one by one) with k times one
+    row's scratch to load. Where ``max_seq`` is no multiple of the
+    chunk the bucket is not chunk-padded and the wave does cover
+    fewer: it stays.
+
+    Only for waves whose rows are EACH longer than one prefill
+    chunk: a chunk already streams the weights once per
+    ``prefill_chunk`` tokens, so such a wave is compute-bound and
+    batching its rows buys nothing, while every padding row and
+    every slot a short row is padded to the longest's bucket costs
+    what a real one does. Shorter rows are weights-bound: one padded
+    wave streams the weights once for all of them, and stays."""
+    if not chunk or min(lens) <= chunk:
+        return False
+    batched = wave_k_pad(len(lens), max_batch) * rows_bucket(max(lens))
+    return sum(-(-n // chunk) * chunk for n in lens) <= batched
+
+
+def idle_frontier(lens: list, shared_prefix: bool, max_seq: int,
+                  bucket: Callable[[int], int],
+                  rows_bucket: Callable[[int], int]) -> int:
+    """Frontier for an idle pool's wave: the longest prompt among
+    the LEADING candidates (admission order) that can right-align to
+    one frontier within cache capacity.
+
+    A row of n tokens at frontier ``pos`` splices a w-wide bucket at
+    ``pos - n``, so it fits only while ``(pos - n) + w <= max_seq``.
+    When the wave's prompts are long enough that w saturates
+    capacity, only rows AT the frontier fit: resetting the frontier
+    to the longest prompt then requeues every shorter row — and if a
+    shorter row heads the queue, the no-leapfrog rule requeues the
+    longer ones behind it too, nothing is admitted, the pool stays
+    idle and the pass repeats forever (found on the chip: two
+    concurrent ~1.7k-token judge prompts in a 2048-slot cache hung
+    until their deadlines). Stopping at the first candidate that
+    breaks the fit always admits the queue head; the rest wait for
+    the frontier or the next idle pool, as any long prompt does."""
+    # Every member fits one frontier and width exactly when the SHORTEST
+    # does (it starts furthest in), so the wave is its two extremes.
+    n_min = n_max = lens[0]
+    for n in lens[1:]:
+        top = max(n_max, n)  # the frontier this wave would take
+        if shared_prefix:
+            w = bucket(top)
+        else:
+            w = max(bucket(top), rows_bucket(top))
+        if not fits(min(n_min, n), top, w, max_seq):
+            break
+        n_min, n_max = min(n_min, n), top
+    return n_max
+
+
+class Pending(NamedTuple):
+    """What admission policy may know of one queued stream."""
+
+    ids: Sequence        # prompt ids (only their length, with sharing off)
+    priority: int = 1    # pressure/priority.py: HIGH=0 < NORMAL=1 < LOW=2
+    done: bool = False   # deadline passed or cancelled while queued
+    max_new: int = 1     # tokens it may still decode (<= 0: nothing to do)
+
+
+class AdmissionPlan(NamedTuple):
+    """The decision of one admission pass (``plan_admission``). Streams
+    are named by their index in the ``pending`` the planner was given."""
+
+    # Resolved without prefill: expired/cancelled, or ``max_new <= 0``.
+    resolve: tuple = ()
+    # Drop the pool's shared prefix (nothing can use it any more).
+    clear_prefix: bool = False
+    # Establish these ids (the wave's common prefix) as the pool's shared
+    # prefix before the wave (empty: don't). The rest of the plan ASSUMES
+    # it lands; where it does not, the pass is planned again with
+    # sharing off.
+    establish: tuple = ()
+    # Shared-prefix length the wave's rows admit under (0: full prompts).
+    wave_p: int = 0
+    # The frontier the wave splices at (an idle pool's resets to fit it).
+    pos: int = 0
+    # (pending index, slot) in admission order.
+    admitted: tuple = ()
+    # How the admitted rows prefill: "rows" (one padded wave) or "single"
+    # (row by row); None when nothing is admitted. ``interleave``: first
+    # try to open a paced wave between decode chunks instead.
+    route: Optional[str] = None
+    interleave: bool = False
+    # Back to the queue head, in this order.
+    requeue: tuple = ()
+
+
+def plan_admission(
+    pending: Sequence[Pending], free: Sequence[int], pos: int,
+    max_seq: int, pool_idle: bool, *, max_batch: int, chunk: int,
+    bucket: Callable[[int], int], rows_bucket: Callable[[int], int],
+    prefix_enabled: bool = False, prefix_ids: Optional[tuple] = None,
+    prefix_min: int = 0,
+    resident_prefix_len: Optional[Callable[[list], int]] = None,
+    sp_degree: int = 1, may_interleave: bool = False,
+) -> AdmissionPlan:
+    """Admission POLICY, all of it, as a pure function: given the drained
+    queue (``pending``, in queue order), the ``free`` rows, the shared
+    frontier ``pos`` and the pool's prefix state, what is admitted, where
+    and how. No engine, no lock, no clock, no spans — the scheduler
+    carries the plan out (``ContinuousBatcher._execute_admission``).
+
+    ``bucket(n)`` is the single-stream splice width of an n-token row,
+    ``rows_bucket(n)`` the shared width of a wave whose longest row is n;
+    ``chunk`` the prefill chunk; ``prefix_ids`` the established shared
+    prefix (None: none); ``resident_prefix_len(ids)`` how much of ``ids``
+    the paged KV pool already holds (None: no pool); ``may_interleave``
+    whether a paced wave may open (a budget, no wave pending, live rows
+    to overlap with)."""
+    # Priority-ordered admission (pressure/): a stable sort,
+    # so FIFO survives WITHIN a class while a higher class
+    # drained in the same pass takes slots first. Requeued
+    # streams keep their no-leapfrog fairness per class; a
+    # higher class overtaking a requeued lower one is the
+    # point.
+    order = sorted(range(len(pending)), key=lambda i: pending[i].priority)
+    candidates = [
+        pending[i].ids for i in order
+        if not pending[i].done and pending[i].max_new > 0
+    ]
+    # Shared-prefix mode for THIS wave (the one-prompt fan-out
+    # pattern): all-or-nothing per wave. Pool idle → establish
+    # (or re-establish) from the wave's own common prefix;
+    # pool busy → join the established prefix only if every
+    # candidate starts with it. A wave that can't share
+    # admits full-prompt rows; establishment failure degrades
+    # the same way.
+    wave_p = est_p = 0
+    # No live row can reference the prefix any more and
+    # sharing is off (env, or the failure fallback): drop it so
+    # decode returns to the cheaper no-prefix program.
+    clear_prefix = pool_idle and not prefix_enabled and prefix_ids is not None
+    if prefix_enabled and candidates:
+        p0 = len(prefix_ids) if prefix_ids is not None else 0
+        if prefix_ids is not None and all(
+            len(r) > p0 and tuple(r[:p0]) == prefix_ids for r in candidates
+        ):
+            # Join the established prefix (idle or busy, any
+            # wave size) — no re-establishment churn.
+            wave_p = p0
+        elif pool_idle:
+            common = candidates[0]
+            for r in candidates[1:]:
+                m = min(len(common), len(r))
+                i = 0
+                while i < m and common[i] == r[i]:
+                    i += 1
+                common = common[:i]
+            p = min(len(common), min(len(r) for r in candidates) - 1)
+            if p >= prefix_min and len(candidates) > 1:
+                est_p = p
+            if (
+                not est_p
+                and resident_prefix_len is not None
+                and p >= prefix_min
+            ):
+                # Radix consult (paged pool on): a wave with
+                # no intra-wave sharing — a lone candidate is
+                # the common case — still establishes when
+                # the pool already holds its prefix, sized to
+                # the resident span so establishment is a
+                # block gather, not a prefill. Rows then
+                # admit as SUFFIXES: the wave prefills only
+                # unmatched tail tokens and its decode window
+                # shrinks to the suffix, which is where the
+                # pooled max-resident-streams headroom
+                # comes from.
+                hit = resident_prefix_len(list(candidates[0][:p]))
+                if hit >= prefix_min:
+                    est_p = hit
+            if est_p:
+                wave_p = est_p
+            else:
+                # No qualifying shared prefix: drop back to
+                # the cheaper no-prefix decode program.
+                clear_prefix = True
+    if pool_idle and candidates:
+        # Idle frontier resets to the wave's longest prompt
+        # (suffix length under shared-prefix admission) so
+        # the whole wave can right-align to one frontier.
+        pos = idle_frontier(
+            [len(ids) - wave_p for ids in candidates][:max_batch],
+            bool(wave_p), max_seq, bucket, rows_bucket,
+        )
+    free = list(free)
+    resolve: list = []
+    admitted: list = []
+    requeue: list = []
+    # Shortest and longest window admitted so far (none yet: the bounds
+    # any admissible window lies within).
+    n_min, n_max = max_seq, 0
+    for i in order:
+        item = pending[i]
+        if item.done or item.max_new <= 0:
+            # Expired while queued, or nothing to decode: resolve
+            # without prefill.
+            resolve.append(i)
+            continue
+        if requeue or not free:
+            # FIFO fairness: once any stream this round was
+            # requeued (frontier/capacity/slots), later
+            # arrivals must not leapfrog it — under sustained
+            # load a long prompt would otherwise starve until
+            # the pool fully drained.
+            requeue.append(i)
+            continue
+        n = len(item.ids) - wave_p  # window the row will occupy
+        # Capacity must hold for the admission form in play:
+        # full-prompt waves splice rows_bucket(n) wide (and
+        # may fall back to the single-stream bucket(n)
+        # splice), shared-prefix waves splice their suffix
+        # bucket.
+        w_req = bucket(n) if wave_p else max(bucket(n), rows_bucket(n))
+        if not fits(n, pos, w_req, max_seq):
+            requeue.append(i)
+            continue
+        # Batched waves splice rows at one shared width, so
+        # every member must also fit THAT width; a candidate
+        # that would push the wave width past some member's
+        # capacity requeues instead of corrupting the splice.
+        # (The shortest member starts furthest in: if it fits, all do;
+        # for the wave's first row this is the fit above again.)
+        low, top = min(n_min, n), max(n_max, n)
+        w_new = bucket(top) if wave_p else rows_bucket(top)
+        if not fits(low, pos, w_new, max_seq):
+            requeue.append(i)
+            continue
+        n_min, n_max = low, top
+        admitted.append((i, free.pop(0)))
+    route = None
+    if admitted and sp_degree > 1:
+        # sp meshes keep ring prefill (batched admission is
+        # plain left-aligned prefill): row by row.
+        route = "single"
+    elif admitted:
+        # Long rows, few of them: the one-row path covers fewer token
+        # slots than the padded wave. (Suffix waves stay batched: a
+        # single row cannot join the pool's prefix.)
+        route = "single" if not wave_p and singles_cover_fewer(
+            [len(pending[i].ids) for i, _ in admitted],
+            max_batch, chunk, rows_bucket,
+        ) else "rows"
+    return AdmissionPlan(
+        resolve=tuple(resolve), clear_prefix=clear_prefix,
+        establish=tuple(candidates[0][:est_p]) if est_p else (),
+        wave_p=wave_p, pos=pos, admitted=tuple(admitted), route=route,
+        # Interleaved admission (prefill/decode overlap) where it may
+        # open: an idle pool admits classically — there is no decode
+        # to overlap, and the stall-free first chunk matters more than
+        # pacing.
+        interleave=bool(admitted) and sp_degree == 1 and may_interleave,
+        requeue=tuple(requeue),
+    )
+
+
 @dataclass
 class _PendingWave:
     """One interleaved admission wave mid-establishment: its reserved
@@ -171,7 +465,6 @@ class _PendingWave:
     wave_p: int
     k_pad: int
     session: object  # engine.AdmissionPrefill
-    t_start: float
 
 
 @dataclass
@@ -658,6 +951,12 @@ class ContinuousBatcher:
         # bounded like the old single-lookahead loop.
         self._unfetched = 0  # guarded by: _work
         self._nondecode_work = False  # admission/compaction since last dispatch
+        # [(slot list, samples array, owner list)] per admission wave
+        # since the last dispatch — attached to the next dispatched
+        # chunk so prefill-sampled tokens ride down with its fetch (they
+        # persist across iterations that skip dispatching).
+        # Scheduler-owned.
+        self._firsts: list[tuple] = []
         self._worker_exc: Optional[BaseException] = None  # guarded by: _work
         from queue import SimpleQueue
 
@@ -812,8 +1111,6 @@ class ContinuousBatcher:
             # allocated — a caller about to rebuild engines on these
             # devices (re-plan, elastic recovery) is now double-booking
             # HBM. Say so instead of failing silently.
-            import warnings
-
             warnings.warn(
                 "ContinuousBatcher scheduler still running 120s after "
                 "close(); its KV cache remains allocated until in-flight "
@@ -1020,9 +1317,7 @@ class ContinuousBatcher:
         # No fetched token may be lost: the victims' emitted prefixes
         # become their resume context, so the pipeline drains first.
         self._drain_fetches()
-        self._nondecode_work = True
-        self._impure_kind = "prefill"
-        self._gap_phase = "preempt"
+        self._mark_nondecode("preempt", "prefill")
         return self._preempt_slots(victims)
 
     def _preempt_slots(self, victims: list) -> list:
@@ -1088,16 +1383,15 @@ class ContinuousBatcher:
 
     # -- scheduler internals -------------------------------------------------
 
-    def _admit(self, slot: int, prompt_ids: list, s: _Stream):
+    def _admit(self, slot: int, prompt_ids: list, s: _Stream, sp) -> tuple:
         """Prefill and splice so the prompt ends at the shared frontier.
 
-        Returns the (device) prefill-sampled first token to ride down
-        with the next fetch, or None if the stream completed instantly.
+        Returns the firsts entry ``(slots, samples, owners)`` whose
+        (device) prefill-sampled first token rides down with the next
+        fetch. ``sp`` is the caller's open ``pool.admit`` span, as in
+        ``_admit_batch``.
         """
         eng = self.engine
-        if s.max_new <= 0:
-            s.future.set_result(self._result(s))
-            return None
         n = len(prompt_ids)
         self._pin_stream(s)  # before the prefill reads eng.params
         try:
@@ -1132,7 +1426,9 @@ class ContinuousBatcher:
                 tok,
             )
         self._slots[slot] = s
-        return tok
+        chunks, slot_tokens = eng.last_prefill
+        sp.set(chunks=chunks, slot_tokens=slot_tokens)
+        return ([slot], tok, [s])
 
     def _establish_prefix(self, prefix_ids: list[int]) -> bool:
         """Prefill the wave's common prefix ONCE and install it as the
@@ -1177,8 +1473,6 @@ class ContinuousBatcher:
             # common prefix re-runs the same failing full-prefix prefill
             # before degrading — repeated wasted prefill under sustained
             # bursts. Disable like the failed suffix-wave path does.
-            import warnings
-
             warnings.warn(
                 "shared-prefix establishment prefill failed; disabling "
                 "pool prefix sharing for this batcher",
@@ -1202,7 +1496,7 @@ class ContinuousBatcher:
         self._prefix_weight_version = -1
 
     def _admit_batch(self, batch: list[tuple[int, list, _Stream]],
-                     prefix_p: int, sp) -> Optional[list]:
+                     prefix_p: int, sp) -> Optional[tuple]:
         """Admit several streams with ONE batched prefill.
 
         A burst of k admissions prefilled row-by-row streams the full
@@ -1214,7 +1508,7 @@ class ContinuousBatcher:
         shared prefix: only the SUFFIXES prefill (through the prefix-
         merge attention path) and only suffix KV lands in the pool —
         wave prefill compute scales with the new tokens, not the shared
-        prompt. Returns the firsts list entries, or None when the
+        prompt. Returns the firsts entry, or None when the
         batched prefill itself failed (caller falls back to one-by-one
         admission). ``sp`` is the caller's open ``pool.admit`` span: what
         the wave dispatched (padded rows, chunks, the token slots they
@@ -1222,7 +1516,7 @@ class ContinuousBatcher:
         """
         eng = self.engine
         rows = [ids for _, ids, _ in batch]
-        k_pad = self._wave_k_pad(len(rows))
+        k_pad = wave_k_pad(len(rows), self.max_batch)
         pad_rows = rows + [rows[0]] * (k_pad - len(rows))
         for _, _, s in batch:
             self._pin_stream(s)  # before the prefill reads eng.params
@@ -1250,82 +1544,9 @@ class ContinuousBatcher:
             for _, _, s in batch:
                 self._unpin_stream(s)  # one-by-one retry re-pins
             return None
-        return [self._install_wave(
+        return self._install_wave(
             batch, prefix_p, k_pad, last_logits, pcache, width,
-        )]
-
-    def _idle_frontier(self, lens: list, shared_prefix: bool) -> int:
-        """Frontier for an idle pool's wave: the longest prompt among
-        the LEADING candidates (admission order) that can right-align to
-        one frontier within cache capacity.
-
-        A row of n tokens at frontier ``pos`` splices a w-wide bucket at
-        ``pos - n``, so it fits only while ``(pos - n) + w <= max_seq``.
-        When the wave's prompts are long enough that w saturates
-        capacity, only rows AT the frontier fit: resetting the frontier
-        to the longest prompt then requeues every shorter row — and if a
-        shorter row heads the queue, the no-leapfrog rule requeues the
-        longer ones behind it too, nothing is admitted, the pool stays
-        idle and the pass repeats forever (found on the chip: two
-        concurrent ~1.7k-token judge prompts in a 2048-slot cache hung
-        until their deadlines). Stopping at the first candidate that
-        breaks the fit always admits the queue head; the rest wait for
-        the frontier or the next idle pool, as any long prompt does."""
-        eng = self.engine
-        members: list = []
-        for n in lens:
-            n_max = max(members + [n])  # the frontier this wave would take
-            if shared_prefix:
-                w = _bucket(n_max, eng.max_seq)
-            else:
-                w = max(_bucket(n_max, eng.max_seq), eng._rows_bucket(n_max))
-            if members and any(
-                (n_max - nj) + w > eng.max_seq for nj in members + [n]
-            ):
-                break
-            members.append(n)
-        return max(members)
-
-    def _wave_k_pad(self, k: int) -> int:
-        """Pad the wave to a power of two, FLOORED at max_batch/4: every
-        distinct padded size is a compiled program (admission prefill +
-        fused splice), and nondeterministic burst splits otherwise keep
-        discovering new sizes — a fresh full-model compile landing
-        inside serving traffic. The floor caps the variant set at 3 per
-        pool; padding rows repeat row 0 (idempotent). They are real
-        rows to the device: nearly free while a row is at most one
-        prefill chunk (the wave is weights-bound), full price beyond it
-        (measured on a v5e: a lone 1.7k-token prompt padded to six rows
-        took six times one row's prefill), which is why
-        ``_singles_cover_fewer`` sends such waves row by row."""
-        k_pad = 1 << (k - 1).bit_length()
-        return min(max(k_pad, self.max_batch // 4, 8), self.max_batch)
-
-    def _singles_cover_fewer(self, lens: list[int]) -> bool:
-        """Whether a full-prompt wave of rows ``lens`` long should be
-        admitted row by row (``_admit``) instead of as one padded wave
-        (``_admit_batch``): the path that dispatches fewer token slots,
-        and on a tie row by row — a full wave of long rows that fill
-        their bucket covers the same slots either way, and the batched
-        chunk program is the slower one per token (measured on a v5e:
-        six 2k rows 0.84 s batched, 0.51 s one by one) with k times one
-        row's scratch to load. Where ``max_seq`` is no multiple of the
-        chunk the bucket is not chunk-padded and the wave does cover
-        fewer: it stays.
-
-        Only for waves whose rows are EACH longer than one prefill
-        chunk: a chunk already streams the weights once per
-        ``prefill_chunk`` tokens, so such a wave is compute-bound and
-        batching its rows buys nothing, while every padding row and
-        every slot a short row is padded to the longest's bucket costs
-        what a real one does. Shorter rows are weights-bound: one padded
-        wave streams the weights once for all of them, and stays."""
-        eng = self.engine
-        chunk = eng.prefill_chunk
-        if not chunk or min(lens) <= chunk:
-            return False
-        batched = self._wave_k_pad(len(lens)) * eng._rows_bucket(max(lens))
-        return sum(-(-n // chunk) * chunk for n in lens) <= batched
+        )
 
     def _install_wave(self, batch, prefix_p: int, k_pad: int,
                       last_logits, pcache, width: int) -> tuple:
@@ -1422,7 +1643,7 @@ class ContinuousBatcher:
         interleaving implies, or when the session cannot open."""
         eng = self.engine
         rows = [ids for _, ids, _ in batch]
-        k_pad = self._wave_k_pad(len(rows))
+        k_pad = wave_k_pad(len(rows), self.max_batch)
         pad_rows = rows + [rows[0]] * (k_pad - len(rows))
         if wave_p:
             w_req = _bucket(
@@ -1439,8 +1660,8 @@ class ContinuousBatcher:
         total = sum(len(r) - wave_p for r in pad_rows)
         steps = max(1, -(-total // max(1, self._prefill_budget)))
         growth = (steps + 2) * eng.stream_interval
-        if any(
-            (self._pos + growth - (len(ids) - wave_p)) + w_req > eng.max_seq
+        if not all(
+            fits(len(ids) - wave_p, self._pos + growth, w_req, eng.max_seq)
             for _, ids, _ in batch
         ):
             return False
@@ -1460,132 +1681,80 @@ class ContinuousBatcher:
             return False
         self._pending_wave = _PendingWave(
             batch=batch, wave_p=wave_p, k_pad=k_pad, session=session,
-            t_start=time.monotonic(),
         )
         return True
 
-    def _advance_wave(self, pending_firsts: list, exhaust: bool) -> None:
+    def _advance_wave(self, exhaust: bool) -> None:
         """Dispatch one prefill credit (``LLMC_PREFILL_BUDGET`` total
         prompt tokens) of the pending wave — or, with ``exhaust`` (pool
         has nothing live to overlap with), run it to completion. On the
         final credit: splice at the CURRENT frontier, install the
         streams, and attach their first tokens to the next dispatched
         chunk's fetch. Each credit is one ``pool.admit`` span
-        (``interleaved``); the wave's counters are booked with the credit
-        that installs it."""
+        (``interleaved``), booked whichever way it ends; the wave's
+        counters are booked with the credit that installs it."""
+        eng = self.engine
         wave = self._pending_wave
-        with self._spans.span(
-            "pool.admit", self._tid, model=self._model, route="rows",
+        with self._booked(
+            "prefill", "pool.admit", "admit_s", route="rows",
             interleaved=True, exhaust=exhaust, rows_real=len(wave.batch),
             rows_padded=wave.k_pad, prefix=wave.wave_p,
             traces=[s.trace for _, _, s in wave.batch if s.trace],
-        ) as sp:
+        ) as (sp, deltas):
+            # Marked AFTER the gap closed, as at pool.establish.
+            self._mark_nondecode("admit", "prefill")
             for _, _, s in wave.batch:
                 s.marks.setdefault("admit_ns", sp.t0_ns)
-            self._advance_wave_credit(wave, pending_firsts, exhaust, sp)
-
-    def _advance_wave_credit(self, wave: "_PendingWave",
-                             pending_firsts: list, exhaust: bool,
-                             sp) -> None:
-        eng = self.engine
-        t_adm = time.monotonic()
-        # lint-ok: GS01 — scheduler-monotone read: only this thread
-        # increments _unfetched, so ==0 here is stable; a stale >0 just
-        # skips one gap-telemetry close.
-        adm_drained = self._unfetched == 0  # lint-ok: GS01 monotone read
-        if adm_drained:
-            self._close_gap(t_adm)
-        # Any prefill dispatch makes the next arrival interval impure —
-        # the device ran admission work between decode chunks.
-        self._nondecode_work = True
-        self._impure_kind = "prefill"
-        self._gap_phase = "admit"
-
-        def _book_prefill() -> None:
-            # Chip-time attribution: with the pipeline drained (exhaust
-            # path — nothing live to overlap) the credit's host wall is
-            # the device window; paced credits book through the impure
-            # arrival interval instead.
-            if self._attrib is not None and adm_drained:
-                self._attrib.observe_device(
-                    "prefill", time.monotonic() - t_adm
+            try:
+                done = wave.session.step(
+                    None if exhaust else self._prefill_budget
                 )
-
-        done = False
-        try:
-            budget = None if exhaust else self._prefill_budget
-            with _attrib_tag("prefill"):
-                done = wave.session.step(budget)
-            sp.set(done=done)
-            if not done:
-                self._stat_add(admit_s=time.monotonic() - t_adm)
-                _book_prefill()
-                return
-            with _attrib_tag("prefill"):
+                sp.set(done=done)
+                if not done:
+                    return
                 last_logits, pcache, width = wave.session.finish()
-        except Exception:  # noqa: BLE001
-            # Prefill-side failure (the _admit_batch try's territory):
-            # requeue the wave's streams and drop to classic admission,
-            # whose per-stream fallback ladder always progresses.
-            self._stat_add(admit_s=time.monotonic() - t_adm)
-            _book_prefill()
-            self._wave_fallback(wave)
-            return
-        # Frontier re-check at install time: decode advanced while the
-        # wave established. The headroom check in _begin_wave makes an
-        # overrun rare; when it happens anyway (stragglers broke the
-        # depth gate and extra chunks dispatched), requeue — wasted
-        # prefill, never a clamped (misaligned) splice.
-        if any(
-            n > self._pos or (self._pos - n) + width > eng.max_seq
-            for n in (len(ids) - wave.wave_p for _, ids, _ in wave.batch)
-        ):
+            except Exception:  # noqa: BLE001
+                # Prefill-side failure (the _admit_batch try's territory):
+                # requeue the wave's streams and drop to classic admission,
+                # whose per-stream fallback ladder always progresses.
+                self._wave_fallback(wave)
+                return
+            # Frontier re-check at install time: decode advanced while the
+            # wave established. The headroom check in _begin_wave makes an
+            # overrun rare; when it happens anyway (stragglers broke the
+            # depth gate and extra chunks dispatched), requeue — wasted
+            # prefill, never a clamped (misaligned) splice.
+            if not all(
+                fits(len(ids) - wave.wave_p, self._pos, width, eng.max_seq)
+                for _, ids, _ in wave.batch
+            ):
+                self._requeue_wave(wave)
+                return
+            # The wave stays pending until the install LANDS: a pool-fatal
+            # splice/sample failure propagates to _run, whose cleanup reaches
+            # these streams only through self._pending_wave (they are in
+            # neither the queue nor — fully — the slots); the final
+            # credit's wall is booked either way (ADVICE r5 parity with
+            # the classic sites).
+            entry = self._install_wave(
+                wave.batch, wave.wave_p, wave.k_pad, last_logits, pcache,
+                width,
+            )
+            sp.set(ok=True,
+                   tokens_real=sum(
+                       len(ids) - wave.wave_p for _, ids, _ in wave.batch
+                   ),
+                   chunks=wave.session.chunks,
+                   slot_tokens=wave.session.slot_tokens)
+            deltas.update(_wave_counts(sp.args))
             self._pending_wave = None
-            self._stat_add(admit_s=time.monotonic() - t_adm)
-            _book_prefill()
-            for _, _, s in wave.batch:
-                self._unpin_stream(s)  # requeued: re-pins at re-admission
-            with self._work:
-                self._queue[:0] = [
-                    (ids, s) for _, ids, s in wave.batch
-                ]
-                self._work.notify()
-            return
-        # The wave stays pending until the install LANDS: a pool-fatal
-        # splice/sample failure propagates to _run, whose cleanup reaches
-        # these streams only through self._pending_wave (they are in
-        # neither the queue nor — fully — the slots); the finally books
-        # the final credit's wall either way (ADVICE r5 parity with the
-        # classic sites).
-        installed = False
-        try:
-            with _attrib_tag("prefill"):
-                entry = self._install_wave(
-                    wave.batch, wave.wave_p, wave.k_pad, last_logits,
-                    pcache, width,
-                )
-            installed = True
-        finally:
-            deltas = {"admit_s": time.monotonic() - t_adm}
-            _book_prefill()
-            if installed:
-                tokens_real = sum(
-                    len(ids) - wave.wave_p for _, ids, _ in wave.batch
-                )
-                sp.set(ok=True, tokens_real=tokens_real,
-                       chunks=wave.session.chunks,
-                       slot_tokens=wave.session.slot_tokens)
-                deltas.update(_wave_counts(sp.args))
-                self._pending_wave = None
-            self._stat_add(**deltas)
-        pending_firsts.append(entry)
+        self._firsts.append(entry)
 
     def _wave_fallback(self, wave: "_PendingWave") -> None:
         """An interleaved wave's prefill failed: requeue its streams and
         disable interleaving for this batcher, so the retry takes the
         classic admission path (whose one-by-one fallback fails at most
         one stream) instead of re-entering the same failing session."""
-        self._pending_wave = None
         warnings.warn(
             "interleaved admission prefill failed; reverting to classic "
             "admission for this batcher",
@@ -1593,8 +1762,14 @@ class ContinuousBatcher:
             stacklevel=2,
         )
         self._prefill_budget = 0
+        self._requeue_wave(wave)
+
+    def _requeue_wave(self, wave: "_PendingWave") -> None:
+        """Give up the pending wave: its streams go back to the queue
+        head and re-pin at re-admission."""
+        self._pending_wave = None
         for _, _, s in wave.batch:
-            self._unpin_stream(s)  # classic retry re-pins
+            self._unpin_stream(s)
         with self._work:
             self._queue[:0] = [(ids, s) for _, ids, s in wave.batch]
             self._work.notify()
@@ -1729,6 +1904,57 @@ class ContinuousBatcher:
         phase, self._gap_phase = self._gap_phase, "schedule"
         if gap > 0:
             self._attrib.gap(gap, phase)
+
+    def _mark_nondecode(self, phase: str, family: str) -> None:
+        # Any non-decode device work makes the next arrival interval
+        # impure for decode-phase accounting — even if it fails and
+        # emits no firsts — and names the family that interval books
+        # against and the scheduler phase a host gap belongs to.
+        self._nondecode_work = True
+        self._impure_kind = family
+        self._gap_phase = phase
+
+    @contextmanager
+    def _booked(self, family: str, span_name: str,
+                wall_stat: Optional[str] = None, **span_args):
+        """Book one non-decode dispatch of the scheduler thread: the ONE
+        place such a dispatch feeds the phase walls, the attribution
+        ledger and the host-gap account. Yields the span (on this pool's
+        row, ``span_args`` its opening arguments) and the ``stats``
+        deltas booked with the wall at exit, for the body to add to."""
+        # ADVICE r5: the wall starts BEFORE the work and is accumulated
+        # in a finally — a failed prefill's wall, or a pool-fatal
+        # splice/sample failure's, is booked exactly like a successful
+        # one's (admission work is admission work whether or not it
+        # lands).
+        t0 = time.monotonic()
+        # lint-ok: GS01 — scheduler-monotone read: only this thread
+        # increments _unfetched, so ==0 here is stable; a stale >0 just
+        # skips one gap-telemetry close.
+        drained = self._unfetched == 0  # lint-ok: GS01 monotone read
+        if drained:
+            # The armed bubble ends where this drained dispatch's
+            # DEVICE window begins.
+            self._close_gap(t0)
+        deltas: dict = {}
+        with self._spans.span(
+            span_name, self._tid, model=self._model, **span_args
+        ) as sp, _attrib_tag(family):
+            try:
+                yield sp, deltas
+            finally:
+                if wall_stat is not None:
+                    deltas[wall_stat] = time.monotonic() - t0
+                if deltas:
+                    self._stat_add(**deltas)
+                if self._attrib is not None and drained:
+                    # Drained pipeline: nothing else was on the device
+                    # clock, so the host wall IS this dispatch's device
+                    # window (busy-pipeline work books through the
+                    # impure arrival interval instead).
+                    self._attrib.observe_device(
+                        family, time.monotonic() - t0
+                    )
 
     def _stat_add_locked(self, **deltas) -> None:
         sanitizer.assert_held(self._work)
@@ -1931,9 +2157,7 @@ class ContinuousBatcher:
             if self._shrink_patience >= 3:
                 self._shrink_patience = 0
                 self._drain_fetches()
-                self._nondecode_work = True
-                self._impure_kind = "compact"
-                self._gap_phase = "resize"
+                self._mark_nondecode("resize", "compact")
                 self._resize_to(target)
         else:
             self._shrink_patience = 0
@@ -2545,6 +2769,157 @@ class ContinuousBatcher:
             and not (self._closed and self._unfetched == 0)
         )
 
+    def _plan_admission(self, pending: list,
+                        share: bool = True) -> AdmissionPlan:
+        """``plan_admission`` over this pool's state (``share`` False:
+        with prefix sharing off for this pass)."""
+        eng = self.engine
+        kvp = getattr(eng, "_kv_pool", None)
+        pool_idle = not any(st is not None for st in self._slots)
+        mesh = getattr(eng, "mesh", None)
+        sp_degree = dict(mesh.shape).get("sp", 1) if mesh is not None else 1
+        return plan_admission(
+            [
+                Pending(ids, s.priority, s.ctx.done(), s.max_new)
+                for ids, s in pending
+            ],
+            [i for i in range(self._rows_cap) if self._slots[i] is None],
+            self._pos, eng.max_seq, pool_idle,
+            max_batch=self.max_batch, chunk=eng.prefill_chunk,
+            bucket=lambda n: _bucket(n, eng.max_seq),
+            rows_bucket=eng._rows_bucket,
+            prefix_enabled=share and self._prefix_enabled,
+            prefix_ids=self._prefix_ids, prefix_min=self._prefix_min,
+            resident_prefix_len=None if kvp is None else kvp.match_len,
+            sp_degree=sp_degree,
+            may_interleave=self._prefill_budget > 0
+            and self._pending_wave is None and not pool_idle,
+        )
+
+    def _execute_admission(self, plan: AdmissionPlan,
+                           pending: list) -> tuple:
+        """Carry one plan out — the one admission dispatcher: establish
+        the prefix it asks for, resolve, then ``rows``, falling to
+        ``single``. Returns ``(requeue, admitted)``: the streams that go
+        back to the queue, and whether the pass admitted classically (an
+        interleaved wave that opened ends the pass: one wave at a time,
+        later arrivals queue until it installs)."""
+        eng = self.engine
+        if plan.establish:
+            with self._booked(
+                "prefill", "pool.establish", "establish_s",
+                prefix=len(plan.establish),
+            ) as (sp, _):
+                # Marked AFTER the gap closed: that gap keeps the phase
+                # that ran during it (the absorb, as a rule).
+                self._mark_nondecode("establish", "prefill")
+                est_ok = self._establish_prefix(list(plan.establish))
+                sp.set(ok=est_ok)
+            if not est_ok:
+                # Establishment failure degrades to full-prompt rows:
+                # the plan assumed the prefix, so plan again without.
+                plan = self._plan_admission(pending, share=False)
+        if plan.clear_prefix:
+            self._clear_prefix()
+        self._pos = plan.pos
+        for i in plan.resolve:
+            _, stream = pending[i]
+            if stream.ctx.done():
+                # Expired while queued: resolve without prefill.
+                stream.finish = (
+                    "deadline" if stream.ctx.remaining() == 0.0
+                    else "cancelled"
+                )
+            stream.future.set_result(self._result(stream))
+        requeue = [pending[i] for i in plan.requeue]
+        batch = [(slot, *pending[i]) for i, slot in plan.admitted]
+        route = plan.route
+        if plan.interleave and self._begin_wave(batch, plan.wave_p):
+            # The wave's prefill session is open; _advance_wave
+            # paces its chunks between the decode dispatches,
+            # so resident streams never stall behind
+            # this wave's prefill. (Classic admission instead
+            # when the wave wouldn't fit the projected
+            # frontier or the session can't open.)
+            return requeue, False
+        if route == "rows" and not self._dispatch_admit(
+            "rows", batch, plan.wave_p
+        ):
+            route = "single"
+            if plan.wave_p:
+                # A failed SUFFIX-wave prefill would
+                # retry forever: the single-stream
+                # fallback can't fit a full prompt into
+                # the suffix-sized frontier, the rows
+                # requeue, and the next pass re-enters
+                # the same failing prefix path. Disable
+                # pool sharing (the established KV stays
+                # for rows already live on it) so the
+                # retry degrades to full-prompt
+                # admission, which always progresses.
+                warnings.warn(
+                    "shared-prefix wave prefill failed; "
+                    "disabling pool prefix sharing for "
+                    "this batcher",
+                    RuntimeWarning,
+                    stacklevel=2,
+                )
+                self._prefix_enabled = False
+        if route == "single":
+            for row in batch:
+                # The one-row path (the plan's choice, or the fallback of
+                # a failed wave) splices the FULL prompt (it never
+                # joins the shared prefix), so a row that was
+                # admitted under suffix accounting must re-check the
+                # full-window fit before _admit can misalign it.
+                n = len(row[1])
+                if not fits(n, self._pos, _bucket(n, eng.max_seq),
+                            eng.max_seq):
+                    requeue.append(row[1:])
+                    continue
+                self._dispatch_admit("single", [row], 0)
+        return requeue, bool(batch)
+
+    def _dispatch_admit(self, route: str, batch: list, wave_p: int) -> bool:
+        """One admission wave, from its dispatch to its last chunk
+        dispatched (the device runs on): ``rows`` is one padded wave
+        (``_admit_batch``), ``single`` one row through the engine's
+        single-stream prefill (``_admit``). False where its prefill
+        failed: a failed wave leaves every row to the caller's one-row
+        fallback; a failed one-row prefill (bad prompt, OOM on a new
+        bucket) fails THAT stream and the pool keeps serving the others."""
+        opening = {"rows_padded": 1} if route == "single" else {}
+        entry = None
+        self._mark_nondecode("admit", "prefill")
+        with self._booked(
+            "prefill", "pool.admit", "admit_s", route=route,
+            rows_real=len(batch), **opening,
+            tokens_real=sum(len(ids) - wave_p for _, ids, _ in batch),
+            prefix=wave_p,
+            traces=[s.trace for _, _, s in batch if s.trace],
+        ) as (sp, deltas):
+            for _, _, s in batch:
+                s.marks.setdefault("admit_ns", sp.t0_ns)
+            try:
+                if route == "single":
+                    (slot, ids, stream), = batch
+                    try:
+                        entry = self._admit(slot, ids, stream, sp)
+                    except Exception as exc:  # noqa: BLE001
+                        stream.future.set_exception(exc)
+                        if stream.jentry is not None:
+                            # Terminal for this stream on a HEALTHY
+                            # pool: not a replay candidate.
+                            stream.jentry.close("failed")
+                else:
+                    entry = self._admit_batch(batch, wave_p, sp)
+            finally:
+                sp.set(ok=entry is not None)
+                if entry is not None:
+                    deltas.update(_wave_counts(sp.args))
+                    self._firsts.append(entry)
+        return entry is not None
+
     def _loop(self) -> None:
         eng = self.engine
         chunk = eng.stream_interval
@@ -2557,12 +2932,9 @@ class ContinuousBatcher:
         # bounded. Only at the compaction waterline does the loop drain
         # the pipeline FIRST (a full row about to be retired must not
         # lose its fetched tokens) and give up the overlap.
-        #
-        # pending_firsts: [(slot list, samples array, owner list)] per
-        # admission wave since the last dispatch — attached to the next
-        # dispatched chunk so prefill-sampled tokens ride down with its
-        # fetch (they persist across iterations that skip dispatching).
-        pending_firsts: list[tuple] = []
+        # Fetch, emit, retirement, and cancellation sweeps all run on
+        # the fetch worker (_fetch_worker); the scheduler loops
+        # straight back to admission/dispatch.
         while True:
             # Schedule-exploration seam (analysis/schedule.py): one
             # iteration of the scheduler loop is the protocol step the
@@ -2662,22 +3034,13 @@ class ContinuousBatcher:
                 # Waterline: drain the pipeline before compaction's
                 # full-row retires, so no fetched token is lost.
                 self._drain_fetches()
-                self._nondecode_work = True  # compaction breaks steadiness
-                self._impure_kind = "compact"
-                self._gap_phase = "compact"
-                t_cpt = time.monotonic()
-                self._close_gap(t_cpt)  # compaction runs pipeline-drained
-                with self._spans.span(
-                    "pool.compact", self._tid, model=self._model,
-                ) as sp, _attrib_tag("compact"):
+                # Compaction breaks steadiness; it runs pipeline-drained,
+                # so its booked wall is the host dispatch wall of the
+                # roll (nothing else is on the device clock).
+                self._mark_nondecode("compact", "compact")
+                with self._booked("compact", "pool.compact") as (sp, _):
                     self._compact()
                     sp.set(pos=self._pos)
-                if self._attrib is not None:
-                    # Host dispatch wall of the roll (the pipeline is
-                    # drained, so nothing else is on the device clock).
-                    self._attrib.observe_device(
-                        "compact", time.monotonic() - t_cpt
-                    )
                 if self._pos >= eng.max_seq:
                     # Compaction could not make room (unreachable by
                     # construction — the full-row retire precedes the
@@ -2688,8 +3051,7 @@ class ContinuousBatcher:
                             self._retire(i, "length")
             # Admission (outside the lock: prefill can compile/run long).
             # A prompt longer than the current frontier — or whose splice
-            # bucket would overrun capacity (dynamic_update_slice clamps,
-            # which would silently misalign the row) — waits; when the
+            # bucket would overrun capacity — waits (``fits``); when the
             # pool is idle the frontier resets to fit the wave. Splices
             # are enqueued behind the in-flight chunk on the device, and a
             # replaced slot's in-flight tokens are dropped by the owner
@@ -2723,16 +3085,7 @@ class ContinuousBatcher:
                     # pools on this engine). Bounded wait, not hot spin.
                     with self._work:
                         self._work.wait(timeout=0.01)
-            firsts = pending_firsts  # waves accumulate until a dispatch
-            requeue: list[tuple[list, _Stream]] = []
             while True:
-                # Priority-ordered admission (pressure/): a stable sort,
-                # so FIFO survives WITHIN a class while a higher class
-                # drained in the same pass takes slots first. Requeued
-                # streams keep their no-leapfrog fairness per class; a
-                # higher class overtaking a requeued lower one is the
-                # point.
-                pending.sort(key=lambda item: item[1].priority)
                 if self._rows_bucket_enabled and self._rows_cap < self.max_batch:
                     # Admission-driven regrowth: a burst that needs more
                     # slots than the shrunken row bucket offers
@@ -2746,347 +3099,12 @@ class ContinuousBatcher:
                     target = self._rows_target(demand)
                     if target > self._rows_cap:
                         self._drain_fetches()
-                        self._nondecode_work = True
-                        self._impure_kind = "compact"
-                        self._gap_phase = "resize"
+                        self._mark_nondecode("resize", "compact")
                         self._resize_to(target)
-                free = [
-                    i for i in range(self._rows_cap)
-                    if self._slots[i] is None
-                ]
-                batch: list[tuple[int, list, _Stream]] = []
-                pool_idle = not any(st is not None for st in self._slots)
-                candidates = [
-                    ids for ids, s in pending
-                    if not s.ctx.done() and s.max_new > 0
-                ]
-                # Shared-prefix mode for THIS wave (the one-prompt fan-out
-                # pattern): all-or-nothing per wave. Pool idle → establish
-                # (or re-establish) from the wave's own common prefix;
-                # pool busy → join the established prefix only if every
-                # candidate starts with it. A wave that can't share
-                # admits full-prompt rows; establishment failure degrades
-                # the same way.
-                wave_p = 0
-                if (
-                    pool_idle
-                    and not self._prefix_enabled
-                    and self._prefix_cache is not None
-                ):
-                    # No live row can reference the prefix any more and
-                    # sharing is off (env, or the failure fallback
-                    # above): drop it so decode returns to the cheaper
-                    # no-prefix program.
-                    self._clear_prefix()
-                if self._prefix_enabled and candidates and not requeue:
-                    p0 = self._prefix_len_host
-                    matches_current = self._prefix_cache is not None and all(
-                        len(r) > p0 and tuple(r[:p0]) == self._prefix_ids
-                        for r in candidates
-                    )
-                    if matches_current:
-                        # Join the established prefix (idle or busy, any
-                        # wave size) — no re-establishment churn.
-                        wave_p = p0
-                    elif pool_idle:
-                        common = candidates[0]
-                        for r in candidates[1:]:
-                            m = min(len(common), len(r))
-                            i = 0
-                            while i < m and common[i] == r[i]:
-                                i += 1
-                            common = common[:i]
-                        p = min(len(common), min(len(r) for r in candidates) - 1)
-                        est_p = (
-                            p if p >= self._prefix_min
-                            and len(candidates) > 1 else 0
-                        )
-                        kvp = getattr(self.engine, "_kv_pool", None)
-                        if not est_p and kvp is not None and \
-                                p >= self._prefix_min:
-                            # Radix consult (paged pool on): a wave with
-                            # no intra-wave sharing — a lone candidate is
-                            # the common case — still establishes when
-                            # the pool already holds its prefix, sized to
-                            # the resident span so establishment is a
-                            # block gather, not a prefill. Rows then
-                            # admit as SUFFIXES: the wave prefills only
-                            # unmatched tail tokens and its decode window
-                            # shrinks to the suffix, which is where the
-                            # pooled max-resident-streams headroom
-                            # comes from.
-                            hit = kvp.match_len(list(candidates[0][:p]))
-                            if hit >= self._prefix_min:
-                                est_p = hit
-                        if est_p:
-                            t_est = time.monotonic()
-                            est_drained = self._unfetched == 0  # lint-ok: GS01 monotone read
-                            if est_drained:
-                                self._close_gap(t_est)
-                            self._gap_phase = "establish"
-                            with self._spans.span(
-                                "pool.establish", self._tid,
-                                model=self._model, prefix=est_p,
-                            ) as sp, _attrib_tag("prefill"):
-                                est_ok = self._establish_prefix(
-                                    list(candidates[0][:est_p])
-                                )
-                                sp.set(ok=est_ok)
-                            self._stat_add(
-                                establish_s=time.monotonic() - t_est
-                            )
-                            if self._attrib is not None and est_drained:
-                                self._attrib.observe_device(
-                                    "prefill", time.monotonic() - t_est
-                                )
-                            if est_ok:
-                                wave_p = est_p
-                        else:
-                            # No qualifying shared prefix: drop back to
-                            # the cheaper no-prefix decode program.
-                            self._clear_prefix()
-                if pool_idle and pending and not requeue:
-                    # Idle frontier resets to the wave's longest prompt
-                    # (suffix length under shared-prefix admission) so
-                    # the whole wave can right-align to one frontier.
-                    live = [len(ids) - wave_p for ids in candidates]
-                    if live:
-                        self._pos = self._idle_frontier(
-                            live[:len(self._slots)], bool(wave_p)
-                        )
-                for ids, stream in pending:
-                    if stream.ctx.done():
-                        # Expired while queued: resolve without prefill.
-                        stream.finish = (
-                            "deadline" if stream.ctx.remaining() == 0.0
-                            else "cancelled"
-                        )
-                        stream.future.set_result(self._result(stream))
-                        continue
-                    if stream.max_new <= 0:
-                        stream.future.set_result(self._result(stream))
-                        continue
-                    if requeue or not free:
-                        # FIFO fairness: once any stream this round was
-                        # requeued (frontier/capacity/slots), later
-                        # arrivals must not leapfrog it — under sustained
-                        # load a long prompt would otherwise starve until
-                        # the pool fully drained.
-                        requeue.append((ids, stream))
-                        continue
-                    n = len(ids) - wave_p  # window the row will occupy
-                    # Capacity must hold for the admission form in play:
-                    # full-prompt waves splice _rows_bucket(n) wide (and
-                    # may fall back to the single-stream _bucket(n)
-                    # splice), shared-prefix waves splice their suffix
-                    # bucket — an unchecked overrun makes
-                    # dynamic_update_slice clamp and silently misalign
-                    # the row.
-                    if wave_p:
-                        w_req = _bucket(n, eng.max_seq)
-                    else:
-                        w_req = max(
-                            _bucket(n, eng.max_seq), eng._rows_bucket(n)
-                        )
-                    if n > self._pos or (self._pos - n) + w_req > eng.max_seq:
-                        requeue.append((ids, stream))
-                        continue
-                    # Batched waves splice rows at one shared width, so
-                    # every member must also fit THAT width; a candidate
-                    # that would push the wave width past some member's
-                    # capacity requeues instead of corrupting the splice.
-                    if batch:
-                        members = [
-                            len(i2) - wave_p for _, i2, _ in batch
-                        ] + [n]
-                        if wave_p:
-                            w_new = _bucket(max(members), eng.max_seq)
-                        else:
-                            w_new = eng._rows_bucket(max(members))
-                        if any(
-                            (self._pos - nj) + w_new > eng.max_seq
-                            for nj in members
-                        ):
-                            requeue.append((ids, stream))
-                            continue
-                    batch.append((free.pop(0), ids, stream))
-                pending = []
-                if batch and getattr(eng, "mesh", None) is not None and (
-                    dict(eng.mesh.shape).get("sp", 1) > 1
-                ):
-                    # sp meshes keep ring prefill (batched admission is
-                    # plain left-aligned prefill).
-                    batch_singles = batch
-                else:
-                    batch_singles = []
-                    if batch and (
-                        self._prefill_budget > 0
-                        and self._pending_wave is None
-                        and any(st is not None for st in self._slots)
-                    ):
-                        # Interleaved admission (prefill/decode overlap):
-                        # open the wave's prefill session; _advance_wave
-                        # paces its chunks between the decode dispatches
-                        # below, so resident streams never stall behind
-                        # this wave's prefill. Falls through to classic
-                        # admission when the wave wouldn't fit the
-                        # projected frontier or the session can't open.
-                        # An idle pool admits classically too — there is
-                        # no decode to overlap, and the stall-free first
-                        # chunk matters more than pacing.
-                        if self._begin_wave(batch, wave_p):
-                            # Admission pass ends here (empty batch breaks
-                            # the loop below): one wave at a time, later
-                            # arrivals queue until it installs.
-                            batch = []
-                    if batch and not wave_p and self._singles_cover_fewer(
-                        [len(i2) for _, i2, _ in batch]
-                    ):
-                        # Long rows, few of them: the one-row path
-                        # covers fewer token slots than the padded wave.
-                        # (Suffix waves stay batched: a single row
-                        # cannot join the pool's prefix.)
-                        batch_singles = batch
-                    elif batch:
-                        # Any admission work makes the next arrival
-                        # interval impure for decode-phase accounting,
-                        # even if the prefill fails and emits no firsts.
-                        self._nondecode_work = True
-                        self._impure_kind = "prefill"
-                        self._gap_phase = "admit"
-                        # ADVICE r5 (batcher.py:1326 area): t_adm BEFORE
-                        # the admit try, admit_s accumulated in a finally
-                        # — a pool-fatal splice/sample failure's wall is
-                        # booked like any other failed prefill's.
-                        t_adm = time.monotonic()
-                        adm_drained = self._unfetched == 0  # lint-ok: GS01 monotone read
-                        if adm_drained:
-                            # The armed bubble ends where this drained
-                            # admission's DEVICE window begins.
-                            self._close_gap(t_adm)
-                        admitted = None
-                        tokens_real = sum(
-                            len(i2) - wave_p for _, i2, _ in batch
-                        )
-                        # One admission wave, from its dispatch to its
-                        # last chunk dispatched (the device runs on).
-                        with self._spans.span(
-                            "pool.admit", self._tid, model=self._model,
-                            route="rows", rows_real=len(batch),
-                            tokens_real=tokens_real, prefix=wave_p,
-                            traces=[s.trace for _, _, s in batch if s.trace],
-                        ) as sp:
-                            for _, _, s in batch:
-                                s.marks.setdefault("admit_ns", sp.t0_ns)
-                            try:
-                                with _attrib_tag("prefill"):
-                                    admitted = self._admit_batch(
-                                        batch, wave_p, sp
-                                    )
-                            finally:
-                                sp.set(ok=admitted is not None)
-                                deltas = {"admit_s": time.monotonic() - t_adm}
-                                if admitted is not None:
-                                    deltas.update(_wave_counts(sp.args))
-                                self._stat_add(**deltas)
-                                if self._attrib is not None and adm_drained:
-                                    # Drained pipeline: nothing else was
-                                    # on the device clock, so the
-                                    # admission host wall IS this
-                                    # dispatch's device window
-                                    # (busy-pipeline admissions book
-                                    # through the impure arrival interval
-                                    # instead).
-                                    self._attrib.observe_device(
-                                        "prefill", time.monotonic() - t_adm
-                                    )
-                        if admitted is None:
-                            batch_singles = batch
-                            if wave_p:
-                                # A failed SUFFIX-wave prefill would
-                                # retry forever: the single-stream
-                                # fallback can't fit a full prompt into
-                                # the suffix-sized frontier, the rows
-                                # requeue, and the next pass re-enters
-                                # the same failing prefix path. Disable
-                                # pool sharing (the established KV stays
-                                # for rows already live on it) so the
-                                # retry degrades to full-prompt
-                                # admission, which always progresses.
-                                import warnings
-
-                                warnings.warn(
-                                    "shared-prefix wave prefill failed; "
-                                    "disabling pool prefix sharing for "
-                                    "this batcher",
-                                    RuntimeWarning,
-                                    stacklevel=2,
-                                )
-                                self._prefix_enabled = False
-                        else:
-                            firsts += admitted
-                for slot, ids, stream in batch_singles:
-                    # The one-row path (chosen above, or the fallback of
-                    # a failed wave) splices the FULL prompt (it never
-                    # joins the shared prefix), so a row that was
-                    # admitted under suffix accounting must re-check the
-                    # full-window fit before _admit can misalign it.
-                    n = len(ids)
-                    if n > self._pos or (
-                        (self._pos - n) + _bucket(n, eng.max_seq)
-                        > eng.max_seq
-                    ):
-                        requeue.append((ids, stream))
-                        continue
-                    self._nondecode_work = True
-                    self._impure_kind = "prefill"
-                    self._gap_phase = "admit"
-                    # ADVICE r5: t_adm before the admit try, admit_s in a
-                    # finally — a failed prefill's wall is booked exactly
-                    # like a successful one's (admission work is
-                    # admission work whether or not it lands; the
-                    # impurity comment above already promises this).
-                    t_adm = time.monotonic()
-                    adm_drained = self._unfetched == 0  # lint-ok: GS01 monotone read
-                    if adm_drained:
-                        self._close_gap(t_adm)
-                    tok = None
-                    admit_ok = False
-                    with self._spans.span(
-                        "pool.admit", self._tid, model=self._model,
-                        route="single", rows_real=1, rows_padded=1,
-                        tokens_real=len(ids), prefix=0,
-                        traces=[stream.trace] if stream.trace else [],
-                    ) as sp:
-                        stream.marks.setdefault("admit_ns", sp.t0_ns)
-                        try:
-                            with _attrib_tag("prefill"):
-                                tok = self._admit(slot, ids, stream)
-                            admit_ok = True
-                        except Exception as exc:  # noqa: BLE001
-                            # A failed prefill (bad prompt, OOM on a new
-                            # bucket) fails THIS stream; the pool keeps
-                            # serving others.
-                            stream.future.set_exception(exc)
-                            if stream.jentry is not None:
-                                # Terminal for this stream on a HEALTHY
-                                # pool: not a replay candidate.
-                                stream.jentry.close("failed")
-                        finally:
-                            sp.set(ok=admit_ok)
-                            deltas = {"admit_s": time.monotonic() - t_adm}
-                            if admit_ok:
-                                chunks, slot_tokens = eng.last_prefill
-                                sp.set(chunks=chunks, slot_tokens=slot_tokens)
-                                deltas.update(_wave_counts(sp.args))
-                            self._stat_add(**deltas)
-                            if self._attrib is not None and adm_drained:
-                                self._attrib.observe_device(
-                                    "prefill", time.monotonic() - t_adm
-                                )
-                    if admit_ok and tok is not None:
-                        firsts.append(([slot], tok, [self._slots[slot]]))
-                if requeue or not batch:
+                requeue, admitted = self._execute_admission(
+                    self._plan_admission(pending), pending
+                )
+                if requeue or not admitted:
                     break
                 if not any(st is None for st in self._slots):
                     break
@@ -3157,7 +3175,6 @@ class ContinuousBatcher:
                 # pool with nothing live has nothing to overlap: exhaust
                 # the session and install immediately.
                 self._advance_wave(
-                    pending_firsts,
                     exhaust=not any(s is not None for s in self._slots),
                 )
             if any(s is not None for s in self._slots):
@@ -3222,7 +3239,7 @@ class ContinuousBatcher:
                     # progress is guaranteed.
                 if (
                     self._rows_bucket_enabled
-                    and not pending_firsts
+                    and not self._firsts
                     and self._pending_wave is None
                 ):
                     # Never shrink with undispatched firsts pending:
@@ -3344,7 +3361,7 @@ class ContinuousBatcher:
                 # Pure decode interval iff nothing but the previous
                 # chunk ran on the device since the last dispatch — no
                 # admission prefills (even failed ones), no compaction.
-                pure = not pending_firsts and not self._nondecode_work
+                pure = not self._firsts and not self._nondecode_work
                 self._beat = time.monotonic()  # dispatch = progress
                 for s in self._slots[:self._rows_cap]:
                     if s is not None:
@@ -3361,9 +3378,9 @@ class ContinuousBatcher:
                 t_dispatch = time.monotonic()
                 item = (
                     payload, list(self._slots[:self._rows_cap]),
-                    pending_firsts, pure, t_dispatch, mode,
+                    self._firsts, pure, t_dispatch, mode,
                 )
-                pending_firsts = []
+                self._firsts = []
                 self._nondecode_work = False
                 with self._work:
                     self._unfetched += 1
@@ -3377,6 +3394,3 @@ class ContinuousBatcher:
                     # ran during it.
                     self._close_gap(t_dispatch)
                 self._fetch_q.put(item)
-            # Fetch, emit, retirement, and cancellation sweeps all run on
-            # the fetch worker (_fetch_worker); the scheduler loops
-            # straight back to admission/dispatch.

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 
 from llm_consensus_tpu.engine import ContinuousBatcher, Engine, SamplingParams
+from llm_consensus_tpu.engine.batcher import singles_cover_fewer
 from llm_consensus_tpu.models import get_config, init_params
 from llm_consensus_tpu.utils import Context
 
@@ -568,8 +569,9 @@ def test_wave_prefix_reuse_across_bursts():
     assert 0 < wave2_chunks < wave1_chunks, (wave1_chunks, wave2_chunks)
     b, gate = _gated_batcher(eng, max_batch=2)
     try:
-        assert b._singles_cover_fewer(
-            [len(eng.tokenizer.encode(p)) for p in w1])  # the tie
+        assert singles_cover_fewer(
+            [len(eng.tokenizer.encode(p)) for p in w1],
+            b.max_batch, eng.prefill_chunk, eng._rows_bucket)  # the tie
         futs = [b.submit(p, s) for p in w1]
         gate.set()
         r1 = [f.result(timeout=300) for f in futs]
@@ -704,8 +706,9 @@ def test_wave_admission_non_chunk_multiple_capacity():
     # (padded to chunks they would cover 208), so it stays one wave;
     # shorter rows would be admitted one by one.
     prompts = ["x " * 94 + "yone", "x " * 94 + "ytwo"]
-    assert not b._singles_cover_fewer(
-        [len(eng.tokenizer.encode(p)) for p in prompts])
+    assert not singles_cover_fewer(
+        [len(eng.tokenizer.encode(p)) for p in prompts],
+        b.max_batch, eng.prefill_chunk, eng._rows_bucket)
     try:
         futs = [b.submit(p, s) for p in prompts]
         gate.set()
