@@ -161,6 +161,36 @@ def _wave_counts(admit: dict) -> dict:
     }
 
 
+# What a pool row WITHOUT a stream carries as its device ``row_start``:
+# past any frontier, so the row has no valid cache slot. The decode
+# kernel's sweep plan (ops/pallas/decode_attention.py _sweep_plan) then
+# neither fetches nor computes it, and the XLA decode route masks its
+# every slot (finite logits; the row's tokens are dropped, and the
+# finite-logit sentinel skips rows without an owner). It is data beside
+# ``pos``, not shape: no program per occupancy.
+DEAD_ROW = 1 << 30
+
+
+@jax.jit
+def _mark_dead(row_start, dead):
+    return jnp.where(dead, DEAD_ROW, row_start)
+
+
+def kv_slots_live(pos: int, steps: int, stride: int, row_starts: Sequence[int],
+                  window: Optional[int] = None) -> int:
+    """Cache slots the live rows' attention may read over one decode
+    dispatch: forward ``t`` of ``steps`` (each advancing the frontier by
+    ``stride`` slots from ``pos``) reads a row's slots from its
+    ``row_start`` to the frontier it writes, a sliding ``window`` of them
+    at most. The ``decode_kv_slots_live`` counter sums this; against
+    ``decode_kv_slots_swept`` (steps x pool rows x bucket width) it is the
+    share of the sweep the traffic leaves to do."""
+    ends = [pos + (t + 1) * stride for t in range(steps)]
+    if window is None or not row_starts or ends[-1] - min(row_starts) <= window:
+        return sum(ends) * len(row_starts) - steps * sum(row_starts)
+    return sum(min(e - rs, window) for rs in row_starts for e in ends)
+
+
 def fits(n: int, pos: int, width: int, max_seq: int) -> bool:
     """THE fit rule — the one safety condition of admission, written
     once: a row of ``n`` tokens admitted at frontier ``pos`` splices a
@@ -765,6 +795,12 @@ class ContinuousBatcher:
         self._token = place(jnp.zeros((max_batch,), jnp.int32))
         self._row_start = place(jnp.zeros((max_batch,), jnp.int32))
         self._row_start_host = [0] * max_batch
+        # Which rows of the device ``row_start`` carry DEAD_ROW (a tuple of
+        # bools, one a pool row; None: not known, mark again). Slots are
+        # freed on the fetch worker and the device vector is the
+        # scheduler's, so the marks are brought up to ``_slots`` where the
+        # next decode chunk is dispatched (_mark_dead_rows).
+        self._dead_marked: Optional[tuple] = None
         self._pos = 0  # shared frontier (host int; traced into the chunk)
         self._key = place(jax.random.PRNGKey(0))
         # Shared-prefix pool state (the one-prompt fan-out pattern): when
@@ -861,6 +897,11 @@ class ContinuousBatcher:
             # the token slots their prefill programs covered (padding
             # share = 1 − admit_tokens ÷ prefill_slot_tokens).
             "decode_chunks": 0, "decode_steps": 0, "decode_row_steps": 0,
+            # Cache slots the decode steps' attention spanned as pool rows
+            # x bucket width (what a sweep of every row reads) and the
+            # slots of rows with a stream inside their own windows
+            # (kv_slots_live): their ratio is what is left to sweep.
+            "decode_kv_slots_swept": 0, "decode_kv_slots_live": 0,
             "prefill_waves": 0, "prefill_rows_real": 0,
             "prefill_rows_padded": 0, "prefill_slot_tokens": 0,
         }
@@ -2145,6 +2186,7 @@ class ContinuousBatcher:
                     [sp.blen, place(jnp.zeros((pad,), jnp.int32))]
                 )
         self._rows_cap = target
+        self._dead_marked = None  # rows moved, cut or zero-padded
 
     def _maybe_shrink(self) -> None:
         """Shrink the decode row bucket when occupancy has stayed below
@@ -2192,6 +2234,7 @@ class ContinuousBatcher:
         self._cache = _compact_cache(self._cache, jnp.asarray(shift))
         self._row_start_host = [r - shift for r in self._row_start_host]
         self._row_start = self._row_start - shift
+        self._dead_marked = None  # the shift moved the dead rows' mark too
         self._pos -= shift
         if self._spec is not None:
             # The bitmap slides with the KV it describes; slots that wrap
@@ -2201,6 +2244,20 @@ class ContinuousBatcher:
             self._spec.valid = _roll_valid(
                 self._spec.valid, jnp.asarray(shift)
             )
+
+    def _mark_dead_rows(self) -> None:
+        """Bring the device ``row_start`` up to ``_slots`` before a decode
+        dispatch: every row without a stream (never admitted, retired,
+        failed, preempted) gets DEAD_ROW. Admission writes a row's real
+        start over the mark (_admit, _admit_finish); compaction and row
+        moves forget the marks (``_dead_marked = None``), and a spec
+        round's hole count only moves a dead row's start further out."""
+        dead = tuple(s is None for s in self._slots[:self._rows_cap])
+        if dead != self._dead_marked:
+            self._row_start = _mark_dead(
+                self._row_start, self.engine._place(jnp.asarray(dead))
+            )
+            self._dead_marked = dead
 
     def _plan_steps(self, chunk: int) -> int:
         """The n_steps policy, shared by the classic dispatch path and a
@@ -3288,9 +3345,15 @@ class ContinuousBatcher:
                 # One decode-chunk dispatch: the host wall of the async
                 # enqueue (device time surfaces as fetch arrivals). Live
                 # rows are counted here, where the chunk is issued.
-                rows_live = sum(
-                    1 for s in self._slots[:self._rows_cap] if s is not None
-                )
+                live_starts = [
+                    self._row_start_host[i]
+                    for i, s in enumerate(self._slots[:self._rows_cap])
+                    if s is not None
+                ]
+                rows_live = len(live_starts)
+                pos0 = self._pos
+                window = eng.cfg.sliding_window
+                self._mark_dead_rows()
                 if self._spec is not None and sampling.temperature == 0.0:
                     # Speculative decode mode: the dispatch becomes a
                     # ROUND GROUP (or a bitmap-maintaining plain window
@@ -3302,10 +3365,18 @@ class ContinuousBatcher:
                         rows_live=rows_live, rows=self._rows_cap,
                     ) as sp, _attrib_tag("spec_verify"):
                         payload, covered, mode = self._dispatch_spec(chunk)
-                        sp.set(steps=covered, pos=self._pos, spec=mode)
+                        slots_live = kv_slots_live(
+                            pos0, covered, (self._pos - pos0) // covered,
+                            live_starts, window,
+                        )
+                        sp.set(steps=covered, pos=self._pos, spec=mode,
+                               slots_live=slots_live)
                 else:
                     n_steps = self._plan_steps(chunk)
                     kv_width = eng._decode_width(self._pos + n_steps)
+                    slots_live = kv_slots_live(
+                        pos0, n_steps, 1, live_starts, window
+                    )
                     sentinel = self._integrity is not None
                     poison = None
                     if sentinel and eng._faults is not None:
@@ -3324,7 +3395,7 @@ class ContinuousBatcher:
                         "pool.decode", self._tid, model=self._model,
                         steps=n_steps, kv_width=kv_width or 0,
                         rows_live=rows_live, rows=self._rows_cap,
-                        pos=self._pos + n_steps,
+                        pos=self._pos + n_steps, slots_live=slots_live,
                     ), _attrib_tag("decode"):
                         out = eng._flash_guard(
                             lambda impl: _decode_chunk(
@@ -3387,6 +3458,10 @@ class ContinuousBatcher:
                     self._stat_add_locked(
                         decode_chunks=1, decode_steps=covered,
                         decode_row_steps=covered * rows_live,
+                        decode_kv_slots_swept=covered * self._rows_cap * (
+                            eng._decode_width(self._pos) or eng.max_seq
+                        ),
+                        decode_kv_slots_live=slots_live,
                     )
                     # Host gap closed: the device sat idle from the
                     # drain to this dispatch while the batcher was busy

@@ -237,6 +237,7 @@ def _layer(
     ring_mesh=None,  # SP prefill: ring attention over this mesh's sp axis
     decode_flash: bool = False,  # T=1: fused Pallas decode-attention kernel
     row_start: Optional[jax.Array] = None,  # [B] (decode_flash path only)
+    decode_sweep: Optional[jax.Array] = None,  # the step's sweep plan (ditto)
     prefix_k=None,        # shared-prefix K stack [L, 1, P, Hkv, dh] (or int8 dict)
     prefix_v=None,
     prefix_len=None,      # scalar i32: valid prefix slots
@@ -389,14 +390,18 @@ def _layer(
         from llm_consensus_tpu.ops.pallas import decode_attention
 
         with_state = prefix_k is not None
-        da = partial(
-            decode_attention,
-            scale=dh ** -0.5,
-            sliding_window=cfg.sliding_window,
-            logit_softcap=cfg.attn_logit_softcap,
-            kv_width=kv_width,
-            return_state=with_state,
-        )
+
+        def da(q_, k_, v_, pos_, li_, rs_, sweep_):
+            return decode_attention(
+                q_, k_, v_, pos_, li_, rs_,
+                scale=dh ** -0.5,
+                sliding_window=cfg.sliding_window,
+                logit_softcap=cfg.attn_logit_softcap,
+                kv_width=kv_width,
+                return_state=with_state,
+                sweep=sweep_,
+            )
+
         rs = row_start
         if rs is None:
             rs = jnp.zeros((b,), jnp.int32)
@@ -418,15 +423,18 @@ def _layer(
                 )
                 if is_quantized(k_att) else spec5
             )
+            # The scalars (pos, layer, row_start, the step's sweep plan)
+            # are the same on every shard.
             da = jax.shard_map(
                 da, mesh=flash_mesh,
-                in_specs=(spec, kv_spec, kv_spec, P(), P(), P(None)),
+                in_specs=(spec, kv_spec, kv_spec, P(), P(), P(None), P(None)),
                 out_specs=(spec, P(None, "tp"), P(None, "tp"))
                 if with_state else spec,
                 check_vma=False,
             )
         attn_out = da(
-            q, k_att, v_att, jnp.asarray(start_pos, jnp.int32), layer_idx, rs
+            q, k_att, v_att, jnp.asarray(start_pos, jnp.int32), layer_idx, rs,
+            decode_sweep,
         )
         if with_state:
             attn_out, m2, l2 = attn_out
@@ -715,10 +723,27 @@ def forward(
         tp_sz = dict(mesh.shape).get("tp", 1)
         if tp_sz > 1 and (cfg.n_heads % tp_sz or cfg.n_kv_heads % tp_sz):
             qkv_pin = mesh
+    sweep = None
+    if decode_flash:
+        # Which kv blocks of which rows this step's attention sweeps is
+        # the same in every layer: planned once here, beside the layer
+        # scan, from the frontier and the rows' starts (a row without a
+        # stream starts past the frontier and is left out).
+        from llm_consensus_tpu.ops.pallas.decode_attention import (
+            decode_sweep_plan)
+
+        sweep = decode_sweep_plan(
+            start,
+            jnp.zeros((b,), jnp.int32) if row_start is None else row_start,
+            width=decode_width,
+            n_kv_heads=cfg.n_kv_heads // max(shard_tp, 1),  # a shard's
+            dh=cfg.head_dim, kv_item=k_store.dtype.itemsize,
+            quantized=decode_quantized, sliding_window=cfg.sliding_window,
+        )
     layer_fn = partial(
         _layer, cfg, flash_offset=flash_offset, flash_mesh=flash_mesh,
         kv_width=kv_width, qkv_pin=qkv_pin,
-        decode_flash=decode_flash, row_start=row_start,
+        decode_flash=decode_flash, row_start=row_start, decode_sweep=sweep,
         prefix_k=prefix["k"] if prefix is not None else None,
         prefix_v=prefix["v"] if prefix is not None else None,
         prefix_len=prefix_len,

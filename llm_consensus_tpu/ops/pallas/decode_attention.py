@@ -31,14 +31,28 @@ Design notes, TPU-first:
     level — fewer kv blocks, not a sliced operand — so attention cost
     scales with the causal frontier, never with cache capacity, and no
     bytes are ever copied to enforce the bound.
+  * **The sweep follows live rows and valid slots, not pool rows ×
+    bucket width** (``_sweep_plan``). A pool row without a stream
+    carries a ``row_start`` past the frontier (engine/batcher.py
+    DEAD_ROW): data like ``pos``, so one program serves every
+    occupancy. Every K, V and scale index map clamps its kv block into
+    the blocks that hold a row block's ``[row_start, pos]`` (and the
+    sliding window), and a row block with no valid slot is given the
+    block index the step beside it already holds; the pipeline does not
+    fetch an index twice, so dead rows and the blocks before a row's
+    start cost a grid step each (~0.35 us) and neither bytes nor
+    compute. Measured on a v5e at the 3B's shape (6 rows, 16 / 2 heads,
+    1,920 slots, one live row): 74.7 → 19.0 us a layer call.
   * Grid (B/b_block, kv_blocks), kv innermost, with a statically
     unrolled per-head loop INSIDE each iteration whose matmuls are
-    BATCHED over up to 8 batch rows: the per-head matmuls are tiny, so
-    per-grid-point overhead and small DMAs — not FLOPs — bound the
+    BATCHED over the block's batch rows: the per-head matmuls are tiny,
+    so per-grid-point overhead and small DMAs — not FLOPs — bound the
     kernel. One [b_block, block_k, Hkv, dh] transfer per iteration
-    amortizes both across heads AND rows. (b_block, block_k) are chosen
-    to maximize bytes per iteration within a VMEM budget that counts
-    code blocks, scale blocks, and dequant temporaries.
+    amortizes both across heads AND rows. ``_choose_blocks`` takes the
+    longest block of ONE row that fits VMEM and ``_ITER_BYTES`` (any
+    128-multiple divisor of the bucket up to ``_ITER_SLOTS``: 1,920
+    slots are three blocks of a 2-KV-head model, not fifteen), and
+    groups rows only while an iteration still moves less than that.
   * GQA without expansion: kv head h serves its ``g`` query heads as a
     static [g, dh] row slice; both matmuls run bf16 → fp32 accumulation.
   * int8 KV ({"q8": [L, B, S, Hkv, dh] int8, "s": [L, B, Hkv, S]}) is
@@ -69,10 +83,23 @@ from llm_consensus_tpu.utils.backend import pallas_interpret
 
 NEG_INF = -1e30
 _LANES = 128
-_BLOCK_K_CAP = 512
+# Most cache slots (rows × block_k) one grid iteration covers. The body is
+# unrolled over its block, and Mosaic's compile time grows with it (a
+# 1,920-slot block of a 2-KV-head model compiles in 4.2 s where a
+# 640-slot one takes 0.9 and runs as fast: every new bucket a pool's
+# frontier reaches is one more such compile in somebody's warm-up); wider
+# spans are cut at their largest 128-multiple divisor below this.
+_ITER_SLOTS = 1024
 # Conservative share of the 16 MB scoped VMEM limit left to the K/V code
 # blocks, their scale blocks and the dequant temporaries (see _fits).
 _VMEM_BUDGET = 12 * 1024 * 1024
+# K + V bytes one grid iteration should move before rows are grouped into
+# one block: a grid step costs a fixed ~0.35 us whatever it does, so a
+# block of a few hundred KB (a short bucket of a few-head model) is
+# grouped over rows until an iteration moves about this much, and a
+# block that already does stays ONE row, so that a row without a stream
+# is skipped alone (see _sweep_plan).
+_ITER_BYTES = 2 * 1024 * 1024
 
 
 def _pow2_block(width: int, cap: int) -> int:
@@ -86,19 +113,27 @@ def _pow2_block(width: int, cap: int) -> int:
 def _legal_block_ks(width: int, quantized: bool) -> list[int]:
     """Legal kv block lengths for an attention span of ``width``, largest
     first. A block must divide the span exactly (the grid covers it with
-    no padding — padding would mean copying the cache), so candidates
-    are the power-of-two divisors. The collapsed (block_k, Hkv·dh) view
-    the kernel matmuls over needs 8 sublanes; int8 KV additionally puts
-    block_k on the LANES of its seq-minor scale block [1, bb, Hkv,
-    block_k], which Mosaic tiles in 128s. One block spanning the whole
-    width is always a legal shape ("equal to the array dim")."""
-    top = _pow2_block(width, _BLOCK_K_CAP)
-    floor = _LANES if quantized else 8
-    out = []
-    bk = top
-    while bk >= floor:
-        out.append(bk)
-        bk //= 2
+    no padding — padding would mean copying the cache). Candidates are
+    every divisor that is a multiple of 128 slots — block_k rides the
+    LANES of the score tiles, and of int8 KV's seq-minor scale block
+    [1, bb, Hkv, block_k], which Mosaic tiles in 128s — so a bucket of
+    the 128-slot ladder is not cut into its smallest pieces (1,920 →
+    640 / 384 / 128, where the power-of-two rule alone gave 128);
+    and, for spans that are no multiple of 128, the power-of-two divisors
+    down to the 8 sublanes the collapsed (block_k, Hkv·dh) view needs
+    (bf16 KV only). One block spanning the whole width is always a legal
+    shape ("equal to the array dim")."""
+    out = [
+        width // n for n in range(1, width // _LANES + 1)
+        if width % n == 0 and (width // n) % _LANES == 0
+        and width // n <= _ITER_SLOTS
+    ]
+    top = _pow2_block(width, _ITER_SLOTS)
+    if not quantized:
+        bk = min(top, _LANES // 2)
+        while bk >= 8:
+            out.append(bk)
+            bk //= 2
     if not out and top == width:
         out = [width]
     return out
@@ -122,20 +157,59 @@ def _fits(b_block: int, block_k: int, hkv: int, dh: int, kv_item: int,
 
 def _choose_blocks(b: int, width: int, hkv: int, dh: int, kv_item: int,
                    quantized: bool) -> Optional[tuple[int, int]]:
-    """(b_block, block_k) maximizing bytes per grid iteration —
-    per-iteration overhead (semaphores, DMA issue) dwarfs the tiny
-    per-head matmuls — among legal block shapes that fit VMEM; None when
-    no legal shape fits (the caller's predicate routes to XLA)."""
-    best = None
-    for cand_b in (8, 4, 2, 1):
-        if b % cand_b:
-            continue
-        for cand_k in _legal_block_ks(width, quantized):
-            if _fits(cand_b, cand_k, hkv, dh, kv_item, quantized):
-                if best is None or cand_b * cand_k > best[0] * best[1]:
-                    best = (cand_b, cand_k)
-                break
-    return best
+    """(b_block, block_k): ONE batch row of the longest legal kv block
+    that fits VMEM and moves no more than ``_ITER_BYTES``, then as many
+    rows a block as stay within both and within ``_ITER_SLOTS``.
+    Per-iteration overhead (semaphores, DMA issue) dwarfs the tiny
+    per-head matmuls, so an iteration should move enough bytes; but a
+    call's first block is fetched with nothing to hide behind, and rows
+    are skipped block by block (_sweep_plan), so a block is no longer
+    and holds no more rows than that takes. None when no legal shape
+    fits (the caller's predicate routes to XLA)."""
+    ks = [
+        k for k in _legal_block_ks(width, quantized)
+        if _fits(1, k, hkv, dh, kv_item, quantized)
+    ]
+    if not ks:
+        return None
+    row_bytes = 2 * hkv * dh * kv_item  # K + V, one slot of one row
+    block_k = next((k for k in ks if k * row_bytes <= _ITER_BYTES), ks[-1])
+    for cand_b in (8, 4, 2):
+        if (
+            b % cand_b == 0
+            and cand_b * block_k <= _ITER_SLOTS
+            and cand_b * block_k * row_bytes <= _ITER_BYTES
+            and _fits(cand_b, block_k, hkv, dh, kv_item, quantized)
+        ):
+            return cand_b, block_k
+    return 1, block_k
+
+
+def _blocks(b: int, width: int, hkv: int, dh: int, kv_item: int,
+            quantized: bool) -> tuple[int, int]:
+    """The (b_block, block_k) a call at these shapes runs with: the
+    chooser's, or the ``LLMC_DECODE_BLOCKS`` sweep override."""
+    # forward() only dispatches here when decode_flash_supported — the
+    # same chooser — found a legal block. Direct callers at other spans
+    # (the interpret-mode parity tests at ragged widths) get the
+    # smallest dividing block, which only the interpreter accepts.
+    b_block, block_k = _choose_blocks(
+        b, width, hkv, dh, kv_item, quantized
+    ) or (1, _pow2_block(width, 8))
+    forced = knobs.get_str("LLMC_DECODE_BLOCKS")
+    if forced:
+        # Tuning override "bbxbk" (e.g. "2x512"): bypasses the chooser so
+        # block-shape sweeps on real hardware need no code edits. Any
+        # malformed, non-dividing or Mosaic-illegal value is ignored (a
+        # tuning knob must never take down the decode hot path).
+        try:
+            fb, _, fk = forced.partition("x")
+            fb, fk = int(fb), int(fk)
+        except ValueError:
+            fb = fk = 0
+        if fb > 0 and b % fb == 0 and fk in _legal_block_ks(width, quantized):
+            b_block, block_k = fb, fk
+    return b_block, block_k
 
 
 def decode_flash_supported(
@@ -163,8 +237,80 @@ def decode_flash_supported(
     ) is not None
 
 
+def _sweep_plan(pos, row_start, b_block: int, block_k: int,
+                n_kv_blocks: int, sliding_window: Optional[int]):
+    """What the grid sweeps, per batch-row block: ``(src, lo, hi)``, three
+    [B / b_block] i32 vectors that ride the scalar-prefetch vector behind
+    ``row_start``.
+
+    A row's valid slots are ``[max(row_start, pos − window + 1), pos]``,
+    and a row whose ``row_start`` lies past ``pos`` has none: that is how
+    the scheduler marks a pool row without a stream (it is data, like
+    ``pos``: no program per occupancy). A row block is LIVE when any of
+    its rows has a valid slot; ``lo`` and ``hi`` are then the first and
+    last kv block that hold one, its grid steps fetch block
+    ``clip(j, lo, hi)`` of its own rows (``src`` is the block itself) and
+    compute for ``lo <= j <= hi``. The pipeline does not fetch a block
+    index it already holds, so the steps before ``lo`` and after ``hi``
+    cost no bytes. A DEAD row block is given the block index its live
+    neighbour's sweep ends on (the nearest live block before it: ``src``
+    that block, ``lo = hi =`` its last kv block) or, before the first
+    live block, the one that block's sweep starts on: the same index as
+    the step beside it, so a dead row is neither fetched nor computed.
+    With no live row at all every step names one block.
+    """
+    pos = jnp.asarray(pos, jnp.int32)
+    n_b = row_start.shape[0] // b_block
+    rs_min = jnp.min(row_start.reshape(n_b, b_block), axis=1)
+    live = rs_min <= pos
+    first = jnp.maximum(rs_min, 0)
+    if sliding_window is not None:
+        first = jnp.maximum(first, pos - sliding_window + 1)
+    hi = jnp.clip(pos // block_k, 0, n_kv_blocks - 1)
+    lo = jnp.minimum(first // block_k, hi)
+    idx = jnp.arange(n_b, dtype=jnp.int32)
+    before = jax.lax.cummax(jnp.where(live, idx, -1))  # nearest live <= b
+    ahead = jnp.argmax(live).astype(jnp.int32)         # first live (or 0)
+    src = jnp.where(before >= 0, before, ahead)
+    at = jnp.where(before >= 0, hi, lo[ahead])  # a dead block's one index
+    return (
+        src,
+        jnp.where(live, lo, at),
+        jnp.where(live, jnp.broadcast_to(hi, (n_b,)), at),
+    )
+
+
+def _plan_entry(scalars_ref, n_rows, n_b_blocks, b_):
+    """Row block ``b_``'s ``(src, lo, hi)`` out of the prefetched scalars
+    ``[pos, layer, row_start × B, src × n_b, lo × n_b, hi × n_b]``."""
+    base = 2 + n_rows
+    return (
+        scalars_ref[base + b_],
+        scalars_ref[base + n_b_blocks + b_],
+        scalars_ref[base + 2 * n_b_blocks + b_],
+    )
+
+
+def decode_sweep_plan(pos, row_start, *, width: int, n_kv_heads: int,
+                      dh: int, kv_item: int, quantized: bool,
+                      sliding_window: Optional[int]) -> jax.Array:
+    """The sweep plan (_sweep_plan) of one decode step as the i32 vector
+    ``decode_attention(sweep=...)`` takes: the same for every layer of the
+    step, so a model computes it once beside its layer loop. ``width``,
+    ``n_kv_heads`` (a shard's, under tensor parallelism), ``kv_item`` (the
+    cache's bytes an element) and ``quantized`` are those of the calls it
+    is for: they pick the blocks the plan counts in."""
+    row_start = row_start.astype(jnp.int32)
+    b_block, block_k = _blocks(
+        row_start.shape[0], width, n_kv_heads, dh, kv_item, quantized
+    )
+    return jnp.concatenate(_sweep_plan(
+        pos, row_start, b_block, block_k, width // block_k, sliding_window
+    ))
+
+
 def _kernel(
-    scalars_ref,  # [2 + B] i32 SMEM: [pos, layer, row_start_0, ...]
+    scalars_ref,  # i32 SMEM: [pos, layer, row_start × B, sweep plan]
     q_ref,   # [bb, 1, Hq, dh]; qstruct: [bb, Hq, Hkv·dh] pre-structured
     k_ref,   # [1, bb, block_k, Hkv, dh] — this layer's block, bb rows
     v_ref,   # [1, bb, block_k, Hkv, dh]
@@ -172,6 +318,7 @@ def _kernel(
     scale: float,
     block_k: int,
     n_kv_blocks: int,
+    n_b_blocks: int,
     n_kv_heads: int,
     group: int,
     dh: int,
@@ -235,11 +382,15 @@ def _kernel(
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     k_start = j * block_k
-    live = k_start <= pos  # any valid column in this block?
-    if sliding_window is not None:
-        live = jnp.logical_and(live, k_start + block_k > pos - sliding_window + 1)
-    # Live if ANY row in the block still needs these columns.
-    live = jnp.logical_and(live, k_start + block_k > rs_min)
+    # The sweep plan (_sweep_plan), written once for every form below and
+    # every operand's index map: the body runs only for the kv blocks
+    # that hold a valid slot of some row of this block, and for no block
+    # at all when none of its rows has a stream (its output stays the
+    # zeros of _init, its state (NEG_INF, 0)).
+    _, lo, hi = _plan_entry(scalars_ref, b_block * n_b_blocks, n_b_blocks, bb)
+    live = jnp.logical_and(
+        rs_min <= pos, jnp.logical_and(j >= lo, j <= hi)
+    )
 
     def expand_scales(ref):
         """[1, bb, Hkv, bk] scale block → [bb, Hq, bk] f32: each kv
@@ -465,8 +616,13 @@ def _kernel(
         else:
             o_ref[:, 0, :, :] = out
         if return_state:
+            # A row that met no valid slot (no stream, or its row_start
+            # past the frontier) reports (NEG_INF, 0): absent to the
+            # shared-prefix merge, whatever its block-mates summed.
             ms_ref[...] = m_ref[...]
-            ls_ref[...] = l_ref[...]
+            ls_ref[...] = jnp.where(
+                m_ref[...] <= NEG_INF, 0.0, l_ref[...]
+            )
 
 
 def decode_attention(
@@ -483,6 +639,7 @@ def decode_attention(
     kv_width: Optional[int] = None,  # static attention span bound (≥ pos+1)
     interpret: Optional[bool] = None,
     return_state: bool = False,
+    sweep: Optional[jax.Array] = None,  # decode_sweep_plan() of (pos, row_start)
 ):
     """Single-step GQA attention over one layer of the cache → [B, 1, Hq, dh].
 
@@ -497,6 +654,11 @@ def decode_attention(
     XLA stage them into the custom call's operand space each call.
     ``kv_width`` bounds the kv grid — attention work scales with the
     caller's frontier bucket, not cache capacity.
+
+    ``sweep`` is ``decode_sweep_plan`` of the same ``pos``, ``row_start`` and
+    shapes: a caller that runs many layers at one ``pos`` computes it once
+    outside its layer loop (left to this call it costs every layer three
+    small programs beside the kernel, which XLA does not hoist).
 
     ``return_state=True`` additionally returns the online-softmax state
     ``(m, l)`` as fp32 [B, Hq] (running max of scaled scores; softmax
@@ -534,36 +696,35 @@ def decode_attention(
 
     w = s_dim if kv_width is None else min(kv_width, s_dim)
     kv_item = kq.dtype.itemsize
-    # forward() only dispatches here when decode_flash_supported — the
-    # same chooser — found a legal block. Direct callers at other spans
-    # (the interpret-mode parity tests at ragged widths) get the
-    # smallest dividing block, which only the interpreter accepts.
-    b_block, block_k = _choose_blocks(
-        b, w, hkv, dh, kv_item, quantized
-    ) or (1, _pow2_block(w, 8))
-    forced = knobs.get_str("LLMC_DECODE_BLOCKS")
-    if forced:
-        # Tuning override "bbxbk" (e.g. "2x512"): bypasses the chooser so
-        # block-shape sweeps on real hardware need no code edits. Any
-        # malformed, non-dividing or Mosaic-illegal value is ignored (a
-        # tuning knob must never take down the decode hot path).
-        try:
-            fb, _, fk = forced.partition("x")
-            fb, fk = int(fb), int(fk)
-        except ValueError:
-            fb = fk = 0
-        if fb > 0 and b % fb == 0 and fk in _legal_block_ks(w, quantized):
-            b_block, block_k = fb, fk
+    b_block, block_k = _blocks(b, w, hkv, dh, kv_item, quantized)
     n_kv_blocks = w // block_k
     n_b_blocks = b // b_block
 
     if row_start is None:
         row_start = jnp.zeros((b,), jnp.int32)
+    row_start = row_start.astype(jnp.int32)
+    if sweep is None:
+        sweep = decode_sweep_plan(
+            pos, row_start, width=w, n_kv_heads=hkv, dh=dh, kv_item=kv_item,
+            quantized=quantized, sliding_window=sliding_window,
+        )
+    elif sweep.shape != (3 * n_b_blocks,):
+        raise ValueError(
+            f"sweep plan of {sweep.shape} does not fit {n_b_blocks} row "
+            "blocks: plan it from this call's shapes (decode_sweep_plan)"
+        )
     scalars = jnp.concatenate([
         jnp.asarray(pos, jnp.int32).reshape(1),
         jnp.asarray(layer_idx, jnp.int32).reshape(1),
-        row_start.astype(jnp.int32),
+        row_start,
+        sweep,
     ])
+
+    def kv_block(b_, j, s_):
+        """(row block, kv block) that grid step (b_, j) reads — the one
+        clamp every K, V and scale index map shares."""
+        src, lo, hi = _plan_entry(s_, b, n_b_blocks, b_)
+        return src, jnp.clip(j, lo, hi)
 
     # Dense-GQA ("qstruct") form for small GQA groups: the per-head form's
     # 2·Hkv tiny matmuls (M = group) are MXU-fill-bound at serving batch
@@ -590,6 +751,7 @@ def decode_attention(
         scale=scale,
         block_k=block_k,
         n_kv_blocks=n_kv_blocks,
+        n_b_blocks=n_b_blocks,
         n_kv_heads=hkv,
         group=group,
         dh=dh,
@@ -607,7 +769,7 @@ def decode_attention(
     # the stacked cache, no per-layer materialization.
     kv_spec = pl.BlockSpec(
         (1, b_block, block_k, hkv, dh),
-        lambda b_, j, s_: (s_[1], b_, j, 0, 0),
+        lambda b_, j, s_: (s_[1], *kv_block(b_, j, s_), 0, 0),
     )
     q_scale_op = None
     if qstruct:
@@ -645,10 +807,11 @@ def decode_attention(
         # score rows' lanes with no transpose.
         # Layer dim is pre-sliced above, so the scale index map pins it
         # to 0 (codes still page their layer via s_[1]).
-        scale_spec = pl.BlockSpec(
-            (1, b_block, hkv, block_k),
-            lambda b_, j, s_: (0, b_, 0, j),
-        )
+        def scale_block(b_, j, s_):
+            src, jk = kv_block(b_, j, s_)
+            return 0, src, 0, jk
+
+        scale_spec = pl.BlockSpec((1, b_block, hkv, block_k), scale_block)
         in_specs += [scale_spec, scale_spec]
         operands += [ks, vs]
         if w8a8:

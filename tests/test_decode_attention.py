@@ -88,6 +88,153 @@ def test_decode_matches_xla_reference_f32(case):
     )
 
 
+# A pool row without a stream carries a row_start past every frontier
+# (engine/batcher.py DEAD_ROW): no valid slot, so the sweep plan neither
+# fetches nor computes it and its output is zeros.
+DEAD = 1 << 30
+
+SWEEP_CASES = [
+    # (id, b, w, hq, hkv, pos, window, row_start, int8_kv)
+    ("1of6-perhead", 6, 1920, 16, 2, 1850, None,
+     (DEAD, DEAD, 96, DEAD, DEAD, DEAD), False),
+    ("1of6-first", 6, 1920, 16, 2, 1850, None,
+     (300, DEAD, DEAD, DEAD, DEAD, DEAD), False),
+    ("1of6-last", 6, 384, 16, 2, 200, None,
+     (DEAD, DEAD, DEAD, DEAD, DEAD, 17), False),
+    ("1of8-qstruct", 8, 1920, 16, 4, 1800, None,
+     (DEAD, DEAD, DEAD, 64, DEAD, DEAD, DEAD, DEAD), False),
+    ("2of8-qstruct-between", 8, 640, 16, 4, 600, None,
+     (DEAD, 10, DEAD, DEAD, 500, DEAD, DEAD, DEAD), False),
+    ("start-in-last-block", 6, 1920, 16, 2, 1900, None,
+     (1800, DEAD, 1899, 0, DEAD, 1900), False),
+    ("pos-in-first-block", 6, 2048, 16, 2, 40, None,
+     (0, DEAD, 33, DEAD, 40, DEAD), False),
+    ("window-shorter", 6, 1920, 16, 2, 1850, 256,
+     (DEAD, 0, 1700, DEAD, DEAD, DEAD), False),
+    ("window-qstruct", 8, 1024, 32, 8, 1000, 300,
+     (DEAD, DEAD, 0, DEAD, 900, DEAD, DEAD, DEAD), False),
+    ("1of6-int8", 6, 1920, 16, 2, 1850, None,
+     (DEAD, DEAD, 96, DEAD, DEAD, DEAD), True),
+    ("1of8-qstruct-int8", 8, 640, 16, 4, 600, None,
+     (DEAD, DEAD, DEAD, DEAD, DEAD, DEAD, 130, DEAD), True),
+    ("all-dead", 6, 256, 16, 2, 100, None, (DEAD,) * 6, False),
+    ("rows-blocked", 6, 256, 16, 2, 200, None,
+     (DEAD, DEAD, DEAD, 150, DEAD, DEAD), False),  # b_block 2 at 256
+    ("rows-blocked-qstruct", 8, 128, 16, 4, 100, None,
+     (DEAD, DEAD, DEAD, DEAD, DEAD, 20, DEAD, 90), True),  # b_block 8
+]
+
+
+@pytest.mark.parametrize("return_state", [False, True], ids=["out", "state"])
+@pytest.mark.parametrize("case", SWEEP_CASES, ids=[c[0] for c in SWEEP_CASES])
+def test_decode_sweeps_only_live_rows(case, return_state):
+    """Live rows equal the XLA reference whatever lies around them; rows
+    without a stream give zeros and, with ``return_state``, the absent
+    state ``(NEG_INF, 0)`` the shared-prefix merge drops."""
+    from llm_consensus_tpu.ops.pallas.decode_attention import NEG_INF
+    from llm_consensus_tpu.ops.quant import kv_read
+
+    _, b, w, hq, hkv, pos, window, rs, int8_kv = case
+    q, k, v = _qkv(jax.random.PRNGKey(11), b, w, hq, hkv, 128)
+    # Garbage wherever no row may look: a fetched-but-masked slot shows.
+    k = k.at[:, pos + 1:].set(jnp.nan)
+    v = v.at[:, pos + 1:].set(jnp.nan)
+    kk, vv, tol = k, v, 1e-5
+    if int8_kv:
+        k, v = jnp.nan_to_num(k), jnp.nan_to_num(v)  # codes cannot be NaN
+        kk, vv, tol = _quantize_entry(k), _quantize_entry(v), 2e-4
+        k, v = kv_read(kk, jnp.float32), kv_read(vv, jnp.float32)
+    row_start = jnp.asarray(rs, jnp.int32)
+    live = [i for i, r in enumerate(rs) if r <= pos]
+    dead = [i for i, r in enumerate(rs) if r > pos]
+    with jax.default_matmul_precision("highest"):
+        got = decode_attention(
+            q, _stack(kk), _stack(vv), jnp.int32(pos), 0, row_start,
+            sliding_window=window, interpret=True, return_state=return_state,
+        )
+        want = _reference(
+            q, jnp.nan_to_num(k), jnp.nan_to_num(v), pos, row_start, window
+        )
+    if return_state:
+        got, m, l = got
+        assert bool((l[jnp.asarray(live, int)] > 0).all())
+        if dead:
+            d = jnp.asarray(dead)
+            assert bool((m[d] <= NEG_INF).all()) and bool((l[d] == 0).all())
+    assert bool(jnp.isfinite(got).all())
+    if live:
+        lv = jnp.asarray(live)
+        assert jnp.allclose(got[lv], want[lv], atol=tol, rtol=tol), (
+            float(jnp.abs(got[lv] - want[lv]).max())
+        )
+    if dead:
+        assert bool((got[jnp.asarray(dead)] == 0).all())
+
+
+def _parent_blocks(b, width, hkv, dh, kv_item, quantized):
+    """The block chooser as it stood before kv blocks followed the live
+    sweep (power-of-two divisors up to 512, most bytes an iteration):
+    what the new chooser must not fall below."""
+    from llm_consensus_tpu.ops.pallas.decode_attention import (
+        _fits, _pow2_block)
+
+    floor = 128 if quantized else 8
+    ks, bk = [], _pow2_block(width, 512)
+    top = bk
+    while bk >= floor:
+        ks.append(bk)
+        bk //= 2
+    if not ks and top == width:
+        ks = [width]
+    best = None
+    for cand_b in (8, 4, 2, 1):
+        if b % cand_b:
+            continue
+        for cand_k in ks:
+            if _fits(cand_b, cand_k, hkv, dh, kv_item, quantized):
+                if best is None or cand_b * cand_k > best[0] * best[1]:
+                    best = (cand_b, cand_k)
+                break
+    return best
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize("hkv", [2, 4, 8])
+@pytest.mark.parametrize("b", [1, 6, 8, 18])
+def test_block_chooser_over_the_bucket_ladder(b, hkv, quantized):
+    """Every bucket of the 128-slot ladder: a block wherever the parent
+    found one, dividing the span, inside VMEM, and covering no fewer
+    slots of a live row an iteration than the parent's did."""
+    from llm_consensus_tpu.ops.pallas.decode_attention import (
+        _choose_blocks, _fits)
+
+    item = 1 if quantized else 2
+    for width in range(128, 4096 + 1, 128):
+        parent = _parent_blocks(b, width, hkv, 128, item, quantized)
+        got = _choose_blocks(b, width, hkv, 128, item, quantized)
+        assert parent is not None and got is not None, width
+        b_block, block_k = got
+        assert width % block_k == 0 and b % b_block == 0, (width, got)
+        assert _fits(b_block, block_k, hkv, 128, item, quantized), (width, got)
+        assert block_k >= parent[1], (width, got, parent)
+        assert block_k % 128 == 0, (width, got)
+
+
+def test_block_chooser_does_not_cut_a_judge_bucket_into_128s():
+    """1,920 = 15 x 128 is no longer fifteen blocks a row, and rows of a
+    wide bucket are swept one by one."""
+    from llm_consensus_tpu.ops.pallas.decode_attention import (
+        _choose_blocks, _legal_block_ks)
+
+    assert _legal_block_ks(1920, False)[:3] == [640, 384, 128]
+    assert _legal_block_ks(1792, True) == [896, 256, 128]
+    assert _choose_blocks(6, 1920, 2, 128, 2, False) == (1, 640)   # Qwen2.5
+    assert _choose_blocks(6, 2048, 2, 128, 2, False) == (1, 1024)
+    assert _choose_blocks(6, 1920, 8, 128, 2, False) == (1, 384)   # Mistral
+    assert _choose_blocks(8, 1920, 4, 128, 2, False) == (1, 640)   # tp = 2
+    assert _choose_blocks(6, 256, 2, 128, 2, False) == (2, 256)    # short
+
+
 def test_decode_never_reads_beyond_frontier():
     """NaNs in unwritten cache slots must not leak into the output."""
     b, w, hq, hkv, dh, pos = 1, 512, 8, 4, 128, 100

@@ -42,6 +42,20 @@ DECODE_CASES = [
     (preset, int8_kv, batch)
     for preset in sorted(WIDTHS) for int8_kv in (False, True) for batch in (1, 8)
 ]
+# The benchmark cells' own decode shapes (BENCHMARK.json): pool rows, Q / KV
+# heads (dh 128, bf16 cache) and the 128-slot buckets their traffic meets,
+# in a 4,096-slot cache — so that no block the chooser picks for them is
+# one Mosaic refuses on the chip.
+CELL_MAX_SEQ = 4096
+CELL_WIDTHS = (128, 256, 384, 1792, 1920, 2048)
+CELL_SHAPES = {  # name -> (rows, Hq, Hkv)
+    "qwen2.5-3b": (6, 16, 2),
+    "qwen2.5-1.5b": (6, 12, 2),
+    "mistral-7b": (6, 32, 8),
+    "mistral-7b-tp2-shard": (8, 16, 4),
+    "qwen2.5-1.5b-8rows": (8, 12, 2),  # the four-chip cell's panelist
+}
+CELL_CASES = [(name, w) for name in CELL_SHAPES for w in CELL_WIDTHS]
 FLASH_T = (128, 2048)
 STEP_CASES = {
     "llama-3.2-3b": "pallas",  # dh 128: both kernels
@@ -51,6 +65,10 @@ STEP_CASES = {
 
 def _decode_id(preset, int8_kv, batch) -> str:
     return f"decode:{preset}:{'int8kv' if int8_kv else 'bf16'}:B{batch}"
+
+
+def _cell_id(name, width) -> str:
+    return f"decode-cell:{name}:kv{width}"
 
 
 # -- the child: every compile, one report --------------------------------------
@@ -110,6 +128,17 @@ def _compile_all() -> dict:
             hq, hkv, dh, width=KV_WIDTH, quantized=int8_kv
         )
         report[_decode_id(preset, int8_kv, batch)] = entry
+    for name, width in CELL_CASES:
+        rows, hq, hkv = CELL_SHAPES[name]
+        kv = sds((2, rows, CELL_MAX_SEQ, hkv, 128), jnp.bfloat16)
+        entry = has_kernel(jax.jit(functools.partial(
+            decode_attention, kv_width=width, interpret=False,
+        )).lower(
+            sds((rows, 1, hq, 128), jnp.bfloat16), kv, kv, sds(()), sds(()),
+            sds((rows,)),
+        ))
+        entry["predicate"] = decode_flash_supported(hq, hkv, 128, width=width)
+        report[_cell_id(name, width)] = entry
     hq, hkv, dh = WIDTHS["llama-3.2-3b"]
     for t in FLASH_T:
         q = sds((1, t, hq, dh), jnp.bfloat16)
@@ -199,7 +228,8 @@ def report(tmp_path_factory):
 
 
 @pytest.mark.parametrize(
-    "case", [_decode_id(*c) for c in DECODE_CASES]
+    "case",
+    [_decode_id(*c) for c in DECODE_CASES] + [_cell_id(*c) for c in CELL_CASES],
 )
 def test_decode_kernel_compiles(report, case):
     # What the predicate admits, the compiler must take.
