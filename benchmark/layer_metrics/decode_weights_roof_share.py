@@ -3,15 +3,28 @@ step: (its weight bytes as stored / the device kind's peak bytes per
 second) / decode_dev_ms_per_step. Named for what it is: the least time the
 step could take if it only streamed its weights once, over the time it
 took. Key/value reads and compute are not in the numerator. With the model
-sharded over n chips each streams 1/n of the bytes."""
+sharded over n chips each streams 1/n of the bytes.
+
+It counts a DENSE block, every weight streamed once a step, so
+``BENCHMARK.json`` lists the cells it is read in. A cell of another family
+(experts of which a step streams only those its rows chose, a latent cache,
+recurrent state) brings a reader and a count of bytes of its own."""
 
 from benchmark.layer_metrics import decode_dev_ms_per_step
 
+DENSE_FAMILIES = ("qwen2", "mistral")
+
 
 def weight_bytes(spec: dict, stored: str) -> float:
-    """Bytes of one model as the program stores it: matmul weights in the
-    stated type (int8: one byte, plus a bf16 scale per output channel), the
-    embedding and the norms in bf16."""
+    """Bytes of one dense model as the program stores it: matmul weights in
+    the stated type (int8: one byte, plus a bf16 scale per output channel),
+    the embedding and the norms in bf16. Raises for an entry it cannot
+    count, rather than count a dense block of its ``d_ff``."""
+    if spec["family"] not in DENSE_FAMILIES or spec.get("more_fields"):
+        raise ValueError(
+            f"decode_weights_roof_share counts dense blocks of {DENSE_FAMILIES} "
+            f"with no more_fields, not family {spec['family']!r} with "
+            f"{sorted(spec.get('more_fields') or {})}")
     d, f, l, v = spec["d_model"], spec["d_ff"], spec["n_layers"], spec["vocab_size"]
     q, kv = spec["n_heads"] * spec["head_dim"], spec["n_kv_heads"] * spec["head_dim"]
     per_layer = d * q + 2 * d * kv + q * d + 3 * d * f

@@ -29,6 +29,9 @@ import jax
 import jax.numpy as jnp
 
 FAMILIES = ("qwen2", "mistral")
+# The matmul leaves whose storage says what precision the tree is served in
+# (the contract: benchmark/reference/__init__.py).
+STORED_LEAVES = (("layers", "wq"), ("layers", "w_up"), ("layers", "w_down"))
 
 
 def dense(w) -> jax.Array:
@@ -72,8 +75,8 @@ def attention(q, k, v, window):
     return jnp.einsum("hts,shd->thd", p, v)
 
 
-def layer(x, w, *, n_heads, n_kv_heads, head_dim, theta, eps, window):
-    """One decoder block on x [T, D]; ``w`` holds this layer's leaves."""
+def attention_block(x, w, *, n_heads, n_kv_heads, head_dim, theta, eps, window):
+    """The attention half of a block on x [T, D], residual included."""
     t = x.shape[0]
     pos = jnp.arange(t)
     h = rms_norm(x, dense(w["attn_norm"]), eps)
@@ -84,7 +87,12 @@ def layer(x, w, *, n_heads, n_kv_heads, head_dim, theta, eps, window):
     k = rope(k.reshape(t, n_kv_heads, head_dim), pos, theta)
     v = v.reshape(t, n_kv_heads, head_dim)
     a = attention(q, k, v, window).reshape(t, n_heads * head_dim)
-    x = x + a @ dense(w["wo"])
+    return x + a @ dense(w["wo"])
+
+
+def layer(x, w, *, eps, **attn):
+    """One decoder block on x [T, D]; ``w`` holds this layer's leaves."""
+    x = attention_block(x, w, eps=eps, **attn)
     h = rms_norm(x, dense(w["mlp_norm"]), eps)
     gate = jax.nn.silu(h @ dense(w["w_gate"]))
     return x + (gate * (h @ dense(w["w_up"]))) @ dense(w["w_down"])
@@ -109,11 +117,11 @@ def _head(x, final_norm, head, eps):
 def forward(params: dict, shape: dict, token_ids) -> jax.Array:
     """Logits [T, V] in float32 for one sequence of token ids.
 
-    ``shape`` is the model's published sizes as the configuration file
-    states them (``family``, ``n_layers``, ``n_heads``, ``n_kv_heads``,
-    ``head_dim``, ``rope_theta``, ``rms_eps``, ``sliding_window``,
-    ``tie_embeddings``) — the benchmark's own copy, not the program's
-    ``ModelConfig``."""
+    ``shape`` is the model's entry in the configuration file, of which it
+    reads the published sizes (``family``, ``n_layers``, ``n_heads``,
+    ``n_kv_heads``, ``head_dim``, ``rope_theta``, ``rms_eps``,
+    ``sliding_window``, ``tie_embeddings``) — the benchmark's own copy, not
+    the program's ``ModelConfig``."""
     if shape["family"] not in FAMILIES:
         raise ValueError(
             f"no plain reference for family {shape['family']!r}; "
@@ -150,3 +158,8 @@ def forward(params: dict, shape: dict, token_ids) -> jax.Array:
 # the best reading one step lower: it passes what the files state and fails
 # a lower precision.
 TOLERANCE = 0.022
+
+
+def compared(err, n_prefill: int) -> dict:
+    """The worst position, prefilled or decoded, against TOLERANCE."""
+    return {"rel_err_max": [float(err.max()), TOLERANCE]}

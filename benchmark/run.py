@@ -25,9 +25,11 @@ one metric is a file found by the name ``BENCHMARK.json`` gives it:
 
 Every earlier line of stdout is one JSON object of observations; the LAST
 line is the result: ``correct``, ``attempted``, ``failed``, ``metrics``,
-``device`` and, when traced, ``breakdown``. No result line is printed, and
-the exit code is not 0, when the program is not there, when JAX found no
-TPU or fewer chips than the cell asks for, or when the child died.
+``device``, when traced ``breakdown``, and last ``compared`` (each number the
+verdict compared, beside its limit; the same as the last lines of stderr).
+No result line is printed, and the exit code is not 0, when the program is
+not there, when JAX found no TPU or fewer chips than the cell asks for, or
+when the child died.
 """
 
 from __future__ import annotations
@@ -440,6 +442,17 @@ def measure(args, bench, cell, config, plan, child, port, workdir) -> int:
         result["breakdown"] = {
             "device_ops": trace["device_programs"], "idle_gaps": trace["idle_gaps"],
         }
+    # Every number the verdict compared, beside its limit: the last lines of
+    # stderr and the last key of the result line.
+    result["compared"] = compared = {
+        "runs_failed": [len(failed), 0],
+        "compiles_in_window": [compiled or 0, 0],
+        **{f"{m['model']}.{name}": pair
+           for m in parity.get("models") or []
+           for name, pair in (m.get("compared") or {}).items()},
+    }
+    for name, (value, limit) in compared.items():
+        print(f"compared {name} {value} limit {limit}", file=sys.stderr, flush=True)
     emit(result)
     return 0
 

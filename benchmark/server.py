@@ -11,7 +11,8 @@ directory and a launcher script, and nothing else:
   * sets the deployment's environment knobs from the file (``env``);
   * puts the file's models that are not presets into ``MODEL_PRESETS`` (a
     dict insert), and checks that those that are presets have the sizes the
-    file states;
+    file states: the core fields every entry states and, under
+    ``more_fields``, any other field of the program's ``ModelConfig``;
   * decodes every answer to exactly ``max_tokens`` through the provider's
     own constructor argument (``TPUProvider(ignore_eos=True)``; ``serve``
     has no switch for it) — random weights would otherwise end answers at
@@ -43,7 +44,8 @@ import sys
 import threading
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-# The fields of the program's ModelConfig a configuration file may state.
+# The core of the program's ModelConfig that every model's entry states. Any
+# other field of it goes in the entry's "more_fields" object.
 MODEL_FIELDS = (
     "family", "vocab_size", "d_model", "n_layers", "n_heads", "n_kv_heads",
     "head_dim", "d_ff", "rope_theta", "rms_eps", "qkv_bias",
@@ -51,24 +53,58 @@ MODEL_FIELDS = (
 )
 
 
+def _frozen(value):
+    """JSON lists as tuples, all the way down: ModelConfig is frozen and
+    hashed (it is a static argument of the jitted programs)."""
+    if isinstance(value, list):
+        return tuple(_frozen(v) for v in value)
+    return value
+
+
+def model_config(name: str, spec: dict):
+    """The program's ModelConfig for one entry of a file's ``models``: the
+    core fields and ``more_fields`` together. A key of ``more_fields`` that
+    is no field of the dataclass stops the child: a mistyped width is never
+    dropped silently."""
+    from dataclasses import fields
+
+    from llm_consensus_tpu.models.config import ModelConfig
+
+    more = spec.get("more_fields") or {}
+    settable = {f.name for f in fields(ModelConfig)} - {"name", *MODEL_FIELDS}
+    for key in more:
+        if key not in settable:
+            raise SystemExit(
+                f"{name}: more_fields.{key} is "
+                + ("a core field: state it beside the others" if key in MODEL_FIELDS
+                   else f"no field of the program's ModelConfig; have {sorted(settable)}"))
+    cfg = ModelConfig(
+        name=name, **{k: spec[k] for k in MODEL_FIELDS},
+        **{k: _frozen(v) for k, v in more.items()})
+    hash(cfg)  # a value that cannot be hashed fails here, not in a jit
+    return cfg
+
+
 def install_models(models: dict) -> None:
     from dataclasses import asdict
 
-    from llm_consensus_tpu.models.config import MODEL_PRESETS, ModelConfig
+    from llm_consensus_tpu.models.config import MODEL_PRESETS
 
     for name, spec in models.items():
-        fields = {k: spec[k] for k in MODEL_FIELDS}
-        want = ModelConfig(name=name, **fields)
+        want = model_config(name, spec)
         if spec.get("preset"):
             have = MODEL_PRESETS.get(name)
             if have is None:
                 raise SystemExit(f"{name}: the file says preset, the program has none")
-            if asdict(have) != asdict(want):
+            if have != want:
                 diff = {
-                    k: (v, asdict(want)[k]) for k, v in asdict(have).items()
-                    if v != asdict(want)[k]
+                    k: {"preset": v, "file": asdict(want)[k]}
+                    for k, v in asdict(have).items() if v != asdict(want)[k]
                 }
-                raise SystemExit(f"{name}: preset differs from the file: {diff}")
+                outside = sorted(set(diff) - set(MODEL_FIELDS))
+                raise SystemExit(
+                    f"{name}: preset differs from the file: {diff}"
+                    + (f"; state {outside} under more_fields" if outside else ""))
         elif name in MODEL_PRESETS:
             raise SystemExit(f"{name}: already a preset; say so in the file")
         else:
@@ -145,15 +181,11 @@ def parity_on_signal(cfg: dict, workdir: str, seed: int) -> None:
         try:
             from benchmark import parity
 
-            shapes = {
-                name: {k: spec[k] for k in MODEL_FIELDS}
-                for name, spec in cfg["models"].items()
-            }
             if not _provider or not _provider[0]._ignore_eos:
                 raise RuntimeError(
                     "serve did not build its provider through the patched "
                     "TPUProvider: answers are not of fixed length")
-            doc = parity.check_all(_provider[0], shapes, cfg["weights"], seed)
+            doc = parity.check_all(_provider[0], cfg, seed)
         except Exception as err:  # noqa: BLE001 — reported, fails `correct`
             import traceback
 
@@ -183,6 +215,12 @@ def main() -> int:
     sys.path.insert(0, REPO)
 
     install_models(cfg["models"])
+    # A configuration the harness cannot compare stops here, before it serves.
+    from benchmark import parity
+
+    parity.lengths(cfg)
+    for name, spec in cfg["models"].items():
+        parity.reference_for(name, spec)
     visible_bytes()
     fixed_length_provider()
     lean_profiler()

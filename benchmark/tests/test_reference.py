@@ -18,7 +18,6 @@ def test_reference_matches_the_program_in_float32(name):
     import jax.numpy as jnp
 
     from benchmark.reference import decoder
-    from benchmark.server import MODEL_FIELDS
     from llm_consensus_tpu.models import forward, get_config, init_params
 
     with open(os.path.join(REPO, "benchmark/configs/tiny-rehearsal.json")) as f:
@@ -32,7 +31,7 @@ def test_reference_matches_the_program_in_float32(name):
     ids = np.random.default_rng(0).integers(0, cfg.vocab_size, 96)
     with jax.default_matmul_precision("highest"):
         want, _ = forward(params, cfg, jnp.asarray(ids[None], jnp.int32))
-    got = decoder.forward(params, {k: spec[k] for k in MODEL_FIELDS}, ids)
+    got = decoder.forward(params, spec, ids)
     err = np.linalg.norm(np.asarray(got) - np.asarray(want[0]), axis=-1) / np.linalg.norm(np.asarray(want[0]), axis=-1)
     assert err.max() < 1e-4
 
