@@ -83,6 +83,7 @@ from llm_consensus_tpu.obs import roofline as _roofline
 from llm_consensus_tpu.ops.quant import kv_seq_axis as _seq_axis
 from llm_consensus_tpu.ops.sampling import sample_token
 from llm_consensus_tpu.utils.context import Context
+from llm_consensus_tpu.utils.flops import cache_bytes_per_token
 from llm_consensus_tpu.analysis import sanitizer
 from llm_consensus_tpu.utils import knobs
 
@@ -734,6 +735,10 @@ class ContinuousBatcher:
         # byte-identical to the classic batcher.
         self._spec_cfg = spec
         self._spec = None
+        if spec is not None and engine.cfg.is_latent:
+            raise ValueError(
+                f"{engine.cfg.name}: no speculative pool decode over a "
+                "latent (MLA) cache")
         if spec is not None and engine.cfg.sliding_window is not None:
             # Same warn-once courtesy the model-draft+batching case gets
             # (providers/tpu.py): an operator who configured speculation
@@ -824,6 +829,8 @@ class ContinuousBatcher:
         self._prefix_enabled = (
             knobs.get_bool("LLMC_POOL_PREFIX")
             and engine.cfg.sliding_window is None
+            # No prefix-merge form over a latent (MLA) cache yet: off.
+            and not engine.cfg.is_latent
             and mesh_ok
             # Spec rounds hold each row's FULL prompt in its own window
             # (the batched verify program has no prefix-merge form);
@@ -905,6 +912,20 @@ class ContinuousBatcher:
             "prefill_waves": 0, "prefill_rows_real": 0,
             "prefill_rows_padded": 0, "prefill_slot_tokens": 0,
         }
+        if engine.cfg.is_moe:
+            # A routed model's programs return their routing sums
+            # (ops/moe.py), which ride each fetch: (token, chosen expert)
+            # pairs and those on experts held here, over decode chunks and
+            # prefill programs alike; held experts that took at least one
+            # row, one count an expert layer a decode step, beside the
+            # (expert layer, decode step)s counted; and the held pairs of
+            # the prefill programs alone, whose every program reads nearly
+            # every held expert.
+            self.stats.update(
+                moe_pairs_total=0, moe_pairs_held=0, moe_expert_reads=0,
+                moe_layer_steps=0, moe_prefill_pairs_held=0,
+            )
+            engine._moe_bank = []  # the engine's prefills bank theirs here
         # Priority-aware preemption (pressure/): when a queued stream of
         # a strictly higher class is blocked on a slot, the scheduler
         # preempts the lowest-priority / least-progress resident stream
@@ -1496,11 +1517,8 @@ class ContinuousBatcher:
         # here is safe — and required: leaving it resident would keep the
         # exact HBM this cap exists to bound, plus the costlier
         # prefix-merge decode program, with no row ever using it.
-        cfg = eng.cfg
-        dense_bytes = (
-            2 * cfg.n_layers * p_cap * cfg.n_kv_heads * cfg.head_dim
-            * jnp.dtype(eng._dtype).itemsize
-        )
+        dense_bytes = p_cap * cache_bytes_per_token(
+            eng.cfg, jnp.dtype(eng._dtype).itemsize)
         if dense_bytes > eng._prefix_max_bytes:
             self._clear_prefix()
             return False
@@ -2622,7 +2640,7 @@ class ContinuousBatcher:
             item = self._fetch_q.get()
             if item is None:
                 return
-            toks, owners, firsts, pure, t_dispatch, mode = item
+            toks, owners, firsts, pure, t_dispatch, mode, moe = item
             if self._worker_exc is not None:  # lint-ok: GS01 own-write read
                 # A prior chunk's fetch failed: emitting later chunks
                 # would resolve streams "successfully" with the failed
@@ -2640,6 +2658,8 @@ class ContinuousBatcher:
                 ) as sp:
                     first_vals, body = self._fetch_get(toks, firsts)
                     sp.set(tokens=int(getattr(body[1], "size", 0)))
+                    if moe is not None:
+                        sp.set(**self._book_moe(*moe))
             except BaseException as exc:  # noqa: BLE001
                 self._fetch_failed(exc)
                 continue  # keep draining so the scheduler never deadlocks
@@ -2672,6 +2692,29 @@ class ContinuousBatcher:
                             "deadline" if s.ctx.remaining() == 0.0 else "cancelled",
                         )
                 self._book_arrival(pure, mode, emitted, t_arrival, t_dispatch)
+
+    def _book_moe(self, decode, layer_steps: int, prefills: list) -> dict:
+        """A routed model's sums, fetched with their chunk: into the
+        counters, and back as the ``pool.fetch`` span's arguments (the
+        chunk's own values: they exist only on the device while
+        ``pool.decode`` and ``pool.admit`` are open)."""
+        decode, prefills = jax.device_get((decode, prefills))
+        pre_total = sum(int(p[0]) for p in prefills)
+        pre_held = sum(int(p[1]) for p in prefills)
+        if decode is None:  # a speculative round group returns no sums
+            total = held = reads = layer_steps = 0
+        else:
+            total, held, reads = (int(v) for v in decode)
+        self._stat_add(
+            moe_pairs_total=total + pre_total, moe_pairs_held=held + pre_held,
+            moe_expert_reads=reads, moe_layer_steps=layer_steps,
+            moe_prefill_pairs_held=pre_held,
+        )
+        return {
+            "moe_pairs": total, "moe_pairs_held": held,
+            "moe_expert_reads": reads, "moe_layer_steps": layer_steps,
+            "moe_prefill_pairs_held": pre_held,
+        }
 
     def _fetch_failed(self, exc: BaseException) -> None:
         """A chunk's fetch or emit raised: record it for the scheduler
@@ -3352,6 +3395,7 @@ class ContinuousBatcher:
                 ]
                 rows_live = len(live_starts)
                 pos0 = self._pos
+                moe_decode = None
                 window = eng.cfg.sliding_window
                 self._mark_dead_rows()
                 if self._spec is not None and sampling.temperature == 0.0:
@@ -3418,8 +3462,12 @@ class ContinuousBatcher:
                                 if self._prefix_cache is not None else None,
                                 w8a8=eng.w8a8,
                                 sentinel=sentinel, poison_row=poison,
+                                moe_stats=eng.cfg.is_moe,
                             )
                         )
+                    if eng.cfg.is_moe:
+                        # The chunk's routing sums ride the fetch too.
+                        *out, moe_decode = out
                     if sentinel:
                         self._token, toks, self._cache, verdict = out
                         # The verdict rides the fetch with its tokens.
@@ -3447,9 +3495,17 @@ class ContinuousBatcher:
                 # Owner snapshot sliced to the CURRENT row bucket: the
                 # chunk's token matrix has _rows_cap columns.
                 t_dispatch = time.monotonic()
+                moe = None
+                if eng.cfg.is_moe:
+                    # (this chunk's sums, its steps x expert layers, the
+                    # sums of the prefill programs dispatched since the
+                    # last chunk): device arrays until the fetch.
+                    moe = (moe_decode, covered * eng.cfg.n_expert_layers,
+                           eng._moe_bank[:])
+                    del eng._moe_bank[:]
                 item = (
                     payload, list(self._slots[:self._rows_cap]),
-                    self._firsts, pure, t_dispatch, mode,
+                    self._firsts, pure, t_dispatch, mode, moe,
                 )
                 self._firsts = []
                 self._nondecode_work = False

@@ -94,13 +94,21 @@ class GenerateResult:
     marks: Optional[dict] = None
 
 
+def _with_moe(out, first):
+    """A step program's result from ``forward``'s: ``first`` in place of
+    the logits, and a routed model's sums (``moe_stats``) kept last."""
+    return (first, *out[1:])
+
+
 @partial(
-    jax.jit, static_argnames=("cfg", "attn_impl", "mesh", "kv_width", "w8a8"),
+    jax.jit,
+    static_argnames=("cfg", "attn_impl", "mesh", "kv_width", "w8a8", "moe_stats"),
     donate_argnames=("cache",),
 )
 def _prefill_step(params, cfg: ModelConfig, tokens, last_index, cache,
                   attn_impl="xla", mesh=None, row_start=None, kv_width=None,
-                  prefix=None, prefix_len=None, w8a8: bool = False):
+                  prefix=None, prefix_len=None, w8a8: bool = False,
+                  moe_stats: bool = False):
     """Prefill ``tokens`` (padded) into the cache; return last real logits.
 
     ``row_start`` serves the right-aligned batch path (left-padded rows,
@@ -111,14 +119,17 @@ def _prefill_step(params, cfg: ModelConfig, tokens, last_index, cache,
     prefix length (the pool's one-prompt fan-out pattern). ``w8a8`` (a
     STATIC arg, so part of program identity — a bare env read would let
     a stale cached executable ignore the flag) scopes the activation-
-    quantized matmul lane for everything traced inside."""
+    quantized matmul lane for everything traced inside. ``moe_stats``
+    (static): a routed model's program also returns its routing sums,
+    int32[3], last (models/transformer.py ``forward``)."""
     with w8a8_scope(w8a8):
-        logits, cache = forward(
+        out = forward(
             params, cfg, tokens, cache, start_pos=0, attn_impl=attn_impl,
             mesh=mesh, logits_index=last_index, row_start=row_start,
             kv_width=kv_width, prefix=prefix, prefix_len=prefix_len,
+            moe_stats=moe_stats,
         )
-    return logits[:, 0], cache
+    return _with_moe(out, out[0][:, 0])
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh"), donate_argnames=("cache",))
@@ -204,7 +215,8 @@ def _extract_row0(template, pcache, width: int):
 
 def _prefill_chunk(params, cfg: ModelConfig, tokens, start_pos, last_index,
                    cache, kv_width: int, row_start=None, prefix=None,
-                   prefix_len=None, w8a8: bool = False):
+                   prefix_len=None, w8a8: bool = False,
+                   moe_stats: bool = False):
     """One fixed-size prefill chunk at a *traced* ``start_pos``.
 
     The dynamic start means ONE compiled program (per prompt bucket) serves
@@ -218,17 +230,17 @@ def _prefill_chunk(params, cfg: ModelConfig, tokens, start_pos, last_index,
     path, which GSPMD also partitions for TP-sharded engines.
     """
     with w8a8_scope(w8a8):
-        logits, cache = forward(
+        out = forward(
             params, cfg, tokens, cache, start_pos=start_pos,
             kv_width=kv_width, logits_index=last_index, row_start=row_start,
-            prefix=prefix, prefix_len=prefix_len,
+            prefix=prefix, prefix_len=prefix_len, moe_stats=moe_stats,
         )
-    return logits[:, 0], cache
+    return _with_moe(out, out[0][:, 0])
 
 
 def _prefill_chunks_loop(params, cfg: ModelConfig, tokens, base, n_real,
                          last_index, cache, max_chunks: int, kv_width: int,
-                         w8a8: bool = False):
+                         w8a8: bool = False, moe_stats: bool = False):
     """Every chunk of one prompt's prefill as ONE device program.
 
     The per-chunk jit form pays one host dispatch + one token transfer
@@ -245,25 +257,26 @@ def _prefill_chunks_loop(params, cfg: ModelConfig, tokens, base, n_real,
     """
     chunk = tokens.shape[-1]
     with w8a8_scope(w8a8):
-        logits0, cache = forward(
+        logits0, cache, *moe = forward(
             params, cfg, tokens[0], cache, start_pos=base,
-            kv_width=kv_width, logits_index=last_index,
+            kv_width=kv_width, logits_index=last_index, moe_stats=moe_stats,
         )
 
     def body(i, carry):
-        cache, _ = carry
+        cache, _, *moe = carry
         toks = jax.lax.dynamic_index_in_dim(tokens, i, 0, keepdims=False)
         with w8a8_scope(w8a8):
-            logits, cache = forward(
+            logits, cache, *more = forward(
                 params, cfg, toks, cache, start_pos=base + i * chunk,
                 kv_width=kv_width, logits_index=last_index,
+                moe_stats=moe_stats,
             )
-        return (cache, logits[:, 0])
+        return (cache, logits[:, 0], *(a + b for a, b in zip(moe, more)))
 
-    cache, last_logits = jax.lax.fori_loop(
-        1, n_real, body, (cache, logits0[:, 0]),
+    cache, last_logits, *moe = jax.lax.fori_loop(
+        1, n_real, body, (cache, logits0[:, 0], *moe),
     )
-    return last_logits, cache
+    return (last_logits, cache, *moe)
 
 
 def _decode_chunk(params, cfg: ModelConfig, token, pos, cache, key,
@@ -271,7 +284,7 @@ def _decode_chunk(params, cfg: ModelConfig, token, pos, cache, key,
                   kv_width=None, attn_impl="xla", mesh=None,
                   prefix=None, prefix_len=None, prefix_rows=None,
                   w8a8: bool = False, sentinel: bool = False,
-                  poison_row=None):
+                  poison_row=None, moe_stats: bool = False):
     """``n_steps`` decode steps as ONE device program (lax.scan).
 
     One dispatch and one host fetch per chunk instead of per token: fewer
@@ -297,15 +310,23 @@ def _decode_chunk(params, cfg: ModelConfig, token, pos, cache, key,
     existing transfer. ``poison_row`` (traced, or None) is the
     ``nan_logits`` fault's injection operand: that row's logits become
     NaN before sampling, exactly what a corrupted accumulator emits.
+
+    ``moe_stats=True`` (static) on a routed model returns, last, the
+    chunk's routing sums over its steps and expert layers, int32[3]:
+    (token, chosen expert) pairs, pairs on held experts, held experts that
+    took at least one row. They ride the same fetch as the tokens.
     """
+    moe_stats = moe_stats and cfg.is_moe
+
     def body(carry, _):
-        token, pos, cache, ok = carry
-        logits, cache = forward(
+        token, pos, cache, ok, *moe = carry
+        logits, cache, *more = forward(
             params, cfg, token[:, None], cache, start_pos=pos,
             row_start=row_start, kv_width=kv_width, attn_impl=attn_impl,
             mesh=mesh, prefix=prefix, prefix_len=prefix_len,
-            prefix_rows=prefix_rows,
+            prefix_rows=prefix_rows, moe_stats=moe_stats,
         )
+        moe = [a + b for a, b in zip(moe, more)]
         last = logits[:, -1]
         if poison_row is not None:
             rows = jnp.arange(last.shape[0], dtype=jnp.int32)
@@ -319,17 +340,18 @@ def _decode_chunk(params, cfg: ModelConfig, token, pos, cache, key,
             last, step_key,
             temperature=temperature, top_k=top_k, top_p=top_p,
         )
-        return (next_token, pos + 1, cache, ok), next_token
+        return (next_token, pos + 1, cache, ok, *moe), next_token
 
     ok0 = jnp.ones((token.shape[0],), dtype=bool)
+    moe0 = [jnp.zeros((3,), jnp.int32)] if moe_stats else []
     with w8a8_scope(w8a8):
-        (token, pos, cache, ok), toks = jax.lax.scan(
-            body, (token, jnp.asarray(pos, jnp.int32), cache, ok0), None,
-            length=n_steps,
+        (token, pos, cache, ok, *moe), toks = jax.lax.scan(
+            body, (token, jnp.asarray(pos, jnp.int32), cache, ok0, *moe0),
+            None, length=n_steps,
         )
     if sentinel:
-        return token, toks, cache, ok
-    return token, toks, cache
+        return (token, toks, cache, ok, *moe)
+    return (token, toks, cache, *moe)
 
 
 def _nrows(x) -> int:
@@ -444,7 +466,7 @@ class _NamedPrograms:
 
 
 _prefill_chunk = _NamedPrograms(
-    _prefill_chunk, "prefill_chunk", ("cfg", "kv_width", "w8a8"),
+    _prefill_chunk, "prefill_chunk", ("cfg", "kv_width", "w8a8", "moe_stats"),
     lambda a, k: (a[1].name, _kvw(a, k, 6)),
     family="prefill",
     key=lambda a, k: (_roofline.shape_of(a[2]), _kvw(a, k, 6)),
@@ -452,7 +474,7 @@ _prefill_chunk = _NamedPrograms(
 )
 _prefill_chunks_loop = _NamedPrograms(
     _prefill_chunks_loop, "prefill_chunks_loop",
-    ("cfg", "max_chunks", "kv_width", "w8a8"),
+    ("cfg", "max_chunks", "kv_width", "w8a8", "moe_stats"),
     lambda a, k: (a[1].name, _kvw(a, k, 8)),
     family="prefill",
     key=lambda a, k: (_roofline.shape_of(a[2]), _kvw(a, k, 8)),
@@ -462,7 +484,7 @@ _prefill_chunks_loop = _NamedPrograms(
 _decode_chunk = _NamedPrograms(
     _decode_chunk, "decode_chunk",
     ("cfg", "n_steps", "temperature", "top_k", "top_p", "kv_width",
-     "attn_impl", "mesh", "w8a8", "sentinel"),
+     "attn_impl", "mesh", "w8a8", "sentinel", "moe_stats"),
     lambda a, k: (
         a[1].name, _kvw(a, k, 11),
         int(k["n_steps"] if "n_steps" in k else a[6]),
@@ -645,6 +667,11 @@ class Engine:
         # above LLMC_PREFIX_CACHE_MAX_MB (default 2048) so a 128k-context
         # cache can't silently double its HBM footprint.
         self.prefix_cache_enabled = knobs.get_bool("LLMC_PREFIX_CACHE")
+        if cfg.is_latent:
+            self._refuse_latent(mesh)
+            # The retained prefix snapshot is not built over a latent yet:
+            # off, as pooled prefix sharing is (engine/batcher.py).
+            self.prefix_cache_enabled = False
         self._prefix_max_bytes = (
             knobs.get_float("LLMC_PREFIX_CACHE_MAX_MB") * 1e6
         )
@@ -751,6 +778,11 @@ class Engine:
         # (``_prefill_ids`` or an admission wave), padding included: the
         # pool's admission counts from it.
         self.last_prefill = (0, 0)
+        # A routed model's prefill programs return their routing sums
+        # (``moe_stats``) only while someone collects them: the pool
+        # scheduler opens this bank and drains it into its fetches
+        # (engine/batcher.py); None, nothing is asked of the programs.
+        self._moe_bank: Optional[list] = None
         # Chip-time attribution (obs/attrib): single-stream prefill and
         # decode walls book here; the weights register as a modeled
         # resident-HBM component for the watermark sentinel.
@@ -805,17 +837,55 @@ class Engine:
                 per_chip[shard.device.id] = (
                     per_chip.get(shard.device.id, 0) + shard.data.nbytes
                 )
+        from llm_consensus_tpu.utils.flops import cache_bytes_per_token
+
         self.build_stats = {
             "tp": int(dict(mesh.shape).get("tp", 1)) if mesh is not None else 1,
             "param_bytes_per_chip": max(per_chip.values(), default=0),
             "build_s": round((time.monotonic_ns() - t_build_ns) / 1e9, 3),
+            # What a share of an expert-parallel layer holds (0 and 0 for a
+            # model without a router), and what a token costs the cache.
+            "experts_held": cfg.n_experts,
+            "router_width": cfg.n_router,
+            "cache_bytes_per_token": cache_bytes_per_token(
+                cfg, 1 if self.kv_quant == "int8"
+                else jnp.dtype(dtype).itemsize),
         }
         self._spans.complete(
             "engine.build", t_build_ns, "engine", model=cfg.name,
-            devices=sorted(per_chip), tp=self.build_stats["tp"],
-            param_bytes_per_chip=self.build_stats["param_bytes_per_chip"],
-            init_s=round(init_s, 3),
+            devices=sorted(per_chip), init_s=round(init_s, 3),
+            **{k: v for k, v in self.build_stats.items() if k != "build_s"},
         )
+
+    def _refuse_latent(self, mesh) -> None:
+        """What a latent-attention (MLA) model does not get yet is refused
+        by name when its engine is built, not computed wrongly."""
+        from llm_consensus_tpu.kv import pool_enabled
+        from llm_consensus_tpu.models.transformer import refuse_latent_mesh
+
+        name = self.cfg.name
+        if self.kv_quant is not None:
+            raise ValueError(
+                f"{name}: no {self.kv_quant} cache for a latent (MLA) model; "
+                "unset LLMC_KV_QUANT / kv_quant")
+        if pool_enabled():
+            raise ValueError(
+                f"{name}: the radix KV arena (LLMC_KV_POOL) does not hold a "
+                "latent (MLA) cache")
+        refuse_latent_mesh(self.cfg, mesh)
+
+    @property
+    def _moe_on(self) -> bool:
+        return self._moe_bank is not None
+
+    def _bank_moe(self, out: tuple) -> tuple:
+        """Strip the routing sums a prefill program returned last (asked
+        for with ``moe_stats=self._moe_on``) into the bank; the rest is
+        what the program returns without them."""
+        if not self._moe_on:  # opened for routed models only
+            return out
+        self._moe_bank.append(out[-1])
+        return out[:-1]
 
     def _flash_guard(self, dispatch: Callable[[str], tuple]):
         """Run a jitted dispatch parameterized on attention impl; if the
@@ -1211,24 +1281,24 @@ class Engine:
                     jnp.int32,
                 ).reshape(max_chunks, 1, chunk)
             )
-            last_logits, cache = _prefill_chunks_loop(
+            last_logits, cache = self._bank_moe(_prefill_chunks_loop(
                 self.params, self.cfg, toks,
                 self._place(jnp.asarray(base, jnp.int32)),
                 self._place(jnp.asarray(n_tail, jnp.int32)),
                 last_in_chunk, cache, max_chunks=max_chunks,
-                kv_width=kv_width, w8a8=self.w8a8,
-            )
+                kv_width=kv_width, w8a8=self.w8a8, moe_stats=self._moe_on,
+            ))
         else:
             for i in range(n_tail):
                 toks = self._place(jnp.asarray(
                     padded[i * chunk:(i + 1) * chunk], jnp.int32
                 )[None, :])
-                last_logits, cache = _prefill_chunk(
+                last_logits, cache = self._bank_moe(_prefill_chunk(
                     self.params, self.cfg, toks,
                     self._place(jnp.asarray(base + i * chunk, jnp.int32)),
                     last_in_chunk, cache, kv_width=kv_width,
-                    w8a8=self.w8a8,
-                )
+                    w8a8=self.w8a8, moe_stats=self._moe_on,
+                ))
         # What was prefilled, for the caller's accounting: chunks
         # dispatched and the token slots they covered (padding included).
         self.last_prefill = (n_tail, n_tail * chunk)
@@ -1319,11 +1389,13 @@ class Engine:
             bucket = _bucket(n_prompt, self.max_seq)
             padded = prompt_ids + [0] * (bucket - n_prompt)
             tokens = self._place(jnp.asarray(padded, jnp.int32)[None, :])
-            last_logits, cache = self._flash_guard(lambda impl: _prefill_step(
-                self.params, cfg, tokens,
-                self._place(jnp.asarray([n_prompt - 1])),
-                cache, attn_impl=impl, mesh=self.mesh, w8a8=self.w8a8,
-            ))
+            last_logits, cache = self._bank_moe(
+                self._flash_guard(lambda impl: _prefill_step(
+                    self.params, cfg, tokens,
+                    self._place(jnp.asarray([n_prompt - 1])),
+                    cache, attn_impl=impl, mesh=self.mesh, w8a8=self.w8a8,
+                    moe_stats=self._moe_on,
+                )))
             self.last_prefill = (1, bucket)
         return last_logits, cache, reuse_len if reuse_ok else 0
 
@@ -2032,19 +2104,20 @@ class AdmissionPrefill:
                 jnp.asarray([len(r) - 1 for r in self.rows], jnp.int32)
             )
             if self._suffix:
-                self._last_logits, self._cache = _prefill_step(
+                self._last_logits, self._cache = eng._bank_moe(_prefill_step(
                     eng.params, cfg, tokens, last_index, self._cache,
                     attn_impl="xla", mesh=eng.mesh,
                     prefix=self._prefix_cache, prefix_len=self._plen_dev,
-                    w8a8=eng.w8a8,
-                )
+                    w8a8=eng.w8a8, moe_stats=eng._moe_on,
+                ))
             else:
-                self._last_logits, self._cache = eng._flash_guard(
+                self._last_logits, self._cache = eng._bank_moe(eng._flash_guard(
                     lambda impl: _prefill_step(
                         eng.params, cfg, tokens, last_index, self._cache,
                         attn_impl=impl, mesh=eng.mesh, w8a8=eng.w8a8,
+                        moe_stats=eng._moe_on,
                     )
-                )
+                ))
             self._done = True
             return True
         chunk_len = self._chunk_len
@@ -2065,13 +2138,13 @@ class AdmissionPrefill:
                  for r in self.rows],
                 jnp.int32,
             ))
-            lg, self._cache = _prefill_chunk(
+            lg, self._cache = eng._bank_moe(_prefill_chunk(
                 eng.params, cfg, toks,
                 place(jnp.asarray(c * chunk_len, jnp.int32)),
                 idx, self._cache, kv_width=self.width,
                 prefix=self._prefix_cache, prefix_len=self._plen_dev,
-                w8a8=eng.w8a8,
-            )
+                w8a8=eng.w8a8, moe_stats=eng._moe_on,
+            ))
             self._per_chunk.append(lg)
             self._next_chunk += 1
             spent += self.k * chunk_len

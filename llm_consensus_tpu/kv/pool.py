@@ -46,6 +46,7 @@ from llm_consensus_tpu.obs.attrib import tag as attrib_tag
 from llm_consensus_tpu.obs import roofline as _roofline
 from llm_consensus_tpu.analysis import sanitizer
 from llm_consensus_tpu.utils import knobs
+from llm_consensus_tpu.utils.flops import cache_bytes_per_token
 
 
 @partial(jax.jit, static_argnames=("k", "bs"), donate_argnames=("dst",))
@@ -112,7 +113,7 @@ class KVPool:
         # caches) — the arena sizing unit, also exported for the bench's
         # resident-stream capacity model.
         itemsize = jnp.dtype(dtype).itemsize
-        per_tok = 2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim
+        per_tok = cache_bytes_per_token(cfg, 1)
         if kv_quant == "int8":
             self.bytes_per_token = per_tok + 2 * cfg.n_layers * cfg.n_kv_heads * itemsize
         else:

@@ -1,9 +1,10 @@
 """Model family configurations.
 
 One generic decoder-only transformer (models/transformer.py) covers every
-family the framework serves — Llama-2/3, Mistral, Gemma, Qwen2, Mixtral —
-via static config switches, so each (family, shape) pair compiles to a
-single XLA program. The reference framework's "model set" is a table of
+family the framework serves — Llama-2/3, Mistral, Gemma, Qwen2, Mixtral,
+DeepSeek-V2 — via static config switches, so each (family, shape) pair
+compiles to a single XLA program. Every field a family adds defaults to
+"off", so the older presets hash and compare as they did. The reference framework's "model set" is a table of
 remote API names (/root/reference/cmd/llm-consensus/main.go:49-61); here the
 catalog describes real on-device architectures.
 """
@@ -17,7 +18,7 @@ from typing import Optional
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                     # llama | mistral | gemma | qwen2 | mixtral
+    family: str                     # llama | mistral | gemma | qwen2 | mixtral | deepseek_v2
     vocab_size: int
     d_model: int
     n_layers: int
@@ -38,13 +39,61 @@ class ModelConfig:
     attn_logit_softcap: Optional[float] = None
     final_logit_softcap: Optional[float] = None
     tie_embeddings: bool = False
-    n_experts: int = 0              # mixtral: 8
+    n_experts: int = 0              # routed experts HELD here (mixtral: 8)
     experts_per_token: int = 0      # mixtral: 2
     max_seq_len: int = 8192
+    # -- routed experts beyond Mixtral's (ops/moe.py) ----------------------
+    # The router keeps its published width; a chip that holds a share of an
+    # expert-parallel layer states which experts are its own:
+    # [first_expert, first_expert + n_experts) of router_width.
+    router_width: int = 0           # router outputs; 0 = n_experts (all held)
+    first_expert: int = 0           # index of the first expert held here
+    d_expert: int = 0               # one routed expert's width; 0 = d_ff
+    n_shared_experts: int = 0       # always-on experts, d_expert wide each
+    n_expert_groups: int = 1        # device-limited routing: groups ...
+    groups_per_token: int = 1       # ... and how many of them a token may reach
+    routed_scale: float = 1.0       # multiplies the routed sum
+    norm_topk: bool = True          # chosen weights renormalised to sum to 1
+    router_scoring: str = "softmax"  # over the router's whole width
+    n_dense_layers: int = 0         # leading layers with a dense MLP of d_ff
+    # -- latent attention (MLA, ops/latent_attention.py); 0 = off ----------
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0           # the cache holds kv_lora_rank + qk_rope_dim a token a layer
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN: (factor, beta_fast, beta_slow, mscale, mscale_all_dim,
+    # original_max_position_embeddings); tuple so the config stays hashable.
+    rope_yarn: Optional[tuple[float, float, float, float, float, int]] = None
 
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def is_latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def n_router(self) -> int:
+        """Outputs of the router: the published expert count."""
+        return self.router_width or self.n_experts
+
+    @property
+    def expert_width(self) -> int:
+        return self.d_expert or self.d_ff
+
+    @property
+    def n_expert_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers if self.is_moe else 0
+
+    @property
+    def cache_width(self) -> int:
+        """Values the cache holds a token a layer: K and V heads, or the
+        latent with its one shared rotary key."""
+        if self.is_latent:
+            return self.kv_lora_rank + self.qk_rope_dim
+        return 2 * self.n_kv_heads * self.head_dim
 
     @property
     def rope_scaling_dict(self) -> Optional[dict]:
@@ -107,6 +156,16 @@ MODEL_PRESETS: dict[str, ModelConfig] = {c.name: c for c in [
        rope_theta=1000000.0, n_experts=8, experts_per_token=2,
        max_seq_len=32768),
     # -- Tiny variants: CI / CPU-mesh tests --------------------------------
+    # DeepSeek-V2's block at CI size: 1 dense + 2 expert layers, 8 experts
+    # in 4 groups of 2, 2 groups and 3 experts a token, 1 shared expert;
+    # ranks and head sizes that are not powers of one another.
+    _L("tiny-deepseek-v2", "deepseek_v2", 512, 96, 3, 4, 4, 40, 192,
+       rms_eps=1e-6, n_experts=8, experts_per_token=3, d_expert=48,
+       n_shared_experts=1, n_expert_groups=4, groups_per_token=2,
+       routed_scale=4.0, norm_topk=False, n_dense_layers=1,
+       q_lora_rank=56, kv_lora_rank=40, qk_nope_dim=24, qk_rope_dim=16,
+       v_head_dim=20, rope_yarn=(8.0, 32.0, 1.0, 0.707, 0.707, 64),
+       max_seq_len=4096),
     _L("tiny-llama", "llama", 512, 128, 2, 4, 2, 32, 256, max_seq_len=4096),
     _L("tiny-gemma", "gemma", 512, 128, 2, 4, 4, 32, 256, activation="gelu_tanh",
        norm_offset=1.0, embed_scale=True, tie_embeddings=True, max_seq_len=4096),

@@ -1,7 +1,7 @@
 """Generic decoder-only transformer in functional JAX.
 
 One implementation serves every model family (llama/mistral/gemma/qwen2/
-mixtral) via static ``ModelConfig`` switches. This replaces the reference's
+mixtral/deepseek_v2) via static ``ModelConfig`` switches. This replaces the reference's
 "compute layer" — three HTTP clients (/root/reference/internal/provider/
 {openai,anthropic,google}.go) — with real on-device compute.
 
@@ -17,6 +17,25 @@ TPU-first design decisions:
     (softmax, norms, router, final logits).
   * Sharding is applied externally via ``parallel.sharding.param_axes``,
     which mirrors this module's pytree structure with logical axis names.
+
+The DeepSeek-V2 block (``family="deepseek_v2"``; every size from the
+published ``config.json``): pre-norm residual blocks, final norm, untied
+head. Attention is latent (ops/latent_attention.py has the equations): the
+cache holds ``c_kv ‖ k_rope`` (``kv_lora_rank + qk_rope_dim`` values a token
+a layer); ``forward`` takes the prefill form for T > 1 and the absorbed form
+for T = 1. The softmax scale is ``(qk_nope_dim + qk_rope_dim)^-0.5 · m²``
+with ``m = 0.1 · mscale_all_dim · ln(factor) + 1``; the rotary table is
+YaRN's (ops/rope.py ``yarn_inv_freq``), cos and sin multiplied by
+``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``. The first
+``n_dense_layers`` layers (stack ``layers_dense``) have a dense SwiGLU of
+``d_ff``; the others (stack ``layers``) the routed expert layer of
+ops/moe.py: ``s = softmax(h · W_g)`` over the router's whole width, the best
+``groups_per_token`` of ``n_expert_groups`` groups by their largest ``s``,
+the ``experts_per_token`` largest ``s`` among them, weights those ``s`` not
+renormalised times ``routed_scale``, plus the shared experts. Departure
+from the checkpoint's layout: rotary pairs are half-split (i, i + d/2) where
+the published code interleaves (2i, 2i + 1); under random weights the
+pairing is immaterial as long as program and reference pair alike.
 """
 
 from __future__ import annotations
@@ -36,7 +55,9 @@ from llm_consensus_tpu.ops.moe import moe_block
 from llm_consensus_tpu.ops.quant import (
     is_quantized, kv_layer, kv_read, kv_write_rows, qeinsum)
 from llm_consensus_tpu.ops.norms import rms_norm
-from llm_consensus_tpu.ops.rope import apply_rope, rope_angles, rope_inv_freq
+from llm_consensus_tpu.ops.latent_attention import latent_attention
+from llm_consensus_tpu.ops.rope import (
+    apply_rope, rope_angles, rope_inv_freq, yarn_inv_freq, yarn_mscale)
 
 
 class AttentionRoutes:
@@ -82,7 +103,9 @@ attention_routes = AttentionRoutes()
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
                 leaf_hook=None, shardings: Optional[dict] = None) -> dict:
-    """Random-init parameter pytree (layers stacked on axis 0).
+    """Random-init parameter pytree (layers stacked on axis 0; a family
+    with leading dense layers has two stacks, ``layers_dense`` and then
+    ``layers``).
 
     ``shardings`` (a tree of ``jax.sharding.Sharding`` shaped like the
     result: ``parallel.sharding.param_shardings``) makes every leaf
@@ -103,9 +126,10 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
     key sequence is independent of the hook, so hooked and post-hoc
     quantization produce identical values.
     """
-    keys = iter(jax.random.split(key, 16))
-    # Leaf names are unique across the two levels of the tree.
-    sharding_of = {**shardings, **shardings["layers"]} if shardings else {}
+    keys = iter(jax.random.split(key, 32))
+    # Leaf names are unique within the top level and within a stack;
+    # ``stack`` below points this at the stack it is making.
+    sharding_of = dict(shardings or {})
 
     def make(name, fn, *args):
         # Jitted so XLA fuses normal→scale→astype into one kernel that
@@ -131,37 +155,71 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
         fill = jnp.zeros if cfg.norm_offset else jnp.ones
         return make(name, lambda: fill(shape, dtype))
 
-    d, dh, hq, hkv, f, l = (
-        cfg.d_model, cfg.head_dim, cfg.n_heads, cfg.n_kv_heads, cfg.d_ff, cfg.n_layers,
+    d, dh, hq, hkv, f = (
+        cfg.d_model, cfg.head_dim, cfg.n_heads, cfg.n_kv_heads, cfg.d_ff,
     )
     proj_std = d ** -0.5
-    layers: dict = {
-        "attn_norm": norm((l, d), "attn_norm"),
-        "mlp_norm": norm((l, d), "mlp_norm"),
-        "wq": normal(next(keys), (l, d, hq * dh), proj_std, "wq"),
-        "wk": normal(next(keys), (l, d, hkv * dh), proj_std, "wk"),
-        "wv": normal(next(keys), (l, d, hkv * dh), proj_std, "wv"),
-        "wo": normal(next(keys), (l, hq * dh, d), (hq * dh) ** -0.5, "wo"),
-    }
-    if cfg.qkv_bias:
-        for name, width in (("bq", hq * dh), ("bk", hkv * dh), ("bv", hkv * dh)):
-            layers[name] = make(name, lambda w=width: jnp.zeros((l, w), dtype))
-    if cfg.is_moe:
-        e = cfg.n_experts
-        layers["w_router"] = normal(next(keys), (l, d, e), proj_std, "w_router")
-        layers["w_gate"] = normal(next(keys), (l, e, d, f), proj_std, "w_gate")
-        layers["w_up"] = normal(next(keys), (l, e, d, f), proj_std, "w_up")
-        layers["w_down"] = normal(next(keys), (l, e, f, d), f ** -0.5, "w_down")
-    else:
-        layers["w_gate"] = normal(next(keys), (l, d, f), proj_std, "w_gate")
-        layers["w_up"] = normal(next(keys), (l, d, f), proj_std, "w_up")
-        layers["w_down"] = normal(next(keys), (l, f, d), f ** -0.5, "w_down")
+
+    def stack(name: str, l: int, routed: bool) -> dict:
+        """``l`` layers stacked on axis 0, with a dense or a routed MLP."""
+        sharding_of.update((shardings or {}).get(name, {}))
+        layers: dict = {
+            "attn_norm": norm((l, d), "attn_norm"),
+            "mlp_norm": norm((l, d), "mlp_norm"),
+        }
+        if cfg.is_latent:
+            qr, kr = cfg.q_lora_rank, cfg.kv_lora_rank
+            qk, rope, v = cfg.qk_nope_dim + cfg.qk_rope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+            layers.update({
+                "wq_a": normal(next(keys), (l, d, qr), proj_std, "wq_a"),
+                "q_norm": norm((l, qr), "q_norm"),
+                "wq_b": normal(next(keys), (l, qr, hq * qk), qr ** -0.5, "wq_b"),
+                "wkv_a": normal(next(keys), (l, d, kr + rope), proj_std, "wkv_a"),
+                "kv_norm": norm((l, kr), "kv_norm"),
+                "wkv_b": normal(
+                    next(keys), (l, kr, hq * (cfg.qk_nope_dim + v)), kr ** -0.5,
+                    "wkv_b"),
+                "wo": normal(next(keys), (l, hq * v, d), (hq * v) ** -0.5, "wo"),
+            })
+        else:
+            layers.update({
+                "wq": normal(next(keys), (l, d, hq * dh), proj_std, "wq"),
+                "wk": normal(next(keys), (l, d, hkv * dh), proj_std, "wk"),
+                "wv": normal(next(keys), (l, d, hkv * dh), proj_std, "wv"),
+                "wo": normal(next(keys), (l, hq * dh, d), (hq * dh) ** -0.5, "wo"),
+            })
+        if cfg.qkv_bias:
+            for name, width in (("bq", hq * dh), ("bk", hkv * dh), ("bv", hkv * dh)):
+                layers[name] = make(name, lambda w=width: jnp.zeros((l, w), dtype))
+        if routed:
+            e, fe = cfg.n_experts, cfg.expert_width
+            layers["w_router"] = normal(
+                next(keys), (l, d, cfg.n_router), proj_std, "w_router")
+            layers["w_gate"] = normal(next(keys), (l, e, d, fe), proj_std, "w_gate")
+            layers["w_up"] = normal(next(keys), (l, e, d, fe), proj_std, "w_up")
+            layers["w_down"] = normal(next(keys), (l, e, fe, d), fe ** -0.5, "w_down")
+            if cfg.n_shared_experts:
+                fs = cfg.n_shared_experts * fe
+                layers["ws_gate"] = normal(next(keys), (l, d, fs), proj_std, "ws_gate")
+                layers["ws_up"] = normal(next(keys), (l, d, fs), proj_std, "ws_up")
+                layers["ws_down"] = normal(next(keys), (l, fs, d), fs ** -0.5, "ws_down")
+        else:
+            layers["w_gate"] = normal(next(keys), (l, d, f), proj_std, "w_gate")
+            layers["w_up"] = normal(next(keys), (l, d, f), proj_std, "w_up")
+            layers["w_down"] = normal(next(keys), (l, f, d), f ** -0.5, "w_down")
+        return layers
+
+    n_dense = cfg.n_dense_layers if cfg.is_moe else 0
+    layers = stack("layers", cfg.n_layers - n_dense, cfg.is_moe)
+    layers_dense = stack("layers_dense", n_dense, False) if n_dense else None
 
     params = {
         "embed": normal(next(keys), (cfg.vocab_size, d), 0.02, "embed"),
         "final_norm": norm((d,), "final_norm"),
         "layers": layers,
     }
+    if layers_dense is not None:
+        params["layers_dense"] = layers_dense
     if not cfg.tie_embeddings:
         params["lm_head"] = normal(
             next(keys), (d, cfg.vocab_size), proj_std, "lm_head"
@@ -177,8 +235,21 @@ def init_kv_cache(
 
     ``quant="int8"`` stores codes + per-row scales (ops/quant.py): half the
     HBM capacity and decode read bandwidth of a bf16 cache.
+
+    A latent-attention model's cache is ONE leaf ``{"kv": [L, B, S, 1,
+    kv_lora_rank + qk_rope_dim]}``, the sequence on axis 2 like every
+    other stack, so whatever maps over the tree (splice, compact, resize)
+    takes it unchanged. ``cfg.cache_width`` is the one place that says how
+    many values a token a layer holds.
     """
     s = max_seq or cfg.max_seq_len
+    if cfg.is_latent:
+        if quant is not None:
+            raise ValueError(
+                f"{cfg.name}: no quantized cache for a latent (MLA) model: "
+                f"kv cache quant {quant!r} is not computed")
+        return {"kv": jnp.zeros(
+            (cfg.n_layers, batch, s, 1, cfg.cache_width), dtype)}
     shape = (cfg.n_layers, batch, s, cfg.n_kv_heads, cfg.head_dim)
     if quant == "int8":
         # Scales are stored seq-MINOR [L, B, Hkv, S]: with seq on lanes
@@ -242,11 +313,31 @@ def _layer(
     prefix_v=None,
     prefix_len=None,      # scalar i32: valid prefix slots
     prefix_rows=None,     # [B] bool: rows that attend the shared prefix
-) -> tuple[jax.Array, Optional[jax.Array], Optional[jax.Array]]:
+    routed: Optional[bool] = None,  # this stack's MLP is the routed expert
+                                    # layer (None: whatever the model has)
+    moe_stats: bool = False,  # also return the expert layer's three sums
+    expert_stacks=None,  # (w_gate, w_up, w_down) whole [L, E, ...] stacks and
+                         # this layer's index in them, in place of lp's own
+):
+    """One block. Returns ``(x, cache_k, cache_v)`` and, with
+    ``moe_stats`` on a routed stack, the expert layer's sums last."""
+    routed = cfg.is_moe if routed is None else routed
     b, t, d = x.shape
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
     h = rms_norm(x, lp["attn_norm"], cfg.rms_eps, cfg.norm_offset)
+    if cfg.is_latent:
+        # The latent stack rides where the K stack does; there is no V.
+        attn_out, cache_k = latent_attention(
+            h, lp, cos, sin, mask, cache_k, start_pos, layer_idx,
+            n_heads=hq, kv_lora_rank=cfg.kv_lora_rank,
+            qk_nope_dim=cfg.qk_nope_dim, qk_rope_dim=cfg.qk_rope_dim,
+            v_head_dim=cfg.v_head_dim, scale=_latent_scale(cfg),
+            rms_eps=cfg.rms_eps, kv_width=kv_width, absorbed=t == 1,
+        )
+        x = x + qeinsum("btk,kd->btd", attn_out, lp["wo"])
+        return _mlp_half(
+            cfg, x, lp, routed, moe_stats, cache_k, cache_v, expert_stacks)
     q = qeinsum("btd,dk->btk", h, lp["wq"])
     k = qeinsum("btd,dk->btk", h, lp["wk"])
     v = qeinsum("btd,dk->btk", h, lp["wv"])
@@ -470,17 +561,46 @@ def _layer(
         attn_out = merge_attention_states(o1, m1, l1, attn_out, m2, l2)
     x = x + qeinsum("btk,kd->btd", attn_out.reshape(b, t, hq * dh), lp["wo"])
 
-    h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps, cfg.norm_offset)
-    if cfg.is_moe:
-        mlp_out = moe_block(
-            h, lp["w_router"], lp["w_gate"], lp["w_up"], lp["w_down"],
-            top_k=cfg.experts_per_token, activation=cfg.activation,
-        )
-    else:
-        mlp_out = gated_mlp(h, lp["w_gate"], lp["w_up"], lp["w_down"], cfg.activation)
     if ring_mesh is not None:
-        return x + mlp_out, k, v  # fresh k/v for the caller's cache build
-    return x + mlp_out, cache_k, cache_v
+        cache_k, cache_v = k, v  # fresh k/v for the caller's cache build
+    return _mlp_half(
+        cfg, x, lp, routed, moe_stats, cache_k, cache_v, expert_stacks)
+
+
+def _latent_scale(cfg: ModelConfig) -> float:
+    """Softmax scale of a latent-attention model: the whole query head's
+    ``dh^-0.5`` times YaRN's ``m²`` (m from ``mscale_all_dim``)."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    if cfg.rope_yarn is not None:
+        factor, _, _, _, mscale_all_dim, _ = cfg.rope_yarn
+        scale *= yarn_mscale(factor, mscale_all_dim) ** 2
+    return scale
+
+
+def _mlp_half(cfg: ModelConfig, x, lp, routed: bool, moe_stats: bool,
+              cache_k, cache_v, expert_stacks=None):
+    """The MLP half of a block on the post-attention residual ``x``: the
+    dense gated MLP, or on a routed stack the expert layer (ops/moe.py),
+    whose expert leaves are this layer's own (``lp``) or, from ``forward``'s
+    scan, the whole stacks with this layer's index."""
+    h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps, cfg.norm_offset)
+    if not routed:
+        mlp_out = gated_mlp(h, lp["w_gate"], lp["w_up"], lp["w_down"], cfg.activation)
+        return x + mlp_out, cache_k, cache_v
+    *experts, layer = expert_stacks or (lp["w_gate"], lp["w_up"], lp["w_down"], None)
+    out = moe_block(
+        h, lp["w_router"], *experts, layer=layer,
+        top_k=cfg.experts_per_token, activation=cfg.activation,
+        first_expert=cfg.first_expert, n_groups=cfg.n_expert_groups,
+        groups_per_token=cfg.groups_per_token, norm_topk=cfg.norm_topk,
+        routed_scale=cfg.routed_scale, scoring=cfg.router_scoring,
+        shared=(lp["ws_gate"], lp["ws_up"], lp["ws_down"])
+        if cfg.n_shared_experts else None,
+        with_stats=moe_stats,
+    )
+    if moe_stats:
+        return x + out[0], cache_k, cache_v, out[1]
+    return x + out, cache_k, cache_v
 
 
 def forward(
@@ -499,8 +619,15 @@ def forward(
     prefix_len: Optional[jax.Array] = None,  # scalar i32 valid prefix slots
     prefix_rows: Optional[jax.Array] = None,  # [B] bool: rows attending prefix
     kv_mask: Optional[jax.Array] = None,  # [B, S] bool: written-slot bitmap
-) -> tuple[jax.Array, Optional[dict]]:
+    moe_stats: bool = False,           # routed model: also return its sums
+):
     """Run the model. Returns (logits [B, T, V] fp32, updated cache).
+
+    ``moe_stats=True`` on a routed model returns a third value, int32[3]:
+    over this call's expert layers the (token, chosen expert) pairs in all,
+    the pairs on held experts, and the held experts that took at least one
+    row (one count a layer). A model without a router returns two values
+    whatever the flag says.
 
     Without a cache this is a plain training/eval forward over ``tokens``.
     With a cache it serves both prefill (T = prompt chunk) and decode (T = 1):
@@ -529,6 +656,8 @@ def forward(
     meshes whose degree divides both head counts; anything else falls back
     to the XLA path, which GSPMD partitions natively.
     """
+    if cfg.is_latent:
+        _refuse_latent(cfg, attn_impl, mesh, prefix, kv_mask)
     if attn_impl == "ring":
         if cache is None or mesh is None or not (
             isinstance(start_pos, int) and start_pos == 0
@@ -604,6 +733,7 @@ def forward(
         int(start_pos)
         if (
             attn_impl == "flash"
+            and not cfg.is_latent  # no kernel computes over a latent yet
             and cache is not None
             and isinstance(start_pos, int)
             and row_start is None  # kernel assumes one shared offset
@@ -620,11 +750,11 @@ def forward(
         decode_flash_supported)
 
     if cache is not None:
-        k_store = cache["k"]["q8"] if is_quantized(cache["k"]) else cache["k"]
+        k_store = _k_store(cache)
         decode_width = k_store.shape[2] if kv_width is None else min(
             kv_width, k_store.shape[2]
         )
-        decode_quantized = is_quantized(cache["k"])
+        decode_quantized = is_quantized(cache.get("k"))
     else:
         decode_width, decode_quantized = None, False
     if shard_tp == 1:
@@ -646,6 +776,7 @@ def forward(
         decode_heads_ok = False
     decode_flash = (
         attn_impl == "flash"
+        and not cfg.is_latent
         and cache is not None
         and t == 1
         and flash_offset is None
@@ -656,10 +787,13 @@ def forward(
         (flash_offset is not None or decode_flash) and shard_tp > 1
     ) else None
     if cache is not None:
-        attention_routes.note(
-            cfg.name, "decode" if t == 1 else "prefill",
-            "pallas" if flash_offset is not None or decode_flash else "xla",
-        )
+        if cfg.is_latent:
+            # A route name of its own for each form, so that a kernel for
+            # either shows in what a configuration expects.
+            path = "xla_latent_absorbed" if t == 1 else "xla_latent"
+        else:
+            path = "pallas" if flash_offset is not None or decode_flash else "xla"
+        attention_routes.note(cfg.name, "decode" if t == 1 else "prefill", path)
 
     start = jnp.asarray(start_pos, jnp.int32)
     positions = start + jnp.arange(t, dtype=jnp.int32)[None, :]  # [1, T]
@@ -682,14 +816,12 @@ def forward(
         else:
             pos_offset = jnp.broadcast_to(plen, (b,))
         positions = positions + pos_offset[:, None]
-    inv_freq = rope_inv_freq(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling_dict)
-    cos, sin = rope_angles(positions, inv_freq)
+    cos, sin = _rotary_tables(cfg, positions)
 
     if flash_offset is not None or decode_flash:
         mask = None  # the kernels derive causality from pos/q_offset
     elif cache is not None:
-        k_store = cache["k"]["q8"] if is_quantized(cache["k"]) else cache["k"]
-        s = k_store.shape[2]
+        s = _k_store(cache).shape[2]
         if kv_width is not None:
             s = min(s, kv_width)
         kv_slots = jnp.arange(s, dtype=jnp.int32)[None, :]
@@ -750,38 +882,124 @@ def forward(
         prefix_rows=prefix_rows,
     )
 
-    if cache is not None:
-        # The cache rides the scan CARRY (full stacks, in-place row
-        # writes), not xs/ys: the xs→ys form makes XLA materialize a
-        # fresh copy of both stacks every outer decode step.
-        def scan_body(carry, lp):
-            x, ck, cv, li = carry
-            x, ck, cv = layer_fn(x, lp, cos, sin, mask, ck, cv, start,
-                                 layer_idx=li)
-            return (x, ck, cv, li + 1), None
+    # A family with leading dense layers has two stacks, scanned one after
+    # the other; the cache's layer axis counts both.
+    stacks = [(params["layers"], cfg.is_moe)]
+    if "layers_dense" in params:
+        stacks.insert(0, (params["layers_dense"], False))
+    moe_stats = moe_stats and cfg.is_moe
+    stats = jnp.zeros((3,), jnp.int32) if moe_stats else None
 
-        (x, new_k, new_v, _), _ = jax.lax.scan(
-            scan_body,
-            (x, cache["k"], cache["v"], jnp.asarray(0, jnp.int32)),
-            params["layers"],
-        )
-        new_cache = {"k": new_k, "v": new_v}
+    def scanned(stack: dict, routed: bool):
+        """A stack's leaves as the scan slices them, and apart from them a
+        routed stack's expert leaves, which stay whole: the grouped product
+        fetches its experts out of the stacks (ops/moe.py)."""
+        if not routed:
+            return stack, None
+        whole = ("w_gate", "w_up", "w_down")
+        return ({k: v for k, v in stack.items() if k not in whole},
+                tuple(stack[k] for k in whole))
+
+    def block(x, lp, experts, at, stats, *cache_args, **kw):
+        routed = experts is not None
+        out = layer_fn(
+            x, lp, cos, sin, mask, *cache_args, routed=routed,
+            moe_stats=moe_stats and routed,
+            expert_stacks=(*experts, at) if routed else None, **kw)
+        if moe_stats and routed:
+            stats = stats + out[3]
+        return (*out[:3], stats)
+
+    # The cache rides the scan CARRY (full stacks, in-place row writes), not
+    # xs/ys: the xs→ys form makes XLA materialize a fresh copy of both
+    # stacks every outer decode step. A latent model's one stack rides
+    # where K does; without a cache both places hold nothing.
+    if cache is None:
+        ck = cv = at = None
     else:
-        def scan_body(x, lp):
-            x, _, _ = layer_fn(x, lp, cos, sin, mask, None, None, None)
-            return x, None
+        ck, cv = (cache["kv"], None) if cfg.is_latent else (cache["k"], cache["v"])
+        at = start
+    li = jnp.asarray(0, jnp.int32)  # the layer, counted over both stacks
+    for stack, routed in stacks:
+        xs, experts = scanned(stack, routed)
+        first = li  # this stack's first layer
 
-        if remat:
+        def scan_body(carry, lp, experts=experts, first=first):
+            x, ck, cv, li, stats = carry
+            x, ck, cv, stats = block(
+                x, lp, experts, li - first, stats, ck, cv, at, layer_idx=li)
+            return (x, ck, cv, li + 1, stats), None
+
+        if remat and cache is None:
             scan_body = jax.checkpoint(scan_body)
-        x, _ = jax.lax.scan(scan_body, x, params["layers"])
+        (x, ck, cv, li, stats), _ = jax.lax.scan(
+            scan_body, (x, ck, cv, li, stats), xs)
+    if cache is None:
         new_cache = None
+    else:
+        new_cache = {"kv": ck} if cfg.is_latent else {"k": ck, "v": cv}
 
     if logits_index is not None:
         # Prefill only samples one position; unembedding every position
         # would spend T×V×D FLOPs on logits nobody reads (~30% of an 8B
         # prefill at a 128k vocab).
         x = jnp.take_along_axis(x, logits_index[:, None, None], axis=1)
+    if moe_stats:
+        return unembed(params, cfg, x), new_cache, stats
     return unembed(params, cfg, x), new_cache
+
+
+def _k_store(cache: dict) -> jax.Array:
+    """The array whose axis 2 is the cache's slots: the latent stack, or
+    the K stack (its codes when quantized)."""
+    if "kv" in cache:
+        return cache["kv"]
+    return cache["k"]["q8"] if is_quantized(cache["k"]) else cache["k"]
+
+
+def _rotary_tables(cfg: ModelConfig, positions: jax.Array):
+    """cos and sin [B, T, rotary/2] for ``positions``: over the whole head,
+    or for a latent model over its rotary part with YaRN's blend."""
+    if not cfg.is_latent:
+        inv_freq = rope_inv_freq(
+            cfg.head_dim, cfg.rope_theta, cfg.rope_scaling_dict)
+        return rope_angles(positions, inv_freq)
+    if cfg.rope_yarn is None:
+        return rope_angles(
+            positions, rope_inv_freq(cfg.qk_rope_dim, cfg.rope_theta))
+    factor, beta_fast, beta_slow, mscale, mscale_all_dim, orig = cfg.rope_yarn
+    cos, sin = rope_angles(positions, yarn_inv_freq(
+        cfg.qk_rope_dim, cfg.rope_theta, factor, beta_fast, beta_slow, orig))
+    ratio = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)
+    return cos * ratio, sin * ratio
+
+
+def _refuse_latent(cfg: ModelConfig, attn_impl: str, mesh, prefix,
+                   kv_mask) -> None:
+    """What a latent-attention model does not get yet is refused by name,
+    not computed wrongly."""
+    if attn_impl == "ring":
+        raise ValueError(
+            f"{cfg.name}: no sequence-parallel (ring) prefill over a latent "
+            "(MLA) cache")
+    if prefix is not None:
+        raise ValueError(
+            f"{cfg.name}: no shared-prefix attention over a latent (MLA) cache")
+    if kv_mask is not None:
+        raise ValueError(
+            f"{cfg.name}: no speculative decoding (written-slot bitmap) over "
+            "a latent (MLA) cache")
+    refuse_latent_mesh(cfg, mesh)
+
+
+def refuse_latent_mesh(cfg: ModelConfig, mesh) -> None:
+    """A latent-attention model is not sharded yet: ``forward`` and the
+    engine's constructor both refuse a mesh that would split it."""
+    if mesh is not None and any(
+            dict(mesh.shape).get(ax, 1) > 1 for ax in ("tp", "ep")):
+        raise ValueError(
+            f"{cfg.name}: a latent (MLA) model runs on one chip: a mesh with "
+            f"tp or ep > 1 is not computed, got {dict(mesh.shape)}")
 
 
 def _forward_ring_prefill(
@@ -814,8 +1032,7 @@ def _forward_ring_prefill(
         x, NamedSharding(mesh, P(None, "sp", None))
     )
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None, :], (b, t))
-    inv_freq = rope_inv_freq(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling_dict)
-    cos, sin = rope_angles(positions, inv_freq)
+    cos, sin = _rotary_tables(cfg, positions)
     layer_fn = partial(_layer, cfg, ring_mesh=mesh)
 
     def scan_body(x, lp):

@@ -64,7 +64,9 @@ from llm_consensus_tpu.utils import knobs
 # Weight names eligible for quantization (init_params layout, all
 # [..., contract, out]).
 QUANT_KEYS = frozenset(
-    {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "lm_head"}
+    {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "lm_head",
+     # latent attention's projections and the shared experts
+     "wq_a", "wq_b", "wkv_a", "wkv_b", "ws_gate", "ws_up", "ws_down"}
 )
 
 
@@ -126,6 +128,18 @@ def _unpack4(w: dict, dtype) -> jax.Array:
     q = (jnp.concatenate([lo, hi], axis=-2) - 8.0) * w["s"].astype(dtype)
     *lead, groups, g, o = q.shape
     return q.reshape(*lead, groups * g, o)
+
+
+def dequantize(w, dtype) -> jax.Array:
+    """A stored weight as a plain ``dtype`` array (a plain leaf passes
+    through): for the consumers ``qeinsum`` cannot serve, which reshape a
+    weight (the latent attention's absorbed halves) or hand it to a grouped
+    product (ops/moe.py)."""
+    if not is_quantized(w):
+        return w
+    if "q4" in w:
+        return _unpack4(w, dtype)
+    return w["q8"].astype(dtype) * w["s"].astype(dtype)
 
 
 # Donating variant frees each bfloat16 original as it converts (peak HBM
@@ -200,11 +214,12 @@ def quantize_params(params: dict, donate: bool = False,
     out = dict(params)
     if "lm_head" in out:
         out["lm_head"] = maybe(out["lm_head"])
-    layers = dict(out["layers"])
-    for name in list(layers):
-        if name in QUANT_KEYS:
-            layers[name] = maybe(layers[name])
-    out["layers"] = layers
+    for stack in ("layers_dense", "layers"):
+        if stack in out:
+            out[stack] = {
+                name: maybe(w) if name in QUANT_KEYS else w
+                for name, w in out[stack].items()
+            }
     return out
 
 

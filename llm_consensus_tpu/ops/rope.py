@@ -2,7 +2,8 @@
 
 Uses the half-split ("rotate_half") convention matching HuggingFace weight
 layouts for Llama/Mistral/Qwen/Gemma, so imported checkpoints work without
-permuting projection weights. Supports Llama-3-style NTK frequency scaling.
+permuting projection weights. Supports Llama-3-style NTK frequency scaling
+and YaRN (the frequency blend and the softmax-scale correction).
 
 TPU notes: angles are computed from integer positions inside the jitted
 function (cheap VPU work, avoids carrying a [max_seq, dim] table in HBM), and
@@ -12,6 +13,8 @@ everything stays static-shaped so decode steps hit the same compiled program.
 from __future__ import annotations
 
 from typing import Optional
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +49,43 @@ def rope_inv_freq(
         inv_freq = jnp.where(wavelen > low_wavelen, scaled,
                              jnp.where(wavelen < high_wavelen, inv_freq, interp))
     return inv_freq
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature correction: ``0.1 * mscale * ln(factor)
+    + 1`` for a factor above 1."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1.0 else 1.0
+
+
+def yarn_inv_freq(
+    head_dim: int, theta: float, factor: float, beta_fast: float,
+    beta_slow: float, original_max_position: int,
+) -> jax.Array:
+    """YaRN's inverse frequencies [head_dim/2], fp32 (arXiv 2309.00071, as
+    DeepSeek-V2's published modelling code computes them).
+
+    Per frequency a blend of ``theta^(-2i/d)`` (kept: it turns more than
+    ``beta_fast`` times within the original context) and the same ÷
+    ``factor`` (interpolated: fewer than ``beta_slow`` turns), by a linear
+    ramp over the dimensions in between.
+    """
+    def turns_dim(turns: float) -> float:
+        # the dimension whose wavelength makes `turns` rotations over the
+        # original context
+        return (
+            head_dim * math.log(original_max_position / (turns * 2 * math.pi))
+        ) / (2 * math.log(theta))
+
+    low = max(math.floor(turns_dim(beta_fast)), 0)
+    high = min(math.ceil(turns_dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001  # the published guard against a zero-width ramp
+    kept = rope_inv_freq(head_dim, theta)
+    ramp = jnp.clip(
+        (jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / (high - low),
+        0.0, 1.0,
+    )
+    return (kept / factor) * ramp + kept * (1.0 - ramp)
 
 
 def rope_angles(positions: jax.Array, inv_freq: jax.Array) -> tuple[jax.Array, jax.Array]:

@@ -89,8 +89,11 @@ def best_tp(cfg: ModelConfig, n_devices: int) -> int:
 
     TP shards attention heads and the MLP hidden dim, so it must divide
     ``n_kv_heads`` (the binding constraint under GQA), ``n_heads`` and
-    ``d_ff``. Falls back toward 1, which always works.
+    ``d_ff``. Falls back toward 1, which always works. A latent (MLA)
+    model is not sharded yet (its engine refuses a mesh with tp > 1): 1.
     """
+    if cfg.is_latent:
+        return 1
     tp = 1
     d = 1
     while d <= n_devices:

@@ -64,37 +64,55 @@ def param_specs(cfg: ModelConfig, mesh: Optional[Mesh] = None) -> dict:
         # exactly the miscompiling mesh shape so inference meshes
         # (tp-only, tp×sp, dp×tp) keep the sharded LM head.
         tp_vocab = None
-    layers: dict = {
-        "attn_norm": P(None, None),
-        "mlp_norm": P(None, None),
-        "wq": P(None, None, tp_q),
-        "wk": P(None, None, tp_kv),
-        "wv": P(None, None, tp_kv),
-        "wo": P(None, tp_q, None),
-    }
-    if cfg.qkv_bias:
-        layers["bq"] = P(None, tp_q)
-        layers["bk"] = P(None, tp_kv)
-        layers["bv"] = P(None, tp_kv)
-    if cfg.is_moe:
-        ep_name = "ep" if (mesh is None or "ep" in mesh.axis_names) else "tp"
-        ep = _axis(mesh, ep_name, cfg.n_experts)
-        layers["w_router"] = P(None, None, None)
-        # Experts shard over ep; each expert's hidden dim additionally
-        # shards over tp when both axes exist (ep×tp 2-D sharding).
-        inner = tp_ff if ep != "tp" else None
-        layers["w_gate"] = P(None, ep, None, inner)
-        layers["w_up"] = P(None, ep, None, inner)
-        layers["w_down"] = P(None, ep, inner, None)
-    else:
-        layers["w_gate"] = P(None, None, tp_ff)
-        layers["w_up"] = P(None, None, tp_ff)
-        layers["w_down"] = P(None, tp_ff, None)
+    def stack(routed: bool) -> dict:
+        layers: dict = {"attn_norm": P(None, None), "mlp_norm": P(None, None)}
+        if cfg.is_latent:
+            # A latent (MLA) model runs on one chip (a mesh with tp or ep
+            # > 1 is refused when its engine is built): every leaf whole.
+            layers.update({
+                name: P(None, None, None)
+                for name in ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo")
+            })
+            layers.update({"q_norm": P(None, None), "kv_norm": P(None, None)})
+        else:
+            layers.update({
+                "wq": P(None, None, tp_q),
+                "wk": P(None, None, tp_kv),
+                "wv": P(None, None, tp_kv),
+                "wo": P(None, tp_q, None),
+            })
+        if cfg.qkv_bias:
+            layers["bq"] = P(None, tp_q)
+            layers["bk"] = P(None, tp_kv)
+            layers["bv"] = P(None, tp_kv)
+        if routed:
+            ep_name = "ep" if (mesh is None or "ep" in mesh.axis_names) else "tp"
+            ep = _axis(mesh, ep_name, cfg.n_experts)
+            layers["w_router"] = P(None, None, None)
+            # Experts shard over ep; each expert's hidden dim additionally
+            # shards over tp when both axes exist (ep×tp 2-D sharding).
+            inner = _axis(mesh, "tp", cfg.expert_width) if ep != "tp" else None
+            layers["w_gate"] = P(None, ep, None, inner)
+            layers["w_up"] = P(None, ep, None, inner)
+            layers["w_down"] = P(None, ep, inner, None)
+            if cfg.n_shared_experts:
+                tp_fs = _axis(mesh, "tp", cfg.n_shared_experts * cfg.expert_width)
+                layers["ws_gate"] = P(None, None, tp_fs)
+                layers["ws_up"] = P(None, None, tp_fs)
+                layers["ws_down"] = P(None, tp_fs, None)
+        else:
+            layers["w_gate"] = P(None, None, tp_ff)
+            layers["w_up"] = P(None, None, tp_ff)
+            layers["w_down"] = P(None, tp_ff, None)
+        return layers
+
     specs = {
         "embed": P(tp_vocab, None),
         "final_norm": P(None),
-        "layers": layers,
+        "layers": stack(cfg.is_moe),
     }
+    if cfg.is_moe and cfg.n_dense_layers:
+        specs["layers_dense"] = stack(False)
     if not cfg.tie_embeddings:
         specs["lm_head"] = P(None, tp_vocab)
     return specs
@@ -141,9 +159,12 @@ def cache_specs(cfg: ModelConfig, mesh: Optional[Mesh] = None, batch: int = 1) -
 
     KV heads shard with the attention TP split; batch shards over dp when
     it divides (decode streams are batch=1, so dp stays replicated there).
+    A latent model's one leaf ``kv`` has a single shared head: never split.
     """
-    tp_kv = _axis(mesh, "tp", cfg.n_kv_heads)
     dp = _axis(mesh, "dp", batch)
+    if cfg.is_latent:
+        return {"kv": P(None, dp, None, None, None)}
+    tp_kv = _axis(mesh, "tp", cfg.n_kv_heads)
     spec = P(None, dp, None, tp_kv, None)
     return {"k": spec, "v": spec}
 
@@ -210,7 +231,7 @@ def cache_shardings(cfg: ModelConfig, mesh: Mesh, cache) -> dict:
     single owner."""
     from llm_consensus_tpu.ops.quant import kv_seq_axis
 
-    k_spec = cache_specs(cfg, mesh)["k"]
+    k_spec = next(iter(cache_specs(cfg, mesh).values()))
     s_spec = P(k_spec[0], k_spec[1], k_spec[3], k_spec[2])
     return jax.tree.map(
         lambda leaf: NamedSharding(
@@ -224,13 +245,14 @@ def make_shard_fn(cfg: ModelConfig, mesh: Mesh) -> Callable:
     """Shard fn for ``engine.Engine(shard_fn=...)``.
 
     Dispatches on pytree shape: the params tree (has ``embed``) gets
-    ``param_specs``, the KV cache (has ``k``/``v``) gets ``cache_specs``.
+    ``param_specs``, the KV cache (``k``/``v``, or a latent model's ``kv``)
+    gets ``cache_specs``.
     """
 
     def shard(tree):
         if isinstance(tree, dict) and "embed" in tree:
             return shard_pytree(tree, param_specs(cfg, mesh), mesh)
-        if isinstance(tree, dict) and set(tree) == {"k", "v"}:
+        if isinstance(tree, dict) and set(tree) in ({"k", "v"}, {"kv"}):
             return jax.tree.map(
                 jax.device_put, tree, cache_shardings(cfg, mesh, tree)
             )

@@ -192,6 +192,12 @@ def fetch_local_models() -> list[ModelRecord]:
                     "n_layers": cfg.n_layers,
                     "d_model": cfg.d_model,
                     "moe": cfg.is_moe,
+                    # a routed model: the experts held here of the router's
+                    # outputs; a latent (MLA) model: what a token costs the cache
+                    "experts_held": cfg.n_experts,
+                    "router_width": cfg.n_router,
+                    "latent_attention": cfg.is_latent,
+                    "cache_width": cfg.cache_width,
                 },
             )
         )

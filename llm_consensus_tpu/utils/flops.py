@@ -23,23 +23,47 @@ def param_count(cfg: ModelConfig, active_only: bool = False) -> int:
     drives per-token compute (and therefore MFU), not checkpoint size.
     """
     d, dh = cfg.d_model, cfg.head_dim
-    q = d * cfg.n_heads * dh
-    kv = 2 * d * cfg.n_kv_heads * dh
-    o = cfg.n_heads * dh * d
-    attn = q + kv + o
+    if cfg.is_latent:
+        # low-rank q and kv paths with their norms, and the output
+        qk = cfg.qk_nope_dim + cfg.qk_rope_dim
+        attn = (
+            d * cfg.q_lora_rank + cfg.q_lora_rank
+            + cfg.q_lora_rank * cfg.n_heads * qk
+            + d * (cfg.kv_lora_rank + cfg.qk_rope_dim) + cfg.kv_lora_rank
+            + cfg.kv_lora_rank * cfg.n_heads * (cfg.qk_nope_dim + cfg.v_head_dim)
+            + cfg.n_heads * cfg.v_head_dim * d
+        )
+    else:
+        q = d * cfg.n_heads * dh
+        kv = 2 * d * cfg.n_kv_heads * dh
+        o = cfg.n_heads * dh * d
+        attn = q + kv + o
     if cfg.qkv_bias:
         attn += (cfg.n_heads + 2 * cfg.n_kv_heads) * dh
-    mlp_one = 3 * d * cfg.d_ff  # gate + up + down
-    if cfg.is_moe:
-        n_mlp = cfg.experts_per_token if active_only else cfg.n_experts
-        mlp = n_mlp * mlp_one + d * cfg.n_experts  # + router
-    else:
-        mlp = mlp_one
+    dense_mlp = 3 * d * cfg.d_ff  # gate + up + down
     norms = 2 * d
-    per_layer = attn + mlp + norms
+    n_dense = cfg.n_layers - cfg.n_expert_layers
+    total = n_dense * (attn + dense_mlp + norms)
+    if cfg.is_moe:
+        # The experts HELD here (a chip's share counts what it holds); a
+        # token visits at most experts_per_token of them. The router keeps
+        # its whole width, the shared experts see every token.
+        n_mlp = min(cfg.experts_per_token, cfg.n_experts) if active_only else cfg.n_experts
+        expert_one = 3 * d * cfg.expert_width
+        routed = (
+            (n_mlp + cfg.n_shared_experts) * expert_one + d * cfg.n_router
+        )
+        total += cfg.n_expert_layers * (attn + routed + norms)
     embed = cfg.vocab_size * d
     head = 0 if cfg.tie_embeddings else cfg.vocab_size * d
-    return cfg.n_layers * per_layer + embed + head + d  # + final norm
+    return total + embed + head + d  # + final norm
+
+
+def cache_bytes_per_token(cfg: ModelConfig, itemsize: int = 2) -> int:
+    """Bytes one token holds in the cache over every layer: the ONE count
+    the pools, the prefix budget and the bandwidth models read
+    (``cfg.cache_width`` values a layer: K and V heads, or a latent)."""
+    return cfg.n_layers * cfg.cache_width * itemsize
 
 
 def flops_per_token(cfg: ModelConfig, context_len: int = 0) -> float:
@@ -55,9 +79,13 @@ def flops_per_token(cfg: ModelConfig, context_len: int = 0) -> float:
         # The embedding table is a lookup, not a matmul; subtract it. With
         # tied embeddings the same table IS the unembed matmul, so it stays.
         weights -= cfg.vocab_size * cfg.d_model
-    attn_quad = (
-        2 * 2 * cfg.n_layers * cfg.n_heads * cfg.head_dim * max(0, context_len)
+    # scores and values over the context: per head q·k and p·v, which for a
+    # latent model (absorbed decode form) both sweep the latent
+    swept = (
+        2 * cfg.kv_lora_rank + cfg.qk_rope_dim if cfg.is_latent
+        else 2 * cfg.head_dim
     )
+    attn_quad = 2 * cfg.n_layers * cfg.n_heads * swept * max(0, context_len)
     return 2.0 * weights + float(attn_quad)
 
 
@@ -184,10 +212,8 @@ def decode_bytes_per_token(
     1 = int8 quantized).
     """
     weights = param_count(cfg, active_only=True)
-    kv = (
-        2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim * max(0, context_len)
-    )
-    return float(weights * weight_bytes + kv * kv_bytes)
+    kv = cache_bytes_per_token(cfg, kv_bytes) * max(0, context_len)
+    return float(weights * weight_bytes + kv)
 
 
 def decode_mbu(

@@ -19,7 +19,8 @@ from llm_consensus_tpu.utils.flops import (
 
 
 @pytest.mark.parametrize(
-    "preset", ["tiny-llama", "tiny-gemma", "tiny-qwen2", "tiny-mistral", "tiny-mixtral"]
+    "preset", ["tiny-llama", "tiny-gemma", "tiny-qwen2", "tiny-mistral", "tiny-mixtral",
+               "tiny-deepseek-v2"]
 )
 def test_param_count_matches_init_params(preset):
     cfg = get_config(preset)

@@ -138,7 +138,7 @@ def test_rope_llama3_scaling_changes_long_wavelengths():
     np.testing.assert_allclose(base[0], scaled[0], rtol=1e-6)  # highest freq kept
 
 
-def test_moe_routes_all_tokens_with_ample_capacity():
+def test_moe_routes_all_tokens():
     key = jax.random.PRNGKey(0)
     e, d, f, k = 4, 32, 64, 2
     ks = jax.random.split(key, 5)
@@ -147,24 +147,12 @@ def test_moe_routes_all_tokens_with_ample_capacity():
     wg = jax.random.normal(ks[2], (e, d, f)) * 0.1
     wu = jax.random.normal(ks[3], (e, d, f)) * 0.1
     wd = jax.random.normal(ks[4], (e, f, d)) * 0.1
-    out = moe_block(x, wr, wg, wu, wd, top_k=k, capacity_factor=8.0)
+    out, stats = moe_block(x, wr, wg, wu, wd, top_k=k, with_stats=True)
     assert out.shape == x.shape
     assert bool(jnp.all(jnp.isfinite(out)))
-    # With huge capacity no token is dropped: output must differ from zero
+    # Dropless, every expert held: all 16 * 2 pairs are computed here.
+    assert stats.tolist()[:2] == [32, 32]
     assert float(jnp.abs(out).mean()) > 0
-
-
-def test_moe_zero_capacity_drops_everything():
-    e, d, f = 4, 16, 32
-    x = jnp.ones((1, 4, d))
-    wr = jnp.eye(d, e)
-    wg = jnp.ones((e, d, f)) * 0.01
-    wu = jnp.ones((e, d, f)) * 0.01
-    wd = jnp.ones((e, f, d)) * 0.01
-    # capacity_factor tiny → capacity clamps to 1 slot; most tokens dropped,
-    # but the op must stay finite and well-formed.
-    out = moe_block(x, wr, wg, wu, wd, top_k=2, capacity_factor=0.01)
-    assert bool(jnp.all(jnp.isfinite(out)))
 
 
 def test_sample_greedy_is_argmax():
