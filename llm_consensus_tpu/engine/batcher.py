@@ -159,6 +159,8 @@ def _wave_counts(admit: dict) -> dict:
         "prefill_rows_real": admit["rows_real"],
         "prefill_rows_padded": admit["rows_padded"],
         "prefill_slot_tokens": admit["slot_tokens"],
+        "prefill_kv_pairs_swept": admit["pairs_swept"],
+        "prefill_kv_pairs_live": admit["pairs_live"],
     }
 
 
@@ -190,6 +192,18 @@ def kv_slots_live(pos: int, steps: int, stride: int, row_starts: Sequence[int],
     if window is None or not row_starts or ends[-1] - min(row_starts) <= window:
         return sum(ends) * len(row_starts) - steps * sum(row_starts)
     return sum(min(e - rs, window) for rs in row_starts for e in ends)
+
+
+def causal_pairs(lengths: Sequence[int], base: int = 0) -> int:
+    """(Query, key) pairs causality needs to prefill rows of ``lengths``
+    real tokens whose first ``base`` positions are already in a cache (a
+    shared or a restored prefix): ``n (n + 1) / 2`` a row, less the pairs
+    among the ``base``. The ``prefill_kv_pairs_live`` counter sums this;
+    against ``prefill_kv_pairs_swept`` (what the prefill programs' attention
+    scored, engine.py ``prefill_pairs_swept``) it is the share of the sweep
+    that was needed."""
+    return sum(n * (n + 1) // 2 for n in lengths) - (
+        len(lengths) * (base * (base + 1) // 2))
 
 
 def fits(n: int, pos: int, width: int, max_seq: int) -> bool:
@@ -911,6 +925,10 @@ class ContinuousBatcher:
             "decode_kv_slots_swept": 0, "decode_kv_slots_live": 0,
             "prefill_waves": 0, "prefill_rows_real": 0,
             "prefill_rows_padded": 0, "prefill_slot_tokens": 0,
+            # (Query, key) pairs the waves' prefill programs' attention
+            # scored, and the pairs causality needed for their real tokens
+            # (causal_pairs): their ratio is what a prefill sweeps in vain.
+            "prefill_kv_pairs_swept": 0, "prefill_kv_pairs_live": 0,
         }
         if engine.cfg.is_moe:
             # A routed model's programs return their routing sums
@@ -1488,8 +1506,10 @@ class ContinuousBatcher:
                 tok,
             )
         self._slots[slot] = s
-        chunks, slot_tokens = eng.last_prefill
-        sp.set(chunks=chunks, slot_tokens=slot_tokens)
+        did = eng.last_prefill
+        sp.set(chunks=did.chunks, slot_tokens=did.slot_tokens,
+               pairs_swept=did.pairs_swept,
+               pairs_live=causal_pairs([n], did.reused))
         return ([slot], tok, [s])
 
     def _establish_prefix(self, prefix_ids: list[int]) -> bool:
@@ -1588,8 +1608,11 @@ class ContinuousBatcher:
             else:
                 last_logits, pcache = eng._prefill_rows(pad_rows)
                 width = eng._rows_bucket(max(len(r) for r in rows))
-            chunks, slot_tokens = eng.last_prefill
-            sp.set(rows_padded=k_pad, chunks=chunks, slot_tokens=slot_tokens)
+            did = eng.last_prefill
+            sp.set(rows_padded=k_pad, chunks=did.chunks,
+                   slot_tokens=did.slot_tokens, pairs_swept=did.pairs_swept,
+                   pairs_live=causal_pairs(
+                       [len(r) for r in rows], prefix_p or did.reused))
         except Exception as exc:  # noqa: BLE001
             # The fallback below hides the failure from everyone but the
             # span: say what it was.
@@ -1804,7 +1827,11 @@ class ContinuousBatcher:
                        len(ids) - wave.wave_p for _, ids, _ in wave.batch
                    ),
                    chunks=wave.session.chunks,
-                   slot_tokens=wave.session.slot_tokens)
+                   slot_tokens=wave.session.slot_tokens,
+                   pairs_swept=wave.session.pairs_swept,
+                   pairs_live=causal_pairs(
+                       [len(ids) for _, ids, _ in wave.batch],
+                       wave.wave_p or eng.last_prefill.reused))
             deltas.update(_wave_counts(sp.args))
             self._pending_wave = None
         self._firsts.append(entry)

@@ -32,7 +32,7 @@ import time
 import types
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +43,7 @@ from llm_consensus_tpu.models import forward, init_kv_cache, init_params
 from llm_consensus_tpu.obs.attrib import tag as _attrib_tag
 from llm_consensus_tpu.obs import roofline as _roofline
 from llm_consensus_tpu.models.config import ModelConfig
+from llm_consensus_tpu.ops.latent_attention import prefill_sweep_width
 from llm_consensus_tpu.ops.quant import w8a8_scope
 from llm_consensus_tpu.ops.sampling import sample_token
 from llm_consensus_tpu.utils.context import Context
@@ -227,7 +228,11 @@ def _prefill_chunk(params, cfg: ModelConfig, tokens, start_pos, last_index,
     ``max_seq`` cache capacity (a 128k-context preset prefilling a 1k
     prompt attends 1k wide, not 128k). The traced offset rules out the
     Pallas kernel (static q_offset), so this always takes the XLA attention
-    path, which GSPMD also partitions for TP-sharded engines.
+    path, which GSPMD also partitions for TP-sharded engines. A dense
+    model's chunk sweeps the whole ``kv_width`` whatever its start; a latent
+    model's sweeps the narrowest of the program's static widths (chunk,
+    2 × chunk, ... ``kv_width``) that covers its frontier, chosen at run
+    time from ``start_pos`` (ops/latent_attention.py ``prefill_sweep``).
     """
     with w8a8_scope(w8a8):
         out = forward(
@@ -494,6 +499,32 @@ _decode_chunk = _NamedPrograms(
     tokens=lambda a, k: int(a[6]) * _nrows(a[2]),
     steps=lambda a, k: int(a[6]),
 )
+
+
+class Prefilled(NamedTuple):
+    """What one prefill dispatched, padding rows and padding inside rows
+    and all: ``chunks`` programs over ``slot_tokens`` token slots, whose
+    attention swept ``pairs_swept`` (query, key) pairs
+    (``prefill_pairs_swept``), on top of ``reused`` positions a row that
+    were restored from a retained prefix and not prefilled."""
+    chunks: int
+    slot_tokens: int
+    pairs_swept: int
+    reused: int
+
+
+def prefill_pairs_swept(cfg: ModelConfig, rows: int, t: int, slots: int,
+                        end: int, prefix_slots: int = 0) -> int:
+    """(Query, key) pairs the attention of ONE prefill program sweeps:
+    ``rows`` x ``t`` query slots x the cache slots each is scored against.
+    A dense model's XLA route scores the whole ``slots`` it is given (its
+    bucket), and a shared prefix's ``prefix_slots`` beside them; a latent
+    model's prefill form the width its frontier ``end`` picks
+    (ops/latent_attention.py ``prefill_sweep``, the rule the program's own
+    branches come from)."""
+    if cfg.is_latent:
+        slots = prefill_sweep_width(t, slots, end)
+    return rows * t * (slots + prefix_slots)
 
 
 def _bucket(n: int, cap: int) -> int:
@@ -774,10 +805,9 @@ class Engine:
         # Spans go through the one emitter (obs/spans.py): recorder, flight
         # ring and — inside a profiler window — the device trace's clock.
         self._spans = _obs.emitter()
-        # (chunks dispatched, token slots covered) by the last prefill
-        # (``_prefill_ids`` or an admission wave), padding included: the
-        # pool's admission counts from it.
-        self.last_prefill = (0, 0)
+        # What the last prefill (``_prefill_ids`` or an admission wave)
+        # dispatched, padding included: the pool's admission counts from it.
+        self.last_prefill = Prefilled(0, 0, 0, 0)
         # A routed model's prefill programs return their routing sums
         # (``moe_stats``) only while someone collects them: the pool
         # scheduler opens this bank and drains it into its fetches
@@ -1299,9 +1329,13 @@ class Engine:
                     last_in_chunk, cache, kv_width=kv_width,
                     w8a8=self.w8a8, moe_stats=self._moe_on,
                 ))
-        # What was prefilled, for the caller's accounting: chunks
-        # dispatched and the token slots they covered (padding included).
-        self.last_prefill = (n_tail, n_tail * chunk)
+        # What was prefilled, for the caller's accounting.
+        self.last_prefill = Prefilled(
+            n_tail, n_tail * chunk,
+            sum(prefill_pairs_swept(
+                self.cfg, 1, chunk, kv_width, base + (i + 1) * chunk)
+                for i in range(n_tail)),
+            base)
         return last_logits, cache
 
     def _prefill_ids(self, prompt_ids: list[int]):
@@ -1311,8 +1345,7 @@ class Engine:
         reuse, sequence-parallel (ring) prefill, chunked prefill, and
         one-shot per-bucket prefill — shared by the single-stream decode
         loop and the continuous batcher's admission path.
-        ``self.last_prefill`` is then (chunks dispatched, token slots they
-        covered), padding included.
+        ``self.last_prefill`` then says what was dispatched (``Prefilled``).
         """
         if self._faults is not None:
             self._faults.check("prefill")  # injected device OOM / loss
@@ -1373,7 +1406,7 @@ class Engine:
                 self._place(jnp.asarray([n_prompt - 1])),
                 cache, mesh=self.mesh,
             )
-            self.last_prefill = (1, bucket)
+            self.last_prefill = Prefilled(1, bucket, bucket * bucket, 0)
         elif chunk_len and n_prompt > chunk_len and n_chunks * chunk_len <= self.max_seq:
             # Chunked prefill: the same compiled program dispatched per
             # chunk, dynamic start offset. Dispatches pipeline (no fetch
@@ -1396,7 +1429,13 @@ class Engine:
                     cache, attn_impl=impl, mesh=self.mesh, w8a8=self.w8a8,
                     moe_stats=self._moe_on,
                 )))
-            self.last_prefill = (1, bucket)
+            # The kernel is handed the prompt's bucket; the XLA routes are
+            # given the cache, which here has the engine's whole capacity.
+            slots = bucket if (
+                self.attn_impl == "flash" and not cfg.is_latent
+            ) else self.max_seq
+            self.last_prefill = Prefilled(
+                1, bucket, prefill_pairs_swept(cfg, 1, bucket, slots, bucket), 0)
         return last_logits, cache, reuse_len if reuse_ok else 0
 
     def _rows_bucket(self, n_max: int) -> int:
@@ -2077,6 +2116,19 @@ class AdmissionPrefill:
         return self.k * per_row
 
     @property
+    def pairs_swept(self) -> int:
+        """(Query, key) pairs the wave's dispatches sweep
+        (``prefill_pairs_swept``, a program at a time), padding as in
+        ``slot_tokens``."""
+        prefix_slots = self._prefix_cache["k"].shape[2] if self._suffix else 0
+        t = self._chunk_len if self._use_chunks else self.width
+        return sum(
+            prefill_pairs_swept(
+                self._eng.cfg, self.k, t, self.width, (c + 1) * t, prefix_slots)
+            for c in range(self._first_chunk, self._n_chunks)
+        )
+
+    @property
     def remaining_tokens(self) -> int:
         """Total prompt tokens (rows × chunk length) not yet dispatched —
         the batcher's credit ledger sizes its interleave pacing off this."""
@@ -2211,7 +2263,9 @@ class AdmissionPrefill:
             )
         # What the wave dispatched: the pool's ``pool.admit`` span and its
         # counters read it (there is no span of the session's own).
-        eng.last_prefill = (self.chunks, self.slot_tokens)
+        eng.last_prefill = Prefilled(
+            self.chunks, self.slot_tokens, self.pairs_swept,
+            self._first_chunk * self._chunk_len)
         return last_logits, cache, self.width
 
 

@@ -16,13 +16,18 @@ With ``h`` the normed hidden state of one token:
     of ``W_kvb``: the same mathematics with the products reassociated, so no
     per-head key or value is ever written, only the latent is swept.
 
-Both forms are plain einsums (XLA) over the ``kv_width`` bucket of the
-cache; the mask is the one every XLA route uses (causality, the frontier,
-``row_start``, a dead row's mark). ``wo`` and the residual are the caller's.
+Both forms are plain einsums (XLA); the mask is the one every XLA route
+uses (causality, the frontier, ``row_start``, a dead row's mark). The decode
+form sweeps the ``kv_width`` bucket of the cache. The prefill form sweeps a
+static width chosen at run time by the chunk's frontier (``prefill_sweep``):
+one program serves every chunk of a prompt at a traced start, so it holds a
+branch for T, 2T, ... up to the bucket, and a chunk runs the narrowest that
+covers the slots it may attend. ``wo`` and the residual are the caller's.
 """
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional
 
 import jax
@@ -33,6 +38,34 @@ from llm_consensus_tpu.ops.norms import rms_norm
 from llm_consensus_tpu.ops.quant import (
     dequantize, kv_layer, kv_write_rows, qeinsum)
 from llm_consensus_tpu.ops.rope import apply_rope
+
+
+# The most branches a prefill program holds; past it the widths step coarser.
+MAX_SWEEP_BRANCHES = 8
+
+
+def prefill_sweep(t: int, kv_width: int, end):
+    """THE width rule of the prefill form, written once: ``(widths, at)``
+    for a ``t``-token chunk in a ``kv_width``-slot bucket whose last token
+    is written at slot ``end - 1``. ``widths`` are the static widths the
+    program holds a branch for (``t``, 2 ``t``, ... and the bucket last;
+    steps of 2 ``t``, 4 ``t``, ... where that would pass
+    ``MAX_SWEEP_BRANCHES``), ``at`` the index of the narrowest that covers
+    ``end`` slots, counted past the last for an ``end`` beyond the bucket
+    (``jax.lax.switch`` clamps, ``prefill_sweep_width`` too). ``end`` may
+    be traced. The program's branch list and the pool's
+    ``prefill_kv_pairs_swept`` both come from here."""
+    step = t
+    while -(-kv_width // step) > MAX_SWEEP_BRANCHES:
+        step *= 2
+    widths = (*range(step, kv_width, step), kv_width)
+    return widths, (end - 1) // step
+
+
+def prefill_sweep_width(t: int, kv_width: int, end: int) -> int:
+    """The width a chunk that ends at slot ``end`` sweeps (host arithmetic)."""
+    widths, at = prefill_sweep(t, kv_width, end)
+    return widths[min(at, len(widths) - 1)]
 
 
 def latent_attention(
@@ -70,26 +103,43 @@ def latent_attention(
     latent = jnp.concatenate([c_kv, k_rope], axis=-1)        # [B, T, rank+rope]
     if cache is not None:
         cache = kv_write_rows(cache, latent[:, :, None, :], layer_idx, start_pos)
-        latent = kv_layer(cache, layer_idx, kv_width)[:, :, 0, :].astype(h.dtype)
-    c_all, r_all = latent[..., :kv_lora_rank], latent[..., kv_lora_rank:]
 
     w_kvb = dequantize(lp["wkv_b"], h.dtype).reshape(
         kv_lora_rank, n_heads, qk_nope_dim + v_head_dim)
     w_uk, w_uv = w_kvb[..., :qk_nope_dim], w_kvb[..., qk_nope_dim:]
     f32 = dict(preferred_element_type=jnp.float32)
-    rope_scores = jnp.einsum("bthr,bsr->bhts", q_rope, r_all, **f32)
-    if absorbed:
-        q_lat = jnp.einsum("bthd,chd->bthc", q_nope, w_uk)
-        scores = jnp.einsum("bthc,bsc->bhts", q_lat, c_all, **f32)
-    else:
-        k_nope = jnp.einsum("bsc,chd->bshd", c_all, w_uk)
-        scores = jnp.einsum("bthd,bshd->bhts", q_nope, k_nope, **f32)
-    scores = jnp.where(mask[:, None], (scores + rope_scores) * scale, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
-    if absorbed:
-        o_lat = jnp.einsum("bhts,bsc->bthc", probs, c_all)
-        out = jnp.einsum("bthc,chd->bthd", o_lat, w_uv)
-    else:
+
+    def attend(width: Optional[int]) -> jax.Array:
+        """[B, T, H, v_head_dim] over the first ``width`` slots of this
+        layer's latents (the bucket, or this call's own without a cache)."""
+        if cache is None:
+            lat, keep = latent, mask
+        else:
+            lat = kv_layer(cache, layer_idx, width)[:, :, 0, :].astype(h.dtype)
+            keep = mask[..., :lat.shape[1]]
+        c_all, r_all = lat[..., :kv_lora_rank], lat[..., kv_lora_rank:]
+        rope_scores = jnp.einsum("bthr,bsr->bhts", q_rope, r_all, **f32)
+        if absorbed:
+            q_lat = jnp.einsum("bthd,chd->bthc", q_nope, w_uk)
+            scores = jnp.einsum("bthc,bsc->bhts", q_lat, c_all, **f32)
+        else:
+            k_nope = jnp.einsum("bsc,chd->bshd", c_all, w_uk)
+            scores = jnp.einsum("bthd,bshd->bhts", q_nope, k_nope, **f32)
+        scores = jnp.where(keep[:, None], (scores + rope_scores) * scale, NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
+        if absorbed:
+            o_lat = jnp.einsum("bhts,bsc->bthc", probs, c_all)
+            return jnp.einsum("bthc,chd->bthd", o_lat, w_uv)
         v = jnp.einsum("bsc,chd->bshd", c_all, w_uv)
-        out = jnp.einsum("bhts,bshd->bthd", probs, v)
+        return jnp.einsum("bhts,bshd->bthd", probs, v)
+
+    if cache is None or absorbed:
+        out = attend(kv_width)
+    else:
+        # A slot at or past the frontier is masked to NEG_INF and adds
+        # exactly 0 to every sum, so the narrowest branch that covers the
+        # frontier gives the whole bucket's result up to a reduction's order.
+        slots = mask.shape[-1]
+        widths, at = prefill_sweep(t, slots, start_pos + t)
+        out = jax.lax.switch(at, [partial(attend, w) for w in widths])
     return out.reshape(b, t, n_heads * v_head_dim), cache

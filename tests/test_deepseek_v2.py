@@ -24,12 +24,18 @@ from benchmark.reference import deepseek_v2 as reference
 from llm_consensus_tpu.engine import ContinuousBatcher, Engine, SamplingParams
 from llm_consensus_tpu.models import (
     forward, get_config, init_kv_cache, init_params)
+from llm_consensus_tpu.models import transformer
 from llm_consensus_tpu.models.config import MODEL_PRESETS
 from llm_consensus_tpu.obs import blackbox as bb_mod
 from llm_consensus_tpu.obs.blackbox import FlightRecorder
+from llm_consensus_tpu.ops.attention import NEG_INF
+from llm_consensus_tpu.ops.latent_attention import (
+    prefill_sweep, prefill_sweep_width)
 from llm_consensus_tpu.ops.mlp import gated_mlp
 from llm_consensus_tpu.ops.moe import moe_block, route
-from llm_consensus_tpu.ops.rope import yarn_inv_freq, yarn_mscale
+from llm_consensus_tpu.ops.norms import rms_norm
+from llm_consensus_tpu.ops.quant import dequantize, kv_layer, kv_write_rows, qeinsum
+from llm_consensus_tpu.ops.rope import apply_rope, yarn_inv_freq, yarn_mscale
 from llm_consensus_tpu.utils.flops import cache_bytes_per_token, param_count
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -96,6 +102,156 @@ def test_prefill_then_absorbed_decode_matches_the_reference(share):
     assert out["reference"] == "deepseek_v2" and out["stored_as_stated"]
     assert out["rel_err_max"] < 1e-4 and out["rel_err_decoded_max"] < 1e-4
     paths = eng.attention_stats()["paths"]  # counted per model name, process-wide
+    assert (set(paths["prefill"]), set(paths["decode"])) == (
+        {"xla_latent"}, {"xla_latent_absorbed"})
+
+
+# -- the prefill form's sweep (PR 32) ------------------------------------------
+
+
+SWEEP_WIDTHS = {
+    # name: (T, bucket, the chunk's end, the width it sweeps)
+    "first-chunk": (512, 2048, 512, 512),
+    "second-chunk": (512, 2048, 1024, 1024),
+    "third-chunk": (512, 2048, 1536, 1536),
+    "last-chunk": (512, 2048, 2048, 2048),
+    "one-slot-past-a-width": (512, 2048, 513, 1024),
+    "restored-base-of-64": (512, 2048, 64 + 512, 1024),
+    "past-the-bucket-clamps": (512, 2048, 2560, 2048),
+    "t-is-the-bucket": (256, 256, 256, 256),
+    "bucket-no-multiple-of-t": (960, 1024, 960, 960),
+    "coarser-past-eight-branches": (256, 4096, 256, 512),
+    "coarser-last": (256, 4096, 4096, 4096),
+}
+
+
+@pytest.mark.parametrize("case", SWEEP_WIDTHS)
+def test_the_width_rule(case):
+    t, bucket, end, width = SWEEP_WIDTHS[case]
+    assert prefill_sweep_width(t, bucket, end) == width
+    widths, at = prefill_sweep(t, bucket, end)
+    assert len(widths) <= 8 and widths[-1] == bucket
+    assert list(widths) == sorted(set(widths)) and widths[0] >= min(t, bucket)
+    # the traced index is the host's (jax.lax.switch clamps it as the host does)
+    assert int(prefill_sweep(t, bucket, jnp.asarray(end, jnp.int32))[1]) == at
+
+
+def whole_bucket_latent_attention(
+        h, lp, cos, sin, mask, cache, start_pos, layer_idx, *, n_heads,
+        kv_lora_rank, qk_nope_dim, qk_rope_dim, v_head_dim, scale, rms_eps,
+        kv_width=None, absorbed=False):
+    """The arithmetic of ``ops/latent_attention.py`` as PR 31 had it, kept
+    here as the yardstick: every form sweeps the whole ``kv_width`` bucket
+    and leaves what lies past the frontier to the mask."""
+    b, t, _ = h.shape
+    c_q = rms_norm(qeinsum("btd,dr->btr", h, lp["wq_a"]), lp["q_norm"], rms_eps)
+    q = qeinsum("btr,rk->btk", c_q, lp["wq_b"]).reshape(
+        b, t, n_heads, qk_nope_dim + qk_rope_dim)
+    q_nope = q[..., :qk_nope_dim]
+    q_rope = apply_rope(q[..., qk_nope_dim:], cos, sin)
+    ckr = qeinsum("btd,dr->btr", h, lp["wkv_a"])
+    c_kv = rms_norm(ckr[..., :kv_lora_rank], lp["kv_norm"], rms_eps)
+    k_rope = apply_rope(ckr[..., None, kv_lora_rank:], cos, sin)[:, :, 0]
+    latent = jnp.concatenate([c_kv, k_rope], axis=-1)
+    if cache is not None:
+        cache = kv_write_rows(cache, latent[:, :, None, :], layer_idx, start_pos)
+        latent = kv_layer(cache, layer_idx, kv_width)[:, :, 0, :].astype(h.dtype)
+    c_all, r_all = latent[..., :kv_lora_rank], latent[..., kv_lora_rank:]
+    w_kvb = dequantize(lp["wkv_b"], h.dtype).reshape(
+        kv_lora_rank, n_heads, qk_nope_dim + v_head_dim)
+    w_uk, w_uv = w_kvb[..., :qk_nope_dim], w_kvb[..., qk_nope_dim:]
+    f32 = dict(preferred_element_type=jnp.float32)
+    rope_scores = jnp.einsum("bthr,bsr->bhts", q_rope, r_all, **f32)
+    if absorbed:
+        q_lat = jnp.einsum("bthd,chd->bthc", q_nope, w_uk)
+        scores = jnp.einsum("bthc,bsc->bhts", q_lat, c_all, **f32)
+    else:
+        k_nope = jnp.einsum("bsc,chd->bshd", c_all, w_uk)
+        scores = jnp.einsum("bthd,bshd->bhts", q_nope, k_nope, **f32)
+    scores = jnp.where(mask[:, None], (scores + rope_scores) * scale, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
+    if absorbed:
+        o_lat = jnp.einsum("bhts,bsc->bthc", probs, c_all)
+        out = jnp.einsum("bthc,chd->bthd", o_lat, w_uv)
+    else:
+        v = jnp.einsum("bsc,chd->bshd", c_all, w_uv)
+        out = jnp.einsum("bhts,bshd->bthd", probs, v)
+    return out.reshape(b, t, n_heads * v_head_dim), cache
+
+
+PREFILL_CHUNKS = {
+    # name: (T, bucket, base, chunk index, the width it must sweep)
+    "chunk-0-of-4": (32, 128, 0, 0, 32),
+    "chunk-1-of-4": (32, 128, 0, 1, 64),
+    "chunk-2-of-4": (32, 128, 0, 2, 96),
+    "chunk-3-of-4": (32, 128, 0, 3, 128),
+    "base-40-frontier-no-multiple": (32, 128, 40, 0, 96),
+    "base-40-last": (32, 128, 40, 1, 128),
+    "base-64-frontier-a-multiple": (32, 128, 64, 0, 96),
+    "t-is-the-bucket": (128, 128, 0, 0, 128),
+    "sixteen-chunks-eight-branches": (8, 128, 0, 5, 48),
+}
+
+
+@pytest.mark.parametrize("case", PREFILL_CHUNKS)
+def test_the_prefill_form_sweeps_to_the_frontier(case, monkeypatch):
+    """A chunk at a TRACED start equals the whole-bucket masked form to a
+    reduction's order and the float32 reference inside the file's limit,
+    and reads no slot past the width its frontier picks: those are NaN here,
+    which the whole-bucket form multiplies into every sum."""
+    t, bucket, base, index, width = PREFILL_CHUNKS[case]
+    spec = tiny_spec(True)
+    cfg = server.model_config("m", spec)
+    params = init_params(cfg, jax.random.PRNGKey(3), dtype=jnp.float32)
+    start = base + index * t
+    assert prefill_sweep_width(t, bucket, start + t) == width
+    ids = np.random.default_rng(7).integers(0, cfg.vocab_size, start + t)
+    cache = init_kv_cache(cfg, 1, 256, dtype=jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        if start:  # what came before, in one call at a static start
+            _, cache = forward(
+                params, cfg, jnp.asarray(ids[None, :start], jnp.int32), cache, 0)
+        poisoned = {"kv": cache["kv"].at[:, :, width:].set(jnp.nan)}
+
+        def chunk():  # a function of its own a form: jit caches by function
+            return jax.jit(lambda cache, start_pos: forward(
+                params, cfg, jnp.asarray(ids[None, start:], jnp.int32),
+                cache, start_pos, kv_width=bucket)[0][0])
+
+        at = jnp.asarray(start, jnp.int32)
+        got = chunk()(poisoned, at)
+        monkeypatch.setattr(transformer, "latent_attention", whole_bucket_latent_attention)
+        whole_bucket = chunk()
+        whole, reads_it_all = whole_bucket(cache, at), whole_bucket(poisoned, at)
+    assert np.isfinite(np.asarray(got)).all()
+    assert bool(np.isnan(np.asarray(reads_it_all)).any()) == (width < bucket)
+    assert rel_err(got, whole).max() < 1e-5
+    want = reference.forward(params, spec, ids)[start:]
+    assert rel_err(got, want).max() < 1e-4
+
+
+@pytest.mark.parametrize("share", SHARES)
+def test_chunked_prefill_loop_then_absorbed_decode_matches_the_reference(share):
+    """The judge prompt's path: every chunk in one ``_prefill_chunks_loop``
+    program (four 32-token chunks of a 128-slot bucket, each at its own
+    width), then cached decode steps in the absorbed form."""
+    spec = tiny_spec(SHARES[share])
+    cfg = server.model_config("tiny-dsv2-chunked", spec)
+    eng = Engine(cfg, max_seq=256, seed=0, dtype=jnp.float32, prefill_chunk=32)
+    n_pre, decoded = 100, 12
+    ids = np.random.default_rng(11).integers(0, cfg.vocab_size, n_pre + decoded)
+    with jax.default_matmul_precision("highest"):
+        last, cache = eng._prefill_ids([int(i) for i in ids[:n_pre]])
+        assert eng.last_prefill == (4, 128, 32 * (32 + 64 + 96 + 128), 0)
+        rows = [last]
+        for p in range(n_pre, n_pre + decoded):
+            logits, cache = forward(
+                eng.params, cfg, jnp.asarray(ids[None, p:p + 1], jnp.int32),
+                cache, jnp.asarray(p, jnp.int32))
+            rows.append(logits[0])
+    want = reference.forward(eng.params, spec, ids)[n_pre - 1:]
+    assert rel_err(jnp.concatenate(rows), want).max() < 1e-4
+    paths = eng.attention_stats()["paths"]
     assert (set(paths["prefill"]), set(paths["decode"])) == (
         {"xla_latent"}, {"xla_latent_absorbed"})
 
@@ -340,6 +496,67 @@ def test_a_pool_books_what_its_programs_routed():
         assert not any(key.startswith("moe_") for key in dense.snapshot())
     finally:
         dense.close()
+
+
+POOLS = {
+    # name: (the model, pairs its four-chunk prompt's prefill sweeps)
+    "latent": ("tiny-deepseek-v2", 512 * (512 + 1024 + 1536 + 2048)),
+    "dense": ("tiny-llama", 4 * 512 * 2048),
+}
+
+
+@pytest.mark.parametrize("kind", POOLS)
+def test_a_pool_books_the_pairs_its_prefill_programs_swept(kind):
+    """A judge-sized prompt of 1,8xx tokens goes through one row in four
+    512-token chunks of a 2,048-slot bucket: a latent pool books each chunk
+    at the width its frontier picked (5,120 x 512 pairs), a dense pool its
+    whole bucket a chunk; both book the causal pairs of the real tokens, and
+    the ``pool.admit`` span carries the wave's own."""
+    model, swept = POOLS[kind]
+    ring = FlightRecorder(capacity=512)
+    bb_mod.install(ring)
+    pool = ContinuousBatcher(
+        Engine(get_config(model), max_seq=4096, stream_interval=4), max_batch=2)
+    try:
+        out = pool.submit("judge this: " + "word " * 370,
+                          SamplingParams(max_new_tokens=4, ignore_eos=True))
+        n = out.result(timeout=600).prompt_tokens
+        st = pool.snapshot()
+    finally:
+        pool.close()
+    assert 1536 < n <= 2048
+    assert st["prefill_slot_tokens"] == 4 * 512
+    assert st["prefill_kv_pairs_swept"] == swept
+    assert st["prefill_kv_pairs_live"] == n * (n + 1) // 2
+    admit, = [e.args for e in ring.snapshot()
+              if e.name == "pool.admit" and e.tid == f"pool:{model}"]
+    assert (admit["route"], admit["chunks"]) == ("single", 4)
+    assert (admit["pairs_swept"], admit["pairs_live"]) == (swept, n * (n + 1) // 2)
+
+
+def test_a_wave_of_short_prompts_books_its_bucket_squared_a_row():
+    """The panel prompts' waves: T is the bucket, one branch, rows x bucket x
+    bucket pairs, padding rows and all; live counts the real rows alone."""
+    ring = FlightRecorder(capacity=512)
+    bb_mod.install(ring)
+    pool = ContinuousBatcher(
+        Engine(get_config("tiny-deepseek-v2"), max_seq=256, stream_interval=4),
+        max_batch=4)
+    try:
+        outs = [pool.submit(p, SamplingParams(max_new_tokens=4, ignore_eos=True))
+                for p in ("one prompt", "another, longer prompt", "a third")]
+        ns = [o.result(timeout=300).prompt_tokens for o in outs]
+        st = pool.snapshot()
+    finally:
+        pool.close()
+    admits = [e.args for e in ring.snapshot()
+              if e.name == "pool.admit" and e.tid == "pool:tiny-deepseek-v2"]
+    assert any(a["route"] == "rows" for a in admits)
+    for a in (a for a in admits if a["route"] == "rows"):  # however they fell
+        bucket = a["slot_tokens"] // a["rows_padded"]
+        assert (a["chunks"], a["pairs_swept"]) == (1, a["rows_padded"] * bucket * bucket)
+    assert st["prefill_kv_pairs_swept"] == sum(a["pairs_swept"] for a in admits)
+    assert st["prefill_kv_pairs_live"] == sum(n * (n + 1) // 2 for n in ns)
 
 
 def test_a_consensus_run_with_it_as_panelist_and_judge():

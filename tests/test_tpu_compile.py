@@ -15,6 +15,11 @@ The ``gemma-7b`` int8-KV rows at B = 8 are the combination the support
 predicate used to admit and the compiler refuse (a 32-slot kv block on
 the lanes of the scale operand).
 
+The DeepSeek-V2 cell's judge-prompt prefill (``_prefill_chunks_loop`` of
+``benchmark/configs/deepseek-v2-ep8-trio-bf16.json``'s cut: four 512-token
+chunks of a 2,048-slot bucket) is compiled whole, and its attention must
+stay a ``conditional`` with a branch a width.
+
 All cases compile in ONE child process (this file run as a script) and
 the tests read its report: loading libtpu and switching the persistent
 compilation cache off (an entry written for a described device cannot be
@@ -61,6 +66,8 @@ STEP_CASES = {
     "llama-3.2-3b": "pallas",  # dh 128: both kernels
     "llama-3.2-1b": "xla",     # dh 64: the predicate routes decode to XLA
 }
+LATENT_CONFIG = "benchmark/configs/deepseek-v2-ep8-trio-bf16.json"
+LATENT_CHUNK, LATENT_BUCKET = 512, 2048  # the judge prompt's program
 
 
 def _decode_id(preset, int8_kv, batch) -> str:
@@ -176,7 +183,48 @@ def _compile_all() -> dict:
             "prefill": prefill, "decode": decode,
             "routes": attention_routes.snapshot(preset),
         }
+    report["latent-prefill-loop"] = _latent_prefill_branches(sds, shapes)
     return report
+
+
+def _latent_prefill_branches(sds, shapes) -> dict:
+    """The widths of the float32 score blocks in each branch of each
+    ``conditional`` of the compiled judge-prompt prefill, a list a
+    conditional."""
+    import re
+
+    import jax
+
+    from benchmark import server
+    from llm_consensus_tpu.engine.engine import _prefill_chunks_loop
+    from llm_consensus_tpu.models import init_kv_cache, init_params
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, LATENT_CONFIG)) as f:
+        doc = json.load(f)
+    judge = doc["judge"]
+    cfg = server.model_config(judge, doc["models"][judge])
+    chunks = LATENT_BUCKET // LATENT_CHUNK
+    try:
+        text = _prefill_chunks_loop.lower(
+            shapes(lambda: init_params(cfg, jax.random.PRNGKey(0))), cfg,
+            sds((chunks, 1, LATENT_CHUNK)), sds(()), sds(()), sds((1,)),
+            shapes(lambda: init_kv_cache(cfg, 1, CELL_MAX_SEQ)),
+            max_chunks=chunks, kv_width=LATENT_BUCKET, moe_stats=True,
+        ).compile().as_text()
+    except Exception as err:  # noqa: BLE001 — what the chip would raise
+        return {"error": f"{type(err).__name__}: {str(err)[:300]}"}
+    bodies = {
+        m.group(1): m.group(2) for m in re.finditer(
+            r"^%?([\w.\-]+) \(.*?\) -> .*? \{\n(.*?)^\}", text, re.S | re.M)
+    }
+    scores = re.compile(rf"f32\[1,{cfg.n_heads},{LATENT_CHUNK},(\d+)\]")
+    return {"conditionals": [
+        [sorted({int(w) for w in scores.findall(bodies[name.strip().lstrip("%")])})
+         for name in branches.split(",")]
+        for branches in re.findall(
+            r" conditional\(.*?branch_computations=\{(.*?)\}", text)
+    ]}
 
 
 if __name__ == "__main__":
@@ -250,3 +298,16 @@ def test_step_programs_compile(report, preset, decode_path):
         "decode": {"kernel": decode_path == "pallas"},
         "routes": {"prefill": {"pallas": 1}, "decode": {decode_path: 1}},
     }
+
+
+def test_latent_prefill_loop_holds_a_branch_a_width(report):
+    """The judge prompt's prefill of the DeepSeek-V2 cell, compiled for the
+    described chip, holds its attention as a true ``conditional`` in each of
+    its four places (the dense layer and the expert layers' scan, in the
+    inline first chunk and in the chunk loop), and the four branches score
+    512, 1,024, 1,536 and 2,048 slots: not one branch, and not a ``select``
+    over the widest. This guards the program's SHAPE; it is no time and says
+    nothing about which branch a chunk takes (tests/test_deepseek_v2.py
+    does, on the CPU)."""
+    assert report["latent-prefill-loop"] == {
+        "conditionals": [[[512], [1024], [1536], [2048]]] * 4}
