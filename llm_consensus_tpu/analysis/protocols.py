@@ -127,6 +127,7 @@ def _stub_handoff(crash_wave):
 
     class StubCfg:
         name = "stub"
+        has_ssm = False  # KVHandoff refuses a state-space model by name
 
     class StubEngine:
         cfg = StubCfg()

@@ -72,7 +72,8 @@ import jax
 import jax.numpy as jnp
 
 from llm_consensus_tpu.engine.engine import (
-    Engine, GenerateResult, SamplingParams, _bucket, _decode_chunk)
+    Engine, GenerateResult, SamplingParams, _bucket, _decode_chunk, refuse_ssm,
+    scan_positions_swept)
 from llm_consensus_tpu.engine.speculative import (
     AdaptiveK, SpecGovernor, _install_spec_rows, _junk_propose,
     _lookup_propose, _oracle_propose, _plain_chunk_masked, _roll_valid,
@@ -81,6 +82,7 @@ from llm_consensus_tpu.engine.tokenizer import StreamDecoder
 from llm_consensus_tpu.obs.attrib import tag as _attrib_tag
 from llm_consensus_tpu.obs import roofline as _roofline
 from llm_consensus_tpu.ops.quant import kv_seq_axis as _seq_axis
+from llm_consensus_tpu.ops.quant import kv_tree_map as _kv_tree_map
 from llm_consensus_tpu.ops.sampling import sample_token
 from llm_consensus_tpu.utils.context import Context
 from llm_consensus_tpu.utils.flops import cache_bytes_per_token
@@ -153,8 +155,9 @@ class _Stream:
 def _wave_counts(admit: dict) -> dict:
     """The counter deltas of one landed admission wave, from its
     ``pool.admit`` span's arguments: the ``prefill_*`` counters (and
-    ``admit_tokens``) are those arguments summed over waves."""
-    return {
+    ``admit_tokens``) are those arguments summed over waves. A state-space
+    model's span also says what its scans ran over (``_ssm_admit``)."""
+    counts = {
         "admit_tokens": admit["tokens_real"], "prefill_waves": 1,
         "prefill_rows_real": admit["rows_real"],
         "prefill_rows_padded": admit["rows_padded"],
@@ -162,6 +165,29 @@ def _wave_counts(admit: dict) -> dict:
         "prefill_kv_pairs_swept": admit["pairs_swept"],
         "prefill_kv_pairs_live": admit["pairs_live"],
     }
+    if "ssm_swept" in admit:
+        counts.update(ssm_positions_swept=admit["ssm_swept"],
+                      ssm_positions_live=admit["ssm_live"])
+    return counts
+
+
+def _ssm_admit(cfg, did, rows: int, tokens_real: int) -> dict:
+    """What a ``pool.admit`` span of a state-space model says beside the
+    rest: the positions the scans of its prefill ``did`` of ``rows`` rows
+    ran over (``scan_positions_swept``: rows x slots, padding included) and
+    the real tokens among them. Nothing for a model without a mixer."""
+    if not cfg.has_ssm:
+        return {}
+    return {"ssm_swept": scan_positions_swept(cfg, did, rows),
+            "ssm_live": tokens_real}
+
+
+def _ssm_decode(cfg, steps: int, rows: int) -> dict:
+    """What a ``pool.decode`` span and the decode counters of a state-space
+    model say beside the rest: the rows whose state the dispatch's steps
+    read and write (every row the pool holds, with a stream or not).
+    Nothing for a model without a mixer."""
+    return {"ssm_state_row_steps": steps * rows} if cfg.has_ssm else {}
 
 
 # What a pool row WITHOUT a stream carries as its device ``row_start``:
@@ -555,7 +581,9 @@ def _splice(batch_cache, prefill_cache, slot, dst, width: int):
     """Copy ``prefill_cache``'s slots [0, width) into ``batch_cache``'s
     row ``slot`` at offset ``dst``. Junk past the prompt inside the
     bucket lands at slots ≥ the shared frontier, which decode overwrites
-    before reading."""
+    before reading. A state-space model's per-row state leaves (no
+    sequence axis) replace the pool row's WHOLE: the row starts from its
+    own prefill's state, never from what its last tenant left."""
     def copy(bdst, src):
         if _seq_axis(src) == 2:
             return jax.lax.dynamic_update_slice(
@@ -565,7 +593,11 @@ def _splice(batch_cache, prefill_cache, slot, dst, width: int):
             bdst, src[..., :width], (0, slot, 0, dst)
         )
 
-    return jax.tree.map(copy, batch_cache, prefill_cache)
+    def state(bdst, src):
+        return jax.lax.dynamic_update_slice_in_dim(
+            bdst, src[:, :1].astype(bdst.dtype), slot, axis=1)
+
+    return _kv_tree_map(copy, batch_cache, prefill_cache, state=state)
 
 
 @partial(jax.jit, static_argnames=("k", "width"), donate_argnames=("batch_cache",))
@@ -582,7 +614,15 @@ def _splice_rows(batch_cache, prefill_cache, src_rows, slots, dsts,
     the splices waited behind the admission prefill. Fused, the wave
     holds one in/out pair. Traced index arrays keep slot/offset values
     out of the program identity; padding rows (k padded to a power of
-    two) repeat row 0's splice, which is idempotent."""
+    two) repeat row 0's splice, which is idempotent. A state-space
+    model's per-row state leaves are copied whole, row for row."""
+    def state(bdst, src):
+        for i in range(k):
+            row = jax.lax.dynamic_slice_in_dim(src, src_rows[i], 1, axis=1)
+            bdst = jax.lax.dynamic_update_slice_in_dim(
+                bdst, row.astype(bdst.dtype), slots[i], axis=1)
+        return bdst
+
     def copy(bdst, src):
         seq2 = _seq_axis(src) == 2
         for i in range(k):
@@ -604,7 +644,7 @@ def _splice_rows(batch_cache, prefill_cache, src_rows, slots, dsts,
                 )
         return bdst
 
-    return jax.tree.map(copy, batch_cache, prefill_cache)
+    return _kv_tree_map(copy, batch_cache, prefill_cache, state=state)
 
 
 @partial(jax.jit, static_argnames=("p_cap",))
@@ -655,7 +695,9 @@ def _move_row(cache, src, dst):
     """Copy row ``src``'s full window onto row ``dst`` (one program for
     all moves; traced indices). Used to compact live rows into the low
     slots before the pool's row capacity shrinks — the row carries its
-    ``row_start``-relative positions with it, so no re-RoPE."""
+    ``row_start``-relative positions with it, so no re-RoPE. Every leaf
+    has its rows on axis 1, a state-space model's per-row state leaves
+    too: they move with the row (as they shrink and grow with the pool)."""
     def leaf(x):
         row = jax.lax.dynamic_slice_in_dim(x, src, 1, axis=1)
         return jax.lax.dynamic_update_slice_in_dim(x, row, dst, axis=1)
@@ -694,8 +736,10 @@ def _compact_cache(cache, shift):
     program for all compactions). The shift is the same for all rows by
     construction — every live window ends at the shared frontier — and
     junk that wraps around lands at slots ≥ the new frontier, which the
-    valid mask excludes and future decode writes overwrite."""
-    return jax.tree.map(
+    valid mask excludes and future decode writes overwrite. A state-space
+    model's per-row state leaves have no slots to slide and stay as they
+    are: a row's state does not depend on where its window lies."""
+    return _kv_tree_map(
         lambda leaf: jnp.roll(leaf, -shift, axis=_seq_axis(leaf)), cache
     )
 
@@ -753,6 +797,8 @@ class ContinuousBatcher:
             raise ValueError(
                 f"{engine.cfg.name}: no speculative pool decode over a "
                 "latent (MLA) cache")
+        if spec is not None:
+            refuse_ssm(engine.cfg, "speculative pool decode")
         if spec is not None and engine.cfg.sliding_window is not None:
             # Same warn-once courtesy the model-draft+batching case gets
             # (providers/tpu.py): an operator who configured speculation
@@ -845,6 +891,8 @@ class ContinuousBatcher:
             and engine.cfg.sliding_window is None
             # No prefix-merge form over a latent (MLA) cache yet: off.
             and not engine.cfg.is_latent
+            # A shared prefix has no state for a row to start from: off.
+            and not engine.cfg.has_ssm
             and mesh_ok
             # Spec rounds hold each row's FULL prompt in its own window
             # (the batched verify program has no prefix-merge form);
@@ -930,6 +978,17 @@ class ContinuousBatcher:
             # (causal_pairs): their ratio is what a prefill sweeps in vain.
             "prefill_kv_pairs_swept": 0, "prefill_kv_pairs_live": 0,
         }
+        if engine.cfg.has_ssm:
+            # A state-space model's pool: the positions its prefill
+            # programs' scans ran over (rows x slots, padding and whole scan
+            # chunks included) and the real tokens among them; and the rows
+            # whose state a decode step read and wrote (every row the pool
+            # holds, with a stream or not: the one-step form carries them
+            # all), summed over steps, beside ``decode_steps``.
+            self.stats.update(
+                ssm_positions_swept=0, ssm_positions_live=0,
+                ssm_state_row_steps=0,
+            )
         if engine.cfg.is_moe:
             # A routed model's programs return their routing sums
             # (ops/moe.py), which ride each fetch: (token, chosen expert)
@@ -1509,7 +1568,8 @@ class ContinuousBatcher:
         did = eng.last_prefill
         sp.set(chunks=did.chunks, slot_tokens=did.slot_tokens,
                pairs_swept=did.pairs_swept,
-               pairs_live=causal_pairs([n], did.reused))
+               pairs_live=causal_pairs([n], did.reused),
+               **_ssm_admit(eng.cfg, did, 1, n))
         return ([slot], tok, [s])
 
     def _establish_prefix(self, prefix_ids: list[int]) -> bool:
@@ -1520,6 +1580,7 @@ class ContinuousBatcher:
         recomputing; the prefix is retained as that snapshot afterwards.
         Returns False (state cleared) on any failure."""
         eng = self.engine
+        refuse_ssm(eng.cfg, "pooled shared-prefix admission")
         p = len(prefix_ids)
         # 128-granule cap (not 256): prefix-attention compute scales with
         # p_cap — the XLA path has no Mosaic tiling constraint, and lanes
@@ -1612,7 +1673,9 @@ class ContinuousBatcher:
             sp.set(rows_padded=k_pad, chunks=did.chunks,
                    slot_tokens=did.slot_tokens, pairs_swept=did.pairs_swept,
                    pairs_live=causal_pairs(
-                       [len(r) for r in rows], prefix_p or did.reused))
+                       [len(r) for r in rows], prefix_p or did.reused),
+                   **_ssm_admit(eng.cfg, did, k_pad,
+                                sum(len(r) - prefix_p for r in rows)))
         except Exception as exc:  # noqa: BLE001
             # The fallback below hides the failure from everyone but the
             # span: say what it was.
@@ -1822,10 +1885,11 @@ class ContinuousBatcher:
                 wave.batch, wave.wave_p, wave.k_pad, last_logits, pcache,
                 width,
             )
-            sp.set(ok=True,
-                   tokens_real=sum(
-                       len(ids) - wave.wave_p for _, ids, _ in wave.batch
-                   ),
+            tokens_real = sum(
+                len(ids) - wave.wave_p for _, ids, _ in wave.batch)
+            sp.set(ok=True, tokens_real=tokens_real,
+                   **_ssm_admit(
+                       eng.cfg, eng.last_prefill, wave.k_pad, tokens_real),
                    chunks=wave.session.chunks,
                    slot_tokens=wave.session.slot_tokens,
                    pairs_swept=wave.session.pairs_swept,
@@ -3467,6 +3531,7 @@ class ContinuousBatcher:
                         steps=n_steps, kv_width=kv_width or 0,
                         rows_live=rows_live, rows=self._rows_cap,
                         pos=self._pos + n_steps, slots_live=slots_live,
+                        **_ssm_decode(eng.cfg, n_steps, self._rows_cap),
                     ), _attrib_tag("decode"):
                         out = eng._flash_guard(
                             lambda impl: _decode_chunk(
@@ -3545,6 +3610,7 @@ class ContinuousBatcher:
                             eng._decode_width(self._pos) or eng.max_seq
                         ),
                         decode_kv_slots_live=slots_live,
+                        **_ssm_decode(eng.cfg, covered, self._rows_cap),
                     )
                     # Host gap closed: the device sat idle from the
                     # drain to this dispatch while the batcher was busy

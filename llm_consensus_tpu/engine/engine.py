@@ -44,7 +44,7 @@ from llm_consensus_tpu.obs.attrib import tag as _attrib_tag
 from llm_consensus_tpu.obs import roofline as _roofline
 from llm_consensus_tpu.models.config import ModelConfig
 from llm_consensus_tpu.ops.latent_attention import prefill_sweep_width
-from llm_consensus_tpu.ops.quant import w8a8_scope
+from llm_consensus_tpu.ops.quant import kv_seq_axis, kv_tree_map, w8a8_scope
 from llm_consensus_tpu.ops.sampling import sample_token
 from llm_consensus_tpu.utils.context import Context
 from llm_consensus_tpu.utils import knobs
@@ -109,8 +109,12 @@ def _with_moe(out, first):
 def _prefill_step(params, cfg: ModelConfig, tokens, last_index, cache,
                   attn_impl="xla", mesh=None, row_start=None, kv_width=None,
                   prefix=None, prefix_len=None, w8a8: bool = False,
-                  moe_stats: bool = False):
+                  moe_stats: bool = False, row_end=None):
     """Prefill ``tokens`` (padded) into the cache; return last real logits.
+
+    ``row_end`` [B] (a state-space model's programs only; ``_row_end``)
+    says where each row's real tokens end inside the padded bucket, so that
+    the padding does not advance the row's recurrent state.
 
     ``row_start`` serves the right-aligned batch path (left-padded rows,
     per-row position offsets); ``kv_width`` bounds attention to the prompt
@@ -128,9 +132,16 @@ def _prefill_step(params, cfg: ModelConfig, tokens, last_index, cache,
             params, cfg, tokens, cache, start_pos=0, attn_impl=attn_impl,
             mesh=mesh, logits_index=last_index, row_start=row_start,
             kv_width=kv_width, prefix=prefix, prefix_len=prefix_len,
-            moe_stats=moe_stats,
+            moe_stats=moe_stats, row_end=row_end,
         )
     return _with_moe(out, out[0][:, 0])
+
+
+def _row_end(cfg: ModelConfig, place: Callable, ends):
+    """``forward``'s ``row_end`` for a prefill of rows whose real tokens end
+    at slots ``ends``: an operand of a state-space model's programs alone
+    (None keeps every other model's program as it was)."""
+    return place(jnp.asarray(ends, jnp.int32)) if cfg.has_ssm else None
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh"), donate_argnames=("cache",))
@@ -151,8 +162,11 @@ def _restore_prefix(saved, n_valid):
     cache (bandwidth ≈ one cache read+write) replaces re-prefilling the
     whole shared prefix; the traced length means one compiled program for
     every prefix length. Per-leaf seq axes follow ops.quant.kv_seq_axis
-    (seq-minor int8 scale stacks vs 5-D code/bf16 stacks)."""
-    return jax.tree.map(lambda src: _mask_beyond(src, n_valid), saved)
+    (seq-minor int8 scale stacks vs 5-D code/bf16 stacks). A state-space
+    model's per-row state leaves have no positions to mask and a state cut
+    at ``n_valid`` does not exist: its engine never restores a prefix
+    (``Engine._refuse_ssm``)."""
+    return kv_tree_map(lambda src: _mask_beyond(src, n_valid), saved)
 
 
 @partial(jax.jit, donate_argnames=("saved",))
@@ -163,7 +177,7 @@ def _restore_prefix_owned(saved, n_valid):
     the pool hit path would otherwise pay the gather's HBM cost twice.
     The classic path must keep the non-donating twin: its input is the
     shared snapshot slot, which later reuses read again."""
-    return jax.tree.map(lambda src: _mask_beyond(src, n_valid), saved)
+    return kv_tree_map(lambda src: _mask_beyond(src, n_valid), saved)
 
 
 def _mask_beyond(src, n_valid):
@@ -171,8 +185,6 @@ def _mask_beyond(src, n_valid):
     single owner of the prefix-restore masking invariant (used by both
     _restore_prefix and _fork_prefix so a cache-layout change cannot
     diverge them)."""
-    from llm_consensus_tpu.ops.quant import kv_seq_axis
-
     ax = kv_seq_axis(src)
     shape = [1] * src.ndim
     shape[ax] = src.shape[ax]
@@ -187,21 +199,19 @@ def _fork_prefix(saved, n_valid, k: int, width: int):
     ``n_valid``, and replicate across the k rows. One program per
     (k, width); the copy costs k × bucket bytes — what the wave saves is
     re-COMPUTING the shared prefix chunks through the model."""
-    from llm_consensus_tpu.ops.quant import kv_seq_axis
-
     def leaf(src):
         sl = jax.lax.slice_in_dim(src, 0, width, axis=kv_seq_axis(src))
         return jnp.repeat(_mask_beyond(sl, n_valid), k, axis=1)
 
-    return jax.tree.map(leaf, saved)
+    return kv_tree_map(
+        leaf, saved, state=lambda src: jnp.repeat(src, k, axis=1))
 
 
 @partial(jax.jit, static_argnames=("width",))
 def _extract_row0(template, pcache, width: int):
     """Row 0 of a [k, width] admission prefill cache, re-padded into a
-    full-capacity [1, max_seq] snapshot (``template`` is fresh zeros)."""
-    from llm_consensus_tpu.ops.quant import kv_seq_axis
-
+    full-capacity [1, max_seq] snapshot (``template`` is fresh zeros). A
+    state-space model's per-row state leaves are carried whole."""
     def copy(dst, src):
         if kv_seq_axis(src) == 2:
             return jax.lax.dynamic_update_slice(
@@ -211,14 +221,20 @@ def _extract_row0(template, pcache, width: int):
             dst, src[:, :1, :, :width], (0, 0, 0, 0)
         )
 
-    return jax.tree.map(copy, template, pcache)
+    return kv_tree_map(
+        copy, template, pcache, state=lambda dst, src: src[:, :1])
 
 
 def _prefill_chunk(params, cfg: ModelConfig, tokens, start_pos, last_index,
                    cache, kv_width: int, row_start=None, prefix=None,
                    prefix_len=None, w8a8: bool = False,
-                   moe_stats: bool = False):
+                   moe_stats: bool = False, row_end=None):
     """One fixed-size prefill chunk at a *traced* ``start_pos``.
+
+    ``row_end`` [B] (a state-space model's programs only; ``_row_end``):
+    the slot after each row's last real token, in the cache's coordinates,
+    so that neither a chunk's padded tail nor a whole chunk past a short
+    row's end advances that row's recurrent state.
 
     The dynamic start means ONE compiled program (per prompt bucket) serves
     every chunk of a long prompt, and peak attention memory is
@@ -239,6 +255,7 @@ def _prefill_chunk(params, cfg: ModelConfig, tokens, start_pos, last_index,
             params, cfg, tokens, cache, start_pos=start_pos,
             kv_width=kv_width, logits_index=last_index, row_start=row_start,
             prefix=prefix, prefix_len=prefix_len, moe_stats=moe_stats,
+            row_end=row_end,
         )
     return _with_moe(out, out[0][:, 0])
 
@@ -258,13 +275,19 @@ def _prefill_chunks_loop(params, cfg: ModelConfig, tokens, base, n_real,
     full-model compile mid-admission). Junk chunks past ``n_real`` are
     never executed. Chunk 0 runs inline so the carry's logits dtype
     matches forward's exactly — greedy ties must not flip between this
-    and the per-chunk path.
+    and the per-chunk path. The last chunk's padded tail must not advance
+    a state-space model's recurrent state: the prompt's real end follows
+    from what the program is given already (``n_real`` chunks, the last
+    real token at ``last_index`` of the last one).
     """
     chunk = tokens.shape[-1]
+    row_end = (
+        base + (n_real - 1) * chunk + last_index + 1 if cfg.has_ssm else None)
     with w8a8_scope(w8a8):
         logits0, cache, *moe = forward(
             params, cfg, tokens[0], cache, start_pos=base,
             kv_width=kv_width, logits_index=last_index, moe_stats=moe_stats,
+            row_end=row_end,
         )
 
     def body(i, carry):
@@ -274,7 +297,7 @@ def _prefill_chunks_loop(params, cfg: ModelConfig, tokens, base, n_real,
             logits, cache, *more = forward(
                 params, cfg, toks, cache, start_pos=base + i * chunk,
                 kv_width=kv_width, logits_index=last_index,
-                moe_stats=moe_stats,
+                moe_stats=moe_stats, row_end=row_end,
             )
         return (cache, logits[:, 0], *(a + b for a, b in zip(moe, more)))
 
@@ -513,6 +536,17 @@ class Prefilled(NamedTuple):
     reused: int
 
 
+def scan_positions_swept(cfg: ModelConfig, did: Prefilled, rows: int) -> int:
+    """Positions the scans of a state-space model's prefill ``did`` of
+    ``rows`` rows (padding rows included) ran over: every token slot of
+    every program, with a program's T rounded up to whole scan chunks
+    (ops/ssm.py ``ssd_chunked``). 0 for a model without a mixer."""
+    if not cfg.has_ssm:
+        return 0
+    t = did.slot_tokens // (rows * did.chunks)  # one program's width
+    return rows * did.chunks * (-(-t // cfg.ssm_chunk) * cfg.ssm_chunk)
+
+
 def prefill_pairs_swept(cfg: ModelConfig, rows: int, t: int, slots: int,
                         end: int, prefix_slots: int = 0) -> int:
     """(Query, key) pairs the attention of ONE prefill program sweeps:
@@ -525,6 +559,16 @@ def prefill_pairs_swept(cfg: ModelConfig, rows: int, t: int, slots: int,
     if cfg.is_latent:
         slots = prefill_sweep_width(t, slots, end)
     return rows * t * (slots + prefix_slots)
+
+
+def refuse_ssm(cfg: ModelConfig, what: str) -> None:
+    """Refuse, by name, a path that would cut, fork or move a state-space
+    model's cache at a length its recurrent state was not computed to."""
+    if cfg.has_ssm:
+        raise ValueError(
+            f"{cfg.name}: no {what} for a state-space model: keys and values "
+            "can be cut at any length, its recurrent state exists only at "
+            "the length it was computed to")
 
 
 def _bucket(n: int, cap: int) -> int:
@@ -703,6 +747,13 @@ class Engine:
             # The retained prefix snapshot is not built over a latent yet:
             # off, as pooled prefix sharing is (engine/batcher.py).
             self.prefix_cache_enabled = False
+        if cfg.has_ssm:
+            self._refuse_ssm(mesh)
+            # Keys and values can be cut at any length; a recurrent state
+            # exists only at the length it was saved at. So no retained
+            # prefix snapshot (``Prefilled.reused`` stays 0), as no pooled
+            # prefix sharing (engine/batcher.py).
+            self.prefix_cache_enabled = False
         self._prefix_max_bytes = (
             knobs.get_float("LLMC_PREFIX_CACHE_MAX_MB") * 1e6
         )
@@ -867,7 +918,8 @@ class Engine:
                 per_chip[shard.device.id] = (
                     per_chip.get(shard.device.id, 0) + shard.data.nbytes
                 )
-        from llm_consensus_tpu.utils.flops import cache_bytes_per_token
+        from llm_consensus_tpu.utils.flops import (
+            cache_bytes_per_token, state_bytes_per_row)
 
         self.build_stats = {
             "tp": int(dict(mesh.shape).get("tp", 1)) if mesh is not None else 1,
@@ -880,6 +932,11 @@ class Engine:
             "cache_bytes_per_token": cache_bytes_per_token(
                 cfg, 1 if self.kv_quant == "int8"
                 else jnp.dtype(dtype).itemsize),
+            # What a ROW costs beside its slots, and in how many layers (0
+            # and 0 for a model without a state-space mixer).
+            "state_bytes_per_row": state_bytes_per_row(
+                cfg, jnp.dtype(dtype).itemsize),
+            "ssm_layers": cfg.n_layers if cfg.has_ssm else 0,
         }
         self._spans.complete(
             "engine.build", t_build_ns, "engine", model=cfg.name,
@@ -903,6 +960,25 @@ class Engine:
                 f"{name}: the radix KV arena (LLMC_KV_POOL) does not hold a "
                 "latent (MLA) cache")
         refuse_latent_mesh(self.cfg, mesh)
+
+    def _refuse_ssm(self, mesh) -> None:
+        """What a state-space model does not get yet is refused by name
+        when its engine is built, not computed wrongly. (What is entered
+        elsewhere refuses there through ``refuse_ssm``: speculation, the
+        prefill session, the handoff of a live row.)"""
+        from llm_consensus_tpu.kv import pool_enabled
+        from llm_consensus_tpu.models.transformer import refuse_ssm_mesh
+
+        name = self.cfg.name
+        if self.kv_quant is not None:
+            raise ValueError(
+                f"{name}: no {self.kv_quant} cache for a state-space model; "
+                "unset LLMC_KV_QUANT / kv_quant")
+        if pool_enabled():
+            raise ValueError(
+                f"{name}: the radix KV arena (LLMC_KV_POOL) does not hold a "
+                "state-space model's cache: a block of slots has no state")
+        refuse_ssm_mesh(self.cfg, mesh)
 
     @property
     def _moe_on(self) -> bool:
@@ -1328,6 +1404,7 @@ class Engine:
                     self._place(jnp.asarray(base + i * chunk, jnp.int32)),
                     last_in_chunk, cache, kv_width=kv_width,
                     w8a8=self.w8a8, moe_stats=self._moe_on,
+                    row_end=_row_end(self.cfg, self._place, [n_prompt]),
                 ))
         # What was prefilled, for the caller's accounting.
         self.last_prefill = Prefilled(
@@ -1428,6 +1505,7 @@ class Engine:
                     self._place(jnp.asarray([n_prompt - 1])),
                     cache, attn_impl=impl, mesh=self.mesh, w8a8=self.w8a8,
                     moe_stats=self._moe_on,
+                    row_end=_row_end(cfg, self._place, [n_prompt]),
                 )))
             # The kernel is handed the prompt's bucket; the XLA routes are
             # given the cache, which here has the engine's whole capacity.
@@ -1467,6 +1545,7 @@ class Engine:
         """An incremental prefill session: token chunks append to one
         growing KV cache as they become known (the judge-overlap half of
         the prefill/decode overlap mechanism)."""
+        refuse_ssm(self.cfg, "incremental prefill session")
         return PrefillSession(self)
 
     def _prefill_rows(self, rows: list[list[int]]):
@@ -2149,6 +2228,9 @@ class AdmissionPrefill:
         eng = self._eng
         place = eng._place
         cfg = eng.cfg
+        # Where each (left-aligned) row's real tokens end: a state-space
+        # model's state must not run on into the padding.
+        row_end = _row_end(cfg, place, [len(r) for r in self.rows])
         if not self._use_chunks:
             # One-shot per-bucket program: indivisible by construction.
             tokens = place(jnp.asarray(self._padded, jnp.int32))
@@ -2167,7 +2249,7 @@ class AdmissionPrefill:
                     lambda impl: _prefill_step(
                         eng.params, cfg, tokens, last_index, self._cache,
                         attn_impl=impl, mesh=eng.mesh, w8a8=eng.w8a8,
-                        moe_stats=eng._moe_on,
+                        moe_stats=eng._moe_on, row_end=row_end,
                     )
                 ))
             self._done = True
@@ -2195,7 +2277,7 @@ class AdmissionPrefill:
                 place(jnp.asarray(c * chunk_len, jnp.int32)),
                 idx, self._cache, kv_width=self.width,
                 prefix=self._prefix_cache, prefix_len=self._plen_dev,
-                w8a8=eng.w8a8, moe_stats=eng._moe_on,
+                w8a8=eng.w8a8, moe_stats=eng._moe_on, row_end=row_end,
             ))
             self._per_chunk.append(lg)
             self._next_chunk += 1

@@ -152,6 +152,9 @@ class KVHandoff:
                  wave_rows: Optional[int] = None,
                  wait_s: Optional[float] = None,
                  name: str = ""):
+        from llm_consensus_tpu.engine.engine import refuse_ssm
+
+        refuse_ssm(decode_engine.cfg, "cross-mesh handoff of a prefilled row")
         pool = getattr(decode_engine, "_kv_pool", None)
         if pool is None:
             raise ValueError(

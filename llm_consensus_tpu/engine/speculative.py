@@ -843,7 +843,12 @@ class SpeculativeEngine:
                  probe_tokens: Optional[int] = None):
         if k < 1:
             raise ValueError("k must be >= 1")
+        from llm_consensus_tpu.engine.engine import refuse_ssm
+
+        # A rejected draft cannot be taken back out of a recurrent state.
+        refuse_ssm(target.cfg, "speculative decoding")
         if isinstance(draft, Engine):
+            refuse_ssm(draft.cfg, "speculative drafting")
             draft = ModelDrafter(draft)
         if not isinstance(draft, Drafter):
             raise TypeError("draft must be an Engine or a Drafter")

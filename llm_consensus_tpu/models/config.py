@@ -2,7 +2,7 @@
 
 One generic decoder-only transformer (models/transformer.py) covers every
 family the framework serves — Llama-2/3, Mistral, Gemma, Qwen2, Mixtral,
-DeepSeek-V2 — via static config switches, so each (family, shape) pair
+DeepSeek-V2, Falcon-H1 — via static config switches, so each (family, shape) pair
 compiles to a single XLA program. Every field a family adds defaults to
 "off", so the older presets hash and compare as they did. The reference framework's "model set" is a table of
 remote API names (/root/reference/cmd/llm-consensus/main.go:49-61); here the
@@ -18,7 +18,7 @@ from typing import Optional
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                     # llama | mistral | gemma | qwen2 | mixtral | deepseek_v2
+    family: str                     # llama | mistral | gemma | qwen2 | mixtral | deepseek_v2 | falcon_h1
     vocab_size: int
     d_model: int
     n_layers: int
@@ -65,6 +65,53 @@ class ModelConfig:
     # YaRN: (factor, beta_fast, beta_slow, mscale, mscale_all_dim,
     # original_max_position_embeddings); tuple so the config stays hashable.
     rope_yarn: Optional[tuple[float, float, float, float, float, int]] = None
+    # -- state-space mixer beside attention in every layer (Mamba-2,
+    # ops/ssm.py); ssm_heads 0 = off. The cache then holds, beside keys and
+    # values, a recurrent state and a convolution tail a ROW a layer.
+    ssm_heads: int = 0              # mixer heads
+    ssm_head_dim: int = 0           # inner width = ssm_heads * ssm_head_dim
+    ssm_state: int = 0              # state size a head channel
+    ssm_groups: int = 1             # groups that share one B and one C
+    ssm_conv: int = 4               # causal convolution length
+    ssm_chunk: int = 128            # positions a chunk of the chunked scan
+    # -- fixed multipliers (muP, Falcon-H1); 1.0 = off --------------------
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    # over the in-projection's segments z | x | B | C | dt
+    ssm_multipliers: tuple[float, float, float, float, float] = (1.0,) * 5
+    mlp_multipliers: tuple[float, float] = (1.0, 1.0)  # gate, down
+
+    @property
+    def has_ssm(self) -> bool:
+        return self.ssm_heads > 0
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """Channels the causal convolution runs over: x, B and C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def ssm_proj_width(self) -> int:
+        """Outputs of the mixer's in-projection: z | x | B | C | dt."""
+        return self.ssm_inner + self.ssm_conv_width + self.ssm_heads
+
+    @property
+    def state_bytes_per_row(self) -> int:
+        """Bytes one ROW holds beside its keys and values over every layer,
+        whatever its context, with a bf16 convolution tail (delegates to
+        utils.flops, which takes the tail's item size). 0 without a mixer."""
+        from llm_consensus_tpu.utils.flops import state_bytes_per_row
+
+        return state_bytes_per_row(self)
 
     @property
     def is_moe(self) -> bool:
@@ -166,6 +213,17 @@ MODEL_PRESETS: dict[str, ModelConfig] = {c.name: c for c in [
        q_lora_rank=56, kv_lora_rank=40, qk_nope_dim=24, qk_rope_dim=16,
        v_head_dim=20, rope_yarn=(8.0, 32.0, 1.0, 0.707, 0.707, 64),
        max_seq_len=4096),
+    # Falcon-H1's block at CI size: a Mamba-2 mixer (4 heads of 8, state
+    # 16, 2 groups) beside 4/2-head attention in each of 2 layers; every
+    # multiplier differs from 1.
+    _L("tiny-falcon-h1", "falcon_h1", 512, 96, 2, 4, 2, 32, 192,
+       rope_theta=1e11, ssm_heads=4, ssm_head_dim=8, ssm_state=16,
+       ssm_groups=2, ssm_conv=4, ssm_chunk=8, embedding_multiplier=2.5,
+       lm_head_multiplier=0.25, attention_in_multiplier=0.8,
+       attention_out_multiplier=0.6, key_multiplier=0.5,
+       ssm_in_multiplier=1.25, ssm_out_multiplier=0.7,
+       ssm_multipliers=(0.9, 1.2, 0.75, 1.1, 0.85),
+       mlp_multipliers=(0.7, 1.4), max_seq_len=4096),
     _L("tiny-llama", "llama", 512, 128, 2, 4, 2, 32, 256, max_seq_len=4096),
     _L("tiny-gemma", "gemma", 512, 128, 2, 4, 4, 32, 256, activation="gelu_tanh",
        norm_offset=1.0, embed_scale=True, tie_embeddings=True, max_seq_len=4096),

@@ -1,7 +1,7 @@
 """Generic decoder-only transformer in functional JAX.
 
 One implementation serves every model family (llama/mistral/gemma/qwen2/
-mixtral/deepseek_v2) via static ``ModelConfig`` switches. This replaces the reference's
+mixtral/deepseek_v2/falcon_h1) via static ``ModelConfig`` switches. This replaces the reference's
 "compute layer" — three HTTP clients (/root/reference/internal/provider/
 {openai,anthropic,google}.go) — with real on-device compute.
 
@@ -36,6 +36,17 @@ renormalised times ``routed_scale``, plus the shared experts. Departure
 from the checkpoint's layout: rotary pairs are half-split (i, i + d/2) where
 the published code interleaves (2i, 2i + 1); under random weights the
 pairing is immaterial as long as program and reference pair alike.
+
+The Falcon-H1 block (``family="falcon_h1"``, ``cfg.has_ssm``): every layer
+runs a Mamba-2 mixer (ops/ssm.py has the equations) BESIDE grouped-query
+attention on the same normed input, ``x + mixer + attention``, then a SwiGLU
+MLP; the published fixed multipliers (muP) scale the embedding, the head, the
+attention input, keys and output, the mixer's input, projection segments and
+output, and the MLP's gate and output. The cache holds, beside keys and
+values, a sub-tree ``"ssm"`` of per-ROW state: the recurrence's float32
+state and the convolution's tail, which have no sequence axis. A recurrence
+must know where a row's real tokens END inside a padded chunk as well as
+where they start: ``forward(row_end=...)``.
 """
 
 from __future__ import annotations
@@ -53,11 +64,12 @@ from llm_consensus_tpu.ops.attention import attention, make_attention_mask
 from llm_consensus_tpu.ops.mlp import gated_mlp
 from llm_consensus_tpu.ops.moe import moe_block
 from llm_consensus_tpu.ops.quant import (
-    is_quantized, kv_layer, kv_read, kv_write_rows, qeinsum)
+    STATE_KEY, is_quantized, kv_layer, kv_read, kv_write_rows, qeinsum)
 from llm_consensus_tpu.ops.norms import rms_norm
 from llm_consensus_tpu.ops.latent_attention import latent_attention
 from llm_consensus_tpu.ops.rope import (
     apply_rope, rope_angles, rope_inv_freq, yarn_inv_freq, yarn_mscale)
+from llm_consensus_tpu.ops.ssm import mixer as ssm_mixer
 
 
 class AttentionRoutes:
@@ -191,6 +203,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
         if cfg.qkv_bias:
             for name, width in (("bq", hq * dh), ("bk", hkv * dh), ("bv", hkv * dh)):
                 layers[name] = make(name, lambda w=width: jnp.zeros((l, w), dtype))
+        if cfg.has_ssm:
+            layers.update(_init_mixer(cfg, l, keys, make, normal, norm, dtype))
         if routed:
             e, fe = cfg.n_experts, cfg.expert_width
             layers["w_router"] = normal(
@@ -227,22 +241,74 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
     return params
 
 
+def _init_mixer(cfg: ModelConfig, l: int, keys, make, normal, norm, dtype) -> dict:
+    """The state-space mixer's leaves of ``l`` stacked layers
+    (``init_params``'s makers; ops/ssm.py ``mixer`` reads them). Random like
+    the rest, in the ranges the published initialiser draws from: ``dt``
+    log-uniform in [1e-3, 1e-1] through the inverse softplus, ``A`` in
+    [-16, -1], ``D`` about 1."""
+    d, inner, c = cfg.d_model, cfg.ssm_inner, cfg.ssm_conv_width
+    h, k = cfg.ssm_heads, cfg.ssm_conv
+
+    def uniform(name, shape, fn):
+        return make(name, lambda kk: fn(
+            jax.random.uniform(kk, shape, jnp.float32)).astype(dtype), next(keys))
+
+    def dt_bias(u):
+        dt = jnp.exp(u * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3))
+        return dt + jnp.log(-jnp.expm1(-dt))
+
+    return {
+        "ssm_in": normal(next(keys), (l, d, cfg.ssm_proj_width), d ** -0.5, "ssm_in"),
+        "ssm_conv": normal(next(keys), (l, c, k), k ** -0.5, "ssm_conv"),
+        "ssm_conv_bias": normal(next(keys), (l, c), 0.02, "ssm_conv_bias"),
+        "ssm_dt_bias": uniform("ssm_dt_bias", (l, h), dt_bias),
+        "ssm_a_log": uniform("ssm_a_log", (l, h), lambda u: jnp.log(1 + 15 * u)),
+        "ssm_d": uniform("ssm_d", (l, h), lambda u: 0.5 + u),
+        "ssm_norm": norm((l, inner), "ssm_norm"),
+        "ssm_out": normal(next(keys), (l, inner, d), inner ** -0.5, "ssm_out"),
+    }
+
+
 def init_kv_cache(
     cfg: ModelConfig, batch: int, max_seq: Optional[int] = None,
     dtype=jnp.bfloat16, quant: Optional[str] = None,
 ) -> dict:
-    """Static-shaped KV cache [L, B, S, Hkv, dh] (zeros, nothing valid yet).
+    """A zeroed cache of ``batch`` rows and ``max_seq`` slots (nothing valid
+    yet), in one of three shapes:
 
-    ``quant="int8"`` stores codes + per-row scales (ops/quant.py): half the
-    HBM capacity and decode read bandwidth of a bf16 cache.
-
-    A latent-attention model's cache is ONE leaf ``{"kv": [L, B, S, 1,
-    kv_lora_rank + qk_rope_dim]}``, the sequence on axis 2 like every
-    other stack, so whatever maps over the tree (splice, compact, resize)
-    takes it unchanged. ``cfg.cache_width`` is the one place that says how
-    many values a token a layer holds.
+    * ``{"k", "v"}``, each ``[L, B, S, Hkv, dh]``; with ``quant="int8"`` each
+      a ``{"q8": codes [L, B, S, Hkv, dh], "s": scales [L, B, Hkv, S]}``
+      (ops/quant.py): half the HBM capacity and decode read bandwidth.
+    * a latent-attention model's ONE leaf ``{"kv": [L, B, S, 1,
+      kv_lora_rank + qk_rope_dim]}``, the sequence on axis 2 like every other
+      stack, so whatever maps over the tree (splice, compact, resize) takes
+      it unchanged. ``cfg.cache_width`` is the one place that says how many
+      values a token a layer holds.
+    * a state-space model's ``{"k", "v", "ssm": {"state": [L, B, H, P, N]
+      float32, "conv": [L, B, K-1, C]}}``: beside keys and values the
+      recurrent state and the convolution's tail of each ROW, which have no
+      sequence axis. They are told apart by their KEY (ops/quant.py
+      ``STATE_KEY``, ``kv_tree_map``), never by their rank.
     """
     s = max_seq or cfg.max_seq_len
+    if cfg.has_ssm:
+        if quant is not None:
+            raise ValueError(
+                f"{cfg.name}: no quantized cache for a state-space model: "
+                f"kv cache quant {quant!r} is not computed")
+        shape = (cfg.n_layers, batch, s, cfg.n_kv_heads, cfg.head_dim)
+        return {
+            "k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
+            STATE_KEY: {
+                "state": jnp.zeros(
+                    (cfg.n_layers, batch, cfg.ssm_heads, cfg.ssm_head_dim,
+                     cfg.ssm_state), jnp.float32),
+                "conv": jnp.zeros(
+                    (cfg.n_layers, batch, cfg.ssm_conv - 1,
+                     cfg.ssm_conv_width), dtype),
+            },
+        }
     if cfg.is_latent:
         if quant is not None:
             raise ValueError(
@@ -277,6 +343,8 @@ def embed_tokens(params: dict, cfg: ModelConfig, tokens: jax.Array) -> jax.Array
     x = params["embed"][tokens].astype(params["embed"].dtype)
     if cfg.embed_scale:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     return x
 
 
@@ -285,6 +353,8 @@ def unembed(params: dict, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     x = rms_norm(x, params["final_norm"], cfg.rms_eps, cfg.norm_offset)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = qeinsum("btd,dv->btv", x, head, preferred_element_type=jnp.float32)
+    if cfg.lm_head_multiplier != 1.0:
+        logits = logits * cfg.lm_head_multiplier
     if cfg.final_logit_softcap is not None:
         logits = cfg.final_logit_softcap * jnp.tanh(logits / cfg.final_logit_softcap)
     return logits
@@ -318,14 +388,24 @@ def _layer(
     moe_stats: bool = False,  # also return the expert layer's three sums
     expert_stacks=None,  # (w_gate, w_up, w_down) whole [L, E, ...] stacks and
                          # this layer's index in them, in place of lp's own
+    ssm=None,            # state-space model: the cache's FULL per-row state
+                         # stacks {"state", "conv"}, or None without a cache
+    ssm_span=None,       # (lo, hi) [B] each: a row's real positions in T
 ):
-    """One block. Returns ``(x, cache_k, cache_v)`` and, with
-    ``moe_stats`` on a routed stack, the expert layer's sums last."""
+    """One block. Returns ``(x, cache_k, cache_v)``; with ``moe_stats`` on a
+    routed stack the expert layer's sums follow, and for a state-space model
+    its updated ``ssm`` stacks come last."""
     routed = cfg.is_moe if routed is None else routed
     b, t, d = x.shape
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
     h = rms_norm(x, lp["attn_norm"], cfg.rms_eps, cfg.norm_offset)
+    if cfg.has_ssm:
+        # The mixer reads the same normed input as attention, which takes
+        # its own multiplier from here on.
+        mixed, ssm = _mixer_half(cfg, h, lp, ssm, layer_idx, ssm_span)
+        if cfg.attention_in_multiplier != 1.0:
+            h = h * cfg.attention_in_multiplier
     if cfg.is_latent:
         # The latent stack rides where the K stack does; there is no V.
         attn_out, cache_k = latent_attention(
@@ -343,6 +423,8 @@ def _layer(
     v = qeinsum("btd,dk->btk", h, lp["wv"])
     if cfg.qkv_bias:
         q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+    if cfg.key_multiplier != 1.0:
+        k = k * cfg.key_multiplier
     q = q.reshape(b, t, hq, dh)
     k = k.reshape(b, t, hkv, dh)
     v = v.reshape(b, t, hkv, dh)
@@ -559,12 +641,40 @@ def _layer(
             logit_softcap=cfg.attn_logit_softcap,
         )
         attn_out = merge_attention_states(o1, m1, l1, attn_out, m2, l2)
-    x = x + qeinsum("btk,kd->btd", attn_out.reshape(b, t, hq * dh), lp["wo"])
+    attn_out = qeinsum("btk,kd->btd", attn_out.reshape(b, t, hq * dh), lp["wo"])
+    if cfg.has_ssm:
+        attn_out = attn_out * cfg.attention_out_multiplier + mixed
+    x = x + attn_out
 
     if ring_mesh is not None:
         cache_k, cache_v = k, v  # fresh k/v for the caller's cache build
-    return _mlp_half(
+    out = _mlp_half(
         cfg, x, lp, routed, moe_stats, cache_k, cache_v, expert_stacks)
+    return (*out, ssm) if cfg.has_ssm else out
+
+
+def _mixer_half(cfg: ModelConfig, h, lp, ssm, layer_idx, span):
+    """The state-space mixer of one layer on the normed input ``h``: this
+    layer's state and tail come out of the cache's full stacks ``ssm`` and
+    go back in place (zeros and nothing kept without a cache)."""
+    b = h.shape[0]
+    lo, hi = span if span is not None else (None, None)
+    if ssm is None:
+        state = jnp.zeros(
+            (b, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32)
+        tail = jnp.zeros((b, cfg.ssm_conv - 1, cfg.ssm_conv_width), h.dtype)
+        return ssm_mixer(cfg, h, lp, state, tail, lo, hi)[0], None
+    out, state, tail = ssm_mixer(
+        cfg, h, lp,
+        jax.lax.dynamic_index_in_dim(ssm["state"], layer_idx, 0, keepdims=False),
+        jax.lax.dynamic_index_in_dim(ssm["conv"], layer_idx, 0, keepdims=False),
+        lo, hi)
+    return out, {
+        "state": jax.lax.dynamic_update_index_in_dim(
+            ssm["state"], state.astype(ssm["state"].dtype), layer_idx, 0),
+        "conv": jax.lax.dynamic_update_index_in_dim(
+            ssm["conv"], tail.astype(ssm["conv"].dtype), layer_idx, 0),
+    }
 
 
 def _latent_scale(cfg: ModelConfig) -> float:
@@ -585,7 +695,9 @@ def _mlp_half(cfg: ModelConfig, x, lp, routed: bool, moe_stats: bool,
     scan, the whole stacks with this layer's index."""
     h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps, cfg.norm_offset)
     if not routed:
-        mlp_out = gated_mlp(h, lp["w_gate"], lp["w_up"], lp["w_down"], cfg.activation)
+        mlp_out = gated_mlp(
+            h, lp["w_gate"], lp["w_up"], lp["w_down"], cfg.activation,
+            cfg.mlp_multipliers)
         return x + mlp_out, cache_k, cache_v
     *experts, layer = expert_stacks or (lp["w_gate"], lp["w_up"], lp["w_down"], None)
     out = moe_block(
@@ -620,8 +732,17 @@ def forward(
     prefix_rows: Optional[jax.Array] = None,  # [B] bool: rows attending prefix
     kv_mask: Optional[jax.Array] = None,  # [B, S] bool: written-slot bitmap
     moe_stats: bool = False,           # routed model: also return its sums
+    row_end: Optional[jax.Array] = None,  # [B]: slot after a row's last real token
 ):
     """Run the model. Returns (logits [B, T, V] fp32, updated cache).
+
+    ``row_end`` (with ``row_start``, in the same coordinates as
+    ``start_pos``) bounds each row's REAL tokens inside this call's T for a
+    state-space model, whose recurrent state one padded position would
+    corrupt: positions before ``row_start`` or from ``row_end`` on neither
+    advance the state nor enter the convolution's tail. ``None``: real to
+    the end of the call. Attention never needed it (junk past a row's end is
+    masked later or overwritten), and other models take no notice.
 
     ``moe_stats=True`` on a routed model returns a third value, int32[3]:
     over this call's expert layers the (token, chosen expert) pairs in all,
@@ -658,6 +779,8 @@ def forward(
     """
     if cfg.is_latent:
         _refuse_latent(cfg, attn_impl, mesh, prefix, kv_mask)
+    if cfg.has_ssm:
+        _refuse_ssm(cfg, attn_impl, mesh, prefix, kv_mask)
     if attn_impl == "ring":
         if cache is None or mesh is None or not (
             isinstance(start_pos, int) and start_pos == 0
@@ -872,9 +995,17 @@ def forward(
             dh=cfg.head_dim, kv_item=k_store.dtype.itemsize,
             quantized=decode_quantized, sliding_window=cfg.sliding_window,
         )
+    ssm_span = None
+    if cfg.has_ssm and (row_start is not None or row_end is not None):
+        # Each row's real positions [lo, hi) inside this call's T.
+        lo = jnp.zeros((b,), jnp.int32) if row_start is None else jnp.clip(
+            row_start - start, 0, t)
+        hi = jnp.full((b,), t, jnp.int32) if row_end is None else jnp.clip(
+            row_end - start, 0, t)
+        ssm_span = (lo, jnp.maximum(hi, lo))
     layer_fn = partial(
         _layer, cfg, flash_offset=flash_offset, flash_mesh=flash_mesh,
-        kv_width=kv_width, qkv_pin=qkv_pin,
+        kv_width=kv_width, qkv_pin=qkv_pin, ssm_span=ssm_span,
         decode_flash=decode_flash, row_start=row_start, decode_sweep=sweep,
         prefix_k=prefix["k"] if prefix is not None else None,
         prefix_v=prefix["v"] if prefix is not None else None,
@@ -900,24 +1031,28 @@ def forward(
         return ({k: v for k, v in stack.items() if k not in whole},
                 tuple(stack[k] for k in whole))
 
-    def block(x, lp, experts, at, stats, *cache_args, **kw):
+    def block(x, lp, experts, at, stats, cs, *cache_args, **kw):
         routed = experts is not None
         out = layer_fn(
             x, lp, cos, sin, mask, *cache_args, routed=routed,
             moe_stats=moe_stats and routed,
-            expert_stacks=(*experts, at) if routed else None, **kw)
+            expert_stacks=(*experts, at) if routed else None, ssm=cs, **kw)
         if moe_stats and routed:
             stats = stats + out[3]
-        return (*out[:3], stats)
+        if cfg.has_ssm:
+            cs = out[-1]
+        return (*out[:3], stats, cs)
 
     # The cache rides the scan CARRY (full stacks, in-place row writes), not
     # xs/ys: the xs→ys form makes XLA materialize a fresh copy of both
     # stacks every outer decode step. A latent model's one stack rides
     # where K does; without a cache both places hold nothing.
+    # A state-space model's per-row state stacks ride it beside them.
     if cache is None:
-        ck = cv = at = None
+        ck = cv = cs = at = None
     else:
         ck, cv = (cache["kv"], None) if cfg.is_latent else (cache["k"], cache["v"])
+        cs = cache.get(STATE_KEY)
         at = start
     li = jnp.asarray(0, jnp.int32)  # the layer, counted over both stacks
     for stack, routed in stacks:
@@ -925,19 +1060,21 @@ def forward(
         first = li  # this stack's first layer
 
         def scan_body(carry, lp, experts=experts, first=first):
-            x, ck, cv, li, stats = carry
-            x, ck, cv, stats = block(
-                x, lp, experts, li - first, stats, ck, cv, at, layer_idx=li)
-            return (x, ck, cv, li + 1, stats), None
+            x, ck, cv, li, stats, cs = carry
+            x, ck, cv, stats, cs = block(
+                x, lp, experts, li - first, stats, cs, ck, cv, at, layer_idx=li)
+            return (x, ck, cv, li + 1, stats, cs), None
 
         if remat and cache is None:
             scan_body = jax.checkpoint(scan_body)
-        (x, ck, cv, li, stats), _ = jax.lax.scan(
-            scan_body, (x, ck, cv, li, stats), xs)
+        (x, ck, cv, li, stats, cs), _ = jax.lax.scan(
+            scan_body, (x, ck, cv, li, stats, cs), xs)
     if cache is None:
         new_cache = None
     else:
         new_cache = {"kv": ck} if cfg.is_latent else {"k": ck, "v": cv}
+        if cs is not None:
+            new_cache[STATE_KEY] = cs
 
     if logits_index is not None:
         # Prefill only samples one position; unembedding every position
@@ -990,6 +1127,36 @@ def _refuse_latent(cfg: ModelConfig, attn_impl: str, mesh, prefix,
             f"{cfg.name}: no speculative decoding (written-slot bitmap) over "
             "a latent (MLA) cache")
     refuse_latent_mesh(cfg, mesh)
+
+
+def _refuse_ssm(cfg: ModelConfig, attn_impl: str, mesh, prefix,
+                kv_mask) -> None:
+    """What a state-space model does not get yet is refused by name, not
+    computed wrongly: its state exists only at the length it was saved at."""
+    if attn_impl == "ring":
+        raise ValueError(
+            f"{cfg.name}: no sequence-parallel (ring) prefill of a "
+            "state-space model: the scan is not split over chips")
+    if prefix is not None:
+        raise ValueError(
+            f"{cfg.name}: no shared-prefix attention for a state-space model: "
+            "a shared prefix has keys and values and no state to start from")
+    if kv_mask is not None:
+        raise ValueError(
+            f"{cfg.name}: no speculative decoding (written-slot bitmap) of a "
+            "state-space model: a rejected position cannot be taken back out "
+            "of the state")
+    refuse_ssm_mesh(cfg, mesh)
+
+
+def refuse_ssm_mesh(cfg: ModelConfig, mesh) -> None:
+    """A state-space model is not sharded yet: ``forward`` and the engine's
+    constructor both refuse a mesh that would split it."""
+    if mesh is not None and any(
+            dict(mesh.shape).get(ax, 1) > 1 for ax in ("tp", "ep", "sp")):
+        raise ValueError(
+            f"{cfg.name}: a state-space model runs on one chip: a mesh with "
+            f"tp, ep or sp > 1 is not computed, got {dict(mesh.shape)}")
 
 
 def refuse_latent_mesh(cfg: ModelConfig, mesh) -> None:

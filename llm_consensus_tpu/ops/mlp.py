@@ -28,7 +28,13 @@ def gated_mlp(
     w_up: jax.Array,     # [D, F]
     w_down: jax.Array,   # [F, D]
     activation: str = "silu",
+    multipliers: tuple[float, float] = (1.0, 1.0),  # on the gate, on the output
 ) -> jax.Array:
-    gate = _activate(qeinsum("...d,df->...f", x, w_gate), activation)
+    gate_mult, down_mult = multipliers
+    gate = qeinsum("...d,df->...f", x, w_gate)
+    if gate_mult != 1.0:
+        gate = gate * gate_mult
+    gate = _activate(gate, activation)
     up = qeinsum("...d,df->...f", x, w_up)
-    return qeinsum("...f,fd->...d", gate * up, w_down)
+    out = qeinsum("...f,fd->...d", gate * up, w_down)
+    return out if down_mult == 1.0 else out * down_mult

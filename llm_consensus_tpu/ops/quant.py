@@ -66,7 +66,9 @@ from llm_consensus_tpu.utils import knobs
 QUANT_KEYS = frozenset(
     {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "lm_head",
      # latent attention's projections and the shared experts
-     "wq_a", "wq_b", "wkv_a", "wkv_b", "ws_gate", "ws_up", "ws_down"}
+     "wq_a", "wq_b", "wkv_a", "wkv_b", "ws_gate", "ws_up", "ws_down",
+     # a state-space mixer's two projections
+     "ssm_in", "ssm_out"}
 )
 
 
@@ -240,13 +242,39 @@ def quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     return q8.astype(jnp.int8), scale.astype(x.dtype)
 
 
+# The key of a cache's sub-tree of per-ROW state (a state-space model's
+# recurrent state [L, B, H, P, N] and convolution tail [L, B, K-1, C]):
+# leaves with layers on axis 0 and rows on axis 1 like every other, and NO
+# sequence axis. They are told from the slot leaves by this key, never by
+# their rank (a state is 5-D like a K stack, a tail 4-D like a scale stack).
+STATE_KEY = "ssm"
+
+
 def kv_seq_axis(leaf) -> int:
-    """Seq axis of a stacked-cache leaf: 2 for the 5-D [L, B, S, H, dh]
+    """Seq axis of a stacked-cache SLOT leaf: 2 for the 5-D [L, B, S, H, dh]
     code/bf16 stacks, 3 (minor) for the 4-D seq-minor [L, B, H, S] int8
     scale stacks. This module owns the cache layout — every consumer that
     slices/rolls/masks along seq (batcher splice/compact, engine prefix
-    restore) must route through this rule rather than re-encode it."""
+    restore) must route through this rule rather than re-encode it, and
+    reaches the leaves through ``kv_tree_map``, which keeps the per-row
+    state leaves (``STATE_KEY``: no sequence axis at all) away from it."""
     return 2 if leaf.ndim == 5 else 3
+
+
+def kv_tree_map(slots, cache, *rest, state=None):
+    """``jax.tree.map`` over a cache tree, by kind of leaf: ``slots`` over
+    the leaves that have a sequence axis (``kv_seq_axis`` says which), and
+    ``state`` over the per-row state leaves under ``STATE_KEY``, which have
+    none. ``state=None`` keeps the first tree's state leaves as they are
+    (what a slide or a mask along the sequence means for them). ``rest``
+    are further trees of the same structure."""
+    out = jax.tree.map(
+        slots, *({k: v for k, v in t.items() if k != STATE_KEY}
+                 for t in (cache, *rest)))
+    if STATE_KEY in cache:
+        out[STATE_KEY] = cache[STATE_KEY] if state is None else jax.tree.map(
+            state, *(t[STATE_KEY] for t in (cache, *rest)))
+    return out
 
 
 def kv_write_rows(full, x: jax.Array, layer_idx, start_pos):

@@ -81,6 +81,18 @@ def param_specs(cfg: ModelConfig, mesh: Optional[Mesh] = None) -> dict:
                 "wv": P(None, None, tp_kv),
                 "wo": P(None, tp_q, None),
             })
+        if cfg.has_ssm:
+            # A state-space model runs on one chip (a mesh with tp > 1 is
+            # refused when its engine is built): the mixer's leaves whole.
+            layers.update({
+                name: P(None, None, None)
+                for name in ("ssm_in", "ssm_conv", "ssm_out")
+            })
+            layers.update({
+                name: P(None, None) for name in (
+                    "ssm_conv_bias", "ssm_dt_bias", "ssm_a_log", "ssm_d",
+                    "ssm_norm")
+            })
         if cfg.qkv_bias:
             layers["bq"] = P(None, tp_q)
             layers["bk"] = P(None, tp_kv)
@@ -160,13 +172,23 @@ def cache_specs(cfg: ModelConfig, mesh: Optional[Mesh] = None, batch: int = 1) -
     KV heads shard with the attention TP split; batch shards over dp when
     it divides (decode streams are batch=1, so dp stays replicated there).
     A latent model's one leaf ``kv`` has a single shared head: never split.
+    A state-space model's per-row state leaves (``STATE_KEY``) split over
+    rows alone.
     """
+    from llm_consensus_tpu.ops.quant import STATE_KEY
+
     dp = _axis(mesh, "dp", batch)
     if cfg.is_latent:
         return {"kv": P(None, dp, None, None, None)}
     tp_kv = _axis(mesh, "tp", cfg.n_kv_heads)
     spec = P(None, dp, None, tp_kv, None)
-    return {"k": spec, "v": spec}
+    specs = {"k": spec, "v": spec}
+    if cfg.has_ssm:
+        specs[STATE_KEY] = {
+            "state": P(None, dp, None, None, None),
+            "conv": P(None, dp, None, None),
+        }
+    return specs
 
 
 def abstract_param_bytes(cfg: ModelConfig, mesh: Mesh) -> tuple[int, int]:
@@ -229,15 +251,18 @@ def cache_shardings(cfg: ModelConfig, mesh: Mesh, cache) -> dict:
     on axis 2), so their tp split moves with the head axis. Layout
     discrimination routes through ops.quant.kv_seq_axis, the rule's
     single owner."""
-    from llm_consensus_tpu.ops.quant import kv_seq_axis
+    from llm_consensus_tpu.ops.quant import kv_seq_axis, kv_tree_map
 
     k_spec = next(iter(cache_specs(cfg, mesh).values()))
     s_spec = P(k_spec[0], k_spec[1], k_spec[3], k_spec[2])
-    return jax.tree.map(
+    return kv_tree_map(
         lambda leaf: NamedSharding(
             mesh, k_spec if kv_seq_axis(leaf) == 2 else s_spec
         ),
         cache,
+        # per-row state: layers, rows (as the slot leaves split them), whole
+        state=lambda leaf: NamedSharding(
+            mesh, P(k_spec[0], k_spec[1], *(None,) * (leaf.ndim - 2))),
     )
 
 
@@ -245,14 +270,14 @@ def make_shard_fn(cfg: ModelConfig, mesh: Mesh) -> Callable:
     """Shard fn for ``engine.Engine(shard_fn=...)``.
 
     Dispatches on pytree shape: the params tree (has ``embed``) gets
-    ``param_specs``, the KV cache (``k``/``v``, or a latent model's ``kv``)
-    gets ``cache_specs``.
+    ``param_specs``, the KV cache (``k``/``v``, or a latent model's ``kv``;
+    a state-space model's state leaves beside them) gets ``cache_specs``.
     """
 
     def shard(tree):
         if isinstance(tree, dict) and "embed" in tree:
             return shard_pytree(tree, param_specs(cfg, mesh), mesh)
-        if isinstance(tree, dict) and set(tree) in ({"k", "v"}, {"kv"}):
+        if isinstance(tree, dict) and {"k", "v", "kv"} & set(tree):
             return jax.tree.map(
                 jax.device_put, tree, cache_shardings(cfg, mesh, tree)
             )

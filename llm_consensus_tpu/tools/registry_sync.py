@@ -198,6 +198,9 @@ def fetch_local_models() -> list[ModelRecord]:
                     "router_width": cfg.n_router,
                     "latent_attention": cfg.is_latent,
                     "cache_width": cfg.cache_width,
+                    # a state-space model: what a ROW costs beside its slots
+                    "state_space": cfg.has_ssm,
+                    "state_bytes_per_row": cfg.state_bytes_per_row,
                 },
             )
         )

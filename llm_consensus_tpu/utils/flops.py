@@ -40,6 +40,13 @@ def param_count(cfg: ModelConfig, active_only: bool = False) -> int:
         attn = q + kv + o
     if cfg.qkv_bias:
         attn += (cfg.n_heads + 2 * cfg.n_kv_heads) * dh
+    if cfg.has_ssm:
+        # the mixer beside attention: in-projection, depthwise convolution
+        # with its bias, dt_bias / A_log / D a head, gated norm, out-projection
+        attn += (
+            d * cfg.ssm_proj_width + cfg.ssm_conv_width * (cfg.ssm_conv + 1)
+            + 3 * cfg.ssm_heads + cfg.ssm_inner + cfg.ssm_inner * d
+        )
     dense_mlp = 3 * d * cfg.d_ff  # gate + up + down
     norms = 2 * d
     n_dense = cfg.n_layers - cfg.n_expert_layers
@@ -66,6 +73,18 @@ def cache_bytes_per_token(cfg: ModelConfig, itemsize: int = 2) -> int:
     return cfg.n_layers * cfg.cache_width * itemsize
 
 
+def state_bytes_per_row(cfg: ModelConfig, itemsize: int = 2) -> int:
+    """Bytes one ROW of a state-space model holds beside its keys and
+    values, over every layer and whatever its context: the float32 state
+    and the convolution tail (``itemsize`` bytes a value). 0 without a
+    mixer. What ``cache_bytes_per_token`` is to a slot, this is to a row."""
+    if not cfg.has_ssm:
+        return 0
+    state = cfg.ssm_inner * cfg.ssm_state * 4
+    tail = cfg.ssm_conv_width * (cfg.ssm_conv - 1) * itemsize
+    return cfg.n_layers * (state + tail)
+
+
 def flops_per_token(cfg: ModelConfig, context_len: int = 0) -> float:
     """Forward-pass FLOPs for one token at the given KV-cache depth.
 
@@ -86,7 +105,10 @@ def flops_per_token(cfg: ModelConfig, context_len: int = 0) -> float:
         else 2 * cfg.head_dim
     )
     attn_quad = 2 * cfg.n_layers * cfg.n_heads * swept * max(0, context_len)
-    return 2.0 * weights + float(attn_quad)
+    # a mixer's recurrence a token: decay and update the state (3 a value),
+    # read it out (2 a value); constant in the context
+    scan = 5 * cfg.n_layers * cfg.ssm_inner * cfg.ssm_state
+    return 2.0 * weights + float(attn_quad + scan)
 
 
 class UnknownDeviceError(LookupError):
@@ -205,15 +227,19 @@ def decode_bytes_per_token(
     context_len: int = 0,
     weight_bytes: int = 2,
     kv_bytes: int = 2,
+    rows: int = 1,
 ) -> float:
     """HBM bytes streamed per decode step: active weights + the KV read.
 
     ``weight_bytes``/``kv_bytes`` are the storage widths (2 = bf16,
-    1 = int8 quantized).
+    1 = int8 quantized). A state-space model's recurrent state and
+    convolution tail are read and written once a step by each of the
+    step's ``rows``, whatever the context.
     """
     weights = param_count(cfg, active_only=True)
     kv = cache_bytes_per_token(cfg, kv_bytes) * max(0, context_len)
-    return float(weights * weight_bytes + kv)
+    state = 2 * rows * state_bytes_per_row(cfg, kv_bytes)
+    return float(weights * weight_bytes + kv + state)
 
 
 def decode_mbu(
@@ -258,6 +284,6 @@ def batched_decode_mbu(
     # effective context of batch·context_len (the KV term is linear) —
     # one bytes model serves both the single-stream and batched MBU.
     per_step = decode_bytes_per_token(
-        cfg, batch * context_len, weight_bytes, kv_bytes
+        cfg, batch * context_len, weight_bytes, kv_bytes, rows=batch
     )
     return (tokens_per_sec / batch) * per_step / (peak * n_devices)

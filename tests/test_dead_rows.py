@@ -77,24 +77,46 @@ def test_one_stream_in_a_pool_of_six_through_retire_and_reuse(engine):
 def test_dead_rows_across_a_compaction_shift(engine):
     """Two staggered streams of six rows push the shared frontier past
     capacity: the window slide moves every row_start, the dead rows' mark
-    with it, and the streams after it are still exact."""
+    with it, and the streams after it are still exact.
+
+    The schedule is made certain: every yardstick is generated BEFORE the
+    pool starts, so nothing but a ``submit`` stands between one stream's
+    ``result()`` and the next stream's start, while the stream beside it has
+    thirty tokens to go. (Generating the yardstick in between took longer
+    than those thirty tokens whenever the workers shared their cores
+    unkindly: the pool idled, the frontier was reset, the next two streams
+    started together, and neither the slide nor the 256 steps the assertion
+    counted on came about: 240 steps and no slide, PR 34.) And what is
+    asserted is the slide itself, not a count of steps that depends on how
+    the streams overlapped."""
     s = SamplingParams(max_new_tokens=60, ignore_eos=True)
     s_head = SamplingParams(max_new_tokens=30, ignore_eos=True)
     prompts = [f"staggered stream {i} of the slide" for i in range(8)]
+    wants = [engine.generate(p, s_head if i == 0 else s).token_ids
+             for i, p in enumerate(prompts)]
     b = ContinuousBatcher(engine, max_batch=6)
+    slides = []
+    compact = b._compact
+
+    def spy():
+        before = b._pos
+        compact()
+        slides.append((before, b._pos))
+
+    b._compact = spy
     try:
         futs = {0: b.submit(prompts[0], s_head), 1: b.submit(prompts[1], s)}
         nxt = 2
         while futs:
             i = min(futs)
             r = futs.pop(i).result(timeout=600)
-            want = engine.generate(prompts[i], s_head if i == 0 else s)
-            assert r.token_ids == want.token_ids, prompts[i]
+            assert r.token_ids == wants[i], prompts[i]
             if nxt < len(prompts):  # the pool never idles: no frontier reset
                 futs[nxt] = b.submit(prompts[nxt], s)
                 nxt += 1
-        # 8 x ~60 tokens through a 256-slot cache: it did compact.
-        assert b.stats["decode_steps"] > engine.max_seq
+        # 30 + 7 x 60 tokens, two at a time, behind ~33-token prompts in a
+        # 256-slot cache: the frontier reached capacity and slid back.
+        assert any(after < before for before, after in slides), slides
     finally:
         b.close()
 
