@@ -18,7 +18,8 @@ With ``h`` the normed hidden state of one token:
 
 Both forms are plain einsums (XLA); the mask is the one every XLA route
 uses (causality, the frontier, ``row_start``, a dead row's mark). The decode
-form sweeps the ``kv_width`` bucket of the cache. The prefill form sweeps a
+form writes its one token a row (``_write_token``) and sweeps the ``kv_width``
+bucket of the cache from where the pool lies. The prefill form sweeps a
 static width chosen at run time by the chunk's frontier (``prefill_sweep``):
 one program serves every chunk of a prompt at a traced start, so it holds a
 branch for T, 2T, ... up to the bucket, and a chunk runs the narrowest that
@@ -68,6 +69,22 @@ def prefill_sweep_width(t: int, kv_width: int, end: int) -> int:
     return widths[min(at, len(widths) - 1)]
 
 
+def _write_token(cache: jax.Array, latent: jax.Array, layer_idx, pos):
+    """One token's latents ``[B, rank + rope]`` into the full stack at
+    (``layer_idx``, every row, slot ``pos``), in place, ONE WRITE A ROW. A
+    single ``[1, B, 1, 1, C]`` update has rows and latent as its two real
+    axes, and the chip's layout assignment then lays the whole POOL rows
+    second-minor around it, against the sweeps, which want slots there:
+    the layer loop and the leading dense layer disagreed and the pool was
+    copied twice a decode step. A row's update has one real axis and leaves
+    the pool as the sweeps read it (tests/test_tpu_compile.py holds that)."""
+    for row in range(latent.shape[0]):
+        cache = jax.lax.dynamic_update_slice(
+            cache, latent[row][None, None, None, None, :].astype(cache.dtype),
+            (layer_idx, row, pos, 0, 0))
+    return cache
+
+
 def latent_attention(
     h: jax.Array,               # [B, T, D], already normed
     lp: dict,                   # wq_a, q_norm, wq_b, wkv_a, kv_norm, wkv_b
@@ -92,7 +109,13 @@ def latent_attention(
     call's latents written at (``layer_idx``, ``start_pos``)."""
     b, t, _ = h.shape
     c_q = rms_norm(qeinsum("btd,dr->btr", h, lp["wq_a"]), lp["q_norm"], rms_eps)
-    q = qeinsum("btr,rk->btk", c_q, lp["wq_b"]).reshape(
+    # The barrier stands between the product and its split into heads: the
+    # chip's layout assignment otherwise carries the split back onto the
+    # weight and relays the layer's whole ``wq_b`` before every product
+    # (75 MB a layer a decode step at DeepSeek-V2's widths) where a few
+    # activations would do. It changes no value.
+    q = jax.lax.optimization_barrier(
+        qeinsum("btr,rk->btk", c_q, lp["wq_b"])).reshape(
         b, t, n_heads, qk_nope_dim + qk_rope_dim)
     q_nope = q[..., :qk_nope_dim]
     q_rope = apply_rope(q[..., qk_nope_dim:], cos, sin)
@@ -102,7 +125,9 @@ def latent_attention(
     k_rope = apply_rope(ckr[..., None, kv_lora_rank:], cos, sin)[:, :, 0]
     latent = jnp.concatenate([c_kv, k_rope], axis=-1)        # [B, T, rank+rope]
     if cache is not None:
-        cache = kv_write_rows(cache, latent[:, :, None, :], layer_idx, start_pos)
+        cache = (
+            _write_token(cache, latent[:, 0], layer_idx, start_pos) if t == 1
+            else kv_write_rows(cache, latent[:, :, None, :], layer_idx, start_pos))
 
     w_kvb = dequantize(lp["wkv_b"], h.dtype).reshape(
         kv_lora_rank, n_heads, qk_nope_dim + v_head_dim)

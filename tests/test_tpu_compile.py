@@ -18,7 +18,11 @@ the lanes of the scale operand).
 The DeepSeek-V2 cell's judge-prompt prefill (``_prefill_chunks_loop`` of
 ``benchmark/configs/deepseek-v2-ep8-trio-bf16.json``'s cut: four 512-token
 chunks of a 2,048-slot bucket) is compiled whole, and its attention must
-stay a ``conditional`` with a branch a width.
+stay a ``conditional`` with a branch a width. Its 16-step decode chunk
+(``_decode_chunk``: six rows, the 4,096-slot pool, the sentinel and the
+routing sums, at 384 and 2,048 slots) is compiled whole too, and what its
+text MATERIALISES is held: no copy of the pool inside a step, no pass over a
+layer's ``wq_b``, the weight stacks read where they lie (PR 35).
 
 All cases compile in ONE child process (this file run as a script) and
 the tests read its report: loading libtpu and switching the persistent
@@ -68,6 +72,8 @@ STEP_CASES = {
 }
 LATENT_CONFIG = "benchmark/configs/deepseek-v2-ep8-trio-bf16.json"
 LATENT_CHUNK, LATENT_BUCKET = 512, 2048  # the judge prompt's program
+LATENT_ROWS, LATENT_STEPS = 6, 16        # the judge pool's decode chunk
+LATENT_DECODE_WIDTHS = (384, 2048)       # a panel phase's bucket, a judge phase's
 HYBRID_CONFIG = "benchmark/configs/falcon-h1-34b-pp8-trio-bf16.json"
 
 
@@ -185,6 +191,9 @@ def _compile_all() -> dict:
             "routes": attention_routes.snapshot(preset),
         }
     report["latent-prefill-loop"] = _latent_prefill_branches(sds, shapes)
+    for width in LATENT_DECODE_WIDTHS:
+        report[f"latent-decode:kv{width}"] = _latent_decode_chunk(
+            sds, shapes, width)
     report["hybrid-ssm"] = _hybrid_ssm_programs(sds, shapes, has_kernel)
     return report
 
@@ -228,6 +237,114 @@ def _hybrid_ssm_programs(sds, shapes, has_kernel) -> dict:
     }
 
 
+def _computations(text: str) -> dict:
+    """``{name: (is the entry, body text)}`` of a compiled module's text."""
+    import re
+
+    return {
+        m.group(2): (bool(m.group(1)), m.group(3)) for m in re.finditer(
+            r"^(ENTRY )?%?([\w.\-]+) \(.*?\) -> .*? \{\n(.*?)^\}", text, re.S | re.M)
+    }
+
+
+def _latent_judge():
+    """The DeepSeek-V2 cell's judge as its configuration file states it."""
+    from benchmark import server
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, LATENT_CONFIG)) as f:
+        doc = json.load(f)
+    return server.model_config(doc["judge"], doc["models"][doc["judge"]])
+
+
+def _latent_decode_chunk(sds, shapes, width: int) -> dict:
+    """What the DeepSeek-V2 cell's decode chunk at ``width`` slots
+    materialises, read off its compiled text. Of the TOP-LEVEL instructions
+    (not inside a fusion) of the loop bodies, the step's and the expert
+    layers': ``pool`` the operations that produce an array of the pool's
+    size other than the in-place write (a ``dynamic-update-slice``, bare or
+    as a fusion's root); ``wq_b`` and ``wkv_b`` those that produce a
+    layer's whole ``wq_b`` / ``wkv_b``, each with its layout (a prefetch
+    keeps the stored one, ``{2,1,0}``). Of the entry computation:
+    ``entry_copy_mb`` its copies (bf16), by the leaf of that shape.
+    ``scores`` the types of the products shaped rows x heads x slots;
+    ``routes`` what ``forward`` booked."""
+    import math
+    import re
+
+    import jax
+
+    from llm_consensus_tpu.engine.engine import _decode_chunk
+    from llm_consensus_tpu.models import init_kv_cache, init_params
+    from llm_consensus_tpu.models.transformer import attention_routes
+
+    cfg = _latent_judge()
+    attention_routes.reset()
+    params = shapes(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    cache = shapes(lambda: init_kv_cache(cfg, LATENT_ROWS, CELL_MAX_SEQ))
+    leaves: dict = {}  # dims as the text prints them -> the leaves so shaped
+    for path, leaf in jax.tree_util.tree_leaves_with_path(
+            {"params": params, "cache": cache}):
+        leaves.setdefault(",".join(map(str, leaf.shape)), []).append(
+            "/".join(str(getattr(k, "key", k)) for k in path))
+    try:
+        text = _decode_chunk.lower(
+            params, cfg, sds((LATENT_ROWS,)), sds(()), cache,
+            shapes(lambda: jax.random.PRNGKey(0)), n_steps=LATENT_STEPS,
+            temperature=0.0, top_k=None, top_p=None,
+            row_start=sds((LATENT_ROWS,)), kv_width=width, attn_impl="flash",
+            sentinel=True, moe_stats=True,
+        ).compile().as_text()
+    except Exception as err:  # noqa: BLE001 — what the chip would raise
+        return {"error": f"{type(err).__name__}: {str(err)[:300]}"}
+    computations = _computations(text)
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
+    writes_in_place = {
+        name for name, (_, body) in computations.items()
+        if re.search(r"^\s*ROOT \S+ = \S+ dynamic-update-slice\(", body, re.M)
+    }
+    produced = re.compile(
+        r"^\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\](\{[\d,]*)\S* ([\w\-]+)\((.*)$",
+        re.M)
+    sizes = {
+        "pool": cfg.n_layers * LATENT_ROWS * CELL_MAX_SEQ * cfg.cache_width,
+        "wq_b": cfg.q_lora_rank * cfg.n_heads * (cfg.qk_nope_dim + cfg.qk_rope_dim),
+        "wkv_b": cfg.kv_lora_rank * cfg.n_heads * (cfg.qk_nope_dim + cfg.v_head_dim),
+    }
+    report: dict = {what: [] for what in sizes}
+    entry_copies: dict = {}
+    for name, (is_entry, body) in computations.items():
+        if not is_entry and name not in bodies:
+            continue
+        for dims, layout, op, rest in produced.findall(body):
+            count = math.prod(int(d) for d in dims.split(","))
+            if is_entry:
+                if op == "copy":
+                    what = "|".join(leaves.get(dims, [dims]))
+                    entry_copies[what] = entry_copies.get(what, 0) + count * 2 / 1e6
+                continue
+            if op in ("parameter", "get-tuple-element", "bitcast", "while"):
+                continue
+            in_place = op == "dynamic-update-slice" or (
+                op == "fusion" and re.search(r"calls=%?([\w.\-]+)", rest).group(1)
+                in writes_in_place)
+            for what, size in sizes.items():
+                if count == size and not (what == "pool" and in_place):
+                    report[what].append(f"{op}{layout}}}")
+    scores = {
+        dtype for dtype, dims in re.findall(
+            r"= (\w+)\[([\d,]+)\]\S* convolution\(", text)
+        if sorted(int(d) for d in dims.split(",") if d != "1")
+        == sorted((LATENT_ROWS, cfg.n_heads, width))
+    }
+    return {
+        **{what: sorted(found) for what, found in report.items()},
+        "entry_copy_mb": {k: round(v, 1) for k, v in sorted(entry_copies.items())},
+        "scores": sorted(scores),
+        "routes": attention_routes.snapshot(cfg.name),
+    }
+
+
 def _latent_prefill_branches(sds, shapes) -> dict:
     """The widths of the float32 score blocks in each branch of each
     ``conditional`` of the compiled judge-prompt prefill, a list a
@@ -236,15 +353,10 @@ def _latent_prefill_branches(sds, shapes) -> dict:
 
     import jax
 
-    from benchmark import server
     from llm_consensus_tpu.engine.engine import _prefill_chunks_loop
     from llm_consensus_tpu.models import init_kv_cache, init_params
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, LATENT_CONFIG)) as f:
-        doc = json.load(f)
-    judge = doc["judge"]
-    cfg = server.model_config(judge, doc["models"][judge])
+    cfg = _latent_judge()
     chunks = LATENT_BUCKET // LATENT_CHUNK
     try:
         text = _prefill_chunks_loop.lower(
@@ -255,10 +367,7 @@ def _latent_prefill_branches(sds, shapes) -> dict:
         ).compile().as_text()
     except Exception as err:  # noqa: BLE001 — what the chip would raise
         return {"error": f"{type(err).__name__}: {str(err)[:300]}"}
-    bodies = {
-        m.group(1): m.group(2) for m in re.finditer(
-            r"^%?([\w.\-]+) \(.*?\) -> .*? \{\n(.*?)^\}", text, re.S | re.M)
-    }
+    bodies = {name: body for name, (_, body) in _computations(text).items()}
     scores = re.compile(rf"f32\[1,{cfg.n_heads},{LATENT_CHUNK},(\d+)\]")
     return {"conditionals": [
         [sorted({int(w) for w in scores.findall(bodies[name.strip().lstrip("%")])})
@@ -352,6 +461,54 @@ def test_latent_prefill_loop_holds_a_branch_a_width(report):
     does, on the CPU)."""
     assert report["latent-prefill-loop"] == {
         "conditionals": [[[512], [1024], [1536], [2048]]] * 4}
+
+
+LATENT_DECODE_HOLDS = ("pool", "weights", "entry", "scores-and-route")
+
+
+@pytest.mark.parametrize("width", LATENT_DECODE_WIDTHS)
+@pytest.mark.parametrize("held", LATENT_DECODE_HOLDS)
+def test_latent_decode_chunk_reads_its_operands_where_they_lie(report, width, held):
+    """The DeepSeek-V2 cell's decode chunk, compiled for the described chip,
+    consumes what it is given where it lies (PR 35; the parent's program
+    failed the first three at both widths). This guards the program's SHAPE;
+    the times are the chip's (PERF.md section 5).
+
+    ``pool``: inside a step nothing produces an array of the pool's size
+    but the in-place one-token writes: the dense layer and the expert
+    layers' loop keep the pool in ONE layout (the parent: two copies a
+    step, 1.49 ms of a 7.9 ms step on the chip).
+
+    ``weights``: no layer's ``wq_b`` gets a pass of its own; what is left
+    of that size is the dense layer's prefetch in the stored layout. Of
+    ``wkv_b`` one pass an expert layer into the compiler's fast memory stays
+    (its two products are batched over heads and the stack is stored
+    ``[c, h, d]``): on the chip it streams at the read's own rate (33.6 MB
+    in 0.045 ms, the two products after it 0.009 ms), which the issue
+    takes as no cost, so that half of the case is dropped.
+
+    ``entry``: nothing of ``wq_b`` is transposed a chunk. NOT the issue's
+    16 MB: the pool's device layout as a parameter is slots-minor (576 is
+    no multiple of the 128 lanes) while every product wants the latent
+    minor, so the pool is relaid once in and once out (2 x 170 MB, 0.07 ms
+    a step on the chip), and ``wkv_b``'s stacks are laid head-major for
+    the pass above (201 MB); ``wkv_a`` and the router are 576 and 160
+    wide. Only another leaf shape removes those (ROADMAP Queue 1 #11).
+
+    ``scores-and-route``: float32 scores, booked as ``xla_latent_absorbed``."""
+    got = report[f"latent-decode:kv{width}"]
+    if held == "pool":
+        assert got["pool"] == []
+    elif held == "weights":
+        assert set(got["wq_b"]) <= {"copy-done{2,1,0}"}
+        assert got["wkv_b"].count("fusion{1,2,0}") <= 1
+    elif held == "entry":
+        copies = got["entry_copy_mb"]
+        assert not [name for name in copies if name.endswith("/wq_b")]
+        assert sum(copies.values()) < 600  # the parent: 1,032
+    else:
+        assert got["scores"] == ["f32"]
+        assert got["routes"] == {"decode": {"xla_latent_absorbed": 1}}
 
 
 def test_hybrid_ssm_programs_compile(report):
