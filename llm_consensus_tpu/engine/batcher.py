@@ -81,6 +81,7 @@ from llm_consensus_tpu.engine.speculative import (
 from llm_consensus_tpu.engine.tokenizer import StreamDecoder
 from llm_consensus_tpu.obs.attrib import tag as _attrib_tag
 from llm_consensus_tpu.obs import roofline as _roofline
+from llm_consensus_tpu.obs.scopes import scope
 from llm_consensus_tpu.ops.quant import kv_seq_axis as _seq_axis
 from llm_consensus_tpu.ops.quant import kv_tree_map as _kv_tree_map
 from llm_consensus_tpu.ops.sampling import sample_token
@@ -146,9 +147,13 @@ class _Stream:
     # ``time.monotonic_ns``, taken from the spans that carried it:
     # ``admit_ns`` (start of the first pool.admit wave holding it),
     # ``first_token_ns`` (start of the pool.emit that handed out its
-    # first token) and ``first_chunk_ns`` (its first text pushed to
-    # ``on_text``). They ride GenerateResult.marks to the serving tier's
-    # per-run ``timings``.
+    # first token), ``first_chunk_ns`` (its first text pushed to
+    # ``on_text``) and ``last_token_ns`` (its last token handed out: the
+    # stream resolves); beside the first and the last, ``first_step`` and
+    # ``last_step``: the decode steps of this pool whose tokens had landed
+    # on the host by then (``_steps_landed``), so their difference is the
+    # steps the pool ran between the two, for whichever rows. They ride
+    # GenerateResult.marks to the serving tier's per-run ``timings``.
     marks: dict = field(default_factory=dict)
 
 
@@ -597,7 +602,8 @@ def _splice(batch_cache, prefill_cache, slot, dst, width: int):
         return jax.lax.dynamic_update_slice_in_dim(
             bdst, src[:, :1].astype(bdst.dtype), slot, axis=1)
 
-    return _kv_tree_map(copy, batch_cache, prefill_cache, state=state)
+    with scope("cache.splice"):
+        return _kv_tree_map(copy, batch_cache, prefill_cache, state=state)
 
 
 @partial(jax.jit, static_argnames=("k", "width"), donate_argnames=("batch_cache",))
@@ -644,7 +650,8 @@ def _splice_rows(batch_cache, prefill_cache, src_rows, slots, dsts,
                 )
         return bdst
 
-    return _kv_tree_map(copy, batch_cache, prefill_cache, state=state)
+    with scope("cache.splice"):
+        return _kv_tree_map(copy, batch_cache, prefill_cache, state=state)
 
 
 @partial(jax.jit, static_argnames=("p_cap",))
@@ -684,9 +691,10 @@ def _admit_finish(last_logits, token, row_start, prefix_rows, slots, dsts,
         )[0]
 
     samples = jax.vmap(one)(last_logits[:k], seeds, ns)
-    token = token.at[slots].set(samples)
-    row_start = row_start.at[slots].set(dsts)
-    prefix_rows = prefix_rows.at[slots].set(actives)
+    with scope("chunk.tail"):
+        token = token.at[slots].set(samples)
+        row_start = row_start.at[slots].set(dsts)
+        prefix_rows = prefix_rows.at[slots].set(actives)
     return samples, token, row_start, prefix_rows
 
 
@@ -960,6 +968,12 @@ class ContinuousBatcher:
             "impure_s": 0.0, "impure_tokens": 0,
             "establish_s": 0.0, "admit_s": 0.0, "admit_tokens": 0,
             "absorb_s": 0.0, "preemptions": 0,
+            # Inside admit_s, the one-row route alone (``_admit``): the
+            # dispatches it made and what held the scheduler thread in
+            # them: the row cache's allocation, the prefill programs'
+            # dispatch, the splice's (``pool.admit``'s ``*_ms`` summed).
+            "admit_single_dispatches": 0, "admit_alloc_s": 0.0,
+            "admit_dispatch_s": 0.0, "admit_splice_s": 0.0,
             # Counted where the work is dispatched: decode chunks, Σ steps
             # and Σ steps × live rows (row fill = row_steps ÷ (steps ×
             # rows)); admission waves, their real and padded rows, and
@@ -1089,6 +1103,9 @@ class ContinuousBatcher:
         # fetched/emitted — so speculative overshoot past EOS stays
         # bounded like the old single-lookahead loop.
         self._unfetched = 0  # guarded by: _work
+        # Decode steps whose tokens have landed on the host (plain chunks;
+        # the fetch thread's own count, read into the streams' marks).
+        self._steps_landed = 0
         self._nondecode_work = False  # admission/compaction since last dispatch
         # [(slot list, samples array, owner list)] per admission wave
         # since the last dispatch — attached to the next dispatched
@@ -1533,6 +1550,7 @@ class ContinuousBatcher:
         eng = self.engine
         n = len(prompt_ids)
         self._pin_stream(s)  # before the prefill reads eng.params
+        t_prefill = time.monotonic_ns()
         try:
             last_logits, pcache = eng._prefill_ids(prompt_ids)
         except BaseException:
@@ -1540,10 +1558,18 @@ class ContinuousBatcher:
             # never became resident, so its pin must not park a swap.
             self._unpin_stream(s)
             raise
+        t_splice = time.monotonic_ns()
         dst = self._pos - n
         self._cache = _splice(
             self._cache, pcache, slot, dst, _bucket(n, eng.max_seq)
         )
+        # What held the scheduler thread: the row cache's allocation, the
+        # prefill programs' dispatch (the jitted calls' return: the device
+        # runs on) and the splice's.
+        alloc_ns = eng.last_prefill_alloc_ns
+        sp.set(alloc_ms=alloc_ns / 1e6,
+               dispatch_ms=(t_splice - t_prefill - alloc_ns) / 1e6,
+               splice_ms=(time.monotonic_ns() - t_splice) / 1e6)
         tok = sample_token(
             last_logits,
             jax.random.fold_in(jax.random.PRNGKey(s.sampling.seed), n - 1),
@@ -1979,6 +2005,9 @@ class ContinuousBatcher:
         s.finish = finish
         self._slots[slot] = None
         self._unpin_stream(s)
+        if s.marks:
+            s.marks.setdefault("last_token_ns", time.monotonic_ns())
+            s.marks.setdefault("last_step", self._steps_landed)
         # First-writer-wins (ADVICE r4): if _run's exception path timed
         # out joining a hung fetch worker and failed this future, a
         # later worker emit must not abort mid-chunk. done()-then-set is
@@ -2604,6 +2633,7 @@ class ContinuousBatcher:
                 first_vals, body[1], body[2], owners, firsts, eos, t_emit_ns,
             )
         _, mat, fin = body
+        self._steps_landed += len(mat)
         if fin is not None and self._integrity is not None:
             # Finite-logit sentinel verdict: contain BEFORE the emit
             # loop so a poisoned row's garbage tokens never reach its
@@ -2660,6 +2690,7 @@ class ContinuousBatcher:
             for slot, owner, val in zip(slots, wave_owners, vals.tolist()):
                 if self._slots[slot] is owner:
                     owner.marks.setdefault("first_token_ns", t_emit_ns)
+                    owner.marks.setdefault("first_step", self._steps_landed)
                     self._emit(slot, val, eos)
                     emitted += 1
         return emitted
@@ -3109,6 +3140,14 @@ class ContinuousBatcher:
                 if entry is not None:
                     deltas.update(_wave_counts(sp.args))
                     self._firsts.append(entry)
+                if "alloc_ms" in sp.args:
+                    # A one-row dispatch got as far as its splice: what
+                    # held this thread in it, beside ``admit_s``.
+                    deltas.update(
+                        admit_single_dispatches=1,
+                        admit_alloc_s=sp.args["alloc_ms"] / 1e3,
+                        admit_dispatch_s=sp.args["dispatch_ms"] / 1e3,
+                        admit_splice_s=sp.args["splice_ms"] / 1e3)
         return entry is not None
 
     def _loop(self) -> None:

@@ -45,6 +45,7 @@ from llm_consensus_tpu.obs import roofline as _roofline
 from llm_consensus_tpu.models.config import ModelConfig
 from llm_consensus_tpu.ops.latent_attention import prefill_sweep_width
 from llm_consensus_tpu.ops.quant import kv_seq_axis, kv_tree_map, w8a8_scope
+from llm_consensus_tpu.obs.scopes import scope
 from llm_consensus_tpu.ops.sampling import sample_token
 from llm_consensus_tpu.utils.context import Context
 from llm_consensus_tpu.utils import knobs
@@ -95,10 +96,12 @@ class GenerateResult:
     marks: Optional[dict] = None
 
 
-def _with_moe(out, first):
-    """A step program's result from ``forward``'s: ``first`` in place of
-    the logits, and a routed model's sums (``moe_stats``) kept last."""
-    return (first, *out[1:])
+def _with_moe(out):
+    """A step program's result from ``forward``'s: the one position's
+    logits in place of [B, 1, V], and a routed model's sums (``moe_stats``)
+    kept last."""
+    with scope("head"):
+        return (out[0][:, 0], *out[1:])
 
 
 @partial(
@@ -134,7 +137,7 @@ def _prefill_step(params, cfg: ModelConfig, tokens, last_index, cache,
             kv_width=kv_width, prefix=prefix, prefix_len=prefix_len,
             moe_stats=moe_stats, row_end=row_end,
         )
-    return _with_moe(out, out[0][:, 0])
+    return _with_moe(out)
 
 
 def _row_end(cfg: ModelConfig, place: Callable, ends):
@@ -166,7 +169,8 @@ def _restore_prefix(saved, n_valid):
     model's per-row state leaves have no positions to mask and a state cut
     at ``n_valid`` does not exist: its engine never restores a prefix
     (``Engine._refuse_ssm``)."""
-    return kv_tree_map(lambda src: _mask_beyond(src, n_valid), saved)
+    with scope("cache.splice"):
+        return kv_tree_map(lambda src: _mask_beyond(src, n_valid), saved)
 
 
 @partial(jax.jit, donate_argnames=("saved",))
@@ -177,7 +181,8 @@ def _restore_prefix_owned(saved, n_valid):
     the pool hit path would otherwise pay the gather's HBM cost twice.
     The classic path must keep the non-donating twin: its input is the
     shared snapshot slot, which later reuses read again."""
-    return kv_tree_map(lambda src: _mask_beyond(src, n_valid), saved)
+    with scope("cache.splice"):
+        return kv_tree_map(lambda src: _mask_beyond(src, n_valid), saved)
 
 
 def _mask_beyond(src, n_valid):
@@ -203,8 +208,9 @@ def _fork_prefix(saved, n_valid, k: int, width: int):
         sl = jax.lax.slice_in_dim(src, 0, width, axis=kv_seq_axis(src))
         return jnp.repeat(_mask_beyond(sl, n_valid), k, axis=1)
 
-    return kv_tree_map(
-        leaf, saved, state=lambda src: jnp.repeat(src, k, axis=1))
+    with scope("cache.splice"):
+        return kv_tree_map(
+            leaf, saved, state=lambda src: jnp.repeat(src, k, axis=1))
 
 
 @partial(jax.jit, static_argnames=("width",))
@@ -221,8 +227,9 @@ def _extract_row0(template, pcache, width: int):
             dst, src[:, :1, :, :width], (0, 0, 0, 0)
         )
 
-    return kv_tree_map(
-        copy, template, pcache, state=lambda dst, src: src[:, :1])
+    with scope("cache.splice"):
+        return kv_tree_map(
+            copy, template, pcache, state=lambda dst, src: src[:, :1])
 
 
 def _prefill_chunk(params, cfg: ModelConfig, tokens, start_pos, last_index,
@@ -257,7 +264,7 @@ def _prefill_chunk(params, cfg: ModelConfig, tokens, start_pos, last_index,
             prefix=prefix, prefix_len=prefix_len, moe_stats=moe_stats,
             row_end=row_end,
         )
-    return _with_moe(out, out[0][:, 0])
+    return _with_moe(out)
 
 
 def _prefill_chunks_loop(params, cfg: ModelConfig, tokens, base, n_real,
@@ -281,29 +288,40 @@ def _prefill_chunks_loop(params, cfg: ModelConfig, tokens, base, n_real,
     real token at ``last_index`` of the last one).
     """
     chunk = tokens.shape[-1]
-    row_end = (
-        base + (n_real - 1) * chunk + last_index + 1 if cfg.has_ssm else None)
+    with scope("chunk.tail"):
+        row_end = (
+            base + (n_real - 1) * chunk + last_index + 1
+            if cfg.has_ssm else None)
+        toks0 = tokens[0]
     with w8a8_scope(w8a8):
         logits0, cache, *moe = forward(
-            params, cfg, tokens[0], cache, start_pos=base,
+            params, cfg, toks0, cache, start_pos=base,
             kv_width=kv_width, logits_index=last_index, moe_stats=moe_stats,
             row_end=row_end,
         )
 
     def body(i, carry):
         cache, _, *moe = carry
-        toks = jax.lax.dynamic_index_in_dim(tokens, i, 0, keepdims=False)
+        with scope("chunk.tail"):
+            toks = jax.lax.dynamic_index_in_dim(tokens, i, 0, keepdims=False)
+            start = base + i * chunk
         with w8a8_scope(w8a8):
             logits, cache, *more = forward(
-                params, cfg, toks, cache, start_pos=base + i * chunk,
+                params, cfg, toks, cache, start_pos=start,
                 kv_width=kv_width, logits_index=last_index,
                 moe_stats=moe_stats, row_end=row_end,
             )
-        return (cache, logits[:, 0], *(a + b for a, b in zip(moe, more)))
+        with scope("head"):
+            last = logits[:, 0]
+        with scope("chunk.tail"):
+            return (cache, last, *(a + b for a, b in zip(moe, more)))
 
-    cache, last_logits, *moe = jax.lax.fori_loop(
-        1, n_real, body, (cache, logits0[:, 0], *moe),
-    )
+    with scope("head"):
+        last0 = logits0[:, 0]
+    with scope("chunk.tail"):  # the loop's own: its counter and carry
+        cache, last_logits, *moe = jax.lax.fori_loop(
+            1, n_real, body, (cache, last0, *moe),
+        )
     return (last_logits, cache, *moe)
 
 
@@ -348,34 +366,44 @@ def _decode_chunk(params, cfg: ModelConfig, token, pos, cache, key,
 
     def body(carry, _):
         token, pos, cache, ok, *moe = carry
+        with scope("chunk.tail"):
+            column = token[:, None]
         logits, cache, *more = forward(
-            params, cfg, token[:, None], cache, start_pos=pos,
+            params, cfg, column, cache, start_pos=pos,
             row_start=row_start, kv_width=kv_width, attn_impl=attn_impl,
             mesh=mesh, prefix=prefix, prefix_len=prefix_len,
             prefix_rows=prefix_rows, moe_stats=moe_stats,
         )
-        moe = [a + b for a, b in zip(moe, more)]
-        last = logits[:, -1]
-        if poison_row is not None:
-            rows = jnp.arange(last.shape[0], dtype=jnp.int32)
-            last = jnp.where(
-                (rows == poison_row)[:, None], jnp.nan, last
-            )
-        if sentinel:
-            ok = ok & jnp.all(jnp.isfinite(last), axis=-1)
-        step_key = jax.random.fold_in(key, pos)
+        with scope("chunk.tail"):
+            moe = [a + b for a, b in zip(moe, more)]
+        with scope("head"):
+            last = logits[:, -1]
+        with scope("sentinel"):
+            if poison_row is not None:
+                rows = jnp.arange(last.shape[0], dtype=jnp.int32)
+                last = jnp.where(
+                    (rows == poison_row)[:, None], jnp.nan, last
+                )
+            if sentinel:
+                ok = ok & jnp.all(jnp.isfinite(last), axis=-1)
+        with scope("chunk.tail"):
+            step_key = jax.random.fold_in(key, pos)
         next_token = sample_token(
             last, step_key,
             temperature=temperature, top_k=top_k, top_p=top_p,
         )
-        return (next_token, pos + 1, cache, ok, *moe), next_token
+        with scope("chunk.tail"):
+            return (next_token, pos + 1, cache, ok, *moe), next_token
 
-    ok0 = jnp.ones((token.shape[0],), dtype=bool)
-    moe0 = [jnp.zeros((3,), jnp.int32)] if moe_stats else []
-    with w8a8_scope(w8a8):
+    with scope("chunk.tail"):
+        ok0 = jnp.ones((token.shape[0],), dtype=bool)
+        moe0 = [jnp.zeros((3,), jnp.int32)] if moe_stats else []
+        pos = jnp.asarray(pos, jnp.int32)
+    # The step scan's own work (the tokens stacked a step, its counter)
+    # is the chunk's tail.
+    with w8a8_scope(w8a8), scope("chunk.tail"):
         (token, pos, cache, ok, *moe), toks = jax.lax.scan(
-            body, (token, jnp.asarray(pos, jnp.int32), cache, ok0, *moe0),
-            None, length=n_steps,
+            body, (token, pos, cache, ok0, *moe0), None, length=n_steps,
         )
     if sentinel:
         return (token, toks, cache, ok, *moe)
@@ -859,6 +887,10 @@ class Engine:
         # What the last prefill (``_prefill_ids`` or an admission wave)
         # dispatched, padding included: the pool's admission counts from it.
         self.last_prefill = Prefilled(0, 0, 0, 0)
+        # Host ns the last ``_prefill_ids`` spent making its row cache
+        # (``new_cache(1)``; 0 where a retained prefix was restored into
+        # one): the pool's ``pool.admit`` span takes its ``alloc_ms`` here.
+        self.last_prefill_alloc_ns = 0
         # A routed model's prefill programs return their routing sums
         # (``moe_stats``) only while someone collects them: the pool
         # scheduler opens this bank and drains it into its fetches
@@ -1451,8 +1483,11 @@ class Engine:
             and reuse_len >= chunk_len
             and reuse_len + n_tail * chunk_len <= self.max_seq
         )
+        self.last_prefill_alloc_ns = 0
         if not reuse_ok:
+            t_alloc = time.monotonic_ns()
             cache = self.new_cache(1)
+            self.last_prefill_alloc_ns = time.monotonic_ns() - t_alloc
         # Ring attention shards the bucket over sp; a bucket clamped to a
         # non-divisible max_seq can't, so it falls through to the
         # replicated-over-sp paths below (correct, just not seq-sharded).

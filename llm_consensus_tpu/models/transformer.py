@@ -60,6 +60,7 @@ import jax.numpy as jnp
 
 from llm_consensus_tpu.analysis import sanitizer
 from llm_consensus_tpu.models.config import ModelConfig
+from llm_consensus_tpu.obs.scopes import scope
 from llm_consensus_tpu.ops.attention import attention, make_attention_mask
 from llm_consensus_tpu.ops.mlp import gated_mlp
 from llm_consensus_tpu.ops.moe import moe_block
@@ -340,23 +341,28 @@ def init_kv_cache(
 
 def embed_tokens(params: dict, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
     """Token embedding lookup (+ Gemma's sqrt(d) scale) → [B, T, D]."""
-    x = params["embed"][tokens].astype(params["embed"].dtype)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
-    if cfg.embedding_multiplier != 1.0:
-        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    with scope("embed"):
+        x = params["embed"][tokens].astype(params["embed"].dtype)
+        if cfg.embed_scale:
+            x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     return x
 
 
 def unembed(params: dict, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     """Final norm + LM head (+ final logit softcap) → fp32 logits [B, T, V]."""
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps, cfg.norm_offset)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = qeinsum("btd,dv->btv", x, head, preferred_element_type=jnp.float32)
-    if cfg.lm_head_multiplier != 1.0:
-        logits = logits * cfg.lm_head_multiplier
-    if cfg.final_logit_softcap is not None:
-        logits = cfg.final_logit_softcap * jnp.tanh(logits / cfg.final_logit_softcap)
+    with scope("norm"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_eps, cfg.norm_offset)
+    with scope("head"):
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = qeinsum(
+            "btd,dv->btv", x, head, preferred_element_type=jnp.float32)
+        if cfg.lm_head_multiplier != 1.0:
+            logits = logits * cfg.lm_head_multiplier
+        if cfg.final_logit_softcap is not None:
+            logits = cfg.final_logit_softcap * jnp.tanh(
+                logits / cfg.final_logit_softcap)
     return logits
 
 
@@ -399,13 +405,15 @@ def _layer(
     b, t, d = x.shape
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
-    h = rms_norm(x, lp["attn_norm"], cfg.rms_eps, cfg.norm_offset)
+    with scope("norm"):
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps, cfg.norm_offset)
     if cfg.has_ssm:
         # The mixer reads the same normed input as attention, which takes
         # its own multiplier from here on.
         mixed, ssm = _mixer_half(cfg, h, lp, ssm, layer_idx, ssm_span)
         if cfg.attention_in_multiplier != 1.0:
-            h = h * cfg.attention_in_multiplier
+            with scope("attn.proj"):
+                h = h * cfg.attention_in_multiplier
     if cfg.is_latent:
         # The latent stack rides where the K stack does; there is no V.
         attn_out, cache_k = latent_attention(
@@ -415,43 +423,45 @@ def _layer(
             v_head_dim=cfg.v_head_dim, scale=_latent_scale(cfg),
             rms_eps=cfg.rms_eps, kv_width=kv_width, absorbed=t == 1,
         )
-        x = x + qeinsum("btk,kd->btd", attn_out, lp["wo"])
+        with scope("mla.out"):
+            x = x + qeinsum("btk,kd->btd", attn_out, lp["wo"])
         return _mlp_half(
             cfg, x, lp, routed, moe_stats, cache_k, cache_v, expert_stacks)
-    q = qeinsum("btd,dk->btk", h, lp["wq"])
-    k = qeinsum("btd,dk->btk", h, lp["wk"])
-    v = qeinsum("btd,dk->btk", h, lp["wv"])
-    if cfg.qkv_bias:
-        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-    if cfg.key_multiplier != 1.0:
-        k = k * cfg.key_multiplier
-    q = q.reshape(b, t, hq, dh)
-    k = k.reshape(b, t, hkv, dh)
-    v = v.reshape(b, t, hkv, dh)
-    if qkv_pin is not None and ring_mesh is None:
-        # Non-dividing tp: the projection output shards split WITHIN a
-        # head (e.g. Hkv=2 over tp=4 → 16-wide shards of a 32-wide head),
-        # and GSPMD carrying that layout through the rope/cache-write
-        # scan miscompiles on jax 0.4.x (measured O(1) logit error, not
-        # ulps — the seed test_sp_prefill non-dividing-tp failure). Pin
-        # each tensor to its head-aligned sharding — replicated heads
-        # when tp doesn't divide that head count — BEFORE rope and the
-        # cache write, matching cache_specs' degraded layout. Dividing
-        # meshes never reach here (qkv_pin stays None), so the working
-        # sharded paths are untouched.
-        from jax.sharding import NamedSharding, PartitionSpec as P
+    with scope("attn.proj"):
+        q = qeinsum("btd,dk->btk", h, lp["wq"])
+        k = qeinsum("btd,dk->btk", h, lp["wk"])
+        v = qeinsum("btd,dk->btk", h, lp["wv"])
+        if cfg.qkv_bias:
+            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+        if cfg.key_multiplier != 1.0:
+            k = k * cfg.key_multiplier
+        q = q.reshape(b, t, hq, dh)
+        k = k.reshape(b, t, hkv, dh)
+        v = v.reshape(b, t, hkv, dh)
+        if qkv_pin is not None and ring_mesh is None:
+            # Non-dividing tp: the projection output shards split WITHIN a
+            # head (e.g. Hkv=2 over tp=4 → 16-wide shards of a 32-wide head),
+            # and GSPMD carrying that layout through the rope/cache-write
+            # scan miscompiles on jax 0.4.x (measured O(1) logit error, not
+            # ulps — the seed test_sp_prefill non-dividing-tp failure). Pin
+            # each tensor to its head-aligned sharding — replicated heads
+            # when tp doesn't divide that head count — BEFORE rope and the
+            # cache write, matching cache_specs' degraded layout. Dividing
+            # meshes never reach here (qkv_pin stays None), so the working
+            # sharded paths are untouched.
+            from jax.sharding import NamedSharding, PartitionSpec as P
 
-        tp_sz = dict(qkv_pin.shape)["tp"]
+            tp_sz = dict(qkv_pin.shape)["tp"]
 
-        def pin(t_, n_heads_):
-            ax = "tp" if n_heads_ % tp_sz == 0 else None
-            return jax.lax.with_sharding_constraint(
-                t_, NamedSharding(qkv_pin, P(None, None, ax, None))
-            )
+            def pin(t_, n_heads_):
+                ax = "tp" if n_heads_ % tp_sz == 0 else None
+                return jax.lax.with_sharding_constraint(
+                    t_, NamedSharding(qkv_pin, P(None, None, ax, None))
+                )
 
-        q, k, v = pin(q, hq), pin(k, hkv), pin(v, hkv)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+            q, k, v = pin(q, hq), pin(k, hkv), pin(v, hkv)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
     if cache_k is not None:
         # Write this step's keys/values at (layer_idx, start_pos) into the
@@ -462,9 +472,13 @@ def _layer(
         # per-layer entries through the scan as xs/ys) is what lets XLA
         # alias the cache through both the layer scan and the decode-step
         # scan instead of copying it every step — see kv_write_rows.
-        cache_k = kv_write_rows(cache_k, k, layer_idx, start_pos)
-        cache_v = kv_write_rows(cache_v, v, layer_idx, start_pos)
-        if decode_flash:
+        with scope("attn.kv_write"):
+            cache_k = kv_write_rows(cache_k, k, layer_idx, start_pos)
+            cache_v = kv_write_rows(cache_v, v, layer_idx, start_pos)
+    with scope("attn.sweep"):
+        if cache_k is None:
+            k_att, v_att = k, v
+        elif decode_flash:
             # The decode kernel consumes the FULL code stacks directly
             # and pages its layer via the BlockSpec index map — no
             # per-layer slice, no relayout, no materialized dequant
@@ -509,142 +523,141 @@ def _layer(
             entry_v = kv_layer(cache_v, layer_idx, width)
             k_att = kv_read(entry_k, x.dtype)
             v_att = kv_read(entry_v, x.dtype)
-    else:
-        k_att, v_att = k, v
+        if ring_mesh is not None:
+            from llm_consensus_tpu.parallel.ring import ring_attention
 
-    if ring_mesh is not None:
-        from llm_consensus_tpu.parallel.ring import ring_attention
-
-        # Sequence-parallel prefill: q/k/v are sequence-sharded over sp
-        # (the whole sequence never lands on one device); ring attention
-        # circulates KV blocks over ICI. Heads stay tp-sharded when the
-        # mesh has a tp axis — the ring and the head split compose
-        # without communicating. This layer's k/v are returned (in place
-        # of cache entries) so the caller can assemble the decode cache.
-        # Heads ride the tp axis only when it divides both head counts —
-        # the same gating as the flash path; otherwise heads replicate
-        # over tp and only the ring shards work.
-        tp_size = ring_mesh.shape.get("tp", 1)
-        head_axis = (
-            "tp" if tp_size > 1 and hq % tp_size == 0 and hkv % tp_size == 0
-            else None
-        )
-        attn_out = ring_attention(
-            q, k_att, v_att, ring_mesh,
-            axis_name="sp",
-            head_axis=head_axis,
-            scale=dh ** -0.5,
-            sliding_window=cfg.sliding_window,
-            logit_softcap=cfg.attn_logit_softcap,
-        )
-    elif flash_offset is not None:
-        from llm_consensus_tpu.ops.pallas import flash_attention
-
-        fa = partial(
-            flash_attention,
-            q_offset=flash_offset,
-            scale=dh ** -0.5,
-            sliding_window=cfg.sliding_window,
-            logit_softcap=cfg.attn_logit_softcap,
-        )
-        if flash_mesh is not None:
-            # Per-head attention over TP-sharded heads: each shard runs the
-            # kernel on its own q/kv head slice — no collectives inside.
-            from jax.sharding import PartitionSpec as P
-
-            spec = P(None, None, "tp", None)  # [B, S, H, dh], heads on tp
-            fa = jax.shard_map(
-                fa, mesh=flash_mesh,
-                in_specs=(spec, spec, spec), out_specs=spec,
-                check_vma=False,
+            # Sequence-parallel prefill: q/k/v are sequence-sharded over sp
+            # (the whole sequence never lands on one device); ring attention
+            # circulates KV blocks over ICI. Heads stay tp-sharded when the
+            # mesh has a tp axis — the ring and the head split compose
+            # without communicating. This layer's k/v are returned (in place
+            # of cache entries) so the caller can assemble the decode cache.
+            # Heads ride the tp axis only when it divides both head counts —
+            # the same gating as the flash path; otherwise heads replicate
+            # over tp and only the ring shards work.
+            tp_size = ring_mesh.shape.get("tp", 1)
+            head_axis = (
+                "tp" if tp_size > 1 and hq % tp_size == 0 and hkv % tp_size == 0
+                else None
             )
-        attn_out = fa(q, k_att, v_att)
-    elif decode_flash:
-        from llm_consensus_tpu.ops.pallas import decode_attention
-
-        with_state = prefix_k is not None
-
-        def da(q_, k_, v_, pos_, li_, rs_, sweep_):
-            return decode_attention(
-                q_, k_, v_, pos_, li_, rs_,
+            attn_out = ring_attention(
+                q, k_att, v_att, ring_mesh,
+                axis_name="sp",
+                head_axis=head_axis,
                 scale=dh ** -0.5,
                 sliding_window=cfg.sliding_window,
                 logit_softcap=cfg.attn_logit_softcap,
-                kv_width=kv_width,
-                return_state=with_state,
-                sweep=sweep_,
             )
+        elif flash_offset is not None:
+            from llm_consensus_tpu.ops.pallas import flash_attention
 
-        rs = row_start
-        if rs is None:
-            rs = jnp.zeros((b,), jnp.int32)
-        if flash_mesh is not None:
-            from jax.sharding import PartitionSpec as P
+            fa = partial(
+                flash_attention,
+                q_offset=flash_offset,
+                scale=dh ** -0.5,
+                sliding_window=cfg.sliding_window,
+                logit_softcap=cfg.attn_logit_softcap,
+            )
+            if flash_mesh is not None:
+                # Per-head attention over TP-sharded heads: each shard runs the
+                # kernel on its own q/kv head slice — no collectives inside.
+                from jax.sharding import PartitionSpec as P
 
-            spec = P(None, None, "tp", None)  # [B, 1, H, dh], heads on tp
-            # Codes keep heads on axis 3 ([L, B, S, Hkv, dh]); the
-            # seq-minor scale leaves are 4-D [L, B, Hkv, S] with heads
-            # on axis 2 — each leaf gets the spec matching its rank.
-            from llm_consensus_tpu.ops.quant import kv_seq_axis
-
-            spec5 = P(None, None, None, "tp", None)
-            spec4s = P(None, None, "tp", None)
-            kv_spec = (
-                jax.tree.map(
-                    lambda leaf: spec5 if kv_seq_axis(leaf) == 2 else spec4s,
-                    k_att,
+                spec = P(None, None, "tp", None)  # [B, S, H, dh], heads on tp
+                fa = jax.shard_map(
+                    fa, mesh=flash_mesh,
+                    in_specs=(spec, spec, spec), out_specs=spec,
+                    check_vma=False,
                 )
-                if is_quantized(k_att) else spec5
+            attn_out = fa(q, k_att, v_att)
+        elif decode_flash:
+            from llm_consensus_tpu.ops.pallas import decode_attention
+
+            with_state = prefix_k is not None
+
+            def da(q_, k_, v_, pos_, li_, rs_, sweep_):
+                return decode_attention(
+                    q_, k_, v_, pos_, li_, rs_,
+                    scale=dh ** -0.5,
+                    sliding_window=cfg.sliding_window,
+                    logit_softcap=cfg.attn_logit_softcap,
+                    kv_width=kv_width,
+                    return_state=with_state,
+                    sweep=sweep_,
+                )
+
+            rs = row_start
+            if rs is None:
+                rs = jnp.zeros((b,), jnp.int32)
+            if flash_mesh is not None:
+                from jax.sharding import PartitionSpec as P
+
+                spec = P(None, None, "tp", None)  # [B, 1, H, dh], heads on tp
+                # Codes keep heads on axis 3 ([L, B, S, Hkv, dh]); the
+                # seq-minor scale leaves are 4-D [L, B, Hkv, S] with heads
+                # on axis 2 — each leaf gets the spec matching its rank.
+                from llm_consensus_tpu.ops.quant import kv_seq_axis
+
+                spec5 = P(None, None, None, "tp", None)
+                spec4s = P(None, None, "tp", None)
+                kv_spec = (
+                    jax.tree.map(
+                        lambda leaf: spec5 if kv_seq_axis(leaf) == 2 else spec4s,
+                        k_att,
+                    )
+                    if is_quantized(k_att) else spec5
+                )
+                # The scalars (pos, layer, row_start, the step's sweep plan)
+                # are the same on every shard.
+                da = jax.shard_map(
+                    da, mesh=flash_mesh,
+                    in_specs=(spec, kv_spec, kv_spec, P(), P(), P(None), P(None)),
+                    out_specs=(spec, P(None, "tp"), P(None, "tp"))
+                    if with_state else spec,
+                    check_vma=False,
+                )
+            attn_out = da(
+                q, k_att, v_att, jnp.asarray(start_pos, jnp.int32), layer_idx, rs,
+                decode_sweep,
             )
-            # The scalars (pos, layer, row_start, the step's sweep plan)
-            # are the same on every shard.
-            da = jax.shard_map(
-                da, mesh=flash_mesh,
-                in_specs=(spec, kv_spec, kv_spec, P(), P(), P(None), P(None)),
-                out_specs=(spec, P(None, "tp"), P(None, "tp"))
-                if with_state else spec,
-                check_vma=False,
+            if with_state:
+                attn_out, m2, l2 = attn_out
+                m2, l2 = m2[:, None], l2[:, None]  # [B, Hq] → [B, T=1, Hq]
+        else:
+            attn_out = attention(
+                q, k_att, v_att, mask,
+                scale=dh ** -0.5,
+                logit_softcap=cfg.attn_logit_softcap,
+                return_state=prefix_k is not None,
             )
-        attn_out = da(
-            q, k_att, v_att, jnp.asarray(start_pos, jnp.int32), layer_idx, rs,
-            decode_sweep,
-        )
-        if with_state:
-            attn_out, m2, l2 = attn_out
-            m2, l2 = m2[:, None], l2[:, None]  # [B, Hq] → [B, T=1, Hq]
-    else:
-        attn_out = attention(
-            q, k_att, v_att, mask,
-            scale=dh ** -0.5,
-            logit_softcap=cfg.attn_logit_softcap,
-            return_state=prefix_k is not None,
-        )
+            if prefix_k is not None:
+                attn_out, m2, l2 = attn_out
+
         if prefix_k is not None:
-            attn_out, m2, l2 = attn_out
+            # Shared-prefix merge (the pool's one-prompt fan-out pattern):
+            # every participating row attends ONE replicated prefix KV —
+            # read once per step as a dense MXU matmul — instead of carrying
+            # its own copy of the prompt KV through the per-row cache sweep.
+            # Exact: two-source online-softmax combine of (prefix, own-row)
+            # attention. Rows not flagged in ``prefix_rows`` contribute
+            # (m=−inf, l=0) and pass through unchanged.
+            from llm_consensus_tpu.ops.attention import (
+                merge_attention_states, prefix_attention)
 
-    if prefix_k is not None:
-        # Shared-prefix merge (the pool's one-prompt fan-out pattern):
-        # every participating row attends ONE replicated prefix KV —
-        # read once per step as a dense MXU matmul — instead of carrying
-        # its own copy of the prompt KV through the per-row cache sweep.
-        # Exact: two-source online-softmax combine of (prefix, own-row)
-        # attention. Rows not flagged in ``prefix_rows`` contribute
-        # (m=−inf, l=0) and pass through unchanged.
-        from llm_consensus_tpu.ops.attention import (
-            merge_attention_states, prefix_attention)
-
-        pk = kv_read(kv_layer(prefix_k, layer_idx), x.dtype)[0]  # [P, Hkv, dh]
-        pv = kv_read(kv_layer(prefix_v, layer_idx), x.dtype)[0]
-        o1, m1, l1 = prefix_attention(
-            q, pk, pv, prefix_len, prefix_rows,
-            scale=dh ** -0.5,
-            logit_softcap=cfg.attn_logit_softcap,
-        )
-        attn_out = merge_attention_states(o1, m1, l1, attn_out, m2, l2)
-    attn_out = qeinsum("btk,kd->btd", attn_out.reshape(b, t, hq * dh), lp["wo"])
-    if cfg.has_ssm:
-        attn_out = attn_out * cfg.attention_out_multiplier + mixed
-    x = x + attn_out
+            pk = kv_read(kv_layer(prefix_k, layer_idx), x.dtype)[0]  # [P, Hkv, dh]
+            pv = kv_read(kv_layer(prefix_v, layer_idx), x.dtype)[0]
+            o1, m1, l1 = prefix_attention(
+                q, pk, pv, prefix_len, prefix_rows,
+                scale=dh ** -0.5,
+                logit_softcap=cfg.attn_logit_softcap,
+            )
+            attn_out = merge_attention_states(o1, m1, l1, attn_out, m2, l2)
+    with scope("attn.out"):
+        attn_out = qeinsum(
+            "btk,kd->btd", attn_out.reshape(b, t, hq * dh), lp["wo"])
+        if cfg.has_ssm:
+            attn_out = attn_out * cfg.attention_out_multiplier + mixed
+        x = x + attn_out
 
     if ring_mesh is not None:
         cache_k, cache_v = k, v  # fresh k/v for the caller's cache build
@@ -664,17 +677,20 @@ def _mixer_half(cfg: ModelConfig, h, lp, ssm, layer_idx, span):
             (b, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32)
         tail = jnp.zeros((b, cfg.ssm_conv - 1, cfg.ssm_conv_width), h.dtype)
         return ssm_mixer(cfg, h, lp, state, tail, lo, hi)[0], None
-    out, state, tail = ssm_mixer(
-        cfg, h, lp,
-        jax.lax.dynamic_index_in_dim(ssm["state"], layer_idx, 0, keepdims=False),
-        jax.lax.dynamic_index_in_dim(ssm["conv"], layer_idx, 0, keepdims=False),
-        lo, hi)
-    return out, {
-        "state": jax.lax.dynamic_update_index_in_dim(
-            ssm["state"], state.astype(ssm["state"].dtype), layer_idx, 0),
-        "conv": jax.lax.dynamic_update_index_in_dim(
-            ssm["conv"], tail.astype(ssm["conv"].dtype), layer_idx, 0),
-    }
+    with scope("ssm.state_write"):
+        state = jax.lax.dynamic_index_in_dim(
+            ssm["state"], layer_idx, 0, keepdims=False)
+        tail = jax.lax.dynamic_index_in_dim(
+            ssm["conv"], layer_idx, 0, keepdims=False)
+    out, state, tail = ssm_mixer(cfg, h, lp, state, tail, lo, hi)
+    with scope("ssm.state_write"):
+        ssm = {
+            "state": jax.lax.dynamic_update_index_in_dim(
+                ssm["state"], state.astype(ssm["state"].dtype), layer_idx, 0),
+            "conv": jax.lax.dynamic_update_index_in_dim(
+                ssm["conv"], tail.astype(ssm["conv"].dtype), layer_idx, 0),
+        }
+    return out, ssm
 
 
 def _latent_scale(cfg: ModelConfig) -> float:
@@ -693,12 +709,14 @@ def _mlp_half(cfg: ModelConfig, x, lp, routed: bool, moe_stats: bool,
     dense gated MLP, or on a routed stack the expert layer (ops/moe.py),
     whose expert leaves are this layer's own (``lp``) or, from ``forward``'s
     scan, the whole stacks with this layer's index."""
-    h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps, cfg.norm_offset)
+    with scope("norm"):
+        h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps, cfg.norm_offset)
     if not routed:
-        mlp_out = gated_mlp(
-            h, lp["w_gate"], lp["w_up"], lp["w_down"], cfg.activation,
-            cfg.mlp_multipliers)
-        return x + mlp_out, cache_k, cache_v
+        with scope("mlp"):
+            mlp_out = gated_mlp(
+                h, lp["w_gate"], lp["w_up"], lp["w_down"], cfg.activation,
+                cfg.mlp_multipliers)
+            return x + mlp_out, cache_k, cache_v
     *experts, layer = expert_stacks or (lp["w_gate"], lp["w_up"], lp["w_down"], None)
     out = moe_block(
         h, lp["w_router"], *experts, layer=layer,
@@ -710,9 +728,10 @@ def _mlp_half(cfg: ModelConfig, x, lp, routed: bool, moe_stats: bool,
         if cfg.n_shared_experts else None,
         with_stats=moe_stats,
     )
-    if moe_stats:
-        return x + out[0], cache_k, cache_v, out[1]
-    return x + out, cache_k, cache_v
+    with scope("moe.experts"):  # the residual add rides the layer's last sum
+        if moe_stats:
+            return x + out[0], cache_k, cache_v, out[1]
+        return x + out, cache_k, cache_v
 
 
 def forward(
@@ -919,59 +938,63 @@ def forward(
         attention_routes.note(cfg.name, "decode" if t == 1 else "prefill", path)
 
     start = jnp.asarray(start_pos, jnp.int32)
-    positions = start + jnp.arange(t, dtype=jnp.int32)[None, :]  # [1, T]
-    if row_start is not None:
-        # Right-aligned batch (left-padded rows): positions are
-        # row-relative so every row's first real token is position 0 —
-        # RoPE, causality, and sliding windows all follow.
-        positions = positions - row_start[:, None]
-    positions = jnp.broadcast_to(positions, (b, t))
-    pos_offset = None
-    if prefix is not None:
-        # Suffix-resident rows: cache slot j holds ABSOLUTE position
-        # prefix_len + (j − row_start) for participating rows, so RoPE
-        # angles (and the mask's causal compare below) shift by the
-        # prefix length. Non-participating rows carry their full prompt
-        # in their own window — no shift.
-        plen = jnp.asarray(prefix_len, jnp.int32)
-        if prefix_rows is not None:
-            pos_offset = plen * prefix_rows.astype(jnp.int32)  # [B]
-        else:
-            pos_offset = jnp.broadcast_to(plen, (b,))
-        positions = positions + pos_offset[:, None]
-    cos, sin = _rotary_tables(cfg, positions)
+    # Positions and rotary tables belong to the projections, the mask to
+    # the sweep (a latent model: ``mla.q`` and ``mla.sweep``).
+    with scope("mla.q" if cfg.is_latent else "attn.proj"):
+        positions = start + jnp.arange(t, dtype=jnp.int32)[None, :]  # [1, T]
+        if row_start is not None:
+            # Right-aligned batch (left-padded rows): positions are
+            # row-relative so every row's first real token is position 0 —
+            # RoPE, causality, and sliding windows all follow.
+            positions = positions - row_start[:, None]
+        positions = jnp.broadcast_to(positions, (b, t))
+        pos_offset = None
+        if prefix is not None:
+            # Suffix-resident rows: cache slot j holds ABSOLUTE position
+            # prefix_len + (j − row_start) for participating rows, so RoPE
+            # angles (and the mask's causal compare below) shift by the
+            # prefix length. Non-participating rows carry their full prompt
+            # in their own window — no shift.
+            plen = jnp.asarray(prefix_len, jnp.int32)
+            if prefix_rows is not None:
+                pos_offset = plen * prefix_rows.astype(jnp.int32)  # [B]
+            else:
+                pos_offset = jnp.broadcast_to(plen, (b,))
+            positions = positions + pos_offset[:, None]
+        cos, sin = _rotary_tables(cfg, positions)
 
-    if flash_offset is not None or decode_flash:
-        mask = None  # the kernels derive causality from pos/q_offset
-    elif cache is not None:
-        s = _k_store(cache).shape[2]
-        if kv_width is not None:
-            s = min(s, kv_width)
-        kv_slots = jnp.arange(s, dtype=jnp.int32)[None, :]
-        kv_valid = jnp.broadcast_to(kv_slots < (start + t), (b, s))
-        if kv_mask is not None:
-            # Bitmap validity (speculative holes): slots the bitmap
-            # clears are junk even below the frontier, and valid slots
-            # may sit below row_start (which accrues hole counts, not
-            # the row's first slot) — the bitmap replaces the interval
-            # clamp entirely. Slots at/above the frontier inside this
-            # call's write window are marked valid by the CALLER before
-            # dispatch (intra-window causality comes from the position
-            # compare below).
-            kv_positions = jnp.broadcast_to(kv_slots, (b, s)) - row_start[:, None]
-            kv_valid = jnp.logical_and(kv_valid, kv_mask[:, :s])
-        elif row_start is not None:
-            kv_positions = jnp.broadcast_to(kv_slots, (b, s)) - row_start[:, None]
-            kv_valid = jnp.logical_and(kv_valid, kv_slots >= row_start[:, None])
+    with scope("mla.sweep" if cfg.is_latent else "attn.sweep"):
+        if flash_offset is not None or decode_flash:
+            mask = None  # the kernels derive causality from pos/q_offset
+        elif cache is not None:
+            s = _k_store(cache).shape[2]
+            if kv_width is not None:
+                s = min(s, kv_width)
+            kv_slots = jnp.arange(s, dtype=jnp.int32)[None, :]
+            kv_valid = jnp.broadcast_to(kv_slots < (start + t), (b, s))
+            if kv_mask is not None:
+                # Bitmap validity (speculative holes): slots the bitmap
+                # clears are junk even below the frontier, and valid slots
+                # may sit below row_start (which accrues hole counts, not
+                # the row's first slot) — the bitmap replaces the interval
+                # clamp entirely. Slots at/above the frontier inside this
+                # call's write window are marked valid by the CALLER before
+                # dispatch (intra-window causality comes from the position
+                # compare below).
+                kv_positions = jnp.broadcast_to(kv_slots, (b, s)) - row_start[:, None]
+                kv_valid = jnp.logical_and(kv_valid, kv_mask[:, :s])
+            elif row_start is not None:
+                kv_positions = jnp.broadcast_to(kv_slots, (b, s)) - row_start[:, None]
+                kv_valid = jnp.logical_and(kv_valid, kv_slots >= row_start[:, None])
+            else:
+                kv_positions = jnp.broadcast_to(kv_slots, (b, s))
+            if pos_offset is not None:
+                # Keep the causal compare in the same (absolute) basis the
+                # query positions moved to.
+                kv_positions = kv_positions + pos_offset[:, None]
+            mask = make_attention_mask(positions, kv_positions, kv_valid, cfg.sliding_window)
         else:
-            kv_positions = jnp.broadcast_to(kv_slots, (b, s))
-        if pos_offset is not None:
-            # Keep the causal compare in the same (absolute) basis the
-            # query positions moved to.
-            kv_positions = kv_positions + pos_offset[:, None]
-        mask = make_attention_mask(positions, kv_positions, kv_valid, cfg.sliding_window)
-    else:
-        mask = make_attention_mask(positions, positions, None, cfg.sliding_window)
+            mask = make_attention_mask(positions, positions, None, cfg.sliding_window)
 
     qkv_pin = None
     if mesh is not None and cache is not None:
@@ -987,22 +1010,25 @@ def forward(
         from llm_consensus_tpu.ops.pallas.decode_attention import (
             decode_sweep_plan)
 
-        sweep = decode_sweep_plan(
-            start,
-            jnp.zeros((b,), jnp.int32) if row_start is None else row_start,
-            width=decode_width,
-            n_kv_heads=cfg.n_kv_heads // max(shard_tp, 1),  # a shard's
-            dh=cfg.head_dim, kv_item=k_store.dtype.itemsize,
-            quantized=decode_quantized, sliding_window=cfg.sliding_window,
-        )
+        with scope("attn.sweep"):
+            sweep = decode_sweep_plan(
+                start,
+                jnp.zeros((b,), jnp.int32) if row_start is None else row_start,
+                width=decode_width,
+                n_kv_heads=cfg.n_kv_heads // max(shard_tp, 1),  # a shard's
+                dh=cfg.head_dim, kv_item=k_store.dtype.itemsize,
+                quantized=decode_quantized,
+                sliding_window=cfg.sliding_window,
+            )
     ssm_span = None
     if cfg.has_ssm and (row_start is not None or row_end is not None):
         # Each row's real positions [lo, hi) inside this call's T.
-        lo = jnp.zeros((b,), jnp.int32) if row_start is None else jnp.clip(
-            row_start - start, 0, t)
-        hi = jnp.full((b,), t, jnp.int32) if row_end is None else jnp.clip(
-            row_end - start, 0, t)
-        ssm_span = (lo, jnp.maximum(hi, lo))
+        with scope("ssm.conv"):
+            lo = jnp.zeros((b,), jnp.int32) if row_start is None else jnp.clip(
+                row_start - start, 0, t)
+            hi = jnp.full((b,), t, jnp.int32) if row_end is None else jnp.clip(
+                row_end - start, 0, t)
+            ssm_span = (lo, jnp.maximum(hi, lo))
     layer_fn = partial(
         _layer, cfg, flash_offset=flash_offset, flash_mesh=flash_mesh,
         kv_width=kv_width, qkv_pin=qkv_pin, ssm_span=ssm_span,
@@ -1019,7 +1045,8 @@ def forward(
     if "layers_dense" in params:
         stacks.insert(0, (params["layers_dense"], False))
     moe_stats = moe_stats and cfg.is_moe
-    stats = jnp.zeros((3,), jnp.int32) if moe_stats else None
+    with scope("moe.stats"):
+        stats = jnp.zeros((3,), jnp.int32) if moe_stats else None
 
     def scanned(stack: dict, routed: bool):
         """A stack's leaves as the scan slices them, and apart from them a
@@ -1038,7 +1065,8 @@ def forward(
             moe_stats=moe_stats and routed,
             expert_stacks=(*experts, at) if routed else None, ssm=cs, **kw)
         if moe_stats and routed:
-            stats = stats + out[3]
+            with scope("moe.stats"):
+                stats = stats + out[3]
         if cfg.has_ssm:
             cs = out[-1]
         return (*out[:3], stats, cs)
@@ -1067,8 +1095,12 @@ def forward(
 
         if remat and cache is None:
             scan_body = jax.checkpoint(scan_body)
-        (x, ck, cv, li, stats, cs), _ = jax.lax.scan(
-            scan_body, (x, ck, cv, li, stats, cs), xs)
+        # The scan's own work (a layer's leaves out of their stacks, its
+        # counter) is ``layers``; inside the body each part's own scope is
+        # the innermost and names it.
+        with scope("layers"):
+            (x, ck, cv, li, stats, cs), _ = jax.lax.scan(
+                scan_body, (x, ck, cv, li, stats, cs), xs)
     if cache is None:
         new_cache = None
     else:
@@ -1080,7 +1112,8 @@ def forward(
         # Prefill only samples one position; unembedding every position
         # would spend T×V×D FLOPs on logits nobody reads (~30% of an 8B
         # prefill at a 128k vocab).
-        x = jnp.take_along_axis(x, logits_index[:, None, None], axis=1)
+        with scope("head"):
+            x = jnp.take_along_axis(x, logits_index[:, None, None], axis=1)
     if moe_stats:
         return unembed(params, cfg, x), new_cache, stats
     return unembed(params, cfg, x), new_cache
@@ -1198,15 +1231,18 @@ def _forward_ring_prefill(
     x = jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, P(None, "sp", None))
     )
-    positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None, :], (b, t))
-    cos, sin = _rotary_tables(cfg, positions)
+    with scope("attn.proj"):
+        positions = jnp.broadcast_to(
+            jnp.arange(t, dtype=jnp.int32)[None, :], (b, t))
+        cos, sin = _rotary_tables(cfg, positions)
     layer_fn = partial(_layer, cfg, ring_mesh=mesh)
 
     def scan_body(x, lp):
         x, k, v = layer_fn(x, lp, cos, sin, None, None, None, None)
         return x, (k, v)
 
-    x, (ks, vs) = jax.lax.scan(scan_body, x, params["layers"])
+    with scope("layers"):
+        x, (ks, vs) = jax.lax.scan(scan_body, x, params["layers"])
 
     def write(entry, stack):  # [L, B, T, Hkv, dh] → cache positions [0, T)
         if is_quantized(entry):
@@ -1224,7 +1260,9 @@ def _forward_ring_prefill(
             entry, stack.astype(entry.dtype), (0, 0, 0, 0, 0)
         )
 
-    new_cache = {"k": write(cache["k"], ks), "v": write(cache["v"], vs)}
+    with scope("attn.kv_write"):
+        new_cache = {"k": write(cache["k"], ks), "v": write(cache["v"], vs)}
     if logits_index is not None:
-        x = jnp.take_along_axis(x, logits_index[:, None, None], axis=1)
+        with scope("head"):
+            x = jnp.take_along_axis(x, logits_index[:, None, None], axis=1)
     return unembed(params, cfg, x), new_cache

@@ -26,7 +26,12 @@ host events for five small matmuls, against a 20-26 MB trace for 4 s of
 serving without them), and in their place the program's own spans: while
 the window is open the span emitter (obs/spans.py) writes every span as a
 ``llmc.<name>`` TraceAnnotation with its arguments, so pools, requests,
-device programs and idle gaps sit on one timeline.
+device programs and idle gaps sit on one timeline. Beneath the programs,
+the window's operations carry their part: every hot program is traced
+under ``jax.named_scope("llmc.<part>")`` (obs/scopes.py), so an ``XLA Ops``
+event's ``op_name`` (its metadata's ``tf_op`` stat) says which part of a
+layer or of a chunk it ran for, and ``benchmark/trace_scopes.py`` sums a
+step by part.
 
 Resolution follows the blackbox pattern: ``profiler()`` reads
 ``LLMC_PROFILE*`` once; ``install()``/``reset()`` rebind for tests and

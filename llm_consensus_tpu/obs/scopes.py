@@ -1,0 +1,86 @@
+"""The names of the parts of a device program: one flat vocabulary.
+
+A device trace names an operation by the program it ran in
+(``decode_chunk__<model>__kv<width>__s<steps>``, engine/engine.py) and by
+its HLO text, which says nothing of which PART of a layer or of a chunk it
+belongs to. Every part of every hot program is therefore traced under
+``jax.named_scope("llmc.<part>")``: metadata only (an operation's
+``op_name`` gains a path component; no operand, no program and no number a
+step computes changes), which the profiler's trace carries beside each
+operation and ``benchmark/trace_scopes.py`` sums into a ``step_split``.
+
+This module holds the NAMES and nothing else: the scope sites
+(models/transformer.py, ops/*, engine/engine.py, engine/batcher.py) write
+``with scope("attn.sweep"):``, and tests/test_scopes.py lowers every
+family's programs and fails on a part of a family that has no scope and
+on an ``llmc.`` name that is not here.
+
+docs/observability.md "Scopes" says where each is and what it covers.
+"""
+
+from __future__ import annotations
+
+import jax
+
+PREFIX = "llmc."
+
+# Every model, every hot program.
+COMMON = (
+    "embed",        # the token gather and its multipliers
+    "layers",       # the layer scan's own: a layer's leaves out of their
+                    # stacks, its counter (a part inside the body names itself)
+    "norm",         # a block's two norms and the final norm
+    "mlp",          # the dense gated MLP
+    "head",         # the position pick, the head product, multiplier, softcap
+    "sample",       # the sampler (decode chunks; the pool's admit finish)
+    "sentinel",     # the finite-logit check and the fault lane's poison
+    "chunk.tail",   # token, position and key updates; the sums a chunk returns
+    "cache.splice",  # _splice, _splice_rows: the prefill's cache hand-over
+)
+# Grouped-query attention over keys and values.
+ATTN = (
+    "attn.proj",      # q / k / v products, biases, rotary (and its tables)
+    "attn.kv_write",  # this call's keys and values into the full stacks
+    "attn.sweep",     # mask or sweep plan, cache read, scores, softmax, values
+    "attn.out",       # the output product
+)
+# Latent attention, in place of ATTN.
+MLA = (
+    "mla.q",       # wq_a, q_norm, wq_b, the rotary part (and its tables)
+    "mla.kv_a",    # wkv_a, kv_norm, the shared rotary key, the latent's write
+    "mla.absorb",  # the products with wkv_b's halves: onto the query and the
+                   # output at T = 1, the latents' expansion in the prefill form
+    "mla.sweep",   # mask, latent read, scores, softmax, weighted sum
+    "mla.out",     # the output product
+)
+# The routed expert layer, beside ``mlp`` where a model has dense layers too.
+MOE = (
+    "moe.route",    # router logits, groups, the choice and its weights
+    "moe.experts",  # the sort, the three grouped products, the way back
+    "moe.shared",   # the shared experts' SwiGLU
+    "moe.stats",    # the three sums the tracing fetches
+)
+# The state-space mixer, beside ATTN.
+SSM = (
+    "ssm.in_proj",      # the in-projection, its multipliers, dt, A, D
+    "ssm.conv",         # the causal convolution and its activation
+    "ssm.scan",         # the chunked recurrence (T > 1)
+    "ssm.step",         # one position of the recurrence (T = 1)
+    "ssm.norm",         # the gate and the grouped norm
+    "ssm.out_proj",     # the out-projection and its multiplier
+    "ssm.state_write",  # state and tail out of and back into the full stacks
+)
+
+SCOPES = COMMON + ATTN + MLA + MOE + SSM
+_KNOWN = frozenset(SCOPES)
+
+
+def scope(part: str):
+    """``jax.named_scope("llmc.<part>")`` for a part of the vocabulary; a
+    name that is not in it is a programming error, raised at trace time."""
+    if part not in _KNOWN:
+        raise ValueError(f"llmc.{part} is not in obs/scopes.py SCOPES")
+    return jax.named_scope(PREFIX + part)
+
+
+__all__ = ["ATTN", "COMMON", "MLA", "MOE", "PREFIX", "SCOPES", "SSM", "scope"]

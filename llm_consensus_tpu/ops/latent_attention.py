@@ -34,6 +34,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from llm_consensus_tpu.obs.scopes import scope
 from llm_consensus_tpu.ops.attention import NEG_INF
 from llm_consensus_tpu.ops.norms import rms_norm
 from llm_consensus_tpu.ops.quant import (
@@ -108,55 +109,71 @@ def latent_attention(
     """Attention output [B, T, H * v_head_dim] and the cache with this
     call's latents written at (``layer_idx``, ``start_pos``)."""
     b, t, _ = h.shape
-    c_q = rms_norm(qeinsum("btd,dr->btr", h, lp["wq_a"]), lp["q_norm"], rms_eps)
-    # The barrier stands between the product and its split into heads: the
-    # chip's layout assignment otherwise carries the split back onto the
-    # weight and relays the layer's whole ``wq_b`` before every product
-    # (75 MB a layer a decode step at DeepSeek-V2's widths) where a few
-    # activations would do. It changes no value.
-    q = jax.lax.optimization_barrier(
-        qeinsum("btr,rk->btk", c_q, lp["wq_b"])).reshape(
-        b, t, n_heads, qk_nope_dim + qk_rope_dim)
-    q_nope = q[..., :qk_nope_dim]
-    q_rope = apply_rope(q[..., qk_nope_dim:], cos, sin)
+    with scope("mla.q"):
+        c_q = rms_norm(
+            qeinsum("btd,dr->btr", h, lp["wq_a"]), lp["q_norm"], rms_eps)
+        # The barrier stands between the product and its split into heads:
+        # the chip's layout assignment otherwise carries the split back onto
+        # the weight and relays the layer's whole ``wq_b`` before every
+        # product (75 MB a layer a decode step at DeepSeek-V2's widths)
+        # where a few activations would do. It changes no value.
+        q = jax.lax.optimization_barrier(
+            qeinsum("btr,rk->btk", c_q, lp["wq_b"])).reshape(
+            b, t, n_heads, qk_nope_dim + qk_rope_dim)
+        q_nope = q[..., :qk_nope_dim]
+        q_rope = apply_rope(q[..., qk_nope_dim:], cos, sin)
 
-    ckr = qeinsum("btd,dr->btr", h, lp["wkv_a"])
-    c_kv = rms_norm(ckr[..., :kv_lora_rank], lp["kv_norm"], rms_eps)
-    k_rope = apply_rope(ckr[..., None, kv_lora_rank:], cos, sin)[:, :, 0]
-    latent = jnp.concatenate([c_kv, k_rope], axis=-1)        # [B, T, rank+rope]
-    if cache is not None:
-        cache = (
-            _write_token(cache, latent[:, 0], layer_idx, start_pos) if t == 1
-            else kv_write_rows(cache, latent[:, :, None, :], layer_idx, start_pos))
+    with scope("mla.kv_a"):
+        ckr = qeinsum("btd,dr->btr", h, lp["wkv_a"])
+        c_kv = rms_norm(ckr[..., :kv_lora_rank], lp["kv_norm"], rms_eps)
+        k_rope = apply_rope(ckr[..., None, kv_lora_rank:], cos, sin)[:, :, 0]
+        latent = jnp.concatenate([c_kv, k_rope], axis=-1)    # [B, T, rank+rope]
+        if cache is not None:
+            cache = (
+                _write_token(cache, latent[:, 0], layer_idx, start_pos)
+                if t == 1 else kv_write_rows(
+                    cache, latent[:, :, None, :], layer_idx, start_pos))
 
-    w_kvb = dequantize(lp["wkv_b"], h.dtype).reshape(
-        kv_lora_rank, n_heads, qk_nope_dim + v_head_dim)
-    w_uk, w_uv = w_kvb[..., :qk_nope_dim], w_kvb[..., qk_nope_dim:]
+    with scope("mla.absorb"):
+        w_kvb = dequantize(lp["wkv_b"], h.dtype).reshape(
+            kv_lora_rank, n_heads, qk_nope_dim + v_head_dim)
+        w_uk, w_uv = w_kvb[..., :qk_nope_dim], w_kvb[..., qk_nope_dim:]
     f32 = dict(preferred_element_type=jnp.float32)
 
     def attend(width: Optional[int]) -> jax.Array:
         """[B, T, H, v_head_dim] over the first ``width`` slots of this
         layer's latents (the bucket, or this call's own without a cache)."""
-        if cache is None:
-            lat, keep = latent, mask
-        else:
-            lat = kv_layer(cache, layer_idx, width)[:, :, 0, :].astype(h.dtype)
-            keep = mask[..., :lat.shape[1]]
-        c_all, r_all = lat[..., :kv_lora_rank], lat[..., kv_lora_rank:]
-        rope_scores = jnp.einsum("bthr,bsr->bhts", q_rope, r_all, **f32)
+        with scope("mla.sweep"):
+            if cache is None:
+                lat, keep = latent, mask
+            else:
+                lat = kv_layer(
+                    cache, layer_idx, width)[:, :, 0, :].astype(h.dtype)
+                keep = mask[..., :lat.shape[1]]
+            c_all, r_all = lat[..., :kv_lora_rank], lat[..., kv_lora_rank:]
+            rope_scores = jnp.einsum("bthr,bsr->bhts", q_rope, r_all, **f32)
+        with scope("mla.absorb"):
+            if absorbed:
+                q_lat = jnp.einsum("bthd,chd->bthc", q_nope, w_uk)
+            else:
+                k_nope = jnp.einsum("bsc,chd->bshd", c_all, w_uk)
+        with scope("mla.sweep"):
+            if absorbed:
+                scores = jnp.einsum("bthc,bsc->bhts", q_lat, c_all, **f32)
+            else:
+                scores = jnp.einsum("bthd,bshd->bhts", q_nope, k_nope, **f32)
+            scores = jnp.where(
+                keep[:, None], (scores + rope_scores) * scale, NEG_INF)
+            probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
+            if absorbed:
+                o_lat = jnp.einsum("bhts,bsc->bthc", probs, c_all)
         if absorbed:
-            q_lat = jnp.einsum("bthd,chd->bthc", q_nope, w_uk)
-            scores = jnp.einsum("bthc,bsc->bhts", q_lat, c_all, **f32)
-        else:
-            k_nope = jnp.einsum("bsc,chd->bshd", c_all, w_uk)
-            scores = jnp.einsum("bthd,bshd->bhts", q_nope, k_nope, **f32)
-        scores = jnp.where(keep[:, None], (scores + rope_scores) * scale, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
-        if absorbed:
-            o_lat = jnp.einsum("bhts,bsc->bthc", probs, c_all)
-            return jnp.einsum("bthc,chd->bthd", o_lat, w_uv)
-        v = jnp.einsum("bsc,chd->bshd", c_all, w_uv)
-        return jnp.einsum("bhts,bshd->bthd", probs, v)
+            with scope("mla.absorb"):
+                return jnp.einsum("bthc,chd->bthd", o_lat, w_uv)
+        with scope("mla.absorb"):
+            v = jnp.einsum("bsc,chd->bshd", c_all, w_uv)
+        with scope("mla.sweep"):
+            return jnp.einsum("bhts,bshd->bthd", probs, v)
 
     if cache is None or absorbed:
         out = attend(kv_width)
@@ -165,6 +182,8 @@ def latent_attention(
         # exactly 0 to every sum, so the narrowest branch that covers the
         # frontier gives the whole bucket's result up to a reduction's order.
         slots = mask.shape[-1]
-        widths, at = prefill_sweep(t, slots, start_pos + t)
+        with scope("mla.sweep"):
+            widths, at = prefill_sweep(t, slots, start_pos + t)
         out = jax.lax.switch(at, [partial(attend, w) for w in widths])
-    return out.reshape(b, t, n_heads * v_head_dim), cache
+    with scope("mla.sweep"):
+        return out.reshape(b, t, n_heads * v_head_dim), cache

@@ -39,6 +39,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from llm_consensus_tpu.obs.scopes import scope
 from llm_consensus_tpu.ops.mlp import _activate, gated_mlp
 from llm_consensus_tpu.ops.quant import dequantize
 
@@ -98,8 +99,11 @@ def moe_block(
             f"router scoring {scoring!r} is not computed: only 'softmax'")
     b, t, d = x.shape
     n = b * t
-    tokens = x.reshape(n, d)
-    w_gate, w_up, w_down = (dequantize(w, x.dtype) for w in (w_gate, w_up, w_down))
+    with scope("moe.route"):
+        tokens = x.reshape(n, d)
+    with scope("moe.experts"):
+        w_gate, w_up, w_down = (
+            dequantize(w, x.dtype) for w in (w_gate, w_up, w_down))
     if layer is None:
         held, first_group = w_gate.shape[0], 0
     else:
@@ -110,51 +114,58 @@ def moe_block(
         # (0.9 GB a layer a step at 20 experts of 5,120 x 1,536) first.
         n_stacked, held = w_gate.shape[:2]
         first_group = layer * held
-        w_gate, w_up, w_down = (
-            w.reshape(n_stacked * held, *w.shape[2:]) for w in (w_gate, w_up, w_down))
+        with scope("moe.experts"):
+            w_gate, w_up, w_down = (
+                w.reshape(n_stacked * held, *w.shape[2:])
+                for w in (w_gate, w_up, w_down))
     n_groups_all = w_gate.shape[0]
 
-    logits = jnp.einsum(
-        "nd,dr->nr", tokens.astype(jnp.float32), w_router.astype(jnp.float32)
-    )
-    top_idx, weights = route(
-        logits, top_k, n_groups, groups_per_token, norm_topk, routed_scale)
+    with scope("moe.route"):
+        logits = jnp.einsum(
+            "nd,dr->nr", tokens.astype(jnp.float32),
+            w_router.astype(jnp.float32))
+        top_idx, weights = route(
+            logits, top_k, n_groups, groups_per_token, norm_topk, routed_scale)
 
     # Sort the N*k pairs by held expert; a pair on an absent expert takes
     # a key past every group and sorts last. The buffer is rounded up to
     # whole 8-row tiles (rows that are no pair sort last too): XLA's TPU
     # grouped-product kernel takes no other, and a buffer it refuses is
     # computed as one dense product an expert over every row.
-    pairs = n * top_k
-    local = top_idx.reshape(-1) - first_expert
-    is_held = (local >= 0) & (local < held)
-    key = jnp.pad(
-        jnp.where(is_held, first_group + local, n_groups_all), (0, -pairs % 8),
-        constant_values=n_groups_all)
-    order = jnp.argsort(key, stable=True)
-    pair_token = jnp.minimum(order // top_k, n - 1)
-    group_sizes = jnp.zeros((n_groups_all,), jnp.int32).at[key].add(1, mode="drop")
+    with scope("moe.experts"):
+        pairs = n * top_k
+        local = top_idx.reshape(-1) - first_expert
+        is_held = (local >= 0) & (local < held)
+        key = jnp.pad(
+            jnp.where(is_held, first_group + local, n_groups_all), (0, -pairs % 8),
+            constant_values=n_groups_all)
+        order = jnp.argsort(key, stable=True)
+        pair_token = jnp.minimum(order // top_k, n - 1)
+        group_sizes = jnp.zeros((n_groups_all,), jnp.int32).at[key].add(1, mode="drop")
 
-    rows = tokens[pair_token]                                   # [P, D]
-    h = _activate(jax.lax.ragged_dot(rows, w_gate, group_sizes), activation)
-    h = h * jax.lax.ragged_dot(rows, w_up, group_sizes)
-    y = jax.lax.ragged_dot(h, w_down, group_sizes)              # [P, D]
-    # Back to (token, choice) order; a row past the last group holds
-    # nothing of an expert and is masked, not multiplied by zero.
-    back = jnp.zeros_like(order).at[order].set(jnp.arange(order.size))
-    y = jnp.where(is_held[:, None], y[back[:pairs]], 0).reshape(n, top_k, d)
-    out = jnp.einsum(
-        "nk,nkd->nd", weights, y, preferred_element_type=jnp.float32
-    ).astype(x.dtype)
+        rows = tokens[pair_token]                                   # [P, D]
+        h = _activate(jax.lax.ragged_dot(rows, w_gate, group_sizes), activation)
+        h = h * jax.lax.ragged_dot(rows, w_up, group_sizes)
+        y = jax.lax.ragged_dot(h, w_down, group_sizes)              # [P, D]
+        # Back to (token, choice) order; a row past the last group holds
+        # nothing of an expert and is masked, not multiplied by zero.
+        back = jnp.zeros_like(order).at[order].set(jnp.arange(order.size))
+        y = jnp.where(is_held[:, None], y[back[:pairs]], 0).reshape(n, top_k, d)
+        out = jnp.einsum(
+            "nk,nkd->nd", weights, y, preferred_element_type=jnp.float32
+        ).astype(x.dtype)
 
     if shared is not None:
-        out = out + gated_mlp(tokens, *shared, activation)
-    out = out.reshape(b, t, d)
+        with scope("moe.shared"):
+            out = out + gated_mlp(tokens, *shared, activation)
+    with scope("moe.experts"):
+        out = out.reshape(b, t, d)
     if not with_stats:
         return out
-    stats = jnp.stack([
-        jnp.asarray(n * top_k, jnp.int32),
-        jnp.sum(group_sizes),
-        jnp.sum(group_sizes > 0, dtype=jnp.int32),
-    ])
+    with scope("moe.stats"):
+        stats = jnp.stack([
+            jnp.asarray(n * top_k, jnp.int32),
+            jnp.sum(group_sizes),
+            jnp.sum(group_sizes > 0, dtype=jnp.int32),
+        ])
     return out, stats

@@ -870,6 +870,7 @@ def decode_attention(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="llmc_decode_attention",
     )(*operands)
     if return_state:
         out, m_out, l_out = out

@@ -226,5 +226,6 @@ def flash_attention(
             transcendentals=b * hq * t * s_eff,
         ),
         interpret=interpret,
+        name="llmc_flash_attention",
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

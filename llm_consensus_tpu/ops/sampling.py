@@ -11,6 +11,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from llm_consensus_tpu.obs.scopes import scope
 from llm_consensus_tpu.ops.attention import NEG_INF
 
 
@@ -22,21 +23,22 @@ def sample_token(
     top_p: Optional[float] = None,
 ) -> jax.Array:
     """Sample next-token ids [B]. temperature==0 → greedy argmax."""
-    if temperature == 0.0:
-        return jnp.argmax(logits, axis=-1)
+    with scope("sample"):
+        if temperature == 0.0:
+            return jnp.argmax(logits, axis=-1)
 
-    logits = logits.astype(jnp.float32) / temperature
-    if top_k is not None:
-        kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
-        logits = jnp.where(logits < kth, NEG_INF, logits)
-    if top_p is not None:
-        sorted_logits = jnp.sort(logits, axis=-1)[..., ::-1]
-        probs = jax.nn.softmax(sorted_logits, axis=-1)
-        cumprobs = jnp.cumsum(probs, axis=-1)
-        # smallest set of tokens whose cumulative probability ≥ top_p
-        keep_sorted = cumprobs - probs < top_p
-        threshold = jnp.min(
-            jnp.where(keep_sorted, sorted_logits, jnp.inf), axis=-1, keepdims=True
-        )
-        logits = jnp.where(logits < threshold, NEG_INF, logits)
-    return jax.random.categorical(key, logits, axis=-1)
+        logits = logits.astype(jnp.float32) / temperature
+        if top_k is not None:
+            kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
+            logits = jnp.where(logits < kth, NEG_INF, logits)
+        if top_p is not None:
+            sorted_logits = jnp.sort(logits, axis=-1)[..., ::-1]
+            probs = jax.nn.softmax(sorted_logits, axis=-1)
+            cumprobs = jnp.cumsum(probs, axis=-1)
+            # smallest set of tokens whose cumulative probability ≥ top_p
+            keep_sorted = cumprobs - probs < top_p
+            threshold = jnp.min(
+                jnp.where(keep_sorted, sorted_logits, jnp.inf), axis=-1, keepdims=True
+            )
+            logits = jnp.where(logits < threshold, NEG_INF, logits)
+        return jax.random.categorical(key, logits, axis=-1)

@@ -32,6 +32,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from llm_consensus_tpu.obs.scopes import scope
 from llm_consensus_tpu.ops.quant import qeinsum
 
 _HI = jax.lax.Precision.HIGHEST
@@ -193,38 +194,46 @@ def mixer(cfg, u: jax.Array, lp: dict, state: jax.Array, tail: jax.Array,
     # branch stays float32: the convolution, the gate and the scan are
     # elementwise work on [T, 2 inner], and each bf16 rounding spared here
     # is one the carried state does not inherit.
-    proj = qeinsum("btd,dk->btk", u * cfg.ssm_in_multiplier, lp["ssm_in"],
-                   preferred_element_type=jnp.float32)
-    z, xbc, dt = jnp.split(proj, [inner, inner + cfg.ssm_conv_width], axis=-1)
-    # mup: one multiplier a segment of the in-projection
-    z = z * mz
-    xbc = xbc * jnp.concatenate([
-        jnp.full((inner,), mx, xbc.dtype), jnp.full((gn,), mb, xbc.dtype),
-        jnp.full((gn,), mc, xbc.dtype)])
-    dt = jax.nn.softplus(dt * mdt + lp["ssm_dt_bias"].astype(jnp.float32))
-    a = -jnp.exp(lp["ssm_a_log"].astype(jnp.float32))
-    d = lp["ssm_d"].astype(jnp.float32)
+    with scope("ssm.in_proj"):
+        proj = qeinsum("btd,dk->btk", u * cfg.ssm_in_multiplier, lp["ssm_in"],
+                       preferred_element_type=jnp.float32)
+        z, xbc, dt = jnp.split(
+            proj, [inner, inner + cfg.ssm_conv_width], axis=-1)
+        # mup: one multiplier a segment of the in-projection
+        z = z * mz
+        xbc = xbc * jnp.concatenate([
+            jnp.full((inner,), mx, xbc.dtype), jnp.full((gn,), mb, xbc.dtype),
+            jnp.full((gn,), mc, xbc.dtype)])
+        dt = jax.nn.softplus(dt * mdt + lp["ssm_dt_bias"].astype(jnp.float32))
+        a = -jnp.exp(lp["ssm_a_log"].astype(jnp.float32))
+        d = lp["ssm_d"].astype(jnp.float32)
+    with scope("ssm.conv"):
+        if t == 1:
+            live = None if lo is None else jnp.logical_and(lo == 0, hi == 1)
+            xbc, tail = conv_step(
+                xbc, tail, lp["ssm_conv"], lp["ssm_conv_bias"], live)
+            if live is not None:
+                dt = jnp.where(live[:, None, None], dt, 0.0)
+        else:
+            xbc, tail = causal_conv(
+                xbc, tail, lp["ssm_conv"], lp["ssm_conv_bias"], lo, hi)
+            if lo is not None:
+                dt = jnp.where(span_mask(t, lo, hi)[..., None], dt, 0.0)
+        xbc = jax.nn.silu(xbc)
+        xs, bm, cm = jnp.split(xbc, [inner, inner + gn], axis=-1)
+        xs = xs.reshape(b, t, h, p)
+        bm, cm = bm.reshape(b, t, g, n), cm.reshape(b, t, g, n)
     if t == 1:
-        live = None if lo is None else jnp.logical_and(lo == 0, hi == 1)
-        xbc, tail = conv_step(
-            xbc, tail, lp["ssm_conv"], lp["ssm_conv_bias"], live)
-        if live is not None:
-            dt = jnp.where(live[:, None, None], dt, 0.0)
+        with scope("ssm.step"):
+            y, state = ssd_step(
+                xs[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0], d, state)
+            y = y[:, None]
     else:
-        xbc, tail = causal_conv(
-            xbc, tail, lp["ssm_conv"], lp["ssm_conv_bias"], lo, hi)
-        if lo is not None:
-            dt = jnp.where(span_mask(t, lo, hi)[..., None], dt, 0.0)
-    xbc = jax.nn.silu(xbc)
-    xs, bm, cm = jnp.split(xbc, [inner, inner + gn], axis=-1)
-    xs = xs.reshape(b, t, h, p)
-    bm, cm = bm.reshape(b, t, g, n), cm.reshape(b, t, g, n)
-    if t == 1:
-        y, state = ssd_step(xs[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0], d, state)
-        y = y[:, None]
-    else:
-        y, state = ssd_chunked(xs, dt, a, bm, cm, d, state, cfg.ssm_chunk)
-    y = gated_group_norm(
-        y.reshape(b, t, inner), z, lp["ssm_norm"], g, cfg.rms_eps)
-    out = qeinsum("btk,kd->btd", y.astype(u.dtype), lp["ssm_out"])
-    return out * cfg.ssm_out_multiplier, state, tail
+        with scope("ssm.scan"):
+            y, state = ssd_chunked(xs, dt, a, bm, cm, d, state, cfg.ssm_chunk)
+    with scope("ssm.norm"):
+        y = gated_group_norm(
+            y.reshape(b, t, inner), z, lp["ssm_norm"], g, cfg.rms_eps)
+    with scope("ssm.out_proj"):
+        out = qeinsum("btk,kd->btd", y.astype(u.dtype), lp["ssm_out"])
+        return out * cfg.ssm_out_multiplier, state, tail

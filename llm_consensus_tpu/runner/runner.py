@@ -68,6 +68,13 @@ class RunResult:
     responses: list[Response] = field(default_factory=list)
     warnings: list[str] = field(default_factory=list)
     failed_models: list[str] = field(default_factory=list)
+    # One entry a panelist, in PANEL order (``responses`` is in completion
+    # order): {"model", "t0_ns", "t1_ns"} from its ``worker`` span's own
+    # clock reads, and "marks", its answer's way through its pool
+    # (Response.marks; None for a remote, unpooled or failed panelist).
+    # None for a worker the watchdog abandoned. The serving tier turns
+    # them into the result's ``timings.panel`` (serve/scheduler.py).
+    workers: list[Optional[dict]] = field(default_factory=list)
 
 
 class AllModelsFailed(RuntimeError):
@@ -153,7 +160,7 @@ class Runner:
         passes per-request callbacks here, so no callback state is ever
         shared between runs in flight — ``with_callbacks`` mutates the
         instance and remains the single-run CLI's API."""
-        result = RunResult()
+        result = RunResult(workers=[None] * len(models))
         lock = sanitizer.make_lock("runner.result")
         # Sealed once _collect returns: an abandoned (stalled) worker that
         # wakes up later must not mutate a result the caller already holds.
@@ -190,9 +197,10 @@ class Runner:
             with self._spans.span(
                 "worker", "runner", model=model, role="panel", wid=wid,
                 trace=self._trace,
-            ):
+            ) as sp:
+                marks = None
                 try:
-                    query_one(model, wid)
+                    marks = query_one(model, wid)
                 except Exception as err:
                     with lock:
                         accounted = wid in done or wid in abandoned
@@ -203,8 +211,15 @@ class Runner:
                                 cb.on_model_error(model, err)
                             except Exception:
                                 pass  # the error hook may be the broken one
+            with lock:
+                if not sealed[0]:
+                    result.workers[wid] = {
+                        "model": model, "t0_ns": sp.t0_ns, "t1_ns": sp.t1_ns,
+                        "marks": marks,
+                    }
 
-        def query_one(model: str, wid: int) -> None:
+        def query_one(model: str, wid: int) -> Optional[dict]:
+            """One panelist's query; returns its answer's marks."""
             model_ctx = ctx.with_timeout(self._timeout)
             with lock:
                 ctxs[wid] = model_ctx
@@ -271,6 +286,7 @@ class Runner:
                         pass
                 if cb.on_model_complete:
                     cb.on_model_complete(model)
+                return getattr(resp, "marks", None)
             finally:
                 # The analog of the reference's deferred context cancel:
                 # release the per-model context from the run context.

@@ -208,6 +208,11 @@ class ConsensusGateway:
         # unwritten responses and unflushed follower run dirs.
         self._open_cond = sanitizer.make_condition("serve.gateway.open")
         self._open_requests = 0
+        # Executed replies and the seconds each spent between its
+        # ``consensus_run``'s end and its ``request``'s (/statsz ``serve``).
+        self._tail_lock = sanitizer.make_lock("serve.gateway.tail")
+        self._reply_tail_s = 0.0
+        self._reply_tails = 0
         from llm_consensus_tpu import faults, obs
 
         self._faults = faults.plan()
@@ -994,6 +999,15 @@ class ConsensusGateway:
         reg.register("admission", self.admission.snapshot)
         reg.register("cache", self.cache.stats)
 
+        def serve_block() -> dict:
+            # Executed replies and the seconds between each one's
+            # ``consensus_run`` end and its ``request`` end.
+            with self._tail_lock:
+                return {"reply_tail_s": round(self._reply_tail_s, 6),
+                        "reply_tails": self._reply_tails}
+
+        reg.register("serve", serve_block)
+
         def batchers() -> dict:
             from llm_consensus_tpu.obs.export import collect_batcher_stats
 
@@ -1459,10 +1473,18 @@ class ConsensusGateway:
                 # histogram should show. (A vanished client has no
                 # latency anyone experienced; skip it.)
                 self._observe("e2e", req, time.monotonic() - t0, outcome)
-            self._spans.complete(
+            t1_ns = self._spans.complete(
                 "request", t0_ns, "serve", trace=req.trace_id,
                 outcome=outcome, priority=req.priority,
             )
+            if respond.run_end_ns:
+                # An executed run's reply: what followed its last judge
+                # token (persist, the flight's end, the cache, the last
+                # write) cannot ride in the result it serialises.
+                with self._tail_lock:
+                    self._reply_tail_s += max(
+                        t1_ns - respond.run_end_ns, 0) / 1e9
+                    self._reply_tails += 1
 
     @staticmethod
     def _result_outcome(out, degraded: Optional[str]) -> str:
@@ -1613,10 +1635,18 @@ class ConsensusGateway:
                 self._flights.end(flight)
                 if resident is not None:
                     self._resident_unregister(key)
-            flight.finish(out)
-            self.cache.put(key, out)
-            respond.done(out, session.run_id, coalesced=False,
-                         degraded=degraded)
+            # The reply's last writes, after ``consensus_run`` has ended
+            # and the run is on disk; ``serve_consensus`` books the whole
+            # tail (run end → request end) from ``respond.run_end_ns``.
+            respond.run_end_ns = session.run_end_ns
+            with self._spans.span(
+                "reply.close", "serve", trace=req.trace_id,
+                run_id=session.run_id,
+            ):
+                flight.finish(out)
+                self.cache.put(key, out)
+                respond.done(out, session.run_id, coalesced=False,
+                             degraded=degraded)
             return self._result_outcome(out, degraded)
         finally:
             ctx.close()
@@ -1680,6 +1710,9 @@ class _Responder:
                  trace_id: Optional[str] = None):
         self._handler = handler
         self._sse = sse
+        # Where the executed run's ``consensus_run`` ended (0: this reply
+        # executed none): the gateway counts the reply's tail from it.
+        self.run_end_ns = 0
         self._writer: Optional[_SSEWriter] = None
         self._gateway = handler._gateway
         self._trace = trace_id

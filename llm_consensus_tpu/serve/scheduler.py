@@ -96,10 +96,78 @@ class RunSession:
     run_id: str
     run_dir: str  # "" when persistence is disabled
     ctx: Context
+    # Where ``consensus_run`` ended (``time.monotonic_ns``; 0 until it has):
+    # the gateway counts the reply's tail from here (``serve.reply_tail_s``).
+    run_end_ns: int = 0
+
+
+def panel_timings(run_ns: int, judge_ns: int, workers: list) -> dict:
+    """What divides ``panel_ms``, from the clock reads the panel's spans
+    made: each panelist's ``worker`` span (``t0_ns``, ``t1_ns``) and its
+    answer's marks through its pool (runner/runner.py ``RunResult.workers``,
+    engine/batcher.py ``_Stream.marks``).
+
+      panel             one entry a panelist, in panel order: ``model``;
+                        ``lead_in_ms`` the run starts → its worker starts;
+                        ``wall_ms`` → its answer is back (the worker ends);
+                        and inside the wall, for a pooled panelist,
+                        ``queue_ms`` → its first ``pool.admit``,
+                        ``prefill_ms`` → its first token is on the host,
+                        ``decode_ms`` → its last token is (what is left of
+                        the wall is the answer's way back to the worker),
+                        ``tokens``, ``prompt_tokens`` and ``decode_steps``,
+                        the steps its pool landed between the two tokens.
+                        A panelist without marks (remote, unpooled, failed)
+                        has its lead-in and its wall only.
+      panel_gate        the model whose answer came last
+      panel_skew_ms     first answer → last answer
+      judge_prepare_ms  last answer → the judge's worker starts
+                        (agreement, confidence, render, tokenise)
+
+    so that the gating panelist's ``lead_in_ms + wall_ms`` and
+    ``judge_prepare_ms`` are ``panel_ms``. A boundary never precedes the
+    one before it, as in ``run_timings``."""
+    entries, answers = [], []
+    for w in workers:
+        if not w or w.get("t1_ns") is None:
+            continue
+        t0 = max(w["t0_ns"], run_ns)
+        t1 = max(w["t1_ns"], t0)
+        entry = {"model": w["model"], "lead_in_ms": (t0 - run_ns) / 1e6,
+                 "wall_ms": (t1 - t0) / 1e6}
+        marks = w.get("marks") or {}
+        if marks.get("admit_ns") is not None and marks.get(
+                "first_token_ns") is not None:
+            edges = [t0, marks["admit_ns"], marks["first_token_ns"],
+                     marks.get("last_token_ns", t1)]
+            for i in range(1, len(edges)):
+                edges[i] = min(max(edges[i], edges[i - 1]), t1)
+            entry.update(
+                queue_ms=(edges[1] - edges[0]) / 1e6,
+                prefill_ms=(edges[2] - edges[1]) / 1e6,
+                decode_ms=(edges[3] - edges[2]) / 1e6,
+                tokens=marks.get("tokens"),
+                prompt_tokens=marks.get("prompt_tokens"),
+            )
+            if "first_step" in marks and "last_step" in marks:
+                entry["decode_steps"] = max(
+                    marks["last_step"] - marks["first_step"], 0)
+        entries.append(entry)
+        answers.append((t1, w["model"]))
+    if not entries:
+        return {}
+    last, gate = max(answers)
+    return {
+        "panel": entries,
+        "panel_gate": gate,
+        "panel_skew_ms": (last - min(answers)[0]) / 1e6,
+        "judge_prepare_ms": max(judge_ns - last, 0) / 1e6,
+    }
 
 
 def run_timings(arrival_ns: int, run_ns: int, judge_ns: int, end_ns: int,
-                marks: Optional[dict]) -> Optional[dict]:
+                marks: Optional[dict],
+                workers: Optional[list] = None) -> Optional[dict]:
     """Where one served run's time went, from the clock reads its spans
     made (``time.monotonic_ns``): the request's arrival, the start of
     ``consensus_run``, the start of the judge's ``worker`` span, the end
@@ -117,6 +185,9 @@ def run_timings(arrival_ns: int, run_ns: int, judge_ns: int, end_ns: int,
                             the first decode chunk it rides down with)
       judge_first_chunk_ms  → its first text is handed to the stream
       judge_decode_ms       → the run ends
+
+    ``workers`` (the panel's clock reads, ``RunResult.workers``) adds what
+    divides ``panel_ms``: ``panel_timings``'s keys, beside the six.
 
     None when the judge reported no marks (a single-response passthrough,
     a remote or unpooled judge): the block is then left out."""
@@ -142,6 +213,7 @@ def run_timings(arrival_ns: int, run_ns: int, judge_ns: int, end_ns: int,
     out["total_ms"] = (edges[-1] - edges[0]) / 1e6
     out["judge_prompt_tokens"] = marks.get("prompt_tokens")
     out["judge_tokens"] = marks.get("tokens")
+    out.update(panel_timings(edges[1], edges[2], workers or []))
     return out
 
 
@@ -279,17 +351,22 @@ class Scheduler:
                 )
             result = runner.run(ctx, list(req.models), req.prompt, callbacks=callbacks)
 
-            agreement = score_agreement(result.responses)
-            judge_provider = self._registry.get(req.judge)
-            # Judge work outranks this request's own panel class by one
-            # step (floored at HIGH): the judge serializes the run, so
-            # on a contended engine its stream must not queue behind
-            # other runs' panel streams of the same class.
-            judge = overlap if overlap is not None else Judge(
-                judge_provider, req.judge, max_tokens=req.max_tokens,
-                priority=max(0, req.priority - 1),
-                trace_id=req.trace_id,
-            )
+            # Between the last panel answer and the judge's worker: no
+            # pool has work while the request thread is here.
+            with self._spans.span(
+                "judge.prepare", "runner", trace=req.trace_id,
+            ):
+                agreement = score_agreement(result.responses)
+                judge_provider = self._registry.get(req.judge)
+                # Judge work outranks this request's own panel class by
+                # one step (floored at HIGH): the judge serializes the
+                # run, so on a contended engine its stream must not queue
+                # behind other runs' panel streams of the same class.
+                judge = overlap if overlap is not None else Judge(
+                    judge_provider, req.judge, max_tokens=req.max_tokens,
+                    priority=max(0, req.priority - 1),
+                    trace_id=req.trace_id,
+                )
             judge_cb = None
             if emit is not None:
                 judge_cb = lambda c: emit("judge_chunk", req.judge, c)  # noqa: E731
@@ -338,9 +415,10 @@ class Scheduler:
             out.timings = run_timings(
                 arrival_ns if arrival_ns is not None else t0_run, t0_run,
                 judge_span.t0_ns, t1_run,
-                getattr(judge, "last_marks", None),
+                getattr(judge, "last_marks", None), result.workers,
             )
-            self.persist(session, out, telemetry=True)
+            session.run_end_ns = t1_run
+            self.persist(session, out, telemetry=True, trace=req.trace_id)
             return out
         finally:
             ctx.close()
@@ -348,7 +426,7 @@ class Scheduler:
     # -- persistence ---------------------------------------------------------
 
     def persist(self, session: RunSession, out: output_mod.Result,
-                telemetry: bool = False) -> None:
+                telemetry: bool = False, trace: Optional[str] = None) -> None:
         """Flush one run's artifacts into its reserved dir (non-fatal).
 
         result.json / prompt.txt / consensus.md always; with
@@ -364,9 +442,19 @@ class Scheduler:
         """
         if not session.run_dir:
             return
-        save_file(session.run_dir, "prompt.txt", out.prompt)
-        save_file(session.run_dir, "consensus.md", out.consensus)
-        save_file(session.run_dir, "result.json", self._stamp(out.to_json()))
+        with self._spans.span(
+            "run.persist", "serve", trace=trace, run_id=session.run_id,
+        ) as sp:
+            save_file(session.run_dir, "prompt.txt", out.prompt)
+            save_file(session.run_dir, "consensus.md", out.consensus)
+            payload = self._stamp(out.to_json())
+            save_file(session.run_dir, "result.json", payload)
+            sp.set(bytes=len(out.prompt) + len(out.consensus) + len(payload))
+            self._persist_telemetry(session, out, telemetry)
+
+    def _persist_telemetry(self, session: RunSession,
+                           out: output_mod.Result, telemetry: bool) -> None:
+        """``persist``'s trace.json and metrics.json (its docstring)."""
         if not telemetry or self._obs is None:
             return
         from llm_consensus_tpu.obs import export as obs_export
@@ -417,7 +505,7 @@ class Scheduler:
         """
         session = self.open_session(req)
         try:
-            self.persist(session, out)
+            self.persist(session, out, trace=req.trace_id)
         finally:
             session.ctx.close()
         return session
