@@ -435,6 +435,13 @@ def _layer(
             q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
         if cfg.key_multiplier != 1.0:
             k = k * cfg.key_multiplier
+        # The barrier stands between the three products and their split
+        # into heads: the chip's layout assignment otherwise carries the
+        # split back onto the weights, relays the whole wq / wk / wv stacks
+        # at a program's entry and passes each layer's three leaves into
+        # that layout before the products read them (24 MB a layer a decode
+        # step at Mistral-7B's widths). It changes no value.
+        q, k, v = jax.lax.optimization_barrier((q, k, v))
         q = q.reshape(b, t, hq, dh)
         k = k.reshape(b, t, hkv, dh)
         v = v.reshape(b, t, hkv, dh)

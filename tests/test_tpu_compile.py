@@ -24,6 +24,12 @@ routing sums, at 384 and 2,048 slots) is compiled whole too, and what its
 text MATERIALISES is held: no copy of the pool inside a step, no pass over a
 layer's ``wq_b``, the weight stacks read where they lie (PR 35).
 
+Every dense model's decode chunk at its cell's own shapes (the trio's 3B, the
+int8 7B, the 7B tensor-parallel over two described devices, the hybrid) and
+the int8 7B's judge-prompt loop are read the same way for ``wq`` / ``wk`` /
+``wv``: no stack relaid at a program's entry, no pass over a layer's three
+leaves before their products (PR 38).
+
 All cases compile in ONE child process (this file run as a script) and
 the tests read its report: loading libtpu and switching the persistent
 compilation cache off (an entry written for a described device cannot be
@@ -34,6 +40,7 @@ Skipped where the topology cannot be described (no libtpu).
 import functools
 import json
 import os
+import re
 import subprocess
 import sys
 import time
@@ -75,6 +82,19 @@ LATENT_CHUNK, LATENT_BUCKET = 512, 2048  # the judge prompt's program
 LATENT_ROWS, LATENT_STEPS = 6, 16        # the judge pool's decode chunk
 LATENT_DECODE_WIDTHS = (384, 2048)       # a panel phase's bucket, a judge phase's
 HYBRID_CONFIG = "benchmark/configs/falcon-h1-34b-pp8-trio-bf16.json"
+# The dense cells' judges: program -> (configuration file, int8 leaves, tp,
+# pool rows, slots); the hybrid's decode chunk is ``_hybrid_ssm_programs``'.
+DENSE_PROGRAMS = {
+    "qwen2.5-3b:decode": (
+        "benchmark/configs/qwen25-trio-bf16.json", False, 1, 6, 1920),
+    "mistral-7b-int8:decode": (
+        "benchmark/configs/mistral7b-trio-int8.json", True, 1, 6, 1920),
+    "mistral-7b-tp2:decode": (
+        "benchmark/configs/mistral7b-trio-bf16-x4.json", False, 2, 8, 1792),
+    "mistral-7b-int8:prefill-loop": (
+        "benchmark/configs/mistral7b-trio-int8.json", True, 1, 1, 2048),
+}
+HYBRID_DECODE = "falcon-h1-34b:decode"
 
 
 def _decode_id(preset, int8_kv, batch) -> str:
@@ -118,11 +138,15 @@ def _compile_all() -> dict:
             lambda s: sds(s.shape, s.dtype), jax.eval_shape(fn)
         )
 
-    def has_kernel(lowered) -> dict:
+    def has_kernel(lowered, read=None) -> dict:
+        """``read``: what else to take from the compiled text, if anything."""
         try:
-            return {"kernel": "tpu_custom_call" in lowered.compile().as_text()}
+            text = lowered.compile().as_text()
         except Exception as err:  # noqa: BLE001 — what the chip would raise
             return {"error": f"{type(err).__name__}: {str(err)[:300]}"}
+        if read is not None:
+            read(text)
+        return {"kernel": "tpu_custom_call" in text}
 
     report: dict = {}
     for preset, int8_kv, batch in DECODE_CASES:
@@ -194,29 +218,29 @@ def _compile_all() -> dict:
     for width in LATENT_DECODE_WIDTHS:
         report[f"latent-decode:kv{width}"] = _latent_decode_chunk(
             sds, shapes, width)
-    report["hybrid-ssm"] = _hybrid_ssm_programs(sds, shapes, has_kernel)
+    reads: dict = {}
+    report["hybrid-ssm"] = _hybrid_ssm_programs(sds, shapes, has_kernel, reads)
+    for name in DENSE_PROGRAMS:
+        _dense_program(name, topo, has_kernel, reads)
+    report.update({f"dense-proj:{name}": got for name, got in reads.items()})
     return report
 
 
-def _hybrid_ssm_programs(sds, shapes, has_kernel) -> dict:
+def _hybrid_ssm_programs(sds, shapes, has_kernel, reads: dict) -> dict:
     """The Falcon-H1 cell's three hot programs at its own shapes: the judge
     prompt's prefill loop (four 512-token chunks, XLA attention at a traced
     start), a wave of six panel prompts (padded to eight rows of 256, the
     prefill kernel) and a 16-step decode chunk of six rows with the
-    sentinel (the decode kernel), each with its per-row state stacks."""
+    sentinel (the decode kernel), each with its per-row state stacks. What
+    the decode chunk does to ``wq`` / ``wk`` / ``wv`` goes into ``reads``."""
     import jax
     import jax.numpy as jnp
 
-    from benchmark import server
     from llm_consensus_tpu.engine.engine import (
         _decode_chunk, _prefill_chunks_loop, _prefill_step)
     from llm_consensus_tpu.models import init_kv_cache, init_params
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, HYBRID_CONFIG)) as f:
-        doc = json.load(f)
-    judge = doc["judge"]
-    cfg = server.model_config(judge, doc["models"][judge])
+    cfg = _judge(HYBRID_CONFIG)
     params = shapes(lambda: init_params(cfg, jax.random.PRNGKey(0)))
 
     def cache(rows, slots=CELL_MAX_SEQ):
@@ -233,28 +257,128 @@ def _hybrid_ssm_programs(sds, shapes, has_kernel) -> dict:
             params, cfg, sds((6,)), sds(()), cache(6),
             shapes(lambda: jax.random.PRNGKey(0)), n_steps=16,
             temperature=0.0, top_k=None, top_p=None, row_start=sds((6,)),
-            kv_width=384, attn_impl="flash", sentinel=True)),
+            kv_width=384, attn_impl="flash", sentinel=True),
+            read=lambda text: reads.update({
+                HYBRID_DECODE: _projection_reads(text, cfg, params)})),
     }
+
+
+def _dense_program(name: str, topo, has_kernel, reads: dict) -> None:
+    """One of ``DENSE_PROGRAMS`` compiled for the described chip (a ``tp``
+    mesh of two described devices under ``param_specs`` / ``cache_specs``
+    where the cell has one), and what it does to ``wq`` / ``wk`` / ``wv``
+    into ``reads``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
+
+    from llm_consensus_tpu.engine.engine import _decode_chunk, _prefill_chunks_loop
+    from llm_consensus_tpu.models import init_kv_cache, init_params
+    from llm_consensus_tpu.ops.quant import init_params_quantized
+    from llm_consensus_tpu.parallel.mesh import make_mesh
+    from llm_consensus_tpu.parallel.sharding import cache_shardings, param_shardings
+
+    config, int8, tp, rows, width = DENSE_PROGRAMS[name]
+    cfg = _judge(config)
+    mesh = make_mesh({"dp": 1, "tp": tp}, topo.devices[:tp]) if tp > 1 else None
+    whole = (NamedSharding(mesh, PartitionSpec()) if mesh
+             else SingleDeviceSharding(topo.devices[0]))
+
+    def placed(make, shardings=None):
+        tree = jax.eval_shape(make)
+        return jax.tree.map(
+            lambda s, where: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=where),
+            tree, shardings(tree) if shardings else jax.tree.map(lambda _: whole, tree))
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=whole)
+
+    key = jax.random.PRNGKey(0)
+    params = placed(
+        lambda: (init_params_quantized if int8 else init_params)(cfg, key),
+        mesh and (lambda _: param_shardings(cfg, mesh)))
+    cache = placed(
+        lambda: init_kv_cache(cfg, rows, CELL_MAX_SEQ, jnp.bfloat16),
+        mesh and (lambda tree: cache_shardings(cfg, mesh, tree)))
+    if name.endswith(":decode"):
+        lowered = _decode_chunk.lower(
+            params, cfg, ints(rows), ints(), cache, placed(lambda: key),
+            n_steps=16, temperature=0.0, top_k=None, top_p=None,
+            row_start=ints(rows), kv_width=width, attn_impl="flash", mesh=mesh,
+            sentinel=True)
+    else:  # the judge prompt: four 512-token chunks of its bucket
+        lowered = _prefill_chunks_loop.lower(
+            params, cfg, ints(width // 512, 1, 512), ints(), ints(), ints(1),
+            cache, max_chunks=width // 512, kv_width=width)
+    got = has_kernel(lowered, read=lambda text: reads.update({
+        name: _projection_reads(text, cfg, params)}))
+    reads.setdefault(name, got)  # what the chip would raise, if it would
+
+
+def _projection_reads(text: str, cfg, params) -> dict:
+    """What a compiled program does to the dense attention block's input
+    projections, by the shape a chip holds of them (int8 leaves: the codes).
+    ``entry``: the entry computation's ``copy`` operations that produce an
+    array shaped like a whole ``wq`` / ``wk`` / ``wv`` stack, ``leaf{layout}``
+    each. ``layer``: the TOP-LEVEL instructions (not inside a fusion) of the
+    loop bodies that produce one layer's, or several layers', whole leaf,
+    ``leaf:operation{layout}`` each; a prefetch keeps the stored layout
+    (``copy-done{2,1,0}``). ``wq`` and ``wo`` are one shape in these models:
+    the shape is what is held."""
+    stacks: dict = {}  # (type, per-chip dims) -> the leaves so shaped
+    for leaf in ("wq", "wk", "wv"):
+        held = params["layers"][leaf]
+        held = held["q8"] if isinstance(held, dict) else held
+        dims = held.sharding.shard_shape(held.shape)
+        dtype = {"int8": "s8", "bfloat16": "bf16"}[str(held.dtype)]
+        stacks.setdefault((dtype, dims), []).append(leaf)
+    assert all(dims[0] == cfg.n_layers for _, dims in stacks)
+    entry, layer = [], []
+    for is_entry, dtype, dims, layout, op, _ in _top_level(text):
+        for (held, stack), leaves in stacks.items():
+            if dtype != held:
+                continue
+            if is_entry and op == "copy" and dims == stack:
+                entry.append(f"{'|'.join(leaves)}{layout}")
+            elif not is_entry and dims[-2:] == stack[1:] and op not in (
+                    "parameter", "get-tuple-element", "bitcast"):
+                layer.append(f"{'|'.join(leaves)}:{op}{layout}")
+    return {"entry": sorted(entry), "layer": sorted(layer)}
 
 
 def _computations(text: str) -> dict:
     """``{name: (is the entry, body text)}`` of a compiled module's text."""
-    import re
-
     return {
         m.group(2): (bool(m.group(1)), m.group(3)) for m in re.finditer(
             r"^(ENTRY )?%?([\w.\-]+) \(.*?\) -> .*? \{\n(.*?)^\}", text, re.S | re.M)
     }
 
 
-def _latent_judge():
-    """The DeepSeek-V2 cell's judge as its configuration file states it."""
+def _judge(config: str):
+    """A cell's judge as its configuration file states it."""
     from benchmark import server
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, LATENT_CONFIG)) as f:
+    with open(os.path.join(root, config)) as f:
         doc = json.load(f)
     return server.model_config(doc["judge"], doc["models"][doc["judge"]])
+
+
+_PRODUCED = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = (\w+)\[([\d,]+)\](\{[\d,]*)\S* ([\w\-]+)\((.*)$",
+    re.M)
+
+
+def _top_level(text: str):
+    """``(is the entry, type, dims, layout, operation, the line's rest)`` of
+    every array a compiled module's entry computation and loop bodies
+    produce at their top level (not inside a fusion)."""
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
+    for name, (is_entry, body) in _computations(text).items():
+        if is_entry or name in bodies:
+            for dtype, dims, layout, op, rest in _PRODUCED.findall(body):
+                yield (is_entry, dtype, tuple(int(d) for d in dims.split(",")),
+                       f"{layout}}}", op, rest)
 
 
 def _latent_decode_chunk(sds, shapes, width: int) -> dict:
@@ -270,7 +394,6 @@ def _latent_decode_chunk(sds, shapes, width: int) -> dict:
     ``scores`` the types of the products shaped rows x heads x slots;
     ``routes`` what ``forward`` booked."""
     import math
-    import re
 
     import jax
 
@@ -278,7 +401,7 @@ def _latent_decode_chunk(sds, shapes, width: int) -> dict:
     from llm_consensus_tpu.models import init_kv_cache, init_params
     from llm_consensus_tpu.models.transformer import attention_routes
 
-    cfg = _latent_judge()
+    cfg = _judge(LATENT_CONFIG)
     attention_routes.reset()
     params = shapes(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     cache = shapes(lambda: init_kv_cache(cfg, LATENT_ROWS, CELL_MAX_SEQ))
@@ -297,15 +420,10 @@ def _latent_decode_chunk(sds, shapes, width: int) -> dict:
         ).compile().as_text()
     except Exception as err:  # noqa: BLE001 — what the chip would raise
         return {"error": f"{type(err).__name__}: {str(err)[:300]}"}
-    computations = _computations(text)
-    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
     writes_in_place = {
-        name for name, (_, body) in computations.items()
+        name for name, (_, body) in _computations(text).items()
         if re.search(r"^\s*ROOT \S+ = \S+ dynamic-update-slice\(", body, re.M)
     }
-    produced = re.compile(
-        r"^\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\](\{[\d,]*)\S* ([\w\-]+)\((.*)$",
-        re.M)
     sizes = {
         "pool": cfg.n_layers * LATENT_ROWS * CELL_MAX_SEQ * cfg.cache_width,
         "wq_b": cfg.q_lora_rank * cfg.n_heads * (cfg.qk_nope_dim + cfg.qk_rope_dim),
@@ -313,24 +431,22 @@ def _latent_decode_chunk(sds, shapes, width: int) -> dict:
     }
     report: dict = {what: [] for what in sizes}
     entry_copies: dict = {}
-    for name, (is_entry, body) in computations.items():
-        if not is_entry and name not in bodies:
+    for is_entry, _, dims, layout, op, rest in _top_level(text):
+        count = math.prod(dims)
+        if is_entry:
+            if op == "copy":
+                shape = ",".join(map(str, dims))
+                what = "|".join(leaves.get(shape, [shape]))
+                entry_copies[what] = entry_copies.get(what, 0) + count * 2 / 1e6
             continue
-        for dims, layout, op, rest in produced.findall(body):
-            count = math.prod(int(d) for d in dims.split(","))
-            if is_entry:
-                if op == "copy":
-                    what = "|".join(leaves.get(dims, [dims]))
-                    entry_copies[what] = entry_copies.get(what, 0) + count * 2 / 1e6
-                continue
-            if op in ("parameter", "get-tuple-element", "bitcast", "while"):
-                continue
-            in_place = op == "dynamic-update-slice" or (
-                op == "fusion" and re.search(r"calls=%?([\w.\-]+)", rest).group(1)
-                in writes_in_place)
-            for what, size in sizes.items():
-                if count == size and not (what == "pool" and in_place):
-                    report[what].append(f"{op}{layout}}}")
+        if op in ("parameter", "get-tuple-element", "bitcast", "while"):
+            continue
+        in_place = op == "dynamic-update-slice" or (
+            op == "fusion" and re.search(r"calls=%?([\w.\-]+)", rest).group(1)
+            in writes_in_place)
+        for what, size in sizes.items():
+            if count == size and not (what == "pool" and in_place):
+                report[what].append(f"{op}{layout}")
     scores = {
         dtype for dtype, dims in re.findall(
             r"= (\w+)\[([\d,]+)\]\S* convolution\(", text)
@@ -349,14 +465,12 @@ def _latent_prefill_branches(sds, shapes) -> dict:
     """The widths of the float32 score blocks in each branch of each
     ``conditional`` of the compiled judge-prompt prefill, a list a
     conditional."""
-    import re
-
     import jax
 
     from llm_consensus_tpu.engine.engine import _prefill_chunks_loop
     from llm_consensus_tpu.models import init_kv_cache, init_params
 
-    cfg = _latent_judge()
+    cfg = _judge(LATENT_CONFIG)
     chunks = LATENT_BUCKET // LATENT_CHUNK
     try:
         text = _prefill_chunks_loop.lower(
@@ -520,3 +634,34 @@ def test_hybrid_ssm_programs_compile(report):
         "loop": {"kernel": False}, "wave": {"kernel": True},
         "decode": {"kernel": True}}
 
+
+DENSE_PROJECTION_HOLDS = ("entry", "layer")
+PREFETCHES = ("copy-done", "slice-done")  # asynchronous, in the stored layout
+
+
+@pytest.mark.parametrize("held", DENSE_PROJECTION_HOLDS)
+@pytest.mark.parametrize("program", [*DENSE_PROGRAMS, HYBRID_DECODE])
+def test_dense_programs_read_wq_wk_wv_where_they_lie(report, program, held):
+    """Every dense cell's judge, compiled for the described chip at the
+    cell's own shapes, consumes its attention input projections where they
+    lie (PR 38: the ``optimization_barrier`` in ``_layer`` between the three
+    products and their split into heads; the parent's programs failed every
+    case). This guards the programs' SHAPE; the times are the chip's
+    (PERF.md section 5).
+
+    ``entry``: no ``wq`` / ``wk`` / ``wv`` stack is copied into another
+    layout once a program (the parent: 805 MB a decode chunk of the int8 7B,
+    378 MB of the 3B, 294 MB of the hybrid).
+
+    ``layer``: inside the loops nothing produces a layer's whole ``wq``,
+    ``wk`` or ``wv`` (the parent: a ``fusion{1,2,0}`` each, a pass into the
+    compiler's fast memory that ``attn.proj`` then read a second time; the
+    hybrid's also a whole stack and four two-layer slices) but, at most,
+    a prefetch in the stored layout."""
+    got = report[f"dense-proj:{program}"]
+    assert "error" not in got, got
+    if held == "entry":
+        assert got["entry"] == []
+    else:
+        stored = [f"{op}{{2,1,0}}" for op in PREFETCHES]
+        assert [p for p in got["layer"] if p.split(":")[1] not in stored] == []
