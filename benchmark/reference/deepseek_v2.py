@@ -66,7 +66,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark.reference.decoder import _take_layer, dense, rms_norm
+from benchmark.reference.decoder import (
+    _take_layer, dense, one_at_a_time, rms_norm)
 
 FAMILIES = ("deepseek_v2",)
 STORED_LEAVES = (
@@ -241,9 +242,10 @@ def experts(h, w, more: dict):
     return out, m_expert, m_group
 
 
-def forward(params: dict, spec: dict, token_ids) -> jax.Array:
-    """Logits [T, V] in float32 for one sequence of token ids; ``spec`` is
-    the model's whole entry in the configuration file."""
+def hidden(params: dict, spec: dict, token_ids) -> jax.Array:
+    """The final-normed hidden states [T, D] in float32 for one sequence of
+    token ids; ``spec`` is the model's whole entry in the configuration
+    file. Leaves the routing margins of the call in ``LAST_MARGINS``."""
     global LAST_MARGINS
     more = spec.get("more_fields") or {}
     if spec["family"] not in FAMILIES or not more.get("kv_lora_rank"):
@@ -278,18 +280,30 @@ def forward(params: dict, spec: dict, token_ids) -> jax.Array:
                 m_expert, m_group = jnp.minimum(m_expert, me), jnp.minimum(m_group, mg)
             else:
                 y = _swiglu(h, w["w_gate"], w["w_up"], w["w_down"])
-            x = x + y
+            x = one_at_a_time(x + y)
         x = _norm(x, params["final_norm"], eps)
-        head = params["embed"].T if spec["tie_embeddings"] else params["lm_head"]
-        cols = jax.tree.leaves(head)[0].shape[-1]
-        logits = jnp.concatenate([
-            _mm(x, jax.tree.map(lambda a: a[..., c:c + VOCAB_BLOCK], head))
-            for c in range(0, cols, VOCAB_BLOCK)], axis=-1)
     LAST_MARGINS = {
         "expert": np.asarray(m_expert, np.float64),
         "group": np.asarray(m_group, np.float64),
     }
-    return logits
+    return x
+
+
+def logits(params: dict, spec: dict, rows) -> jax.Array:
+    """Logits [n, V] in float32 of ``rows`` [n, D], any rows of ``hidden``'s:
+    the head, in blocks of its columns."""
+    with jax.default_matmul_precision("highest"):
+        head = params["embed"].T if spec["tie_embeddings"] else params["lm_head"]
+        cols = jax.tree.leaves(head)[0].shape[-1]
+        return jnp.concatenate([
+            _mm(rows, jax.tree.map(lambda a: a[..., c:c + VOCAB_BLOCK], head))
+            for c in range(0, cols, VOCAB_BLOCK)], axis=-1)
+
+
+def forward(params: dict, spec: dict, token_ids) -> jax.Array:
+    """Logits [T, V] in float32 for one sequence of token ids: ``logits`` of
+    every row of ``hidden``."""
+    return logits(params, spec, hidden(params, spec, token_ids))
 
 
 # What is compared, and at which limit. The worst position of this model
@@ -344,7 +358,12 @@ def compared(err, n_prefill: int) -> dict:
     """The worst position against TOLERANCE (another token), the median
     position against MEDIAN_LIMIT (a lower precision, an error in every
     position), the median of the decoded positions against
-    DECODED_MEDIAN_LIMIT (a broken cache, a lower precision)."""
+    DECODED_MEDIAN_LIMIT (a broken cache, a lower precision).
+
+    Lengths the limits were read at: 1,024 positions, the last 64 decoded,
+    in 1,024 slots, taken whole (PR 31, on the chip). Flips accumulate along
+    a sequence (each moves every later position a little), so the medians
+    read at another length are other numbers: read them there first."""
     return {
         "rel_err_max": [float(err.max()), TOLERANCE],
         "rel_err_median": [float(np.median(err)), MEDIAN_LIMIT],

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmark.reference.decoder import (
-    _head, _take_layer, attention_block, dense, rms_norm)
+    _take_layer, attention_block, dense, logits, one_at_a_time, rms_norm)
 
 FAMILIES = ("mixtral",)
 STORED_LEAVES = (
@@ -62,9 +62,10 @@ _layer_jit = jax.jit(
 )
 
 
-def forward(params: dict, spec: dict, token_ids) -> jax.Array:
-    """Logits [T, V] in float32 for one sequence of token ids; ``spec`` is
-    the model's whole entry in the configuration file."""
+def hidden(params: dict, spec: dict, token_ids) -> jax.Array:
+    """The residual stream [T, D] in float32 after the last block, for one
+    sequence of token ids; ``spec`` is the model's whole entry in the
+    configuration file."""
     more = spec.get("more_fields") or {}
     if spec["family"] not in FAMILIES or not more.get("experts_per_token"):
         raise ValueError(
@@ -79,15 +80,20 @@ def forward(params: dict, spec: dict, token_ids) -> jax.Array:
     with jax.default_matmul_precision("highest"):
         x = params["embed"][ids].astype(jnp.float32)
         for i in range(spec["n_layers"]):
-            x = _layer_jit(
+            x = one_at_a_time(_layer_jit(
                 x, _take_layer(params["layers"], i),
                 top_k=more["experts_per_token"],
                 n_heads=spec["n_heads"], n_kv_heads=spec["n_kv_heads"],
                 head_dim=spec["head_dim"], theta=float(spec["rope_theta"]),
                 eps=float(spec["rms_eps"]), window=spec.get("sliding_window"),
-            )
-        head = params["embed"].T if spec["tie_embeddings"] else params["lm_head"]
-        return _head(x, params["final_norm"], head, float(spec["rms_eps"]))
+            ))
+        return x
+
+
+def forward(params: dict, spec: dict, token_ids) -> jax.Array:
+    """Logits [T, V] in float32 for one sequence of token ids: ``logits``
+    (the dense decoder's: final norm and head) of every row of ``hidden``."""
+    return logits(params, spec, hidden(params, spec, token_ids))
 
 
 # A routed model's worst position cannot be held to the dense decoder's 2.2%.
@@ -122,7 +128,11 @@ DECODED_MEDIAN_LIMIT = 0.055
 def compared(err, n_prefill: int) -> dict:
     """The worst position against TOLERANCE (another token), the median
     position against MEDIAN_LIMIT (an error in every position), the median
-    of the decoded positions against DECODED_MEDIAN_LIMIT (a broken cache)."""
+    of the decoded positions against DECODED_MEDIAN_LIMIT (a broken cache).
+
+    Lengths the limits were read at: 192 positions, the last 48 decoded, in
+    256 slots, at CI size on the CPU (PR 29). No chip reading, none at
+    another length."""
     import numpy as np
 
     return {

@@ -218,9 +218,7 @@ def main() -> int:
     # A configuration the harness cannot compare stops here, before it serves.
     from benchmark import parity
 
-    parity.lengths(cfg)
-    for name, spec in cfg["models"].items():
-        parity.reference_for(name, spec)
+    parity.stated(cfg)
     visible_bytes()
     fixed_length_provider()
     lean_profiler()

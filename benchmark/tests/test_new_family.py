@@ -23,17 +23,6 @@ def config(name: str) -> dict:
         return json.load(f)
 
 
-@pytest.fixture
-def presets():
-    """The program's table, put back as it was after the case."""
-    from llm_consensus_tpu.models.config import MODEL_PRESETS
-
-    before = dict(MODEL_PRESETS)
-    yield MODEL_PRESETS
-    MODEL_PRESETS.clear()
-    MODEL_PRESETS.update(before)
-
-
 def mixtral(**changes) -> dict:
     spec = copy.deepcopy(config("tiny-moe-rehearsal")["models"]["tiny-mixtral"])
     spec.update(changes)
@@ -114,6 +103,8 @@ def test_the_reference_is_chosen_by_name(case):
     assert got.__name__ == f"benchmark.reference.{module}"
     # the contract of benchmark/reference/__init__.py
     assert callable(got.forward) and callable(got.compared)
+    assert callable(got.hidden) and callable(got.logits)  # the blocked form
+    assert "Lengths the limit" in got.compared.__doc__
     assert got.TOLERANCE > 0 and got.STORED_LEAVES
     assert list(got.compared(np.asarray([0.01, 0.02]), 1))[0] == "rel_err_max"
 
