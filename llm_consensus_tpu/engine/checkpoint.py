@@ -168,6 +168,17 @@ def _close_hf_shards(handles, name_to_file) -> None:
             h.__exit__(None, None, None)
 
 
+def _refuse_one_part(cfg: ModelConfig) -> None:
+    """No importer maps a published checkpoint onto a stack a layer kind
+    yet: refused by name, where the llama-family name map would fail on its
+    first missing tensor."""
+    if cfg.layer_kinds:
+        raise ValueError(
+            f"{cfg.name}: no checkpoint importer for a model whose every "
+            f"layer is one part (layer_kinds {cfg.layer_kinds!r}): serve it "
+            "with seeded random weights")
+
+
 def load_hf_safetensors(cfg: ModelConfig, path: str, dtype=jnp.bfloat16) -> dict:
     """Import an HF safetensors checkpoint into the stacked pytree layout.
 
@@ -176,6 +187,7 @@ def load_hf_safetensors(cfg: ModelConfig, path: str, dtype=jnp.bfloat16) -> dict
     Layer tensors are stacked on a leading axis to match the lax.scan
     layout.
     """
+    _refuse_one_part(cfg)
     handles, name_to_file = _open_hf_shards(path)
 
     def get(name: str) -> np.ndarray:
@@ -248,6 +260,7 @@ def load_hf_safetensors_sharded(
     from llm_consensus_tpu.models import init_params
     from llm_consensus_tpu.parallel.sharding import param_specs
 
+    _refuse_one_part(cfg)
     handles, name_to_file = _open_hf_shards(path)
     np_dtype = np.dtype(jnp.zeros((), dtype).dtype.name)
 

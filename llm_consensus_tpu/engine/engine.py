@@ -968,7 +968,12 @@ class Engine:
             # and 0 for a model without a state-space mixer).
             "state_bytes_per_row": state_bytes_per_row(
                 cfg, jnp.dtype(dtype).itemsize),
-            "ssm_layers": cfg.n_layers if cfg.has_ssm else 0,
+            "ssm_layers": cfg.n_ssm_layers,
+            # Layers by what they hold in the cache: keys and values, and
+            # nothing at all (a one-part expert layer); with ``ssm_layers``
+            # they sum to the depth only where every layer is one part.
+            "attn_layers": cfg.n_attn_layers,
+            "expert_layers": cfg.n_expert_layers,
         }
         self._spans.complete(
             "engine.build", t_build_ns, "engine", model=cfg.name,

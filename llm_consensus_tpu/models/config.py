@@ -2,7 +2,7 @@
 
 One generic decoder-only transformer (models/transformer.py) covers every
 family the framework serves — Llama-2/3, Mistral, Gemma, Qwen2, Mixtral,
-DeepSeek-V2, Falcon-H1 — via static config switches, so each (family, shape) pair
+DeepSeek-V2, Falcon-H1, Nemotron-H — via static config switches, so each (family, shape) pair
 compiles to a single XLA program. Every field a family adds defaults to
 "off", so the older presets hash and compare as they did. The reference framework's "model set" is a table of
 remote API names (/root/reference/cmd/llm-consensus/main.go:49-61); here the
@@ -18,7 +18,7 @@ from typing import Optional
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                     # llama | mistral | gemma | qwen2 | mixtral | deepseek_v2 | falcon_h1
+    family: str                     # llama | mistral | gemma | qwen2 | mixtral | deepseek_v2 | falcon_h1 | nemotron_h
     vocab_size: int
     d_model: int
     n_layers: int
@@ -31,7 +31,7 @@ class ModelConfig:
     # original_max_position_embeddings); tuple so the config stays hashable.
     rope_scaling: Optional[tuple[float, float, float, int]] = None
     rms_eps: float = 1e-5
-    activation: str = "silu"        # silu | gelu_tanh
+    activation: str = "silu"        # silu | gelu_tanh | relu2
     norm_offset: float = 0.0        # gemma: weights parameterized as (1 + w)
     embed_scale: bool = False       # gemma: embeddings scaled by sqrt(d_model)
     qkv_bias: bool = False          # qwen2
@@ -54,8 +54,15 @@ class ModelConfig:
     groups_per_token: int = 1       # ... and how many of them a token may reach
     routed_scale: float = 1.0       # multiplies the routed sum
     norm_topk: bool = True          # chosen weights renormalised to sum to 1
-    router_scoring: str = "softmax"  # over the router's whole width
+    router_scoring: str = "softmax"  # over the router's whole width; or
+                                     # "sigmoid_bias": sigmoid scores, chosen
+                                     # by score + a stored per-expert bias
     n_dense_layers: int = 0         # leading layers with a dense MLP of d_ff
+    moe_latent: int = 0             # routed experts work in this width,
+                                    # between two projections; 0 = d_model
+    gated_experts: bool = True      # False: W2 act(W1 x), routed and shared
+    d_shared: int = 0               # the shared expert's width; 0 =
+                                    # n_shared_experts * expert_width
     # -- latent attention (MLA, ops/latent_attention.py); 0 = off ----------
     q_lora_rank: int = 0
     kv_lora_rank: int = 0           # the cache holds kv_lora_rank + qk_rope_dim a token a layer
@@ -85,6 +92,36 @@ class ModelConfig:
     # over the in-projection's segments z | x | B | C | dt
     ssm_multipliers: tuple[float, float, float, float, float] = (1.0,) * 5
     mlp_multipliers: tuple[float, float] = (1.0, 1.0)  # gate, down
+    # -- a stack whose every layer is ONE part (Nemotron-H): one character
+    # a layer, "M" a state-space mixer, "E" the routed expert layer, "*"
+    # attention; "" = the uniform layer (attention [+ mixer], then an MLP).
+    # Each kind has a parameter stack and a cache of its own length.
+    layer_kinds: str = ""
+    rotary: bool = True             # False: attention applies no rotary embedding
+
+    def __post_init__(self):
+        if self.layer_kinds:
+            odd = set(self.layer_kinds) - set("ME*")
+            if odd or len(self.layer_kinds) != self.n_layers:
+                raise ValueError(
+                    f"{self.name}: layer_kinds {self.layer_kinds!r} needs one of "
+                    f"'M', 'E', '*' for each of n_layers = {self.n_layers}")
+
+    def kind_layers(self, kind: str) -> tuple[int, ...]:
+        """The indices, in the whole stack, of the layers of ``kind``."""
+        return tuple(i for i, k in enumerate(self.layer_kinds) if k == kind)
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Layers that hold keys and values in the cache."""
+        return self.layer_kinds.count("*") if self.layer_kinds else self.n_layers
+
+    @property
+    def n_ssm_layers(self) -> int:
+        """Layers that hold a recurrent state and a convolution tail."""
+        if not self.has_ssm:
+            return 0
+        return self.layer_kinds.count("M") if self.layer_kinds else self.n_layers
 
     @property
     def has_ssm(self) -> bool:
@@ -131,7 +168,13 @@ class ModelConfig:
         return self.d_expert or self.d_ff
 
     @property
+    def shared_width(self) -> int:
+        return self.d_shared or self.n_shared_experts * self.expert_width
+
+    @property
     def n_expert_layers(self) -> int:
+        if self.layer_kinds:
+            return self.layer_kinds.count("E")
         return self.n_layers - self.n_dense_layers if self.is_moe else 0
 
     @property
@@ -224,6 +267,18 @@ MODEL_PRESETS: dict[str, ModelConfig] = {c.name: c for c in [
        ssm_in_multiplier=1.25, ssm_out_multiplier=0.7,
        ssm_multipliers=(0.9, 1.2, 0.75, 1.1, 0.85),
        mlp_multipliers=(0.7, 1.4), max_seq_len=4096),
+    # Nemotron-H's stack at CI size: one whole period of the published
+    # pattern, every layer ONE part: 5 Mamba-2 mixers (6 heads of 8, state
+    # 16, 2 groups), 5 LatentMoE layers (16 ungated relu2 experts of 40 in a
+    # latent of 56, 3 a token by sigmoid score + bias, one shared expert of
+    # 72 on the full width) and 1 attention layer without rotary embedding.
+    _L("tiny-nemotron-h", "nemotron_h", 512, 96, 11, 4, 2, 24, 0,
+       activation="relu2", layer_kinds="MEMEMEM*EME", rotary=False,
+       n_experts=16, experts_per_token=3, d_expert=40, n_shared_experts=1,
+       d_shared=72, moe_latent=56, gated_experts=False,
+       router_scoring="sigmoid_bias", routed_scale=2.5, norm_topk=True,
+       ssm_heads=6, ssm_head_dim=8, ssm_state=16, ssm_groups=2, ssm_conv=4,
+       ssm_chunk=8, max_seq_len=4096),
     _L("tiny-llama", "llama", 512, 128, 2, 4, 2, 32, 256, max_seq_len=4096),
     _L("tiny-gemma", "gemma", 512, 128, 2, 4, 4, 32, 256, activation="gelu_tanh",
        norm_offset=1.0, embed_scale=True, tie_embeddings=True, max_seq_len=4096),

@@ -1,7 +1,7 @@
 """Generic decoder-only transformer in functional JAX.
 
 One implementation serves every model family (llama/mistral/gemma/qwen2/
-mixtral/deepseek_v2/falcon_h1) via static ``ModelConfig`` switches. This replaces the reference's
+mixtral/deepseek_v2/falcon_h1/nemotron_h) via static ``ModelConfig`` switches. This replaces the reference's
 "compute layer" — three HTTP clients (/root/reference/internal/provider/
 {openai,anthropic,google}.go) — with real on-device compute.
 
@@ -47,6 +47,18 @@ values, a sub-tree ``"ssm"`` of per-ROW state: the recurrence's float32
 state and the convolution's tail, which have no sequence axis. A recurrence
 must know where a row's real tokens END inside a padded chunk as well as
 where they start: ``forward(row_end=...)``.
+
+The Nemotron-H stack (``family="nemotron_h"``, ``cfg.layer_kinds``): every
+layer is ONE part behind ONE norm and ONE residual add, ``x + part(norm(x))``,
+and the pattern says which: ``M`` a Mamba-2 mixer (ops/ssm.py, every
+multiplier 1), ``E`` the LatentMoE layer (ops/moe.py: sigmoid scores chosen
+with a correction bias, ungated relu2 experts in a latent width, one shared
+expert on the full width), ``*`` grouped-query attention WITHOUT rotary
+embedding (the mixers carry position). Each kind has a parameter stack of
+its own (``layers_ssm``, ``layers_moe``, ``layers_attn``) and a cache of its
+own length: keys and values ``[n_attn_layers, ...]``, state and tail
+``[n_ssm_layers, ...]``, nothing for an expert layer. ``forward`` walks the
+static pattern, unrolled, and gives each layer its index WITHIN its kind.
 """
 
 from __future__ import annotations
@@ -118,7 +130,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
                 leaf_hook=None, shardings: Optional[dict] = None) -> dict:
     """Random-init parameter pytree (layers stacked on axis 0; a family
     with leading dense layers has two stacks, ``layers_dense`` and then
-    ``layers``).
+    ``layers``; a family whose every layer is one part, ``cfg.layer_kinds``,
+    has a stack a kind: ``layers_ssm``, ``layers_moe``, ``layers_attn``).
 
     ``shardings`` (a tree of ``jax.sharding.Sharding`` shaped like the
     result: ``parallel.sharding.param_shardings``) makes every leaf
@@ -173,6 +186,44 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
     )
     proj_std = d ** -0.5
 
+    def attn_leaves(l: int) -> dict:
+        return {
+            "wq": normal(next(keys), (l, d, hq * dh), proj_std, "wq"),
+            "wk": normal(next(keys), (l, d, hkv * dh), proj_std, "wk"),
+            "wv": normal(next(keys), (l, d, hkv * dh), proj_std, "wv"),
+            "wo": normal(next(keys), (l, hq * dh, d), (hq * dh) ** -0.5, "wo"),
+        }
+
+    def routed_leaves(l: int) -> dict:
+        """The expert layer's leaves: the router over its whole width, the
+        held experts (three matrices, or two when ungated) at the model's
+        width or at the latent's, the shared expert."""
+        e, fe = cfg.n_experts, cfg.expert_width
+        z, fs = cfg.moe_latent or d, cfg.shared_width
+        names = ("w_gate", "w_up") if cfg.gated_experts else ("w_up",)
+        out = {"w_router": normal(
+            next(keys), (l, d, cfg.n_router), proj_std, "w_router")}
+        if cfg.router_scoring == "sigmoid_bias":
+            # A stored leaf: zero in a fresh model, moved by the published
+            # training's load balancing; here random and large enough to
+            # change the choice (a sigmoid's scores lie in (0, 1)).
+            out["router_bias"] = normal(
+                next(keys), (l, cfg.n_router), 0.1, "router_bias")
+        if cfg.moe_latent:
+            out["w_latent_in"] = normal(
+                next(keys), (l, d, z), proj_std, "w_latent_in")
+            out["w_latent_out"] = normal(
+                next(keys), (l, z, d), z ** -0.5, "w_latent_out")
+        for name in names:
+            out[name] = normal(next(keys), (l, e, z, fe), z ** -0.5, name)
+        out["w_down"] = normal(next(keys), (l, e, fe, z), fe ** -0.5, "w_down")
+        if cfg.n_shared_experts:
+            for name in names:
+                out["ws" + name[1:]] = normal(
+                    next(keys), (l, d, fs), proj_std, "ws" + name[1:])
+            out["ws_down"] = normal(next(keys), (l, fs, d), fs ** -0.5, "ws_down")
+        return out
+
     def stack(name: str, l: int, routed: bool) -> dict:
         """``l`` layers stacked on axis 0, with a dense or a routed MLP."""
         sharding_of.update((shardings or {}).get(name, {}))
@@ -195,46 +246,47 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
                 "wo": normal(next(keys), (l, hq * v, d), (hq * v) ** -0.5, "wo"),
             })
         else:
-            layers.update({
-                "wq": normal(next(keys), (l, d, hq * dh), proj_std, "wq"),
-                "wk": normal(next(keys), (l, d, hkv * dh), proj_std, "wk"),
-                "wv": normal(next(keys), (l, d, hkv * dh), proj_std, "wv"),
-                "wo": normal(next(keys), (l, hq * dh, d), (hq * dh) ** -0.5, "wo"),
-            })
+            layers.update(attn_leaves(l))
         if cfg.qkv_bias:
             for name, width in (("bq", hq * dh), ("bk", hkv * dh), ("bv", hkv * dh)):
                 layers[name] = make(name, lambda w=width: jnp.zeros((l, w), dtype))
         if cfg.has_ssm:
             layers.update(_init_mixer(cfg, l, keys, make, normal, norm, dtype))
         if routed:
-            e, fe = cfg.n_experts, cfg.expert_width
-            layers["w_router"] = normal(
-                next(keys), (l, d, cfg.n_router), proj_std, "w_router")
-            layers["w_gate"] = normal(next(keys), (l, e, d, fe), proj_std, "w_gate")
-            layers["w_up"] = normal(next(keys), (l, e, d, fe), proj_std, "w_up")
-            layers["w_down"] = normal(next(keys), (l, e, fe, d), fe ** -0.5, "w_down")
-            if cfg.n_shared_experts:
-                fs = cfg.n_shared_experts * fe
-                layers["ws_gate"] = normal(next(keys), (l, d, fs), proj_std, "ws_gate")
-                layers["ws_up"] = normal(next(keys), (l, d, fs), proj_std, "ws_up")
-                layers["ws_down"] = normal(next(keys), (l, fs, d), fs ** -0.5, "ws_down")
+            layers.update(routed_leaves(l))
         else:
             layers["w_gate"] = normal(next(keys), (l, d, f), proj_std, "w_gate")
             layers["w_up"] = normal(next(keys), (l, d, f), proj_std, "w_up")
             layers["w_down"] = normal(next(keys), (l, f, d), f ** -0.5, "w_down")
         return layers
 
-    n_dense = cfg.n_dense_layers if cfg.is_moe else 0
-    layers = stack("layers", cfg.n_layers - n_dense, cfg.is_moe)
-    layers_dense = stack("layers_dense", n_dense, False) if n_dense else None
+    def kind_stack(name: str, l: int, norm_name: str, leaves) -> dict:
+        """``l`` one-part layers of one kind: the part's norm, named as the
+        half that reads it names it, and the part's leaves."""
+        sharding_of.update((shardings or {}).get(name, {}))
+        return {norm_name: norm((l, d), norm_name), **leaves(l)}
+
+    if cfg.layer_kinds:
+        stacks = {
+            "layers_ssm": kind_stack(
+                "layers_ssm", cfg.n_ssm_layers, "attn_norm",
+                lambda l: _init_mixer(cfg, l, keys, make, normal, norm, dtype)),
+            "layers_moe": kind_stack(
+                "layers_moe", cfg.n_expert_layers, "mlp_norm", routed_leaves),
+            "layers_attn": kind_stack(
+                "layers_attn", cfg.n_attn_layers, "attn_norm", attn_leaves),
+        }
+    else:
+        n_dense = cfg.n_dense_layers if cfg.is_moe else 0
+        stacks = {"layers": stack("layers", cfg.n_layers - n_dense, cfg.is_moe)}
+        if n_dense:
+            stacks["layers_dense"] = stack("layers_dense", n_dense, False)
 
     params = {
         "embed": normal(next(keys), (cfg.vocab_size, d), 0.02, "embed"),
         "final_norm": norm((d,), "final_norm"),
-        "layers": layers,
+        **stacks,
     }
-    if layers_dense is not None:
-        params["layers_dense"] = layers_dense
     if not cfg.tie_embeddings:
         params["lm_head"] = normal(
             next(keys), (d, cfg.vocab_size), proj_std, "lm_head"
@@ -290,7 +342,10 @@ def init_kv_cache(
       float32, "conv": [L, B, K-1, C]}}``: beside keys and values the
       recurrent state and the convolution's tail of each ROW, which have no
       sequence axis. They are told apart by their KEY (ops/quant.py
-      ``STATE_KEY``, ``kv_tree_map``), never by their rank.
+      ``STATE_KEY``, ``kv_tree_map``), never by their rank. Where every
+      layer is one part (``cfg.layer_kinds``) each leaf counts the layers of
+      its own kind: keys and values ``cfg.n_attn_layers``, state and tail
+      ``cfg.n_ssm_layers``; an expert layer holds nothing.
     """
     s = max_seq or cfg.max_seq_len
     if cfg.has_ssm:
@@ -298,15 +353,15 @@ def init_kv_cache(
             raise ValueError(
                 f"{cfg.name}: no quantized cache for a state-space model: "
                 f"kv cache quant {quant!r} is not computed")
-        shape = (cfg.n_layers, batch, s, cfg.n_kv_heads, cfg.head_dim)
+        shape = (cfg.n_attn_layers, batch, s, cfg.n_kv_heads, cfg.head_dim)
         return {
             "k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
             STATE_KEY: {
                 "state": jnp.zeros(
-                    (cfg.n_layers, batch, cfg.ssm_heads, cfg.ssm_head_dim,
+                    (cfg.n_ssm_layers, batch, cfg.ssm_heads, cfg.ssm_head_dim,
                      cfg.ssm_state), jnp.float32),
                 "conv": jnp.zeros(
-                    (cfg.n_layers, batch, cfg.ssm_conv - 1,
+                    (cfg.n_ssm_layers, batch, cfg.ssm_conv - 1,
                      cfg.ssm_conv_width), dtype),
             },
         }
@@ -400,14 +455,17 @@ def _layer(
 ):
     """One block. Returns ``(x, cache_k, cache_v)``; with ``moe_stats`` on a
     routed stack the expert layer's sums follow, and for a state-space model
-    its updated ``ssm`` stacks come last."""
+    its updated ``ssm`` stacks come last. Where every layer is one part
+    (``cfg.layer_kinds``) this is an attention layer, whole: no mixer beside
+    it, no MLP after it, ``(x, cache_k, cache_v)`` and nothing else."""
     routed = cfg.is_moe if routed is None else routed
     b, t, d = x.shape
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    beside = cfg.has_ssm and not cfg.layer_kinds  # a mixer beside attention
 
     with scope("norm"):
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps, cfg.norm_offset)
-    if cfg.has_ssm:
+    if beside:
         # The mixer reads the same normed input as attention, which takes
         # its own multiplier from here on.
         mixed, ssm = _mixer_half(cfg, h, lp, ssm, layer_idx, ssm_span)
@@ -467,8 +525,9 @@ def _layer(
                 )
 
             q, k, v = pin(q, hq), pin(k, hkv), pin(v, hkv)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        if cfg.rotary:
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
 
     if cache_k is not None:
         # Write this step's keys/values at (layer_idx, start_pos) into the
@@ -662,15 +721,27 @@ def _layer(
     with scope("attn.out"):
         attn_out = qeinsum(
             "btk,kd->btd", attn_out.reshape(b, t, hq * dh), lp["wo"])
-        if cfg.has_ssm:
+        if beside:
             attn_out = attn_out * cfg.attention_out_multiplier + mixed
         x = x + attn_out
 
+    if cfg.layer_kinds:
+        return x, cache_k, cache_v
     if ring_mesh is not None:
         cache_k, cache_v = k, v  # fresh k/v for the caller's cache build
     out = _mlp_half(
         cfg, x, lp, routed, moe_stats, cache_k, cache_v, expert_stacks)
     return (*out, ssm) if cfg.has_ssm else out
+
+
+def _mixer_layer(cfg: ModelConfig, x, lp, ssm, layer_idx, span):
+    """A one-part layer that is a state-space mixer: ``x + mixer(norm(x))``.
+    Returns ``(x, ssm)``."""
+    with scope("norm"):
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps, cfg.norm_offset)
+    mixed, ssm = _mixer_half(cfg, h, lp, ssm, layer_idx, span)
+    with scope("ssm.out_proj"):  # the residual add rides the layer's last product
+        return x + mixed, ssm
 
 
 def _mixer_half(cfg: ModelConfig, h, lp, ssm, layer_idx, span):
@@ -710,6 +781,11 @@ def _latent_scale(cfg: ModelConfig) -> float:
     return scale
 
 
+# A routed stack's expert leaves (an ungated expert has no ``w_gate``), which
+# ``forward`` hands to the grouped product whole, apart from the layer's own.
+EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
 def _mlp_half(cfg: ModelConfig, x, lp, routed: bool, moe_stats: bool,
               cache_k, cache_v, expert_stacks=None):
     """The MLP half of a block on the post-attention residual ``x``: the
@@ -724,14 +800,18 @@ def _mlp_half(cfg: ModelConfig, x, lp, routed: bool, moe_stats: bool,
                 h, lp["w_gate"], lp["w_up"], lp["w_down"], cfg.activation,
                 cfg.mlp_multipliers)
             return x + mlp_out, cache_k, cache_v
-    *experts, layer = expert_stacks or (lp["w_gate"], lp["w_up"], lp["w_down"], None)
+    *experts, layer = expert_stacks or (
+        *(lp.get(k) for k in EXPERT_LEAVES), None)
     out = moe_block(
         h, lp["w_router"], *experts, layer=layer,
         top_k=cfg.experts_per_token, activation=cfg.activation,
         first_expert=cfg.first_expert, n_groups=cfg.n_expert_groups,
         groups_per_token=cfg.groups_per_token, norm_topk=cfg.norm_topk,
         routed_scale=cfg.routed_scale, scoring=cfg.router_scoring,
-        shared=(lp["ws_gate"], lp["ws_up"], lp["ws_down"])
+        router_bias=lp.get("router_bias"),
+        latent=(lp["w_latent_in"], lp["w_latent_out"])
+        if cfg.moe_latent else None,
+        shared=(lp.get("ws_gate"), lp["ws_up"], lp["ws_down"])
         if cfg.n_shared_experts else None,
         with_stats=moe_stats,
     )
@@ -968,7 +1048,7 @@ def forward(
             else:
                 pos_offset = jnp.broadcast_to(plen, (b,))
             positions = positions + pos_offset[:, None]
-        cos, sin = _rotary_tables(cfg, positions)
+        cos, sin = _rotary_tables(cfg, positions) if cfg.rotary else (None, None)
 
     with scope("mla.sweep" if cfg.is_latent else "attn.sweep"):
         if flash_offset is not None or decode_flash:
@@ -1047,23 +1127,14 @@ def forward(
     )
 
     # A family with leading dense layers has two stacks, scanned one after
-    # the other; the cache's layer axis counts both.
-    stacks = [(params["layers"], cfg.is_moe)]
+    # the other; the cache's layer axis counts both. One whose every layer
+    # is one part has a stack a kind and scans none (``_walk_kinds``).
+    stacks = [] if cfg.layer_kinds else [(params["layers"], cfg.is_moe)]
     if "layers_dense" in params:
         stacks.insert(0, (params["layers_dense"], False))
     moe_stats = moe_stats and cfg.is_moe
     with scope("moe.stats"):
         stats = jnp.zeros((3,), jnp.int32) if moe_stats else None
-
-    def scanned(stack: dict, routed: bool):
-        """A stack's leaves as the scan slices them, and apart from them a
-        routed stack's expert leaves, which stay whole: the grouped product
-        fetches its experts out of the stacks (ops/moe.py)."""
-        if not routed:
-            return stack, None
-        whole = ("w_gate", "w_up", "w_down")
-        return ({k: v for k, v in stack.items() if k not in whole},
-                tuple(stack[k] for k in whole))
 
     def block(x, lp, experts, at, stats, cs, *cache_args, **kw):
         routed = experts is not None
@@ -1089,9 +1160,13 @@ def forward(
         ck, cv = (cache["kv"], None) if cfg.is_latent else (cache["k"], cache["v"])
         cs = cache.get(STATE_KEY)
         at = start
+    if cfg.layer_kinds:
+        x, ck, cv, stats, cs = _walk_kinds(
+            params, cfg, layer_fn, (x, ck, cv, stats, cs), (cos, sin, mask, at),
+            ssm_span, moe_stats, remat and cache is None)
     li = jnp.asarray(0, jnp.int32)  # the layer, counted over both stacks
     for stack, routed in stacks:
-        xs, experts = scanned(stack, routed)
+        xs, experts = _scanned(stack, routed)
         first = li  # this stack's first layer
 
         def scan_body(carry, lp, experts=experts, first=first):
@@ -1124,6 +1199,60 @@ def forward(
     if moe_stats:
         return unembed(params, cfg, x), new_cache, stats
     return unembed(params, cfg, x), new_cache
+
+
+def _scanned(stack: dict, routed: bool):
+    """A stack's leaves as the layer scan (or the walk over one-part layers)
+    slices them, and apart from them a routed stack's expert leaves, which
+    stay whole: the grouped product fetches its experts out of the stacks
+    (ops/moe.py)."""
+    if not routed:
+        return stack, None
+    return ({k: v for k, v in stack.items() if k not in EXPERT_LEAVES},
+            tuple(stack.get(k) for k in EXPERT_LEAVES))
+
+
+def _walk_kinds(params, cfg: ModelConfig, layer_fn, carry, attn_args,
+                ssm_span, moe_stats: bool, remat: bool):
+    """``forward``'s layers where every layer is ONE part: the static
+    pattern ``cfg.layer_kinds`` unrolled, each layer given its leaves out of
+    its kind's stack and its index WITHIN its kind, which is its place in
+    that kind's cache (keys and values for ``*``, state and tail for ``M``)
+    and in the stacked experts (``E``). ``carry`` is ``(x, cache_k, cache_v,
+    stats, ssm)`` as the layer scan carries it. Unrolled, not scanned: the
+    pattern has no period a scan could run over (``MEMEMEM*EME`` is three
+    ``ME`` pairs and five layers that repeat nothing), eleven small bodies
+    compile in the time of a few, and a static index lets each layer read
+    its leaves where they lie."""
+    cos, sin, mask, at = attn_args
+    moe_own, experts = _scanned(params["layers_moe"], True)
+    stack_of = {"M": params["layers_ssm"], "E": moe_own,
+                "*": params["layers_attn"]}
+
+    def part(kind: str, i: int, carry):
+        x, ck, cv, stats, cs = carry
+        with scope("layers"):
+            lp = jax.tree.map(lambda a: a[i], stack_of[kind])
+            idx = jnp.asarray(i, jnp.int32)
+        if kind == "M":
+            x, cs = _mixer_layer(cfg, x, lp, cs, idx, ssm_span)
+        elif kind == "*":
+            x, ck, cv = layer_fn(
+                x, lp, cos, sin, mask, ck, cv, at, layer_idx=idx)
+        else:
+            x, _, _, *more = _mlp_half(
+                cfg, x, lp, True, moe_stats, None, None, (*experts, idx))
+            if moe_stats:
+                with scope("moe.stats"):
+                    stats = stats + more[0]
+        return x, ck, cv, stats, cs
+
+    seen = {"M": 0, "E": 0, "*": 0}
+    for kind in cfg.layer_kinds:
+        fn = partial(part, kind, seen[kind])
+        carry = (jax.checkpoint(fn) if remat else fn)(carry)
+        seen[kind] += 1
+    return carry
 
 
 def _k_store(cache: dict) -> jax.Array:

@@ -57,7 +57,9 @@ MLA = (
 MOE = (
     "moe.route",    # router logits, groups, the choice and its weights
     "moe.experts",  # the sort, the three grouped products, the way back
-    "moe.shared",   # the shared experts' SwiGLU
+    "moe.latent_in",   # LatentMoE: the projection into the experts' width
+    "moe.latent_out",  # LatentMoE: the routed sum's projection back out
+    "moe.shared",   # the shared experts' MLP
     "moe.stats",    # the three sums the tracing fetches
 )
 # The state-space mixer, beside ATTN.

@@ -1,8 +1,14 @@
 """Routed expert layer: dropless, and told which experts it holds.
 
-One layer serves Mixtral (one group, every expert held, weights
-renormalised over the chosen) and DeepSeek-V2 (device-limited routing over
-groups, a chip's share of the experts, shared experts, a routed scale):
+One layer serves three families: ``mixtral`` (one group, every expert held,
+weights renormalised over the chosen), ``deepseek_v2`` (device-limited
+routing over groups, a chip's share of the experts, shared experts, a routed
+scale) and ``nemotron_h`` (LatentMoE: sigmoid scores chosen with a
+correction bias, ungated experts that work in a latent width between two
+projections, one shared expert of a width of its own on the full width).
+Two scorings (``softmax``, ``sigmoid_bias``) and two expert forms (gated,
+``W_down (act(x W_gate) * x W_up)``, three matrices; ungated, ``W_down act(x
+W_up)``, two: ``w_gate=None``):
 
   * **Routing** runs over the router's WHOLE width in float32: scores
     ``s = softmax(h · W_g)``; with groups, a group's score is its largest
@@ -11,7 +17,16 @@ groups, a chip's share of the experts, shared experts, a routed scale):
     choice is made on the logits, of which ``s`` is a monotone function.
     Weights are the chosen ``s``, renormalised to sum to 1 (``norm_topk``:
     a softmax over the chosen logits, as Mixtral publishes it) or not, times
+    ``routed_scale``. Under ``sigmoid_bias`` the scores are ``s = sigmoid(h
+    · W_g)``, the ``top_k`` largest ``s + b`` are chosen (``b`` a stored
+    per-expert correction bias, one group), and the weights are the chosen
+    ``s`` WITHOUT ``b``, divided by their sum under ``norm_topk``, times
     ``routed_scale``.
+  * **The latent** (``latent=(w_in, w_out)``): the routed experts read ``u
+    = h · w_in`` and their weighted sum goes through ``w_out`` back to the
+    model's width; the router and the shared expert read ``h``. ``w_out`` is
+    linear and has no bias, so the shares of an expert-parallel layer still
+    add up after it.
   * **What is held.** The expert leaves carry ``E`` experts, those numbered
     ``[first_expert, first_expert + E)`` of the router's outputs: one chip's
     share of an expert-parallel layer. Of each token's chosen experts the
@@ -24,8 +39,9 @@ groups, a chip's share of the experts, shared experts, a routed scale):
     sorted rows and each token sums its own pairs' results, weighted. Static shapes come
     from the buffer of ``N × top_k`` pairs, not from a capacity: no pair on
     a held expert is ever dropped.
-  * **Shared experts** (one SwiGLU of ``n_shared × d_expert``) see every
-    token and are added once.
+  * **Shared experts** (one MLP of the experts' form, ``n_shared ×
+    d_expert`` wide or of a width of its own) see every token and are added
+    once.
 
 ``moe_block(..., with_stats=True)`` also returns three int32 sums for the
 tracing (docs/observability.md): pairs in all, pairs on held experts, held
@@ -40,8 +56,8 @@ import jax
 import jax.numpy as jnp
 
 from llm_consensus_tpu.obs.scopes import scope
-from llm_consensus_tpu.ops.mlp import _activate, gated_mlp
-from llm_consensus_tpu.ops.quant import dequantize
+from llm_consensus_tpu.ops.mlp import _activate, gated_mlp, plain_mlp
+from llm_consensus_tpu.ops.quant import dequantize, qeinsum
 
 NEG_INF = -jnp.inf
 
@@ -53,10 +69,22 @@ def route(
     groups_per_token: int = 1,
     norm_topk: bool = True,
     routed_scale: float = 1.0,
+    bias: Optional[jax.Array] = None,   # [R]: sigmoid scoring's correction bias
 ) -> tuple[jax.Array, jax.Array]:
     """Chosen experts [N, k] (indices into the router's width) and their
-    weights [N, k] float32."""
+    weights [N, k] float32. With ``bias`` the scoring is ``sigmoid_bias``."""
     n, r = logits.shape
+    if bias is not None:
+        if n_groups > 1:
+            raise ValueError(
+                "router scoring 'sigmoid_bias' over expert groups is not "
+                f"computed, got {n_groups} groups")
+        scores = jax.nn.sigmoid(logits)
+        _, top_idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        weights = jnp.take_along_axis(scores, top_idx, axis=-1)
+        if norm_topk:
+            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        return top_idx, weights * routed_scale
     if n_groups > 1:
         grouped = logits.reshape(n, n_groups, r // n_groups)
         _, top_groups = jax.lax.top_k(grouped.max(axis=-1), groups_per_token)
@@ -90,42 +118,57 @@ def moe_block(
     norm_topk: bool = True,
     routed_scale: float = 1.0,
     scoring: str = "softmax",
+    router_bias=None,      # [R]: the correction bias of "sigmoid_bias"
+    latent: Optional[tuple] = None,   # (w_in [D, Z], w_out [Z, D]) or None
     shared: Optional[tuple] = None,   # (ws_gate, ws_up, ws_down) or None
     layer=None,            # expert leaves are whole stacks [L, E, ...]: which layer
     with_stats: bool = False,
 ):
-    if scoring != "softmax":
+    """``w_gate=None`` (and ``shared[0] is None``): ungated experts."""
+    if scoring not in ("softmax", "sigmoid_bias"):
         raise ValueError(
-            f"router scoring {scoring!r} is not computed: only 'softmax'")
+            f"router scoring {scoring!r} is not computed: only 'softmax' "
+            "and 'sigmoid_bias'")
+    if (scoring == "sigmoid_bias") != (router_bias is not None):
+        raise ValueError(
+            f"router scoring {scoring!r} "
+            + ("needs" if router_bias is None else "takes no")
+            + " correction bias")
     b, t, d = x.shape
     n = b * t
     with scope("moe.route"):
         tokens = x.reshape(n, d)
+    gated = w_gate is not None
+    stacks = (w_gate, w_up, w_down) if gated else (w_up, w_down)
     with scope("moe.experts"):
-        w_gate, w_up, w_down = (
-            dequantize(w, x.dtype) for w in (w_gate, w_up, w_down))
+        stacks = tuple(dequantize(w, x.dtype) for w in stacks)
     if layer is None:
-        held, first_group = w_gate.shape[0], 0
+        held, first_group = stacks[0].shape[0], 0
     else:
         # The stacks of every layer as ONE run of L*E groups, of which only
         # this layer's are given rows: the grouped product then fetches the
         # experts it needs out of the stacks where they lie. Handing it one
         # layer's slice instead makes XLA copy that layer's every expert
         # (0.9 GB a layer a step at 20 experts of 5,120 x 1,536) first.
-        n_stacked, held = w_gate.shape[:2]
+        n_stacked, held = stacks[0].shape[:2]
         first_group = layer * held
         with scope("moe.experts"):
-            w_gate, w_up, w_down = (
-                w.reshape(n_stacked * held, *w.shape[2:])
-                for w in (w_gate, w_up, w_down))
-    n_groups_all = w_gate.shape[0]
+            stacks = tuple(
+                w.reshape(n_stacked * held, *w.shape[2:]) for w in stacks)
+    n_groups_all = stacks[0].shape[0]
 
     with scope("moe.route"):
         logits = jnp.einsum(
             "nd,dr->nr", tokens.astype(jnp.float32),
             w_router.astype(jnp.float32))
         top_idx, weights = route(
-            logits, top_k, n_groups, groups_per_token, norm_topk, routed_scale)
+            logits, top_k, n_groups, groups_per_token, norm_topk, routed_scale,
+            router_bias)
+    wide = tokens  # what the router and the shared expert read
+    if latent is not None:
+        with scope("moe.latent_in"):
+            tokens = qeinsum("nd,dz->nz", tokens, latent[0])
+    z = tokens.shape[-1]  # the width the routed experts work in
 
     # Sort the N*k pairs by held expert; a pair on an absent expert takes
     # a key past every group and sorts last. The buffer is rounded up to
@@ -143,21 +186,28 @@ def moe_block(
         pair_token = jnp.minimum(order // top_k, n - 1)
         group_sizes = jnp.zeros((n_groups_all,), jnp.int32).at[key].add(1, mode="drop")
 
-        rows = tokens[pair_token]                                   # [P, D]
-        h = _activate(jax.lax.ragged_dot(rows, w_gate, group_sizes), activation)
-        h = h * jax.lax.ragged_dot(rows, w_up, group_sizes)
-        y = jax.lax.ragged_dot(h, w_down, group_sizes)              # [P, D]
+        rows = tokens[pair_token]                                   # [P, Z]
+        h = _activate(
+            jax.lax.ragged_dot(rows, stacks[0], group_sizes), activation)
+        if gated:
+            h = h * jax.lax.ragged_dot(rows, stacks[1], group_sizes)
+        y = jax.lax.ragged_dot(h, stacks[-1], group_sizes)          # [P, Z]
         # Back to (token, choice) order; a row past the last group holds
         # nothing of an expert and is masked, not multiplied by zero.
         back = jnp.zeros_like(order).at[order].set(jnp.arange(order.size))
-        y = jnp.where(is_held[:, None], y[back[:pairs]], 0).reshape(n, top_k, d)
+        y = jnp.where(is_held[:, None], y[back[:pairs]], 0).reshape(n, top_k, z)
         out = jnp.einsum(
             "nk,nkd->nd", weights, y, preferred_element_type=jnp.float32
         ).astype(x.dtype)
 
+    if latent is not None:
+        with scope("moe.latent_out"):
+            out = qeinsum("nz,zd->nd", out, latent[1])
     if shared is not None:
         with scope("moe.shared"):
-            out = out + gated_mlp(tokens, *shared, activation)
+            out = out + (
+                gated_mlp(wide, *shared, activation) if shared[0] is not None
+                else plain_mlp(wide, *shared[1:], activation))
     with scope("moe.experts"):
         out = out.reshape(b, t, d)
     if not with_stats:

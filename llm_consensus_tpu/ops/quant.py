@@ -68,7 +68,9 @@ QUANT_KEYS = frozenset(
      # latent attention's projections and the shared experts
      "wq_a", "wq_b", "wkv_a", "wkv_b", "ws_gate", "ws_up", "ws_down",
      # a state-space mixer's two projections
-     "ssm_in", "ssm_out"}
+     "ssm_in", "ssm_out",
+     # LatentMoE's two projections around the routed sum
+     "w_latent_in", "w_latent_out"}
 )
 
 
@@ -216,7 +218,8 @@ def quantize_params(params: dict, donate: bool = False,
     out = dict(params)
     if "lm_head" in out:
         out["lm_head"] = maybe(out["lm_head"])
-    for stack in ("layers_dense", "layers"):
+    for stack in ("layers_dense", "layers", "layers_ssm", "layers_moe",
+                  "layers_attn"):
         if stack in out:
             out[stack] = {
                 name: maybe(w) if name in QUANT_KEYS else w

@@ -64,6 +64,15 @@ def param_specs(cfg: ModelConfig, mesh: Optional[Mesh] = None) -> dict:
         # exactly the miscompiling mesh shape so inference meshes
         # (tp-only, tp×sp, dp×tp) keep the sharded LM head.
         tp_vocab = None
+    if cfg.layer_kinds:
+        # Every layer one part, a stack a kind. The model runs on one chip
+        # (a state-space model's engine refuses a mesh with tp > 1): every
+        # leaf whole, whatever its rank.
+        from llm_consensus_tpu.models import init_params
+
+        shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+        return jax.tree.map(lambda leaf: P(*(None,) * leaf.ndim), shapes)
+
     def stack(routed: bool) -> dict:
         layers: dict = {"attn_norm": P(None, None), "mlp_norm": P(None, None)}
         if cfg.is_latent:

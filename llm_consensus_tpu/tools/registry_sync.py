@@ -201,6 +201,8 @@ def fetch_local_models() -> list[ModelRecord]:
                     # a state-space model: what a ROW costs beside its slots
                     "state_space": cfg.has_ssm,
                     "state_bytes_per_row": cfg.state_bytes_per_row,
+                    # "" for a uniform layer; else one part a layer, by kind
+                    "layer_kinds": cfg.layer_kinds,
                 },
             )
         )

@@ -40,37 +40,51 @@ def param_count(cfg: ModelConfig, active_only: bool = False) -> int:
         attn = q + kv + o
     if cfg.qkv_bias:
         attn += (cfg.n_heads + 2 * cfg.n_kv_heads) * dh
-    if cfg.has_ssm:
-        # the mixer beside attention: in-projection, depthwise convolution
-        # with its bias, dt_bias / A_log / D a head, gated norm, out-projection
-        attn += (
-            d * cfg.ssm_proj_width + cfg.ssm_conv_width * (cfg.ssm_conv + 1)
-            + 3 * cfg.ssm_heads + cfg.ssm_inner + cfg.ssm_inner * d
-        )
+    # a mixer: in-projection, depthwise convolution with its bias, dt_bias /
+    # A_log / D a head, gated norm, out-projection
+    mixer = (
+        d * cfg.ssm_proj_width + cfg.ssm_conv_width * (cfg.ssm_conv + 1)
+        + 3 * cfg.ssm_heads + cfg.ssm_inner + cfg.ssm_inner * d
+    ) if cfg.has_ssm else 0
+    # The experts HELD here (a chip's share counts what it holds); a token
+    # visits at most experts_per_token of them. The router keeps its whole
+    # width (and its correction bias), the shared experts see every token;
+    # an expert has three matrices or, ungated, two, at the model's width
+    # or at the latent's, whose two projections count once a layer.
+    n_mlp = min(cfg.experts_per_token, cfg.n_experts) if active_only else cfg.n_experts
+    mats = 3 if cfg.gated_experts else 2
+    routed = (
+        n_mlp * mats * (cfg.moe_latent or d) * cfg.expert_width
+        + (mats * d * cfg.shared_width if cfg.n_shared_experts else 0)
+        + d * cfg.n_router
+        + (cfg.n_router if cfg.router_scoring == "sigmoid_bias" else 0)
+        + 2 * d * cfg.moe_latent
+    ) if cfg.is_moe else 0
+    embed = cfg.vocab_size * d
+    head = 0 if cfg.tie_embeddings else cfg.vocab_size * d
+    if cfg.layer_kinds:
+        # every layer ONE part behind one norm
+        return (
+            cfg.n_ssm_layers * (mixer + d) + cfg.n_expert_layers * (routed + d)
+            + cfg.n_attn_layers * (attn + d) + embed + head + d)
+    attn += mixer  # the mixer beside attention
     dense_mlp = 3 * d * cfg.d_ff  # gate + up + down
     norms = 2 * d
     n_dense = cfg.n_layers - cfg.n_expert_layers
     total = n_dense * (attn + dense_mlp + norms)
     if cfg.is_moe:
-        # The experts HELD here (a chip's share counts what it holds); a
-        # token visits at most experts_per_token of them. The router keeps
-        # its whole width, the shared experts see every token.
-        n_mlp = min(cfg.experts_per_token, cfg.n_experts) if active_only else cfg.n_experts
-        expert_one = 3 * d * cfg.expert_width
-        routed = (
-            (n_mlp + cfg.n_shared_experts) * expert_one + d * cfg.n_router
-        )
         total += cfg.n_expert_layers * (attn + routed + norms)
-    embed = cfg.vocab_size * d
     head = 0 if cfg.tie_embeddings else cfg.vocab_size * d
     return total + embed + head + d  # + final norm
 
 
 def cache_bytes_per_token(cfg: ModelConfig, itemsize: int = 2) -> int:
-    """Bytes one token holds in the cache over every layer: the ONE count
+    """Bytes one token holds in the cache over every layer that holds keys
+    and values (``cfg.n_attn_layers``: all of them, or where every layer is
+    one part the attention layers alone): the ONE count
     the pools, the prefix budget and the bandwidth models read
     (``cfg.cache_width`` values a layer: K and V heads, or a latent)."""
-    return cfg.n_layers * cfg.cache_width * itemsize
+    return cfg.n_attn_layers * cfg.cache_width * itemsize
 
 
 def state_bytes_per_row(cfg: ModelConfig, itemsize: int = 2) -> int:
@@ -82,7 +96,7 @@ def state_bytes_per_row(cfg: ModelConfig, itemsize: int = 2) -> int:
         return 0
     state = cfg.ssm_inner * cfg.ssm_state * 4
     tail = cfg.ssm_conv_width * (cfg.ssm_conv - 1) * itemsize
-    return cfg.n_layers * (state + tail)
+    return cfg.n_ssm_layers * (state + tail)
 
 
 def flops_per_token(cfg: ModelConfig, context_len: int = 0) -> float:
@@ -104,10 +118,10 @@ def flops_per_token(cfg: ModelConfig, context_len: int = 0) -> float:
         2 * cfg.kv_lora_rank + cfg.qk_rope_dim if cfg.is_latent
         else 2 * cfg.head_dim
     )
-    attn_quad = 2 * cfg.n_layers * cfg.n_heads * swept * max(0, context_len)
+    attn_quad = 2 * cfg.n_attn_layers * cfg.n_heads * swept * max(0, context_len)
     # a mixer's recurrence a token: decay and update the state (3 a value),
     # read it out (2 a value); constant in the context
-    scan = 5 * cfg.n_layers * cfg.ssm_inner * cfg.ssm_state
+    scan = 5 * cfg.n_ssm_layers * cfg.ssm_inner * cfg.ssm_state
     return 2.0 * weights + float(attn_quad + scan)
 
 

@@ -26,13 +26,15 @@ from llm_consensus_tpu.ops.quant import init_params_quantized
 ROWS, SLOTS, CHUNK = 2, 256, 64
 
 # (preset, weight and cache quantisation): dense, sliding-window int8,
-# latent + routed, hybrid state-space, and a routed model without a latent.
+# latent + routed, hybrid state-space, a routed model without a latent, and
+# a stack whose every layer is one part (mixer, LatentMoE or attention).
 FAMILIES = {
     "dense": ("tiny-llama", None),
     "window-int8": ("tiny-mistral", "int8"),
     "latent-routed": ("tiny-deepseek-v2", None),
     "hybrid-ssm": ("tiny-falcon-h1", None),
     "routed": ("tiny-mixtral", None),
+    "one-part": ("tiny-nemotron-h", None),
 }
 PROGRAMS = ("decode_chunk", "prefill_step", "prefill_chunks_loop")
 CASES = [(f, p) for f in FAMILIES for p in PROGRAMS]
@@ -49,6 +51,8 @@ def expected(cfg, program: str) -> set:
         want |= set(scopes.MOE)
         if not cfg.n_shared_experts:
             want.discard("moe.shared")
+        if not cfg.moe_latent:
+            want -= {"moe.latent_in", "moe.latent_out"}
     if not cfg.is_moe or cfg.n_dense_layers:
         want.add("mlp")
     if cfg.has_ssm:

@@ -95,6 +95,8 @@ DENSE_PROGRAMS = {
         "benchmark/configs/mistral7b-trio-int8.json", True, 1, 1, 2048),
 }
 HYBRID_DECODE = "falcon-h1-34b:decode"
+ONE_PART_CONFIG = "benchmark/configs/nemotron3-super-ep8-trio-bf16.json"
+ONE_PART_ROWS, ONE_PART_STEPS, ONE_PART_WIDTH = 6, 16, 384
 
 
 def _decode_id(preset, int8_kv, batch) -> str:
@@ -218,6 +220,7 @@ def _compile_all() -> dict:
     for width in LATENT_DECODE_WIDTHS:
         report[f"latent-decode:kv{width}"] = _latent_decode_chunk(
             sds, shapes, width)
+    report["one-part-decode"] = _one_part_decode_chunk(sds, shapes)
     reads: dict = {}
     report["hybrid-ssm"] = _hybrid_ssm_programs(sds, shapes, has_kernel, reads)
     for name in DENSE_PROGRAMS:
@@ -381,6 +384,25 @@ def _top_level(text: str):
                        f"{layout}}}", op, rest)
 
 
+def _leaves_by_shape(tree) -> dict:
+    """``{dims as the compiled text prints them: [the leaves so shaped]}``."""
+    import jax
+
+    leaves: dict = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(tree):
+        leaves.setdefault(",".join(map(str, leaf.shape)), []).append(
+            "/".join(str(getattr(k, "key", k)) for k in path))
+    return leaves
+
+
+def _writes_in_place(text: str) -> set:
+    """The computations whose root is a ``dynamic-update-slice``."""
+    return {
+        name for name, (_, body) in _computations(text).items()
+        if re.search(r"^\s*ROOT \S+ = \S+ dynamic-update-slice\(", body, re.M)
+    }
+
+
 def _latent_decode_chunk(sds, shapes, width: int) -> dict:
     """What the DeepSeek-V2 cell's decode chunk at ``width`` slots
     materialises, read off its compiled text. Of the TOP-LEVEL instructions
@@ -405,11 +427,7 @@ def _latent_decode_chunk(sds, shapes, width: int) -> dict:
     attention_routes.reset()
     params = shapes(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     cache = shapes(lambda: init_kv_cache(cfg, LATENT_ROWS, CELL_MAX_SEQ))
-    leaves: dict = {}  # dims as the text prints them -> the leaves so shaped
-    for path, leaf in jax.tree_util.tree_leaves_with_path(
-            {"params": params, "cache": cache}):
-        leaves.setdefault(",".join(map(str, leaf.shape)), []).append(
-            "/".join(str(getattr(k, "key", k)) for k in path))
+    leaves = _leaves_by_shape({"params": params, "cache": cache})
     try:
         text = _decode_chunk.lower(
             params, cfg, sds((LATENT_ROWS,)), sds(()), cache,
@@ -420,10 +438,7 @@ def _latent_decode_chunk(sds, shapes, width: int) -> dict:
         ).compile().as_text()
     except Exception as err:  # noqa: BLE001 — what the chip would raise
         return {"error": f"{type(err).__name__}: {str(err)[:300]}"}
-    writes_in_place = {
-        name for name, (_, body) in _computations(text).items()
-        if re.search(r"^\s*ROOT \S+ = \S+ dynamic-update-slice\(", body, re.M)
-    }
+    writes_in_place = _writes_in_place(text)
     sizes = {
         "pool": cfg.n_layers * LATENT_ROWS * CELL_MAX_SEQ * cfg.cache_width,
         "wq_b": cfg.q_lora_rank * cfg.n_heads * (cfg.qk_nope_dim + cfg.qk_rope_dim),
@@ -457,6 +472,74 @@ def _latent_decode_chunk(sds, shapes, width: int) -> dict:
         **{what: sorted(found) for what, found in report.items()},
         "entry_copy_mb": {k: round(v, 1) for k, v in sorted(entry_copies.items())},
         "scores": sorted(scores),
+        "routes": attention_routes.snapshot(cfg.name),
+    }
+
+
+def _one_part_decode_chunk(sds, shapes) -> dict:
+    """What the Nemotron-H cell's decode chunk (six rows, 16 steps, the
+    sentinel and the routing sums, at the cell's own sizes) materialises,
+    read off its compiled text. Of the TOP-LEVEL instructions (not inside a
+    fusion) of the entry computation and of the step's body: ``experts``
+    the operations that produce an array of the size of a held expert stack
+    (``w_up`` / ``w_down`` [5, 64, 1024, 2688]) or of one layer of it;
+    ``state`` those that produce an array of the size of the state stack
+    [5, 6, 128, 64, 128] other than the in-place write of one layer's rows
+    (a ``dynamic-update-slice``, bare or as a fusion's root; the tail stack,
+    1.8 MB, the compiler keeps in its fast memory); ``entry_copy_mb`` the entry's copies by the leaf of that shape.
+    ``kernel`` and ``routes``: the decode kernel is there and booked."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+
+    from llm_consensus_tpu.engine.engine import _decode_chunk
+    from llm_consensus_tpu.models import init_kv_cache, init_params
+    from llm_consensus_tpu.models.transformer import attention_routes
+
+    cfg = _judge(ONE_PART_CONFIG)
+    attention_routes.reset()
+    params = shapes(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    cache = shapes(lambda: init_kv_cache(
+        cfg, ONE_PART_ROWS, CELL_MAX_SEQ, jnp.bfloat16))
+    leaves = _leaves_by_shape({"params": params, "cache": cache})
+    try:
+        text = _decode_chunk.lower(
+            params, cfg, sds((ONE_PART_ROWS,)), sds(()), cache,
+            shapes(lambda: jax.random.PRNGKey(0)), n_steps=ONE_PART_STEPS,
+            temperature=0.0, top_k=None, top_p=None,
+            row_start=sds((ONE_PART_ROWS,)), kv_width=ONE_PART_WIDTH,
+            attn_impl="flash", sentinel=True, moe_stats=True,
+        ).compile().as_text()
+    except Exception as err:  # noqa: BLE001 — what the chip would raise
+        return {"error": f"{type(err).__name__}: {str(err)[:300]}"}
+    writes_in_place = _writes_in_place(text)
+    one_expert_layer = cfg.n_experts * (cfg.moe_latent or cfg.d_model) * cfg.expert_width
+    state = math.prod(cache["ssm"]["state"].shape)
+    sizes = {
+        "experts": (one_expert_layer, cfg.n_expert_layers * one_expert_layer),
+        "state": (state,),
+    }
+    report: dict = {what: [] for what in sizes}
+    entry_copies: dict = {}
+    for is_entry, _, dims, layout, op, rest in _top_level(text):
+        count = math.prod(dims)
+        if is_entry and op == "copy":
+            shape = ",".join(map(str, dims))
+            what = "|".join(leaves.get(shape, [shape]))
+            entry_copies[what] = entry_copies.get(what, 0) + count * 2 / 1e6
+        if op in ("parameter", "get-tuple-element", "bitcast", "while", "tuple"):
+            continue
+        in_place = op == "dynamic-update-slice" or (
+            op == "fusion" and re.search(r"calls=%?([\w.\-]+)", rest).group(1)
+            in writes_in_place)
+        for what, counts in sizes.items():
+            if count in counts and not (what == "state" and in_place):
+                report[what].append(f"{op}{layout}")
+    return {
+        **{what: sorted(found) for what, found in report.items()},
+        "entry_copy_mb": {k: round(v, 1) for k, v in sorted(entry_copies.items())},
+        "kernel": "tpu_custom_call" in text,
         "routes": attention_routes.snapshot(cfg.name),
     }
 
@@ -623,6 +706,42 @@ def test_latent_decode_chunk_reads_its_operands_where_they_lie(report, width, he
     else:
         assert got["scores"] == ["f32"]
         assert got["routes"] == {"decode": {"xla_latent_absorbed": 1}}
+
+
+ONE_PART_HOLDS = ("experts", "state", "entry", "kernel-and-route")
+
+
+@pytest.mark.parametrize("held", ONE_PART_HOLDS)
+def test_one_part_decode_chunk_reads_its_stacks_where_they_lie(report, held):
+    """The Nemotron-H cell's decode chunk (PR 41: eleven one-part layers
+    unrolled, each reading its leaves out of its kind's stack at a static
+    index), compiled for the described chip at the cell's own sizes. This
+    guards the program's SHAPE; the times are the chip's.
+
+    ``experts``: no held expert stack (5 x 64 experts of 1,024 x 2,688: 1.76
+    GB a leaf) and no layer of one (0.35 GB) is copied, relaid or sliced out
+    a layer a step: the grouped products fetch the experts that were hit
+    out of the stacks where they lie (ops/moe.py).
+
+    ``state``: nothing produces an array of the state stack's size (126 MB)
+    but the in-place writes of one layer's rows, five a step: no state
+    stack is relaid.
+
+    ``entry``: the chunk's entry copies under 64 MB in all (the token and
+    key arrays, the keys' and values' one-layer stack).
+
+    ``kernel-and-route``: the one attention layer decodes through the
+    kernel, booked as ``pallas``."""
+    got = report["one-part-decode"]
+    assert "error" not in got, got
+    if held == "experts":
+        assert got["experts"] == []
+    elif held == "state":
+        assert got["state"] == []
+    elif held == "entry":
+        assert sum(got["entry_copy_mb"].values()) < 64
+    else:
+        assert got["kernel"] and got["routes"] == {"decode": {"pallas": 1}}
 
 
 def test_hybrid_ssm_programs_compile(report):
