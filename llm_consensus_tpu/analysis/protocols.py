@@ -127,7 +127,7 @@ def _stub_handoff(crash_wave):
 
     class StubCfg:
         name = "stub"
-        has_ssm = False  # KVHandoff refuses a state-space model by name
+        has_state = False  # KVHandoff refuses a model with per-row state by name
 
     class StubEngine:
         cfg = StubCfg()

@@ -181,7 +181,7 @@ def _ssm_admit(cfg, did, rows: int, tokens_real: int) -> dict:
     rest: the positions the scans of its prefill ``did`` of ``rows`` rows
     ran over (``scan_positions_swept``: rows x slots, padding included) and
     the real tokens among them. Nothing for a model without a mixer."""
-    if not cfg.has_ssm:
+    if not cfg.has_state:
         return {}
     return {"ssm_swept": scan_positions_swept(cfg, did, rows),
             "ssm_live": tokens_real}
@@ -192,7 +192,7 @@ def _ssm_decode(cfg, steps: int, rows: int) -> dict:
     model say beside the rest: the rows whose state the dispatch's steps
     read and write (every row the pool holds, with a stream or not).
     Nothing for a model without a mixer."""
-    return {"ssm_state_row_steps": steps * rows} if cfg.has_ssm else {}
+    return {"ssm_state_row_steps": steps * rows} if cfg.has_state else {}
 
 
 # What a pool row WITHOUT a stream carries as its device ``row_start``:
@@ -900,7 +900,7 @@ class ContinuousBatcher:
             # No prefix-merge form over a latent (MLA) cache yet: off.
             and not engine.cfg.is_latent
             # A shared prefix has no state for a row to start from: off.
-            and not engine.cfg.has_ssm
+            and not engine.cfg.has_state
             and mesh_ok
             # Spec rounds hold each row's FULL prompt in its own window
             # (the batched verify program has no prefix-merge form);
@@ -992,7 +992,7 @@ class ContinuousBatcher:
             # (causal_pairs): their ratio is what a prefill sweeps in vain.
             "prefill_kv_pairs_swept": 0, "prefill_kv_pairs_live": 0,
         }
-        if engine.cfg.has_ssm:
+        if engine.cfg.has_state:
             # A state-space model's pool: the positions its prefill
             # programs' scans ran over (rows x slots, padding and whole scan
             # chunks included) and the real tokens among them; and the rows

@@ -144,7 +144,7 @@ def _row_end(cfg: ModelConfig, place: Callable, ends):
     """``forward``'s ``row_end`` for a prefill of rows whose real tokens end
     at slots ``ends``: an operand of a state-space model's programs alone
     (None keeps every other model's program as it was)."""
-    return place(jnp.asarray(ends, jnp.int32)) if cfg.has_ssm else None
+    return place(jnp.asarray(ends, jnp.int32)) if cfg.has_state else None
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh"), donate_argnames=("cache",))
@@ -291,7 +291,7 @@ def _prefill_chunks_loop(params, cfg: ModelConfig, tokens, base, n_real,
     with scope("chunk.tail"):
         row_end = (
             base + (n_real - 1) * chunk + last_index + 1
-            if cfg.has_ssm else None)
+            if cfg.has_state else None)
         toks0 = tokens[0]
     with w8a8_scope(w8a8):
         logits0, cache, *moe = forward(
@@ -568,11 +568,12 @@ def scan_positions_swept(cfg: ModelConfig, did: Prefilled, rows: int) -> int:
     """Positions the scans of a state-space model's prefill ``did`` of
     ``rows`` rows (padding rows included) ran over: every token slot of
     every program, with a program's T rounded up to whole scan chunks
-    (ops/ssm.py ``ssd_chunked``). 0 for a model without a mixer."""
-    if not cfg.has_ssm:
+    (ops/ssm.py ``ssd_chunked``, ops/delta.py ``kda_chunked``). 0 for a
+    model whose rows hold no state."""
+    if not cfg.has_state:
         return 0
     t = did.slot_tokens // (rows * did.chunks)  # one program's width
-    return rows * did.chunks * (-(-t // cfg.ssm_chunk) * cfg.ssm_chunk)
+    return rows * did.chunks * (-(-t // cfg.scan_chunk) * cfg.scan_chunk)
 
 
 def prefill_pairs_swept(cfg: ModelConfig, rows: int, t: int, slots: int,
@@ -591,8 +592,9 @@ def prefill_pairs_swept(cfg: ModelConfig, rows: int, t: int, slots: int,
 
 def refuse_ssm(cfg: ModelConfig, what: str) -> None:
     """Refuse, by name, a path that would cut, fork or move a state-space
-    model's cache at a length its recurrent state was not computed to."""
-    if cfg.has_ssm:
+    model's cache at a length its recurrent state was not computed to
+    (``cfg.has_state``: a mixer's state or a delta rule's alike)."""
+    if cfg.has_state:
         raise ValueError(
             f"{cfg.name}: no {what} for a state-space model: keys and values "
             "can be cut at any length, its recurrent state exists only at "
@@ -775,7 +777,7 @@ class Engine:
             # The retained prefix snapshot is not built over a latent yet:
             # off, as pooled prefix sharing is (engine/batcher.py).
             self.prefix_cache_enabled = False
-        if cfg.has_ssm:
+        if cfg.has_state:
             self._refuse_ssm(mesh)
             # Keys and values can be cut at any length; a recurrent state
             # exists only at the length it was saved at. So no retained
@@ -969,6 +971,7 @@ class Engine:
             "state_bytes_per_row": state_bytes_per_row(
                 cfg, jnp.dtype(dtype).itemsize),
             "ssm_layers": cfg.n_ssm_layers,
+            "kda_layers": cfg.n_kda_layers,
             # Layers by what they hold in the cache: keys and values, and
             # nothing at all (a one-part expert layer); with ``ssm_layers``
             # they sum to the depth only where every layer is one part.

@@ -2,7 +2,7 @@
 
 One generic decoder-only transformer (models/transformer.py) covers every
 family the framework serves — Llama-2/3, Mistral, Gemma, Qwen2, Mixtral,
-DeepSeek-V2, Falcon-H1, Nemotron-H — via static config switches, so each (family, shape) pair
+DeepSeek-V2, Falcon-H1, Nemotron-H, Solar-Open2 — via static config switches, so each (family, shape) pair
 compiles to a single XLA program. Every field a family adds defaults to
 "off", so the older presets hash and compare as they did. The reference framework's "model set" is a table of
 remote API names (/root/reference/cmd/llm-consensus/main.go:49-61); here the
@@ -18,7 +18,7 @@ from typing import Optional
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                     # llama | mistral | gemma | qwen2 | mixtral | deepseek_v2 | falcon_h1 | nemotron_h
+    family: str                     # llama | mistral | gemma | qwen2 | mixtral | deepseek_v2 | falcon_h1 | nemotron_h | solar_open2
     vocab_size: int
     d_model: int
     n_layers: int
@@ -94,18 +94,47 @@ class ModelConfig:
     mlp_multipliers: tuple[float, float] = (1.0, 1.0)  # gate, down
     # -- a stack whose every layer is ONE part (Nemotron-H): one character
     # a layer, "M" a state-space mixer, "E" the routed expert layer, "*"
-    # attention; "" = the uniform layer (attention [+ mixer], then an MLP).
-    # Each kind has a parameter stack and a cache of its own length.
+    # attention, "K" a delta-rule layer; "" = the uniform layer (attention
+    # [+ mixer], then an MLP). Each kind has a parameter stack and a cache of
+    # its own length. A published two-part layer ``x += mixer(norm(x)); x +=
+    # moe(norm(x))`` is two one-part layers: Solar-Open2's period of four is
+    # "*EKEKEKE".
     layer_kinds: str = ""
     rotary: bool = True             # False: attention applies no rotary embedding
+    attn_out_gate: bool = False     # attention's output times sigmoid(h W_gate)
+                                    # elementwise, before wo
+    # -- delta-rule layer (Kimi Delta Attention, ops/delta.py), kind "K";
+    # kda_heads 0 = off. The cache then holds a matrix state
+    # [kda_head_dim, kda_head_dim] a head and one convolution tail over
+    # q | k | v a ROW a layer.
+    kda_heads: int = 0
+    kda_head_dim: int = 0           # keys and values alike
+    kda_conv: int = 4               # causal convolution length
+    kda_chunk: int = 64             # positions a chunk of the chunked rule
+    kda_rank: int = 0               # width of the two low-rank gates
+    kda_neg_eigval: bool = False    # beta in (0, 2): eigenvalues in [-1, 1]
 
     def __post_init__(self):
         if self.layer_kinds:
-            odd = set(self.layer_kinds) - set("ME*")
+            odd = set(self.layer_kinds) - set("ME*K")
             if odd or len(self.layer_kinds) != self.n_layers:
                 raise ValueError(
                     f"{self.name}: layer_kinds {self.layer_kinds!r} needs one of "
-                    f"'M', 'E', '*' for each of n_layers = {self.n_layers}")
+                    f"'M', 'E', '*', 'K' for each of n_layers = {self.n_layers}")
+            for kind, sized, what in (("M", self.has_ssm, "ssm_heads"),
+                                      ("K", self.has_kda, "kda_heads")):
+                if (kind in self.layer_kinds) != sized:
+                    raise ValueError(
+                        f"{self.name}: layer_kinds {self.layer_kinds!r} and "
+                        f"{what} disagree on whether there is a {kind!r} layer")
+            if self.has_ssm and self.has_kda:
+                raise ValueError(
+                    f"{self.name}: one cache holds one kind of state: 'M' "
+                    "and 'K' layers in one stack are not computed")
+        elif self.has_kda:
+            raise ValueError(
+                f"{self.name}: a delta-rule layer is a one-part layer: "
+                "kda_heads needs layer_kinds with 'K'")
 
     def kind_layers(self, kind: str) -> tuple[int, ...]:
         """The indices, in the whole stack, of the layers of ``kind``."""
@@ -124,8 +153,53 @@ class ModelConfig:
         return self.layer_kinds.count("M") if self.layer_kinds else self.n_layers
 
     @property
+    def n_kda_layers(self) -> int:
+        """Layers that hold a delta rule's matrix state and its tail."""
+        return self.layer_kinds.count("K")
+
+    @property
     def has_ssm(self) -> bool:
         return self.ssm_heads > 0
+
+    @property
+    def has_kda(self) -> bool:
+        return self.kda_heads > 0
+
+    @property
+    def has_state(self) -> bool:
+        """A ROW holds a state beside its keys and values, which exists at
+        one length only: what every refusal and every cache helper asks,
+        whichever recurrence keeps it."""
+        return self.has_ssm or self.has_kda
+
+    @property
+    def n_state_layers(self) -> int:
+        return self.n_ssm_layers + self.n_kda_layers
+
+    @property
+    def scan_chunk(self) -> int:
+        """Positions a chunk of whichever chunked recurrence the model runs."""
+        return self.kda_chunk if self.has_kda else self.ssm_chunk
+
+    def row_state_shapes(self, batch: int) -> tuple[tuple, tuple]:
+        """What ``batch`` rows hold beside keys and values in ONE layer that
+        keeps a state: the recurrent state's shape (float32) and the
+        convolution tail's, for whichever recurrence the model runs."""
+        if self.has_kda:
+            p = self.kda_head_dim
+            return ((batch, self.kda_heads, p, p),
+                    (batch, self.kda_conv - 1, self.kda_conv_width))
+        return ((batch, self.ssm_heads, self.ssm_head_dim, self.ssm_state),
+                (batch, self.ssm_conv - 1, self.ssm_conv_width))
+
+    @property
+    def kda_inner(self) -> int:
+        return self.kda_heads * self.kda_head_dim
+
+    @property
+    def kda_conv_width(self) -> int:
+        """Channels the delta layer's convolution runs over: q | k | v."""
+        return 3 * self.kda_inner
 
     @property
     def ssm_inner(self) -> int:
@@ -279,6 +353,17 @@ MODEL_PRESETS: dict[str, ModelConfig] = {c.name: c for c in [
        router_scoring="sigmoid_bias", routed_scale=2.5, norm_topk=True,
        ssm_heads=6, ssm_head_dim=8, ssm_state=16, ssm_groups=2, ssm_conv=4,
        ssm_chunk=8, max_seq_len=4096),
+    # Solar-Open2's stack at CI size: one whole period of four published
+    # layers as eight one-part layers: an output-gated attention layer
+    # without rotary embedding, three delta-rule layers (3 heads of 16, two
+    # sub-chunks a chunk, beta in (0, 2)), each followed by 16 gated experts
+    # of 40, 3 a token by sigmoid score + bias, one shared expert of 40.
+    _L("tiny-solar-open2", "solar_open2", 512, 96, 8, 4, 2, 24, 0,
+       layer_kinds="*EKEKEKE", rotary=False, attn_out_gate=True,
+       n_experts=16, experts_per_token=3, d_expert=40, n_shared_experts=1,
+       router_scoring="sigmoid_bias", norm_topk=True,
+       kda_heads=3, kda_head_dim=16, kda_conv=4, kda_chunk=32, kda_rank=8,
+       kda_neg_eigval=True, max_seq_len=4096),
     _L("tiny-llama", "llama", 512, 128, 2, 4, 2, 32, 256, max_seq_len=4096),
     _L("tiny-gemma", "gemma", 512, 128, 2, 4, 4, 32, 256, activation="gelu_tanh",
        norm_offset=1.0, embed_scale=True, tie_embeddings=True, max_seq_len=4096),

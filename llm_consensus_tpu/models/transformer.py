@@ -1,7 +1,7 @@
 """Generic decoder-only transformer in functional JAX.
 
 One implementation serves every model family (llama/mistral/gemma/qwen2/
-mixtral/deepseek_v2/falcon_h1/nemotron_h) via static ``ModelConfig`` switches. This replaces the reference's
+mixtral/deepseek_v2/falcon_h1/nemotron_h/solar_open2) via static ``ModelConfig`` switches. This replaces the reference's
 "compute layer" — three HTTP clients (/root/reference/internal/provider/
 {openai,anthropic,google}.go) — with real on-device compute.
 
@@ -59,6 +59,18 @@ its own (``layers_ssm``, ``layers_moe``, ``layers_attn``) and a cache of its
 own length: keys and values ``[n_attn_layers, ...]``, state and tail
 ``[n_ssm_layers, ...]``, nothing for an expert layer. ``forward`` walks the
 static pattern, unrolled, and gives each layer its index WITHIN its kind.
+
+The Solar-Open2 stack (``family="solar_open2"``) is the same walk over two
+more parts. A published layer there is two-part and pre-norm, ``x +=
+mixer(norm(x)); x += experts(norm(x))``, which IS two one-part layers: its
+period of four published layers is ``*EKEKEKE``. ``K`` is a delta-rule layer
+(Kimi Delta Attention, ops/delta.py has the equations): stack ``layers_kda``,
+and in the cache a matrix state ``[H, P, P]`` float32 and one convolution
+tail over ``q | k | v`` a ROW a layer, under the same ``STATE_KEY`` sub-tree
+and names as a mixer's (a stack has one kind of state). ``*`` there passes
+its output through a learned gate before ``wo`` (``cfg.attn_out_gate``: ``o *
+sigmoid(h W_gate)``, elementwise); ``E`` is the gated expert layer of
+ops/moe.py under sigmoid scores with a correction bias.
 """
 
 from __future__ import annotations
@@ -74,6 +86,7 @@ from llm_consensus_tpu.analysis import sanitizer
 from llm_consensus_tpu.models.config import ModelConfig
 from llm_consensus_tpu.obs.scopes import scope
 from llm_consensus_tpu.ops.attention import attention, make_attention_mask
+from llm_consensus_tpu.ops.delta import kda
 from llm_consensus_tpu.ops.mlp import gated_mlp
 from llm_consensus_tpu.ops.moe import moe_block
 from llm_consensus_tpu.ops.quant import (
@@ -131,7 +144,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
     """Random-init parameter pytree (layers stacked on axis 0; a family
     with leading dense layers has two stacks, ``layers_dense`` and then
     ``layers``; a family whose every layer is one part, ``cfg.layer_kinds``,
-    has a stack a kind: ``layers_ssm``, ``layers_moe``, ``layers_attn``).
+    has a stack for each kind it has: ``layers_ssm``, ``layers_moe``,
+    ``layers_attn``, ``layers_kda``).
 
     ``shardings`` (a tree of ``jax.sharding.Sharding`` shaped like the
     result: ``parallel.sharding.param_shardings``) makes every leaf
@@ -187,12 +201,16 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
     proj_std = d ** -0.5
 
     def attn_leaves(l: int) -> dict:
-        return {
+        out = {
             "wq": normal(next(keys), (l, d, hq * dh), proj_std, "wq"),
             "wk": normal(next(keys), (l, d, hkv * dh), proj_std, "wk"),
             "wv": normal(next(keys), (l, d, hkv * dh), proj_std, "wv"),
             "wo": normal(next(keys), (l, hq * dh, d), (hq * dh) ** -0.5, "wo"),
         }
+        if cfg.attn_out_gate:
+            out["w_ogate"] = normal(
+                next(keys), (l, d, hq * dh), proj_std, "w_ogate")
+        return out
 
     def routed_leaves(l: int) -> dict:
         """The expert layer's leaves: the router over its whole width, the
@@ -267,14 +285,18 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
         return {norm_name: norm((l, d), norm_name), **leaves(l)}
 
     if cfg.layer_kinds:
+        makers = {
+            "M": ("layers_ssm", "attn_norm", lambda l: _init_mixer(
+                cfg, l, keys, make, normal, norm, dtype)),
+            "E": ("layers_moe", "mlp_norm", routed_leaves),
+            "*": ("layers_attn", "attn_norm", attn_leaves),
+            "K": ("layers_kda", "attn_norm", lambda l: _init_kda(
+                cfg, l, keys, make, normal, norm, dtype)),
+        }
         stacks = {
-            "layers_ssm": kind_stack(
-                "layers_ssm", cfg.n_ssm_layers, "attn_norm",
-                lambda l: _init_mixer(cfg, l, keys, make, normal, norm, dtype)),
-            "layers_moe": kind_stack(
-                "layers_moe", cfg.n_expert_layers, "mlp_norm", routed_leaves),
-            "layers_attn": kind_stack(
-                "layers_attn", cfg.n_attn_layers, "attn_norm", attn_leaves),
+            name: kind_stack(name, cfg.layer_kinds.count(kind), norm_name, leaves)
+            for kind, (name, norm_name, leaves) in makers.items()
+            if kind in cfg.layer_kinds
         }
     else:
         n_dense = cfg.n_dense_layers if cfg.is_moe else 0
@@ -294,6 +316,13 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
     return params
 
 
+def _dt_bias(u):
+    """Uniform ``u`` to a step bias: ``dt`` log-uniform in [1e-3, 1e-1]
+    through the inverse softplus (both recurrences' published initialiser)."""
+    dt = jnp.exp(u * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
 def _init_mixer(cfg: ModelConfig, l: int, keys, make, normal, norm, dtype) -> dict:
     """The state-space mixer's leaves of ``l`` stacked layers
     (``init_params``'s makers; ops/ssm.py ``mixer`` reads them). Random like
@@ -307,20 +336,46 @@ def _init_mixer(cfg: ModelConfig, l: int, keys, make, normal, norm, dtype) -> di
         return make(name, lambda kk: fn(
             jax.random.uniform(kk, shape, jnp.float32)).astype(dtype), next(keys))
 
-    def dt_bias(u):
-        dt = jnp.exp(u * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3))
-        return dt + jnp.log(-jnp.expm1(-dt))
-
     return {
         "ssm_in": normal(next(keys), (l, d, cfg.ssm_proj_width), d ** -0.5, "ssm_in"),
         "ssm_conv": normal(next(keys), (l, c, k), k ** -0.5, "ssm_conv"),
         "ssm_conv_bias": normal(next(keys), (l, c), 0.02, "ssm_conv_bias"),
-        "ssm_dt_bias": uniform("ssm_dt_bias", (l, h), dt_bias),
+        "ssm_dt_bias": uniform("ssm_dt_bias", (l, h), _dt_bias),
         "ssm_a_log": uniform("ssm_a_log", (l, h), lambda u: jnp.log(1 + 15 * u)),
         "ssm_d": uniform("ssm_d", (l, h), lambda u: 0.5 + u),
         "ssm_norm": norm((l, inner), "ssm_norm"),
         "ssm_out": normal(next(keys), (l, inner, d), inner ** -0.5, "ssm_out"),
     }
+
+
+def _init_kda(cfg: ModelConfig, l: int, keys, make, normal, norm, dtype) -> dict:
+    """The delta-rule layer's leaves of ``l`` stacked layers
+    (``init_params``'s makers; ops/delta.py ``kda`` reads them). Random like
+    the rest, the decay's two leaves in the ranges the published initialiser
+    draws from: ``A`` in [1, 16] a head, ``dt_bias`` the inverse softplus of
+    log-uniform [1e-3, 1e-1] a channel. No bias anywhere."""
+    d, inner, r = cfg.d_model, cfg.kda_inner, cfg.kda_rank
+    h, k = cfg.kda_heads, cfg.kda_conv
+
+    def uniform(name, shape, fn):
+        return make(name, lambda kk: fn(
+            jax.random.uniform(kk, shape, jnp.float32)).astype(dtype), next(keys))
+
+    out = {
+        name: normal(next(keys), (l, d, inner), d ** -0.5, name)
+        for name in ("wq", "wk", "wv")}
+    out["wo"] = normal(next(keys), (l, inner, d), inner ** -0.5, "wo")
+    out["kda_conv"] = normal(
+        next(keys), (l, cfg.kda_conv_width, k), k ** -0.5, "kda_conv")
+    for gate in ("kda_f", "kda_g"):  # the decay's and the output gate's
+        out[gate + "_a"] = normal(next(keys), (l, d, r), d ** -0.5, gate + "_a")
+        out[gate + "_b"] = normal(next(keys), (l, r, inner), r ** -0.5, gate + "_b")
+    out["kda_beta"] = normal(next(keys), (l, d, h), d ** -0.5, "kda_beta")
+    out["kda_dt_bias"] = uniform("kda_dt_bias", (l, inner), _dt_bias)
+    out["kda_a_log"] = uniform(
+        "kda_a_log", (l, h), lambda u: jnp.log(1 + 15 * u))
+    out["kda_norm"] = norm((l, cfg.kda_head_dim), "kda_norm")
+    return out
 
 
 def init_kv_cache(
@@ -345,24 +400,23 @@ def init_kv_cache(
       ``STATE_KEY``, ``kv_tree_map``), never by their rank. Where every
       layer is one part (``cfg.layer_kinds``) each leaf counts the layers of
       its own kind: keys and values ``cfg.n_attn_layers``, state and tail
-      ``cfg.n_ssm_layers``; an expert layer holds nothing.
+      ``cfg.n_state_layers``; an expert layer holds nothing. A delta-rule
+      model's are the same two leaves: ``state`` ``[L, B, H, P, P]`` and
+      ``conv`` over ``q | k | v`` (``cfg.row_state_shapes``).
     """
     s = max_seq or cfg.max_seq_len
-    if cfg.has_ssm:
+    if cfg.has_state:
         if quant is not None:
             raise ValueError(
                 f"{cfg.name}: no quantized cache for a state-space model: "
                 f"kv cache quant {quant!r} is not computed")
         shape = (cfg.n_attn_layers, batch, s, cfg.n_kv_heads, cfg.head_dim)
+        state, tail = cfg.row_state_shapes(batch)
         return {
             "k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
             STATE_KEY: {
-                "state": jnp.zeros(
-                    (cfg.n_ssm_layers, batch, cfg.ssm_heads, cfg.ssm_head_dim,
-                     cfg.ssm_state), jnp.float32),
-                "conv": jnp.zeros(
-                    (cfg.n_ssm_layers, batch, cfg.ssm_conv - 1,
-                     cfg.ssm_conv_width), dtype),
+                "state": jnp.zeros((cfg.n_state_layers, *state), jnp.float32),
+                "conv": jnp.zeros((cfg.n_state_layers, *tail), dtype),
             },
         }
     if cfg.is_latent:
@@ -718,6 +772,11 @@ def _layer(
                 logit_softcap=cfg.attn_logit_softcap,
             )
             attn_out = merge_attention_states(o1, m1, l1, attn_out, m2, l2)
+    if cfg.attn_out_gate:
+        with scope("attn.gate"):
+            gate = qeinsum("btd,dk->btk", h, lp["w_ogate"])
+            attn_out = attn_out.reshape(b, t, hq * dh) * jax.nn.sigmoid(
+                gate.astype(jnp.float32)).astype(attn_out.dtype)
     with scope("attn.out"):
         attn_out = qeinsum(
             "btk,kd->btd", attn_out.reshape(b, t, hq * dh), lp["wo"])
@@ -735,33 +794,37 @@ def _layer(
 
 
 def _mixer_layer(cfg: ModelConfig, x, lp, ssm, layer_idx, span):
-    """A one-part layer that is a state-space mixer: ``x + mixer(norm(x))``.
-    Returns ``(x, ssm)``."""
+    """A one-part layer that keeps a state, a state-space mixer or a
+    delta-rule layer: ``x + mixer(norm(x))``. Returns ``(x, ssm)``."""
     with scope("norm"):
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps, cfg.norm_offset)
     mixed, ssm = _mixer_half(cfg, h, lp, ssm, layer_idx, span)
-    with scope("ssm.out_proj"):  # the residual add rides the layer's last product
+    # the residual add rides the layer's last product
+    with scope("kda.out_proj" if cfg.has_kda else "ssm.out_proj"):
         return x + mixed, ssm
 
 
 def _mixer_half(cfg: ModelConfig, h, lp, ssm, layer_idx, span):
-    """The state-space mixer of one layer on the normed input ``h``: this
+    """The part of one layer that keeps a state (a state-space mixer, or a
+    delta-rule layer) on the normed input ``h``: this
     layer's state and tail come out of the cache's full stacks ``ssm`` and
     go back in place (zeros and nothing kept without a cache)."""
     b = h.shape[0]
     lo, hi = span if span is not None else (None, None)
+    mixer, state_write = (
+        (kda, "kda.state_write") if cfg.has_kda
+        else (ssm_mixer, "ssm.state_write"))
     if ssm is None:
-        state = jnp.zeros(
-            (b, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32)
-        tail = jnp.zeros((b, cfg.ssm_conv - 1, cfg.ssm_conv_width), h.dtype)
-        return ssm_mixer(cfg, h, lp, state, tail, lo, hi)[0], None
-    with scope("ssm.state_write"):
+        state, tail = cfg.row_state_shapes(b)
+        return mixer(cfg, h, lp, jnp.zeros(state, jnp.float32),
+                     jnp.zeros(tail, h.dtype), lo, hi)[0], None
+    with scope(state_write):
         state = jax.lax.dynamic_index_in_dim(
             ssm["state"], layer_idx, 0, keepdims=False)
         tail = jax.lax.dynamic_index_in_dim(
             ssm["conv"], layer_idx, 0, keepdims=False)
-    out, state, tail = ssm_mixer(cfg, h, lp, state, tail, lo, hi)
-    with scope("ssm.state_write"):
+    out, state, tail = mixer(cfg, h, lp, state, tail, lo, hi)
+    with scope(state_write):
         ssm = {
             "state": jax.lax.dynamic_update_index_in_dim(
                 ssm["state"], state.astype(ssm["state"].dtype), layer_idx, 0),
@@ -885,7 +948,7 @@ def forward(
     """
     if cfg.is_latent:
         _refuse_latent(cfg, attn_impl, mesh, prefix, kv_mask)
-    if cfg.has_ssm:
+    if cfg.has_state:
         _refuse_ssm(cfg, attn_impl, mesh, prefix, kv_mask)
     if attn_impl == "ring":
         if cache is None or mesh is None or not (
@@ -1108,9 +1171,9 @@ def forward(
                 sliding_window=cfg.sliding_window,
             )
     ssm_span = None
-    if cfg.has_ssm and (row_start is not None or row_end is not None):
+    if cfg.has_state and (row_start is not None or row_end is not None):
         # Each row's real positions [lo, hi) inside this call's T.
-        with scope("ssm.conv"):
+        with scope("kda.conv" if cfg.has_kda else "ssm.conv"):
             lo = jnp.zeros((b,), jnp.int32) if row_start is None else jnp.clip(
                 row_start - start, 0, t)
             hi = jnp.full((b,), t, jnp.int32) if row_end is None else jnp.clip(
@@ -1217,8 +1280,8 @@ def _walk_kinds(params, cfg: ModelConfig, layer_fn, carry, attn_args,
     """``forward``'s layers where every layer is ONE part: the static
     pattern ``cfg.layer_kinds`` unrolled, each layer given its leaves out of
     its kind's stack and its index WITHIN its kind, which is its place in
-    that kind's cache (keys and values for ``*``, state and tail for ``M``)
-    and in the stacked experts (``E``). ``carry`` is ``(x, cache_k, cache_v,
+    that kind's cache (keys and values for ``*``, state and tail for ``M``
+    or ``K``) and in the stacked experts (``E``). ``carry`` is ``(x, cache_k, cache_v,
     stats, ssm)`` as the layer scan carries it. Unrolled, not scanned: the
     pattern has no period a scan could run over (``MEMEMEM*EME`` is three
     ``ME`` pairs and five layers that repeat nothing), eleven small bodies
@@ -1226,15 +1289,15 @@ def _walk_kinds(params, cfg: ModelConfig, layer_fn, carry, attn_args,
     its leaves where they lie."""
     cos, sin, mask, at = attn_args
     moe_own, experts = _scanned(params["layers_moe"], True)
-    stack_of = {"M": params["layers_ssm"], "E": moe_own,
-                "*": params["layers_attn"]}
+    stack_of = {"M": params.get("layers_ssm"), "E": moe_own,
+                "*": params.get("layers_attn"), "K": params.get("layers_kda")}
 
     def part(kind: str, i: int, carry):
         x, ck, cv, stats, cs = carry
         with scope("layers"):
             lp = jax.tree.map(lambda a: a[i], stack_of[kind])
             idx = jnp.asarray(i, jnp.int32)
-        if kind == "M":
+        if kind in "MK":
             x, cs = _mixer_layer(cfg, x, lp, cs, idx, ssm_span)
         elif kind == "*":
             x, ck, cv = layer_fn(
@@ -1247,7 +1310,7 @@ def _walk_kinds(params, cfg: ModelConfig, layer_fn, carry, attn_args,
                     stats = stats + more[0]
         return x, ck, cv, stats, cs
 
-    seen = {"M": 0, "E": 0, "*": 0}
+    seen = dict.fromkeys(stack_of, 0)
     for kind in cfg.layer_kinds:
         fn = partial(part, kind, seen[kind])
         carry = (jax.checkpoint(fn) if remat else fn)(carry)

@@ -455,24 +455,46 @@ def instrument(fn, family: Optional[str] = None,
     Disabled (ledger None) the wrapper is one None check; the wrapped
     callable is signature- and attribute-transparent (``.lower`` etc.
     delegate to the jitted original).
+
+    What a dispatch books is read before the program runs; the program
+    runs; only then is it booked. A program is thus always traced by its
+    own call, at one place, and the capture's ``lower`` finds that
+    trace. Booked first, whichever pool thread met a ``(family, key)``
+    first traced ITS model's program under ``_capture`` and the other
+    models' under ``call``; a Pallas kernel's serialized body carries
+    the Python frames it was traced under, they are part of the
+    persistent compile cache's key, and so each start of a server
+    compiled a different handful of its programs anew (found on the
+    chip, PR 44: 12, 7 and 2 of 230 programs in the three starts after a
+    cold one). ``lower`` reads shapes only, so the arguments the call
+    donated still serve it.
     """
 
     @wraps(fn)
     def call(*args, **kwargs):
         led = ledger()
-        if led is not None:
-            try:
-                from llm_consensus_tpu.obs import attrib as attrib_mod
+        if led is None:
+            return fn(*args, **kwargs)
+        booking = None
+        try:
+            from llm_consensus_tpu.obs import attrib as attrib_mod
 
-                fam = attrib_mod.current_family() or family or "other"
-                k = key(args, kwargs) if key is not None else _SENTINEL_KEY
-                n_tok = int(tokens(args, kwargs)) if tokens is not None else 0
-                n_steps = int(steps(args, kwargs)) if steps is not None else 1
+            fam = attrib_mod.current_family() or family or "other"
+            k = key(args, kwargs) if key is not None else _SENTINEL_KEY
+            n_tok = int(tokens(args, kwargs)) if tokens is not None else 0
+            n_steps = int(steps(args, kwargs)) if steps is not None else 1
+            booking = (fam, k, n_tok, max(1, n_steps))
+        except Exception:  # noqa: BLE001 — telemetry never raises
+            pass
+        out = fn(*args, **kwargs)
+        if booking is not None:
+            try:
+                fam, k, n_tok, n_steps = booking
                 led.dispatch(fam, k, fn, args, kwargs,
-                             tokens=n_tok, steps=max(1, n_steps))
-            except Exception:  # noqa: BLE001 — telemetry never raises
+                             tokens=n_tok, steps=n_steps)
+            except Exception:  # noqa: BLE001
                 pass
-        return fn(*args, **kwargs)
+        return out
 
     call.__wrapped__ = fn
     for attr in ("lower", "trace", "eval_shape", "clear_cache",

@@ -44,6 +44,10 @@ ATTN = (
     "attn.sweep",     # mask or sweep plan, cache read, scores, softmax, values
     "attn.out",       # the output product
 )
+# An attention layer whose output passes a learned gate (cfg.attn_out_gate).
+ATTN_GATE = (
+    "attn.gate",      # the gate's product, its sigmoid, the multiply
+)
 # Latent attention, in place of ATTN.
 MLA = (
     "mla.q",       # wq_a, q_norm, wq_b, the rotary part (and its tables)
@@ -72,8 +76,21 @@ SSM = (
     "ssm.out_proj",     # the out-projection and its multiplier
     "ssm.state_write",  # state and tail out of and back into the full stacks
 )
+# The delta-rule layer (Kimi Delta Attention), a one-part layer of kind K.
+KDA = (
+    "kda.in_proj",      # the q / k / v products
+    "kda.gate",         # the decay's and the output gate's low-rank pairs,
+                        # dt_bias, A, beta
+    "kda.conv",         # the causal convolution, its activation, the norms
+                        # of q and k, the span's masks
+    "kda.scan",         # the chunked rule (T > 1)
+    "kda.step",         # one position of the rule (T = 1)
+    "kda.norm",         # the per-head norm and the output gate
+    "kda.out_proj",     # the out-projection
+    "kda.state_write",  # state and tail out of and back into the full stacks
+)
 
-SCOPES = COMMON + ATTN + MLA + MOE + SSM
+SCOPES = COMMON + ATTN + ATTN_GATE + MLA + MOE + SSM + KDA
 _KNOWN = frozenset(SCOPES)
 
 
@@ -85,4 +102,6 @@ def scope(part: str):
     return jax.named_scope(PREFIX + part)
 
 
-__all__ = ["ATTN", "COMMON", "MLA", "MOE", "PREFIX", "SCOPES", "SSM", "scope"]
+__all__ = [
+    "ATTN", "ATTN_GATE", "COMMON", "KDA", "MLA", "MOE", "PREFIX", "SCOPES", "SSM",
+    "scope"]

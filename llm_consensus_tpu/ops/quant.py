@@ -70,7 +70,10 @@ QUANT_KEYS = frozenset(
      # a state-space mixer's two projections
      "ssm_in", "ssm_out",
      # LatentMoE's two projections around the routed sum
-     "w_latent_in", "w_latent_out"}
+     "w_latent_in", "w_latent_out",
+     # attention's output gate (a delta-rule layer's four projections go
+     # by attention's names; its low-rank gates stay as made)
+     "w_ogate"}
 )
 
 
@@ -219,7 +222,7 @@ def quantize_params(params: dict, donate: bool = False,
     if "lm_head" in out:
         out["lm_head"] = maybe(out["lm_head"])
     for stack in ("layers_dense", "layers", "layers_ssm", "layers_moe",
-                  "layers_attn"):
+                  "layers_attn", "layers_kda"):
         if stack in out:
             out[stack] = {
                 name: maybe(w) if name in QUANT_KEYS else w

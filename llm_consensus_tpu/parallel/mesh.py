@@ -93,7 +93,7 @@ def best_tp(cfg: ModelConfig, n_devices: int) -> int:
     model is not sharded yet (its engine refuses a mesh with tp > 1): 1.
     Nor is a state-space model (its scan is not split over heads yet): 1.
     """
-    if cfg.is_latent or cfg.has_ssm:
+    if cfg.is_latent or cfg.has_state:
         return 1
     tp = 1
     d = 1

@@ -192,7 +192,7 @@ def cache_specs(cfg: ModelConfig, mesh: Optional[Mesh] = None, batch: int = 1) -
     tp_kv = _axis(mesh, "tp", cfg.n_kv_heads)
     spec = P(None, dp, None, tp_kv, None)
     specs = {"k": spec, "v": spec}
-    if cfg.has_ssm:
+    if cfg.has_state:
         specs[STATE_KEY] = {
             "state": P(None, dp, None, None, None),
             "conv": P(None, dp, None, None),

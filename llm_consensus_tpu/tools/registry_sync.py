@@ -199,7 +199,7 @@ def fetch_local_models() -> list[ModelRecord]:
                     "latent_attention": cfg.is_latent,
                     "cache_width": cfg.cache_width,
                     # a state-space model: what a ROW costs beside its slots
-                    "state_space": cfg.has_ssm,
+                    "state_space": cfg.has_state,
                     "state_bytes_per_row": cfg.state_bytes_per_row,
                     # "" for a uniform layer; else one part a layer, by kind
                     "layer_kinds": cfg.layer_kinds,

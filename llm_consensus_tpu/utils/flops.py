@@ -10,6 +10,7 @@ added explicitly; MoE counts only the experts a token is routed through.
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 from llm_consensus_tpu.models.config import ModelConfig
@@ -37,7 +38,7 @@ def param_count(cfg: ModelConfig, active_only: bool = False) -> int:
         q = d * cfg.n_heads * dh
         kv = 2 * d * cfg.n_kv_heads * dh
         o = cfg.n_heads * dh * d
-        attn = q + kv + o
+        attn = q + kv + o + (q if cfg.attn_out_gate else 0)
     if cfg.qkv_bias:
         attn += (cfg.n_heads + 2 * cfg.n_kv_heads) * dh
     # a mixer: in-projection, depthwise convolution with its bias, dt_bias /
@@ -46,6 +47,14 @@ def param_count(cfg: ModelConfig, active_only: bool = False) -> int:
         d * cfg.ssm_proj_width + cfg.ssm_conv_width * (cfg.ssm_conv + 1)
         + 3 * cfg.ssm_heads + cfg.ssm_inner + cfg.ssm_inner * d
     ) if cfg.has_ssm else 0
+    # a delta-rule layer: four projections, the convolution over q | k | v,
+    # the decay's and the output gate's low-rank pairs, beta, dt_bias a
+    # channel, A_log a head, the per-head norm
+    kda = (
+        4 * d * cfg.kda_inner + cfg.kda_conv_width * cfg.kda_conv
+        + 2 * cfg.kda_rank * (d + cfg.kda_inner) + d * cfg.kda_heads
+        + cfg.kda_inner + cfg.kda_heads + cfg.kda_head_dim
+    ) if cfg.has_kda else 0
     # The experts HELD here (a chip's share counts what it holds); a token
     # visits at most experts_per_token of them. The router keeps its whole
     # width (and its correction bias), the shared experts see every token;
@@ -66,7 +75,8 @@ def param_count(cfg: ModelConfig, active_only: bool = False) -> int:
         # every layer ONE part behind one norm
         return (
             cfg.n_ssm_layers * (mixer + d) + cfg.n_expert_layers * (routed + d)
-            + cfg.n_attn_layers * (attn + d) + embed + head + d)
+            + cfg.n_attn_layers * (attn + d) + cfg.n_kda_layers * (kda + d)
+            + embed + head + d)
     attn += mixer  # the mixer beside attention
     dense_mlp = 3 * d * cfg.d_ff  # gate + up + down
     norms = 2 * d
@@ -89,14 +99,15 @@ def cache_bytes_per_token(cfg: ModelConfig, itemsize: int = 2) -> int:
 
 def state_bytes_per_row(cfg: ModelConfig, itemsize: int = 2) -> int:
     """Bytes one ROW of a state-space model holds beside its keys and
-    values, over every layer and whatever its context: the float32 state
-    and the convolution tail (``itemsize`` bytes a value). 0 without a
-    mixer. What ``cache_bytes_per_token`` is to a slot, this is to a row."""
-    if not cfg.has_ssm:
+    values, over every layer that keeps a state and whatever its context:
+    the float32 state (a mixer's, or a delta rule's matrix a head) and the
+    convolution tail (``itemsize`` bytes a value). 0 without one. What
+    ``cache_bytes_per_token`` is to a slot, this is to a row."""
+    if not cfg.has_state:
         return 0
-    state = cfg.ssm_inner * cfg.ssm_state * 4
-    tail = cfg.ssm_conv_width * (cfg.ssm_conv - 1) * itemsize
-    return cfg.n_ssm_layers * (state + tail)
+    state, tail = cfg.row_state_shapes(1)
+    return cfg.n_state_layers * (
+        math.prod(state) * 4 + math.prod(tail) * itemsize)
 
 
 def flops_per_token(cfg: ModelConfig, context_len: int = 0) -> float:
@@ -122,6 +133,8 @@ def flops_per_token(cfg: ModelConfig, context_len: int = 0) -> float:
     # a mixer's recurrence a token: decay and update the state (3 a value),
     # read it out (2 a value); constant in the context
     scan = 5 * cfg.n_ssm_layers * cfg.ssm_inner * cfg.ssm_state
+    # a delta rule's: decay, read what is held, write, read out (7 a value)
+    scan += 7 * cfg.n_kda_layers * cfg.kda_inner * cfg.kda_head_dim
     return 2.0 * weights + float(attn_quad + scan)
 
 

@@ -13,6 +13,7 @@ import glob
 import http.client
 import json
 import os
+import time
 
 import pytest
 
@@ -136,6 +137,15 @@ def _http(port: int, method: str, path: str, body=None):
     return r.status, json.loads(data)
 
 
+def _wait_for_span(ring, name: str, trace, timeout_s: float = 60.0) -> None:
+    """Until the flight ring holds the ended span ``name`` of ``trace``."""
+    deadline = time.monotonic() + timeout_s
+    while not any(e.name == name and e.args.get("trace") == trace
+                  for e in ring.snapshot()):
+        assert time.monotonic() < deadline, f"{name} of {trace} never ended"
+        time.sleep(0.002)
+
+
 @pytest.fixture(scope="module")
 def served(tmp_path_factory):
     """Two tiny panelists and a judge that is not a panelist, through the
@@ -173,6 +183,10 @@ def served(tmp_path_factory):
         assert armed == "armed"
         status, doc = _http(port, "POST", "/v1/consensus",
                             {"prompt": "which panelist gates a run?"})
+        # The client has the whole reply while the request thread is still
+        # inside ``reply.close``: the window may close only once that span
+        # has ended (its ring event is written after its annotation).
+        _wait_for_span(ring, "reply.close", doc.get("trace_id"))
         assert prof.stop_now() == path
         _, after = _http(port, "GET", "/statsz")
         again, cached = _http(port, "POST", "/v1/consensus",

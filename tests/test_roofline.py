@@ -202,6 +202,43 @@ def test_instrument_books_under_the_ambient_attrib_tag():
     assert fams["draft"]["dispatches"] == 1
 
 
+def test_instrument_lets_the_call_trace_and_books_a_donated_argument():
+    """Two programs that share a (family, key), as two models' steps at
+    one bucket do: each is traced once, under ``call`` and never under
+    ``_capture`` (a Pallas kernel's body carries the frames it was traced
+    under, and they are part of the compile cache's key), and the
+    capture still reads its cost from arguments the call donated."""
+    import inspect
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+
+    led = RooflineLedger(ridge=32.0)
+    roofline_mod.install(led)
+    traced_under = []
+
+    @partial(jax.jit, static_argnames=("model",), donate_argnames=("cache",))
+    def step(cache, x, model):
+        traced_under.append(
+            (model, [f.function for f in inspect.stack()
+                     if f.function in ("call", "_capture")]))
+        return cache + x @ x
+
+    wrapped = roofline_mod.instrument(step, family="decode", key=lambda a, k: "b8")
+    x = jnp.ones((8, 8), dtype=jnp.float32)
+    for model in ("a", "b", "a"):
+        cache = jnp.zeros((8, 8), dtype=jnp.float32)
+        wrapped(cache, x, model=model)
+        assert cache.is_deleted()
+    assert traced_under == [("a", ["call"]), ("b", ["call"])]
+    fam = led.snapshot(device_s={})["families"]["decode"]
+    assert fam["programs"] == 1
+    assert fam["dispatches"] == 3
+    assert fam["source"] == "xla"
+    assert fam["flops"] >= 3 * 1024
+
+
 def test_instrument_disabled_is_transparent():
     roofline_mod.install(None)
     f, x = _jitted_matmul()

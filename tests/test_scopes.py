@@ -27,7 +27,9 @@ ROWS, SLOTS, CHUNK = 2, 256, 64
 
 # (preset, weight and cache quantisation): dense, sliding-window int8,
 # latent + routed, hybrid state-space, a routed model without a latent, and
-# a stack whose every layer is one part (mixer, LatentMoE or attention).
+# a stack whose every layer is one part (mixer, LatentMoE or attention), and
+# one of delta-rule layers beside output-gated attention, each followed by
+# routed experts.
 FAMILIES = {
     "dense": ("tiny-llama", None),
     "window-int8": ("tiny-mistral", "int8"),
@@ -35,6 +37,7 @@ FAMILIES = {
     "hybrid-ssm": ("tiny-falcon-h1", None),
     "routed": ("tiny-mixtral", None),
     "one-part": ("tiny-nemotron-h", None),
+    "delta-rule": ("tiny-solar-open2", None),
 }
 PROGRAMS = ("decode_chunk", "prefill_step", "prefill_chunks_loop")
 CASES = [(f, p) for f in FAMILIES for p in PROGRAMS]
@@ -58,6 +61,11 @@ def expected(cfg, program: str) -> set:
     if cfg.has_ssm:
         want |= set(scopes.SSM) - {
             "ssm.scan" if program == "decode_chunk" else "ssm.step"}
+    if cfg.has_kda:
+        want |= set(scopes.KDA) - {
+            "kda.scan" if program == "decode_chunk" else "kda.step"}
+    if cfg.attn_out_gate:
+        want |= set(scopes.ATTN_GATE)
     if program == "decode_chunk":
         want |= {"sample", "sentinel", "chunk.tail"}
     if program == "prefill_chunks_loop":
@@ -97,7 +105,7 @@ def _call(family: str, program: str):
             params, cfg, _i32(ROWS, CHUNK), _i32(ROWS), cache,
         ), dict(attn_impl="flash", row_start=_i32(ROWS), kv_width=CHUNK,
                 moe_stats=True,
-                row_end=_i32(ROWS) if cfg.has_ssm else None)
+                row_end=_i32(ROWS) if cfg.has_state else None)
     cfg, params, cache = _abstract(family, 1)
     return E._prefill_chunks_loop, (
         params, cfg, _i32(4, 1, CHUNK), _i32(), _i32(), _i32(1), cache, 4,

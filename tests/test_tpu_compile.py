@@ -96,6 +96,7 @@ DENSE_PROGRAMS = {
 }
 HYBRID_DECODE = "falcon-h1-34b:decode"
 ONE_PART_CONFIG = "benchmark/configs/nemotron3-super-ep8-trio-bf16.json"
+DELTA_CONFIG = "benchmark/configs/solar-open2-ep8-trio-bf16.json"
 ONE_PART_ROWS, ONE_PART_STEPS, ONE_PART_WIDTH = 6, 16, 384
 
 
@@ -220,7 +221,8 @@ def _compile_all() -> dict:
     for width in LATENT_DECODE_WIDTHS:
         report[f"latent-decode:kv{width}"] = _latent_decode_chunk(
             sds, shapes, width)
-    report["one-part-decode"] = _one_part_decode_chunk(sds, shapes)
+    report["one-part-decode"] = _one_part_decode_chunk(sds, shapes, ONE_PART_CONFIG)
+    report["delta-decode"] = _one_part_decode_chunk(sds, shapes, DELTA_CONFIG)
     reads: dict = {}
     report["hybrid-ssm"] = _hybrid_ssm_programs(sds, shapes, has_kernel, reads)
     for name in DENSE_PROGRAMS:
@@ -476,10 +478,11 @@ def _latent_decode_chunk(sds, shapes, width: int) -> dict:
     }
 
 
-def _one_part_decode_chunk(sds, shapes) -> dict:
-    """What the Nemotron-H cell's decode chunk (six rows, 16 steps, the
-    sentinel and the routing sums, at the cell's own sizes) materialises,
-    read off its compiled text. Of the TOP-LEVEL instructions (not inside a
+def _one_part_decode_chunk(sds, shapes, config: str) -> dict:
+    """What the decode chunk of a cell whose judge is a stack of one-part
+    layers (``config``: the Nemotron-H cell's, the Solar-Open2 cell's; six
+    rows, 16 steps, the sentinel and the routing sums, at the cell's own
+    sizes) materialises, read off its compiled text. Of the TOP-LEVEL instructions (not inside a
     fusion) of the entry computation and of the step's body: ``experts``
     the operations that produce an array of the size of a held expert stack
     (``w_up`` / ``w_down`` [5, 64, 1024, 2688]) or of one layer of it;
@@ -497,7 +500,7 @@ def _one_part_decode_chunk(sds, shapes) -> dict:
     from llm_consensus_tpu.models import init_kv_cache, init_params
     from llm_consensus_tpu.models.transformer import attention_routes
 
-    cfg = _judge(ONE_PART_CONFIG)
+    cfg = _judge(config)
     attention_routes.reset()
     params = shapes(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     cache = shapes(lambda: init_kv_cache(
@@ -738,6 +741,41 @@ def test_one_part_decode_chunk_reads_its_stacks_where_they_lie(report, held):
         assert got["experts"] == []
     elif held == "state":
         assert got["state"] == []
+    elif held == "entry":
+        assert sum(got["entry_copy_mb"].values()) < 64
+    else:
+        assert got["kernel"] and got["routes"] == {"decode": {"pallas": 1}}
+
+
+DELTA_HOLDS = ("experts", "state", "entry", "kernel-and-route")
+
+
+@pytest.mark.parametrize("held", DELTA_HOLDS)
+def test_delta_decode_chunk_reads_its_stacks_where_they_lie(report, held):
+    """The Solar-Open2 cell's decode chunk (PR 44: eight one-part layers
+    unrolled: one gated attention layer, three delta-rule layers, four
+    expert halves), compiled for the described chip at the cell's own sizes.
+    This guards the program's SHAPE; the times are the chip's.
+
+    ``experts``: no held expert stack (4 x 40 experts of 4,096 x 1,280: 1.68
+    GB a leaf) and no layer of one (0.42 GB) is copied, relaid or sliced out.
+
+    ``state``: the state stack (3 x 6 rows x 64 heads of 128 x 128 float32,
+    75 MB) fits the chip's fast memory, and the compiler stages it there:
+    what produces an array of its size is that staging (``copy-done``, a
+    ``ConcatBitcast`` of three layers' slices) and the in-place writes of one
+    layer's rows; no ``copy``, ``transpose`` or fusion relays it.
+
+    ``entry``: the chunk's entry copies under 64 MB in all.
+
+    ``kernel-and-route``: the one attention layer decodes through the
+    kernel, booked as ``pallas``."""
+    got = report["delta-decode"]
+    assert "error" not in got, got
+    if held == "experts":
+        assert got["experts"] == []
+    elif held == "state":
+        assert {op.split("{")[0] for op in got["state"]} <= {"copy-done", "custom-call"}
     elif held == "entry":
         assert sum(got["entry_copy_mb"].values()) < 64
     else:
