@@ -806,10 +806,11 @@ def _mixer_layer(cfg: ModelConfig, x, lp, ssm, layer_idx, span):
 
 def _mixer_half(cfg: ModelConfig, h, lp, ssm, layer_idx, span):
     """The part of one layer that keeps a state (a state-space mixer, or a
-    delta-rule layer) on the normed input ``h``: this
-    layer's state and tail come out of the cache's full stacks ``ssm`` and
-    go back in place (zeros and nothing kept without a cache)."""
-    b = h.shape[0]
+    delta-rule layer) on the normed input ``h``: this layer's state and tail
+    come out of the cache's full stacks ``ssm`` and go back in place (zeros
+    and nothing kept without a cache). At T = 1 a mixer is handed the state
+    stack whole, and advances this layer's rows where they lie."""
+    b, t = h.shape[:2]
     lo, hi = span if span is not None else (None, None)
     mixer, state_write = (
         (kda, "kda.state_write") if cfg.has_kda
@@ -818,20 +819,19 @@ def _mixer_half(cfg: ModelConfig, h, lp, ssm, layer_idx, span):
         state, tail = cfg.row_state_shapes(b)
         return mixer(cfg, h, lp, jnp.zeros(state, jnp.float32),
                      jnp.zeros(tail, h.dtype), lo, hi)[0], None
+    whole = (layer_idx,) if t == 1 and mixer is ssm_mixer else ()
     with scope(state_write):
-        state = jax.lax.dynamic_index_in_dim(
+        state = ssm["state"] if whole else jax.lax.dynamic_index_in_dim(
             ssm["state"], layer_idx, 0, keepdims=False)
         tail = jax.lax.dynamic_index_in_dim(
             ssm["conv"], layer_idx, 0, keepdims=False)
-    out, state, tail = mixer(cfg, h, lp, state, tail, lo, hi)
+    out, state, tail = mixer(cfg, h, lp, state, tail, lo, hi, *whole)
     with scope(state_write):
-        ssm = {
-            "state": jax.lax.dynamic_update_index_in_dim(
+        return out, {
+            "state": state if whole else jax.lax.dynamic_update_index_in_dim(
                 ssm["state"], state.astype(ssm["state"].dtype), layer_idx, 0),
             "conv": jax.lax.dynamic_update_index_in_dim(
-                ssm["conv"], tail.astype(ssm["conv"].dtype), layer_idx, 0),
-        }
-    return out, ssm
+                ssm["conv"], tail.astype(ssm["conv"].dtype), layer_idx, 0)}
 
 
 def _latent_scale(cfg: ModelConfig) -> float:

@@ -71,10 +71,10 @@ SSM = (
     "ssm.in_proj",      # the in-projection, its multipliers, dt, A, D
     "ssm.conv",         # the causal convolution and its activation
     "ssm.scan",         # the chunked recurrence (T > 1)
-    "ssm.step",         # one position of the recurrence (T = 1)
+    "ssm.step",         # one position (T = 1): in a cache, its rows in place
     "ssm.norm",         # the gate and the grouped norm
     "ssm.out_proj",     # the out-projection and its multiplier
-    "ssm.state_write",  # state and tail out of and back into the full stacks
+    "ssm.state_write",  # state (T > 1) and tail out of and back into the stacks
 )
 # The delta-rule layer (Kimi Delta Attention), a one-part layer of kind K.
 KDA = (

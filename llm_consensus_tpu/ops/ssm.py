@@ -1,4 +1,5 @@
-"""Mamba-2 selective state-space mixer in plain XLA (no kernel yet).
+"""Mamba-2 selective state-space mixer in plain XLA, but for a decode step's
+recurrence in a cache (ops/pallas/ssm_step.py).
 
 The mixer of a hybrid block (``ModelConfig.has_ssm``), beside attention on
 the same normed input ``u`` [T, D]::
@@ -13,7 +14,8 @@ the same normed input ``u`` [T, D]::
 Two forms of the recurrence, the same numbers: ``ssd_chunked`` for T > 1 (the
 state-space-duality form over chunks of ``chunk`` positions: the products
 inside a chunk, each chunk's end state, the recurrence over chunk states and
-the carried-in state's share) and ``ssd_step`` for T = 1. State and decay
+the carried-in state's share) and ``ssd_step`` for T = 1, which a state in
+a cache's stack takes in place (ops/pallas/ssm_step.py). State and decay
 sums are float32, and every product here runs at ``highest`` precision: the
 scan is a few percent of a block's operations and its state is carried
 through thousands of steps.
@@ -178,13 +180,16 @@ def gated_group_norm(y, z, weight, groups: int, eps: float):
 
 
 def mixer(cfg, u: jax.Array, lp: dict, state: jax.Array, tail: jax.Array,
-          lo: Optional[jax.Array] = None, hi: Optional[jax.Array] = None):
+          lo: Optional[jax.Array] = None, hi: Optional[jax.Array] = None,
+          layer: Optional[jax.Array] = None):
     """The mixer branch of one layer on the normed input ``u`` [B, T, D].
 
     ``lp`` holds the layer's ``ssm_*`` leaves; ``state`` [B, H, P, N] float32
     and ``tail`` [B, K-1, C] are the row's carried state; ``lo``, ``hi`` [B]
     bound each row's real positions inside T (``None``: all real). Returns
-    ``(out [B, T, D], new state, new tail)``.
+    ``(out [B, T, D], new state, new tail)``. With ``layer`` (T = 1 only)
+    ``state`` is a cache's whole stack [L, B, H, P, N]: that layer's rows
+    advance where they lie, and the stack comes back.
     """
     b, t, _ = u.shape
     h, p, n, g = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups
@@ -225,8 +230,15 @@ def mixer(cfg, u: jax.Array, lp: dict, state: jax.Array, tail: jax.Array,
         bm, cm = bm.reshape(b, t, g, n), cm.reshape(b, t, g, n)
     if t == 1:
         with scope("ssm.step"):
-            y, state = ssd_step(
-                xs[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0], d, state)
+            step = (xs[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0], d)
+            if layer is None:
+                y, state = ssd_step(*step, state)
+            else:  # imported here, as the attention kernels are: a second
+                # of every process's start that a pool's thread can overlap
+                from llm_consensus_tpu.ops.pallas.ssm_step import (
+                    ssd_step_in_place)
+
+                y, state = ssd_step_in_place(state, layer, *step)
             y = y[:, None]
     else:
         with scope("ssm.scan"):

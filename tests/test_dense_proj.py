@@ -15,7 +15,10 @@ the chip's compiler then materialises) and is the identity, so on one CPU
   * the engine's own ``_decode_chunk`` samples the same tokens;
   * the lowered text of ``_decode_chunk`` is the parent's once the barrier
     is taken for the identity it is, and with it differs by the barrier's
-    own lines and nothing else.
+    own lines and nothing else (``tiny-falcon-h1``'s two digests were made
+    anew in PR 45, whose mixer step advances the state stack in place: the
+    text without the barrier is that PR's; tokens and logits are still
+    PR 38's parent's to the last bit).
 """
 
 import functools

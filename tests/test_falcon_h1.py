@@ -15,6 +15,7 @@ row whose last tenant left its state behind.
 """
 
 import dataclasses
+import functools
 import http.client
 import json
 import os
@@ -28,10 +29,11 @@ import pytest
 from benchmark.reference import falcon_h1 as reference
 from llm_consensus_tpu.engine import ContinuousBatcher, Engine, SamplingParams
 from llm_consensus_tpu.engine.batcher import (
-    _compact_cache, _move_row, _shrink_rows, _splice, _splice_rows)
+    DEAD_ROW, _compact_cache, _move_row, _shrink_rows, _splice, _splice_rows)
 from llm_consensus_tpu.models import (
     forward, get_config, init_kv_cache, init_params)
 from llm_consensus_tpu.ops import ssm
+from llm_consensus_tpu.ops.pallas.ssm_step import ssd_step_in_place
 from llm_consensus_tpu.ops.quant import STATE_KEY, kv_tree_map
 from llm_consensus_tpu.pressure import PRIORITY_HIGH, PRIORITY_LOW
 from llm_consensus_tpu.utils.flops import (
@@ -236,6 +238,112 @@ def test_a_position_with_dt_zero_leaves_the_state_alone():
              for k, v in inputs.items()}
     _, want = ssm.ssd_chunked(**short, chunk=8)
     np.testing.assert_allclose(state, want, rtol=1e-5, atol=1e-5)
+
+
+@functools.cache
+def in_place_against_sliced(name: str, traced: bool):
+    """Three decode steps of every mixer layer of the preset ``name`` over a
+    pool of four rows (two live, one with a state whose position is not
+    live, one that never had a tenant), the layer's index traced (a scan
+    over the stack, as ``forward`` walks a hybrid's layers) or static
+    (unrolled, as ``_walk_kinds`` does): the step in place in the stack, and
+    slice -> ``ssd_step`` -> update. Returns ``(the stack before, (y a step
+    a layer, stack) in place, the same sliced)``."""
+    cfg = get_config(name)
+    h, p, n, g = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups
+    layers = cfg.layer_kinds.count("M") or cfg.n_layers
+    keys = iter(jax.random.split(jax.random.PRNGKey(45), 8))
+    normal = lambda *shape: jax.random.normal(next(keys), shape, jnp.float32)  # noqa: E731
+    stack = normal(layers, 4, h, p, n).at[:, 3].set(0.0)
+    dt = jax.nn.softplus(normal(4, h)).at[2:].set(0.0)
+    step = (normal(4, h, p), dt, -jnp.exp(normal(h)), normal(4, g, n),
+            normal(4, g, n), normal(h))
+
+    def sliced(stack, layer, *step):
+        state = jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+        y, state = ssm.ssd_step(*step, state)
+        return y, jax.lax.dynamic_update_index_in_dim(stack, state, layer, 0)
+
+    def three_steps(one):
+        def every_layer(stack, _):
+            if traced:
+                return jax.lax.scan(
+                    lambda st, li: one(st, li, *step)[::-1], stack,
+                    jnp.arange(layers, dtype=jnp.int32))
+            ys = []
+            for li in range(layers):
+                y, stack = one(stack, li, *step)
+                ys.append(y)
+            return stack, jnp.stack(ys)
+        return jax.jit(lambda st: jax.lax.scan(every_layer, st, None, length=3))
+
+    return (stack, three_steps(ssd_step_in_place)(stack),
+            three_steps(sliced)(stack))
+
+
+IN_PLACE_ROWS = {
+    # name: (the rows, what holds of them beside equality with the sliced step)
+    "live": ((0, 1), "advance"), "position-not-live": ((2,), "keep"),
+    "never-a-tenant": ((3,), "stay zero"),
+}
+
+
+def in_place_step_is_the_sliced_step(name: str, traced: bool, case: str):
+    before, (stack, ys), (want_stack, want_ys) = in_place_against_sliced(name, traced)
+    rows, held = IN_PLACE_ROWS[case]
+    rows = list(rows)
+    stack, was = np.asarray(stack)[:, rows], np.asarray(before)[:, rows]
+    np.testing.assert_array_equal(np.asarray(ys)[:, :, rows], np.asarray(want_ys)[:, :, rows])
+    np.testing.assert_array_equal(stack, np.asarray(want_stack)[:, rows])
+    if held == "advance":
+        assert (stack != was).mean() > 0.99
+    else:
+        np.testing.assert_array_equal(stack, was)
+        assert was.any() == (held == "keep")
+
+
+@pytest.mark.parametrize("case", IN_PLACE_ROWS)
+def test_the_in_place_step_is_the_sliced_step_to_the_last_bit(case):
+    """T = 1: a mixer's rows advance where they lie in the cache's state
+    stack (ops/pallas/ssm_step.py), by the numbers of the step it replaced.
+    Here under a traced layer index; tests/test_nemotron_h.py a static one."""
+    in_place_step_is_the_sliced_step(NAME, True, case)
+
+
+IN_PLACE_BLOCKS = {
+    # name: ((heads, head size, state size), heads a block)
+    "falcon-h1-34b": ((32, 128, 256), 16), "nemotron-3-super": ((128, 64, 128), 64),
+    "tiny-falcon-h1": ((4, 8, 16), 4), "no-whole-sublane-tile-divides": ((12, 8, 16), 12),
+}
+
+
+@pytest.mark.parametrize("case", IN_PLACE_BLOCKS)
+def test_the_in_place_step_takes_two_mebibytes_of_a_rows_state_a_block(case):
+    from llm_consensus_tpu.ops.pallas.ssm_step import _block_heads
+
+    shape, heads = IN_PLACE_BLOCKS[case]
+    assert _block_heads(*shape) == heads
+
+
+@pytest.mark.parametrize("case", ["position-not-live", "never-a-tenant"])
+def test_a_decode_step_keeps_state_and_tail_of_a_row_that_does_not_advance(case, model):
+    """Through ``forward`` at T = 1 over a pool of four: the row whose
+    position is not live keeps its state and its tail to the bit, the row
+    that never had a tenant stays zero, and the live rows advance."""
+    cfg, params = model
+    tokens = jnp.asarray(IDS[:36].reshape(4, 9), jnp.int32)
+    _, cache = forward(
+        params, cfg, tokens[:, :8], init_kv_cache(cfg, 4, 32, jnp.float32), 0,
+        row_end=jnp.asarray([8, 8, 8, 0], jnp.int32))
+    _, after = forward(
+        params, cfg, tokens[:, 8:], cache, jnp.asarray(8, jnp.int32),
+        row_start=jnp.asarray([0, 0, DEAD_ROW, DEAD_ROW], jnp.int32))
+    row = 2 if case == "position-not-live" else 3
+    for leaf in ("state", "conv"):
+        was, now = (np.asarray(c[STATE_KEY][leaf]) for c in (cache, after))
+        np.testing.assert_array_equal(now[:, row], was[:, row])
+        assert was[:, row].any() == (row == 2)
+        assert (now[:, :2] != was[:, :2]).any()
 
 
 CONV_SPANS = {
@@ -478,25 +586,31 @@ def pool_case_wave(engine):
 
 def pool_case_compaction(engine):
     """Staggered streams push the shared frontier past capacity: the slide
-    moves every row's slots and must leave its state where it is."""
-    s = SamplingParams(max_new_tokens=60, **GREEDY)
-    s_head = SamplingParams(max_new_tokens=30, **GREEDY)
-    prompts = [f"staggered stream {i} of the slide" for i in range(9)]
-    wants = [engine.generate(p, s_head if i == 0 else s).token_ids
-             for i, p in enumerate(prompts)]
+    moves every row's slots and must leave its state where it is.
+
+    All the streams are queued at once: three short ones free their rows at
+    three frontiers, and from then on the pool itself admits the next stream
+    as a row ends, where the frontier then stands, so the pool is never idle
+    before the queue is (an idle pool resets its frontier). A prompt of 32
+    tokens is admitted up to frontier 224 (``fits``) and a stream of 96
+    tokens admitted past 160 is still live at 256: a row frees every 24
+    steps or so, so some stream is, whenever the host's threads run. (This
+    thread used to submit the next stream of 60 tokens when it saw one end:
+    on a loaded machine it came late and the pool went idle, or no stream
+    was admitted between 196 and 224, and the frontier never reached
+    capacity: no slide, nothing tested.)"""
+    lengths = [24, 48, 72] + [96] * 10
+    prompts = [f"staggered stream {i} of the slide" for i in range(len(lengths))]
+    params = [SamplingParams(max_new_tokens=n, **GREEDY) for n in lengths]
+    wants = [engine.generate(p, s).token_ids for p, s in zip(prompts, params)]
     b = ContinuousBatcher(engine, max_batch=4)
     slides = []
     compact = b._compact
     b._compact = lambda: slides.append(b._pos) or compact()
     try:
-        futs = {0: b.submit(prompts[0], s_head), 1: b.submit(prompts[1], s)}
-        nxt = 2
-        while futs:
-            i = min(futs)
-            assert futs.pop(i).result(timeout=600).token_ids == wants[i], i
-            if nxt < len(prompts):
-                futs[nxt] = b.submit(prompts[nxt], s)
-                nxt += 1
+        futs = [b.submit(p, s) for p, s in zip(prompts, params)]
+        for i, f in enumerate(futs):
+            assert f.result(timeout=600).token_ids == wants[i], i
         assert slides, "the frontier never reached capacity"
     finally:
         b.close()

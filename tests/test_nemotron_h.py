@@ -42,6 +42,10 @@ from llm_consensus_tpu.ops.quant import (  # noqa: E402
 if __name__ != "__main__":  # a parent checkout has neither
     from benchmark import parity, server
     from benchmark.reference import nemotron_h as reference
+    from tests.test_falcon_h1 import (
+        IN_PLACE_ROWS, in_place_step_is_the_sliced_step)
+else:
+    IN_PLACE_ROWS = ()
 
 NAME = "tiny-nemotron-h"
 PINS = os.path.join(REPO, "tests", "data", "lowered_text_pins.json")
@@ -647,6 +651,15 @@ def test_the_cells_file_states_the_catalogs_numbers():
         assert doc[key] == value, key
     for word in ("rotary", "mtp", "serving_peak"):
         assert word in doc["assumed"]
+
+
+@pytest.mark.parametrize("case", IN_PLACE_ROWS)
+def test_the_in_place_step_is_the_sliced_step_to_the_last_bit(case):
+    """The five mixers' rows advance where they lie in the state stack, each
+    at a STATIC index (``_walk_kinds`` unrolls the pattern), by the numbers
+    of slice -> ``ssd_step`` -> update (tests/test_falcon_h1.py has the
+    cases, and a traced index)."""
+    in_place_step_is_the_sliced_step(NAME, False, case)
 
 
 # -- the older families' programs: the parent's text, byte for byte -------------

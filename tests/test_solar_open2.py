@@ -11,10 +11,10 @@ period of four published layers at CI size (1 attention layer of 4/2 heads of
 32, beta in (0, 2); 4 expert halves of 16 gated experts of 40, 3 a token by
 sigmoid score + bias, one shared expert of 40).
 
-Last: Nemotron-H's programs, which walk the same ``_walk_kinds``, lower to
-the text they lowered to at the parent commit (``tests/data/
-lowered_text_pins.json``, keys ``nemotron_h.*``; the older families' pins are
-tests/test_nemotron_h.py's).
+Last: Nemotron-H's programs, which walk the same ``_walk_kinds``, and this
+family's own lower to their pinned text (``tests/data/
+lowered_text_pins.json``, keys ``nemotron_h.*`` and ``solar_open2.*``; the
+older families' pins are tests/test_nemotron_h.py's).
 """
 
 import copy
@@ -840,17 +840,19 @@ def test_every_configuration_still_loads_through_install_models(path, monkeypatc
     assert set(parity.stated(doc)) == set(doc["models"])
 
 
-# -- Nemotron-H's programs: the parent's text, byte for byte ---------------------
+# -- the one-part families' programs: the parent's text, byte for byte -----------
 
+PINNED = {"nemotron_h": "tiny-nemotron-h", "solar_open2": "tiny-solar-open2"}
 PROGRAMS = ("decode_chunk", "six_row_wave", "judge_prompt_loop")
+LOWERED = [(f, p) for f in PINNED for p in PROGRAMS]
 ROWS, SLOTS, CHUNK = 6, 256, 64
 
 
-def lowered_text(program: str) -> str:
-    """The text a program of ``tiny-nemotron-h`` lowers to, on abstract
-    operands of a pool of six (tests/test_nemotron_h.py ``lowered_text``
-    for the one family that walks ``_walk_kinds`` beside this one)."""
-    cfg = MODEL_PRESETS["tiny-nemotron-h"]
+def lowered_text(family: str, program: str) -> str:
+    """The text a program of a family that walks ``_walk_kinds`` lowers to
+    on its CI-size preset, on abstract operands of a pool of six
+    (tests/test_nemotron_h.py ``lowered_text`` for the older families)."""
+    cfg = MODEL_PRESETS[PINNED[family]]
     params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     rows = 1 if program == "judge_prompt_loop" else ROWS
     cache = jax.eval_shape(lambda: init_kv_cache(cfg, rows, SLOTS))
@@ -873,20 +875,23 @@ def lowered_text(program: str) -> str:
     return lowered.as_text()
 
 
-def digest(program: str) -> str:
-    return hashlib.sha256(lowered_text(program).encode()).hexdigest()
+def digest(family: str, program: str) -> str:
+    return hashlib.sha256(lowered_text(family, program).encode()).hexdigest()
 
 
-@pytest.mark.parametrize("program", PROGRAMS)
-def test_nemotron_hs_program_lowers_to_the_parents_text(program):
+@pytest.mark.parametrize("family,program", LOWERED)
+def test_a_one_part_familys_program_lowers_to_the_parents_text(family, program):
+    """Nemotron-H's decode chunk was pinned anew in PR 45 (its mixers' step
+    went into the state stack in place); the delta rule's kept its path, so
+    Solar-Open2's three are the texts of PR 45's parent."""
     with open(PINS) as f:
         pins = json.load(f)
-    assert digest(program) == pins[f"nemotron_h.{program}"]
+    assert digest(family, program) == pins[f"{family}.{program}"]
 
 
 if __name__ == "__main__":
     # python tests/test_solar_open2.py <out.json>, from a checkout's root:
     # the digests of that checkout's lowered text, the pins above.
     with open(sys.argv[1], "w") as out:
-        json.dump({f"nemotron_h.{p}": digest(p) for p in PROGRAMS}, out, indent=1)
+        json.dump({f"{f}.{p}": digest(f, p) for f, p in LOWERED}, out, indent=1)
         out.write("\n")

@@ -227,6 +227,8 @@ def _compile_all() -> dict:
     report["hybrid-ssm"] = _hybrid_ssm_programs(sds, shapes, has_kernel, reads)
     for name in DENSE_PROGRAMS:
         _dense_program(name, topo, has_kernel, reads)
+    report["state-step:hybrid"] = reads.pop(  # or what the chip would raise
+        "state-step:hybrid", report["hybrid-ssm"]["decode"])
     report.update({f"dense-proj:{name}": got for name, got in reads.items()})
     return report
 
@@ -264,7 +266,8 @@ def _hybrid_ssm_programs(sds, shapes, has_kernel, reads: dict) -> dict:
             temperature=0.0, top_k=None, top_p=None, row_start=sds((6,)),
             kv_width=384, attn_impl="flash", sentinel=True),
             read=lambda text: reads.update({
-                HYBRID_DECODE: _projection_reads(text, cfg, params)})),
+                HYBRID_DECODE: _projection_reads(text, cfg, params),
+                "state-step:hybrid": _state_passes(text, cache(6)["ssm"]["state"])})),
     }
 
 
@@ -403,6 +406,64 @@ def _writes_in_place(text: str) -> set:
         name for name, (_, body) in _computations(text).items()
         if re.search(r"^\s*ROOT \S+ = \S+ dynamic-update-slice\(", body, re.M)
     }
+
+
+def _instructions(body: str):
+    """``(name, [(type, dims) of each array it produces], operation, the
+    names of its operands, the line)`` of every instruction of a
+    computation's text (a tuple's arrays in order)."""
+    for line in body.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*)", line)
+        if not m:
+            continue
+        rest, depth, at = m.group(2), 0, 0
+        if rest.startswith("("):  # a tuple's type: up to its closing parenthesis
+            for at, ch in enumerate(rest):
+                depth += (ch == "(") - (ch == ")")
+                if depth == 0:
+                    break
+        at = rest.index(" ", at)
+        produced = [(dtype, tuple(int(d) for d in dims.split(",") if d))
+                    for dtype, dims in re.findall(r"(\w+)\[([\d,]*)\]", rest[:at])]
+        op, _, operands = rest[at + 1:].partition("(")
+        yield (m.group(1), produced, op,
+               re.findall(r"%([\w.\-]+)", operands.split(")")[0]), line)
+
+
+def _state_passes(text: str, stack) -> dict:
+    """What a compiled decode chunk does to a mixer's state stack ``stack``
+    [L, B, H, P, N] float32, of the TOP-LEVEL instructions (not inside a
+    fusion) of its loop bodies. ``rows``: the operations that produce one
+    layer's rows of state other than in place. ``passes``: the operations
+    that take the whole stack as an operand, each a pass over one layer's
+    rows: ``custom-call:in-place`` is the step's kernel with the stack
+    aliased to its output, ``fusion:in-place`` a fusion whose root is the
+    update, ``fusion`` one that only reads."""
+    import math
+
+    dims, writes_in_place = tuple(stack.shape), _writes_in_place(text)
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
+    carried = ("parameter", "get-tuple-element", "bitcast", "tuple", "while")
+    rows, passes = [], []
+    for name, (_, body) in _computations(text).items():
+        if name not in bodies:
+            continue
+        produced_by = {}
+        for result, produced, op, operands, line in _instructions(body):
+            produced_by[result] = produced
+            if op in carried:
+                continue
+            in_place = (
+                "output_to_operand_aliasing" in line if op == "custom-call"
+                else op == "dynamic-update-slice" or (
+                    op == "fusion" and re.search(
+                        r"calls=%?([\w.\-]+)", line).group(1) in writes_in_place))
+            if any(math.prod(d) == math.prod(dims[1:]) and t == "f32"
+                   for t, d in produced) and not in_place:
+                rows.append(op)
+            if any(produced_by.get(o) == [("f32", dims)] for o in operands):
+                passes.append(op + ":in-place" * in_place)
+    return {"rows": sorted(rows), "passes": sorted(passes)}
 
 
 def _latent_decode_chunk(sds, shapes, width: int) -> dict:
@@ -544,6 +605,7 @@ def _one_part_decode_chunk(sds, shapes, config: str) -> dict:
         "entry_copy_mb": {k: round(v, 1) for k, v in sorted(entry_copies.items())},
         "kernel": "tpu_custom_call" in text,
         "routes": attention_routes.snapshot(cfg.name),
+        "state-step": _state_passes(text, cache["ssm"]["state"]),
     }
 
 
@@ -790,6 +852,28 @@ def test_hybrid_ssm_programs_compile(report):
     assert report["hybrid-ssm"] == {
         "loop": {"kernel": False}, "wave": {"kernel": True},
         "decode": {"kernel": True}}
+
+
+STATE_STEPS = {"hybrid": 1, "one-part": 5}  # cell: mixer layers a loop body
+
+
+@pytest.mark.parametrize("cell", STATE_STEPS)
+def test_a_decoding_rows_state_is_read_once_and_written_once(report, cell):
+    """The Falcon-H1 cell's decode chunk (eight scanned layers, a traced
+    index) and the Nemotron-H cell's (five unrolled mixers, static indices),
+    compiled for the described chip at the cells' own sizes: the state
+    stack is an operand of ONE top-level operation a mixer layer, the step's
+    kernel with the stack aliased to its output (PR 45,
+    ops/pallas/ssm_step.py), and nothing produces one layer's rows of state
+    ([6, 32, 128, 256] and [6, 128, 64, 128] float32) beside it. The
+    parent's programs held two fusions a layer that each read the rows, one
+    to reduce ``y`` and one to write the update: three passes for two. This
+    guards the program's SHAPE; the times are the chip's (PERF.md section 5)."""
+    got = (report["state-step:hybrid"] if cell == "hybrid"
+           else report["one-part-decode"])
+    assert "error" not in got, got
+    got = got.get("state-step", got)
+    assert got == {"rows": [], "passes": ["custom-call:in-place"] * STATE_STEPS[cell]}
 
 
 DENSE_PROJECTION_HOLDS = ("entry", "layer")
