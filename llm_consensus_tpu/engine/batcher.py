@@ -82,6 +82,7 @@ from llm_consensus_tpu.engine.tokenizer import StreamDecoder
 from llm_consensus_tpu.obs.attrib import tag as _attrib_tag
 from llm_consensus_tpu.obs import roofline as _roofline
 from llm_consensus_tpu.obs.scopes import scope
+from llm_consensus_tpu.ops.moe import pairs_kernel_serves
 from llm_consensus_tpu.ops.quant import kv_seq_axis as _seq_axis
 from llm_consensus_tpu.ops.quant import kv_tree_map as _kv_tree_map
 from llm_consensus_tpu.ops.sampling import sample_token
@@ -1011,10 +1012,13 @@ class ContinuousBatcher:
             # row, one count an expert layer a decode step, beside the
             # (expert layer, decode step)s counted; and the held pairs of
             # the prefill programs alone, whose every program reads nearly
-            # every held expert.
+            # every held expert; and the (expert layer, decode step)s whose
+            # products ran in the kernel over the sorted pairs, by the rule
+            # the program itself was traced under (ops/moe.py).
             self.stats.update(
                 moe_pairs_total=0, moe_pairs_held=0, moe_expert_reads=0,
                 moe_layer_steps=0, moe_prefill_pairs_held=0,
+                moe_kernel_layer_steps=0,
             )
             engine._moe_bank = []  # the engine's prefills bank theirs here
         # Priority-aware preemption (pressure/): when a queued stream of
@@ -2815,7 +2819,8 @@ class ContinuousBatcher:
                         )
                 self._book_arrival(pure, mode, emitted, t_arrival, t_dispatch)
 
-    def _book_moe(self, decode, layer_steps: int, prefills: list) -> dict:
+    def _book_moe(self, decode, layer_steps: int, kernel_steps: int,
+                  prefills: list) -> dict:
         """A routed model's sums, fetched with their chunk: into the
         counters, and back as the ``pool.fetch`` span's arguments (the
         chunk's own values: they exist only on the device while
@@ -2824,17 +2829,19 @@ class ContinuousBatcher:
         pre_total = sum(int(p[0]) for p in prefills)
         pre_held = sum(int(p[1]) for p in prefills)
         if decode is None:  # a speculative round group returns no sums
-            total = held = reads = layer_steps = 0
+            total = held = reads = layer_steps = kernel_steps = 0
         else:
             total, held, reads = (int(v) for v in decode)
         self._stat_add(
             moe_pairs_total=total + pre_total, moe_pairs_held=held + pre_held,
             moe_expert_reads=reads, moe_layer_steps=layer_steps,
             moe_prefill_pairs_held=pre_held,
+            moe_kernel_layer_steps=kernel_steps,
         )
         return {
             "moe_pairs": total, "moe_pairs_held": held,
             "moe_expert_reads": reads, "moe_layer_steps": layer_steps,
+            "moe_kernel_layer_steps": kernel_steps,
             "moe_prefill_pairs_held": pre_held,
         }
 
@@ -3628,11 +3635,18 @@ class ContinuousBatcher:
                 t_dispatch = time.monotonic()
                 moe = None
                 if eng.cfg.is_moe:
-                    # (this chunk's sums, its steps x expert layers, the
-                    # sums of the prefill programs dispatched since the
-                    # last chunk): device arrays until the fetch.
-                    moe = (moe_decode, covered * eng.cfg.n_expert_layers,
-                           eng._moe_bank[:])
+                    # (this chunk's sums, its steps x expert layers, those
+                    # of them whose experts ran in the kernel: all, if a
+                    # step of these rows does; the sums of the prefill
+                    # programs dispatched since the last chunk): device
+                    # arrays until the fetch.
+                    stack = eng.params.get("layers_moe") or eng.params["layers"]
+                    layer_steps = covered * eng.cfg.n_expert_layers
+                    in_kernel = pairs_kernel_serves(
+                        self._rows_cap * eng.cfg.experts_per_token,
+                        stack["w_up"], eng.mesh)
+                    moe = (moe_decode, layer_steps,
+                           layer_steps if in_kernel else 0, eng._moe_bank[:])
                     del eng._moe_bank[:]
                 item = (
                     payload, list(self._slots[:self._rows_cap]),

@@ -495,7 +495,7 @@ def _layer(
     row_start: Optional[jax.Array] = None,  # [B] (decode_flash path only)
     decode_sweep: Optional[jax.Array] = None,  # the step's sweep plan (ditto)
     prefix_k=None,        # shared-prefix K stack [L, 1, P, Hkv, dh] (or int8 dict)
-    prefix_v=None,
+    prefix_v=None, mesh=None,  # mesh: forward's own, for a routed layer
     prefix_len=None,      # scalar i32: valid prefix slots
     prefix_rows=None,     # [B] bool: rows that attend the shared prefix
     routed: Optional[bool] = None,  # this stack's MLP is the routed expert
@@ -538,7 +538,7 @@ def _layer(
         with scope("mla.out"):
             x = x + qeinsum("btk,kd->btd", attn_out, lp["wo"])
         return _mlp_half(
-            cfg, x, lp, routed, moe_stats, cache_k, cache_v, expert_stacks)
+            cfg, x, lp, routed, moe_stats, cache_k, cache_v, expert_stacks, mesh)
     with scope("attn.proj"):
         q = qeinsum("btd,dk->btk", h, lp["wq"])
         k = qeinsum("btd,dk->btk", h, lp["wk"])
@@ -789,7 +789,7 @@ def _layer(
     if ring_mesh is not None:
         cache_k, cache_v = k, v  # fresh k/v for the caller's cache build
     out = _mlp_half(
-        cfg, x, lp, routed, moe_stats, cache_k, cache_v, expert_stacks)
+        cfg, x, lp, routed, moe_stats, cache_k, cache_v, expert_stacks, mesh)
     return (*out, ssm) if cfg.has_ssm else out
 
 
@@ -850,7 +850,7 @@ EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 
 
 def _mlp_half(cfg: ModelConfig, x, lp, routed: bool, moe_stats: bool,
-              cache_k, cache_v, expert_stacks=None):
+              cache_k, cache_v, expert_stacks=None, mesh=None):
     """The MLP half of a block on the post-attention residual ``x``: the
     dense gated MLP, or on a routed stack the expert layer (ops/moe.py),
     whose expert leaves are this layer's own (``lp``) or, from ``forward``'s
@@ -876,7 +876,7 @@ def _mlp_half(cfg: ModelConfig, x, lp, routed: bool, moe_stats: bool,
         if cfg.moe_latent else None,
         shared=(lp.get("ws_gate"), lp["ws_up"], lp["ws_down"])
         if cfg.n_shared_experts else None,
-        with_stats=moe_stats,
+        with_stats=moe_stats, mesh=mesh,
     )
     with scope("moe.experts"):  # the residual add rides the layer's last sum
         if moe_stats:
@@ -1185,7 +1185,7 @@ def forward(
         decode_flash=decode_flash, row_start=row_start, decode_sweep=sweep,
         prefix_k=prefix["k"] if prefix is not None else None,
         prefix_v=prefix["v"] if prefix is not None else None,
-        prefix_len=prefix_len,
+        prefix_len=prefix_len, mesh=mesh,
         prefix_rows=prefix_rows,
     )
 
@@ -1226,7 +1226,7 @@ def forward(
     if cfg.layer_kinds:
         x, ck, cv, stats, cs = _walk_kinds(
             params, cfg, layer_fn, (x, ck, cv, stats, cs), (cos, sin, mask, at),
-            ssm_span, moe_stats, remat and cache is None)
+            ssm_span, moe_stats, remat and cache is None, mesh)
     li = jnp.asarray(0, jnp.int32)  # the layer, counted over both stacks
     for stack, routed in stacks:
         xs, experts = _scanned(stack, routed)
@@ -1276,7 +1276,7 @@ def _scanned(stack: dict, routed: bool):
 
 
 def _walk_kinds(params, cfg: ModelConfig, layer_fn, carry, attn_args,
-                ssm_span, moe_stats: bool, remat: bool):
+                ssm_span, moe_stats: bool, remat: bool, mesh=None):
     """``forward``'s layers where every layer is ONE part: the static
     pattern ``cfg.layer_kinds`` unrolled, each layer given its leaves out of
     its kind's stack and its index WITHIN its kind, which is its place in
@@ -1304,7 +1304,7 @@ def _walk_kinds(params, cfg: ModelConfig, layer_fn, carry, attn_args,
                 x, lp, cos, sin, mask, ck, cv, at, layer_idx=idx)
         else:
             x, _, _, *more = _mlp_half(
-                cfg, x, lp, True, moe_stats, None, None, (*experts, idx))
+                cfg, x, lp, True, moe_stats, None, None, (*experts, idx), mesh)
             if moe_stats:
                 with scope("moe.stats"):
                     stats = stats + more[0]
@@ -1434,7 +1434,7 @@ def _forward_ring_prefill(
         positions = jnp.broadcast_to(
             jnp.arange(t, dtype=jnp.int32)[None, :], (b, t))
         cos, sin = _rotary_tables(cfg, positions)
-    layer_fn = partial(_layer, cfg, ring_mesh=mesh)
+    layer_fn = partial(_layer, cfg, ring_mesh=mesh, mesh=mesh)
 
     def scan_body(x, lp):
         x, k, v = layer_fn(x, lp, cos, sin, None, None, None, None)

@@ -34,11 +34,25 @@ W_up)``, two: ``w_gate=None``):
     absent experts would have added is left out (their chips add it, in a
     deployment, through an exchange that one chip does not run).
   * **Dropless dispatch.** The ``N × top_k`` (token, expert) pairs are
-    sorted by held expert, pairs on absent experts last; the held experts
-    run as three grouped matrix products (``jax.lax.ragged_dot``) over the
-    sorted rows and each token sums its own pairs' results, weighted. Static shapes come
-    from the buffer of ``N × top_k`` pairs, not from a capacity: no pair on
-    a held expert is ever dropped.
+    sorted by held expert, pairs on absent experts last; the held experts'
+    three products (two, ungated) run over the sorted rows and each token
+    sums its own pairs' results, weighted. Static shapes come from the
+    buffer of ``N × top_k`` pairs, not from a capacity: no pair on a held
+    expert is ever dropped.
+  * **Which products** (``pairs_kernel_serves``, by the static size of the
+    pairs buffer, the leaves' type and the mesh they lie on, nothing of a
+    model's name). A buffer of at most ``PAIRS_KERNEL_MAX`` pairs over plain
+    expert leaves on one device, a decode step's handful of rows, runs in
+    ONE kernel over the sorted pairs (ops/pallas/moe_pairs.py): it visits
+    only the experts that hold rows and streams each one's matrices once,
+    out of the whole ``[L, E, ...]`` stacks where they lie. A larger buffer
+    (a prompt's chunk, a panel wave: hundreds of rows an expert), int8
+    stacks (dequantized whole) and stacks sharded over a mesh of more than
+    one device (the compiler partitions no kernel; ``ragged_dot`` it does)
+    run as grouped matrix products (``jax.lax.ragged_dot``) over every
+    layer's experts as one run of ``L × E`` groups. The same numbers either
+    way: operands as stored, float32 accumulation, each product rounded to
+    ``x.dtype``.
   * **Shared experts** (one MLP of the experts' form, ``n_shared ×
     d_expert`` wide or of a width of its own) see every token and are added
     once.
@@ -50,6 +64,7 @@ experts that took at least one row.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -57,9 +72,30 @@ import jax.numpy as jnp
 
 from llm_consensus_tpu.obs.scopes import scope
 from llm_consensus_tpu.ops.mlp import _activate, gated_mlp, plain_mlp
-from llm_consensus_tpu.ops.quant import dequantize, qeinsum
+from llm_consensus_tpu.ops.quant import dequantize, is_quantized, qeinsum
+from llm_consensus_tpu.utils.backend import pallas_interpret
 
 NEG_INF = -jnp.inf
+# The largest buffer of pairs whose products run in the kernel over the
+# sorted pairs (ops/pallas/moe_pairs.py). Read on the chip (PERF.md section
+# 6, PR 46: bfloat16, layers x 16 steps in one program, ms a step,
+# ``ragged_dot`` -> the kernel) at the three cells' expert shapes, 1,024 x
+# 2,688 ungated / 4,096 x 1,280 gated / 5,120 x 1,536 gated: a pool of six
+# rows (144 / 48 / 48 pairs) 2.43 -> 1.14 / 1.48 -> 1.03 / 1.39 -> 1.29; a
+# pool of eighteen (400 / 144 / 112 pairs) 5.68 -> 2.59 / 3.75 -> 2.57 /
+# 3.41 -> 3.18; pools of 48 and of 128 rows (up to 2,816 / 1,024 / 768
+# pairs, every held expert hit) 9.93 -> 4.21 and 13.30 -> 4.79 / 8.20 ->
+# 4.80 and 17.77 -> 6.63 / 5.90 -> 5.33 and 9.55 -> 6.34. The kernel won at
+# every size read, the largest expert (15.7 MB a matrix) included. Why there
+# is a largest buffer all the same: the kernel keeps every row of the buffer
+# in fast memory, in and out, beside a float32 accumulator of [stacks, P, N]
+# (P = 400 at N = 2,688: 4.3 MB; a prompt's chunk of 11,264 pairs: 121 MB,
+# which the chip does not have), where ``ragged_dot`` tiles the rows; that
+# bound is asked of the shapes too (``moe_pairs.fits_fast_memory``). The
+# number stands at an eighteen-row pool's largest buffer: no decode step of
+# a cell is larger, and a prompt's chunk (44-78 rows an expert, thousands of
+# pairs) was not read against anything.
+PAIRS_KERNEL_MAX = 400
 
 
 def route(
@@ -103,6 +139,76 @@ def route(
     return top_idx, weights * routed_scale
 
 
+def pairs_kernel_serves(pairs: int, w_up, mesh=None) -> bool:
+    """Whether the held experts' products over a buffer of ``pairs`` (token,
+    expert) pairs run in the kernel over the sorted pairs: a buffer that is
+    small against the tile ``ragged_dot`` was built for, plain expert leaves
+    (``w_up`` as stored: an int8 stack is dequantized whole and keeps the
+    grouped product), on ONE device (``mesh``: the one the program's operands
+    lie on, if any. Over more than one device the expert stacks are sharded,
+    parallel/sharding.py, and the chip's compiler refuses to partition a
+    kernel: the grouped product it partitions), rows and accumulators that
+    fit the chip's fast memory (counted as for gated experts, the larger
+    form), widths in whole lane tiles (the chip's compiler takes no other
+    slice of a stack; the interpreter has no tiling)."""
+    if is_quantized(w_up) or pairs > PAIRS_KERNEL_MAX:
+        return False
+    if mesh is not None and mesh.size > 1:
+        return False
+    from llm_consensus_tpu.ops.pallas.moe_pairs import ROW_TILE, fits_fast_memory
+
+    k, f = w_up.shape[-2:]
+    if not fits_fast_memory(-(-pairs // ROW_TILE) * ROW_TILE, k, f,
+                            True, w_up.dtype.itemsize):
+        return False  # pairs x widths beyond what the kernel may keep
+    return not (k % 128 or f % 128) or pallas_interpret()
+
+
+def _grouped(rows, stacks, group_sizes, activation: str):
+    """The held experts' MLP over the sorted rows as grouped matrix
+    products: ``stacks`` ``[G, ...]`` each, ``group_sizes`` [G]."""
+    h = _activate(jax.lax.ragged_dot(rows, stacks[0], group_sizes), activation)
+    if len(stacks) == 3:
+        h = h * jax.lax.ragged_dot(rows, stacks[1], group_sizes)
+    return jax.lax.ragged_dot(h, stacks[-1], group_sizes)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _over_pairs(rows, stacks, layer, sizes, activation: str):
+    """``_grouped`` for layer ``layer`` of ``stacks`` ``[L, E, ...]``, whose
+    experts take ``sizes`` [E] rows, in the kernel over the sorted pairs
+    (imported here, where it is first traced, as the attention kernels
+    are)."""
+    from llm_consensus_tpu.ops.pallas.moe_pairs import experts_over_pairs
+
+    gate = stacks[0] if len(stacks) == 3 else None
+    return experts_over_pairs(
+        rows, gate, stacks[-2], stacks[-1], layer, sizes, activation)
+
+
+def _over_pairs_fwd(rows, stacks, layer, sizes, activation):
+    return (_over_pairs(rows, stacks, layer, sizes, activation),
+            (rows, stacks, layer, sizes))
+
+
+def _over_pairs_bwd(activation, saved, ct):
+    """The way back is the grouped product's, over the whole run."""
+    rows, stacks, layer, sizes = saved
+    n_stacked, held = stacks[0].shape[:2]
+
+    def flat(rows, stacks):
+        run = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_stacked * held,), sizes.dtype), sizes, (layer * held,))
+        return _grouped(
+            rows, tuple(w.reshape(-1, *w.shape[2:]) for w in stacks), run,
+            activation)
+
+    return (*jax.vjp(flat, rows, stacks)[1](ct), None, None)
+
+
+_over_pairs.defvjp(_over_pairs_fwd, _over_pairs_bwd)
+
+
 def moe_block(
     x: jax.Array,          # [B, T, D]
     w_router: jax.Array,   # [D, R]: the router's whole width
@@ -123,6 +229,7 @@ def moe_block(
     shared: Optional[tuple] = None,   # (ws_gate, ws_up, ws_down) or None
     layer=None,            # expert leaves are whole stacks [L, E, ...]: which layer
     with_stats: bool = False,
+    mesh=None,             # the mesh the operands lie on: a kernel wants ONE device
 ):
     """``w_gate=None`` (and ``shared[0] is None``): ungated experts."""
     if scoring not in ("softmax", "sigmoid_bias"):
@@ -140,9 +247,19 @@ def moe_block(
         tokens = x.reshape(n, d)
     gated = w_gate is not None
     stacks = (w_gate, w_up, w_down) if gated else (w_up, w_down)
+    kernel = pairs_kernel_serves(n * top_k, w_up, mesh)
     with scope("moe.experts"):
         stacks = tuple(dequantize(w, x.dtype) for w in stacks)
-    if layer is None:
+    if kernel:
+        from llm_consensus_tpu.ops.pallas.moe_pairs import ROW_TILE
+
+        # The kernel takes the stacks as they are, [L, E, ...], and the layer
+        # beside them: its groups are this layer's own.
+        if layer is None:
+            with scope("moe.experts"):
+                stacks, layer = tuple(w[None] for w in stacks), jnp.int32(0)
+        held, first_group = stacks[0].shape[1], 0
+    elif layer is None:
         held, first_group = stacks[0].shape[0], 0
     else:
         # The stacks of every layer as ONE run of L*E groups, of which only
@@ -155,7 +272,7 @@ def moe_block(
         with scope("moe.experts"):
             stacks = tuple(
                 w.reshape(n_stacked * held, *w.shape[2:]) for w in stacks)
-    n_groups_all = stacks[0].shape[0]
+    n_groups_all = held if kernel else stacks[0].shape[0]
 
     with scope("moe.route"):
         logits = jnp.einsum(
@@ -174,24 +291,25 @@ def moe_block(
     # a key past every group and sorts last. The buffer is rounded up to
     # whole 8-row tiles (rows that are no pair sort last too): XLA's TPU
     # grouped-product kernel takes no other, and a buffer it refuses is
-    # computed as one dense product an expert over every row.
+    # computed as one dense product an expert over every row. The kernel
+    # over the sorted pairs takes whole tiles of its own.
     with scope("moe.experts"):
         pairs = n * top_k
         local = top_idx.reshape(-1) - first_expert
         is_held = (local >= 0) & (local < held)
         key = jnp.pad(
-            jnp.where(is_held, first_group + local, n_groups_all), (0, -pairs % 8),
+            jnp.where(is_held, first_group + local, n_groups_all),
+            (0, -pairs % (ROW_TILE if kernel else 8)),
             constant_values=n_groups_all)
         order = jnp.argsort(key, stable=True)
         pair_token = jnp.minimum(order // top_k, n - 1)
         group_sizes = jnp.zeros((n_groups_all,), jnp.int32).at[key].add(1, mode="drop")
 
         rows = tokens[pair_token]                                   # [P, Z]
-        h = _activate(
-            jax.lax.ragged_dot(rows, stacks[0], group_sizes), activation)
-        if gated:
-            h = h * jax.lax.ragged_dot(rows, stacks[1], group_sizes)
-        y = jax.lax.ragged_dot(h, stacks[-1], group_sizes)          # [P, Z]
+        if kernel:
+            y = _over_pairs(rows, stacks, layer, group_sizes, activation)
+        else:
+            y = _grouped(rows, stacks, group_sizes, activation)     # [P, Z]
         # Back to (token, choice) order; a row past the last group holds
         # nothing of an expert and is masked, not multiplied by zero.
         back = jnp.zeros_like(order).at[order].set(jnp.arange(order.size))

@@ -30,6 +30,17 @@ the int8 7B's judge-prompt loop are read the same way for ``wq`` / ``wk`` /
 ``wv``: no stack relaid at a program's entry, no pass over a layer's three
 leaves before their products (PR 38).
 
+The three routed cells' decode chunks hold the kernel over the sorted
+pairs (two calls an expert layer), no ``ragged-dot`` and no layer's slice
+of an expert stack; their prefill programs, lowered at the cells' own sizes,
+keep ``ragged_dot``; and every program the kernel is not in (the dense,
+tp = 2 and hybrid configurations', the routed prefills) lowers to the text
+of PR 46's parent (``tests/data/lowered_text_pins.json`` ``cell:*``).
+A routed decode chunk whose expert stacks are SHARDED over two described
+devices (Mixtral's widths, two layers) keeps ``ragged_dot``, which the
+compiler partitions; it refuses a kernel there (``Mosaic kernels cannot be
+automatically partitioned``), and interpret mode never says so.
+
 All cases compile in ONE child process (this file run as a script) and
 the tests read its report: loading libtpu and switching the persistent
 compilation cache off (an entry written for a described device cannot be
@@ -98,6 +109,11 @@ HYBRID_DECODE = "falcon-h1-34b:decode"
 ONE_PART_CONFIG = "benchmark/configs/nemotron3-super-ep8-trio-bf16.json"
 DELTA_CONFIG = "benchmark/configs/solar-open2-ep8-trio-bf16.json"
 ONE_PART_ROWS, ONE_PART_STEPS, ONE_PART_WIDTH = 6, 16, 384
+SHARDED_ROUTED = "mixtral-8x7b-2-layers-tp2:decode"
+ROUTED_CONFIGS = {  # cell -> its configuration file
+    "dsv2": LATENT_CONFIG, "nem3": ONE_PART_CONFIG, "solar2": DELTA_CONFIG}
+TEXT_PINS = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "data", "lowered_text_pins.json")
 
 
 def _decode_id(preset, int8_kv, batch) -> str:
@@ -229,6 +245,13 @@ def _compile_all() -> dict:
         _dense_program(name, topo, has_kernel, reads)
     report["state-step:hybrid"] = reads.pop(  # or what the chip would raise
         "state-step:hybrid", report["hybrid-ssm"]["decode"])
+    for cell in ROUTED_CONFIGS:
+        report[f"routed-prefill:{cell}"] = _routed_prefill_programs(
+            sds, shapes, cell, reads)
+    report[SHARDED_ROUTED] = _sharded_routed_decode(topo, has_kernel, reads)
+    report["moe-pairs:largest"] = _largest_pairs_kernel(sds, has_kernel)
+    report["texts"] = {name[len("text:"):]: reads.pop(name)
+                       for name in sorted(reads) if name.startswith("text:")}
     report.update({f"dense-proj:{name}": got for name, got in reads.items()})
     return report
 
@@ -253,18 +276,22 @@ def _hybrid_ssm_programs(sds, shapes, has_kernel, reads: dict) -> dict:
     def cache(rows, slots=CELL_MAX_SEQ):
         return shapes(lambda: init_kv_cache(cfg, rows, slots, jnp.bfloat16))
 
+    def pinned(program: str, lowered):
+        reads[f"text:falcon-h1-34b:{program}"] = _digest(lowered)
+        return lowered
+
     return {
-        "loop": has_kernel(_prefill_chunks_loop.lower(
+        "loop": has_kernel(pinned("prefill-loop", _prefill_chunks_loop.lower(
             params, cfg, sds((4, 1, 512)), sds(()), sds(()), sds((1,)),
-            cache(1), max_chunks=4, kv_width=2048)),
-        "wave": has_kernel(_prefill_step.lower(
+            cache(1), max_chunks=4, kv_width=2048))),
+        "wave": has_kernel(pinned("wave", _prefill_step.lower(
             params, cfg, sds((8, 256)), sds((8,)), cache(8, 256),
-            attn_impl="flash", row_end=sds((8,)))),
-        "decode": has_kernel(_decode_chunk.lower(
+            attn_impl="flash", row_end=sds((8,))))),
+        "decode": has_kernel(pinned("decode", _decode_chunk.lower(
             params, cfg, sds((6,)), sds(()), cache(6),
             shapes(lambda: jax.random.PRNGKey(0)), n_steps=16,
             temperature=0.0, top_k=None, top_p=None, row_start=sds((6,)),
-            kv_width=384, attn_impl="flash", sentinel=True),
+            kv_width=384, attn_impl="flash", sentinel=True)),
             read=lambda text: reads.update({
                 HYBRID_DECODE: _projection_reads(text, cfg, params),
                 "state-step:hybrid": _state_passes(text, cache(6)["ssm"]["state"])})),
@@ -318,9 +345,132 @@ def _dense_program(name: str, topo, has_kernel, reads: dict) -> None:
         lowered = _prefill_chunks_loop.lower(
             params, cfg, ints(width // 512, 1, 512), ints(), ints(), ints(1),
             cache, max_chunks=width // 512, kv_width=width)
+    reads[f"text:{name}"] = _digest(lowered)
     got = has_kernel(lowered, read=lambda text: reads.update({
         name: _projection_reads(text, cfg, params)}))
     reads.setdefault(name, got)  # what the chip would raise, if it would
+
+
+def _digest(lowered) -> str:
+    """Of the text a program lowers to (before the chip's compiler), less
+    each kernel's serialized body: that carries the Python frames it was
+    traced under, the checkout's own path among them."""
+    import hashlib
+
+    text = re.sub(r'(body\\22: \\22)[A-Za-z0-9+/=]+', r"\1", lowered.as_text())
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def _experts_path(text: str) -> dict:
+    """Which of the two forms a program's routed experts take, counted in
+    its text, lowered or compiled: the kernel over the sorted pairs
+    (ops/pallas/moe_pairs.py) or the grouped product."""
+    return {"kernel": len(re.findall(r"llmc_moe_pairs[.\d]* = ", text))
+            or text.count('kernel_name = "llmc_moe_pairs"'),
+            "ragged-dot": len(re.findall(r"ragged[-_]dot", text))}
+
+
+def _routed_prefill_programs(sds, shapes, cell: str, reads: dict) -> dict:
+    """A routed cell's two prefill programs at the cell's own sizes, LOWERED
+    and not compiled (which form the experts take is in the text before the
+    chip's compiler): the judge prompt's loop of four 512-token chunks and a
+    wave of six panel prompts padded to eight rows of 256. Their digests go
+    into ``reads``."""
+    import jax
+    import jax.numpy as jnp
+
+    from llm_consensus_tpu.engine.engine import _prefill_chunks_loop, _prefill_step
+    from llm_consensus_tpu.models import init_kv_cache, init_params
+
+    cfg = _judge(ROUTED_CONFIGS[cell])
+    params = shapes(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+
+    def cache(rows, slots=CELL_MAX_SEQ):
+        return shapes(lambda: init_kv_cache(cfg, rows, slots, jnp.bfloat16))
+
+    ends = {"row_end": sds((8,))} if cfg.has_state else {}
+    programs = {
+        "prefill-loop": _prefill_chunks_loop.lower(
+            params, cfg, sds((4, 1, 512)), sds(()), sds(()), sds((1,)),
+            cache(1), max_chunks=4, kv_width=2048, moe_stats=True),
+        "wave": _prefill_step.lower(
+            params, cfg, sds((8, 256)), sds((8,)), cache(8, 256),
+            attn_impl="flash", row_start=sds((8,)), kv_width=256,
+            moe_stats=True, **ends),
+    }
+    reads.update({f"text:{cell}:{name}": _digest(lowered)
+                  for name, lowered in programs.items()})
+    return {name: _experts_path(lowered.as_text())
+            for name, lowered in programs.items()}
+
+
+def _largest_pairs_kernel(sds, has_kernel) -> dict:
+    """The kernel over the sorted pairs at the largest buffer the switch
+    gives it (``PAIRS_KERNEL_MAX`` pairs) and Mixtral's widths, the most
+    fast memory it is allowed of the shapes in the presets (90 MB by
+    ``moe_pairs.fast_memory_bytes``): compiled; and what the switch says of
+    widths twice those, which the chip's compiler refuses (146 MB of 128)."""
+    import jax.numpy as jnp
+
+    from llm_consensus_tpu.ops import moe
+    from llm_consensus_tpu.ops.pallas.moe_pairs import experts_over_pairs
+
+    def stacks(k, f):
+        return [sds((2, 8, *shape), jnp.bfloat16)
+                for shape in ((k, f), (k, f), (f, k))]
+
+    pairs = moe.PAIRS_KERNEL_MAX
+    got = has_kernel(experts_over_pairs.lower(
+        sds((pairs, 4096), jnp.bfloat16), *stacks(4096, 14336), sds(()),
+        sds((8,)), "silu", interpret=False))
+    return {**got, "served": moe.pairs_kernel_serves(pairs, stacks(4096, 14336)[1]),
+            "twice-served": moe.pairs_kernel_serves(pairs, stacks(8192, 28672)[1])}
+
+
+def _sharded_routed_decode(topo, has_kernel, reads: dict) -> dict:
+    """A routed model's 16-step decode chunk (Mixtral-8x7B's widths, two
+    layers of its 32 so that two chips hold it; eight rows x 2 choices: 16
+    pairs, a buffer the kernel would take on one device) under a tp = 2 mesh
+    of two described devices, its expert stacks sharded as
+    ``parallel/sharding.py`` shards them: compiled, and which form its
+    experts take. Its digest goes into ``reads``."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from llm_consensus_tpu.engine.engine import _decode_chunk
+    from llm_consensus_tpu.models import get_config, init_kv_cache, init_params
+    from llm_consensus_tpu.parallel.mesh import make_mesh
+    from llm_consensus_tpu.parallel.sharding import cache_shardings, param_shardings
+
+    cfg = dataclasses.replace(get_config("mixtral-8x7b"), n_layers=2)
+    mesh = make_mesh({"dp": 1, "tp": 2}, topo.devices[:2])
+    whole = NamedSharding(mesh, PartitionSpec())
+
+    def placed(make, shardings):
+        tree = jax.eval_shape(make)
+        return jax.tree.map(
+            lambda s, where: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=where),
+            tree, shardings(tree))
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=whole)
+
+    key = jax.random.PRNGKey(0)
+    lowered = _decode_chunk.lower(
+        placed(lambda: init_params(cfg, key), lambda _: param_shardings(cfg, mesh)),
+        cfg, ints(8), ints(),
+        placed(lambda: init_kv_cache(cfg, 8, CELL_MAX_SEQ, jnp.bfloat16),
+               lambda tree: cache_shardings(cfg, mesh, tree)),
+        placed(lambda: key, lambda tree: whole), n_steps=16, temperature=0.0,
+        top_k=None, top_p=None, row_start=ints(8), kv_width=1792,
+        attn_impl="flash", mesh=mesh, sentinel=True, moe_stats=True)
+    reads[f"text:{SHARDED_ROUTED}"] = _digest(lowered)
+    path: dict = {}
+    return {**has_kernel(lowered, read=lambda text: path.update(
+        _experts_path(text))), "experts-path": path}
 
 
 def _projection_reads(text: str, cfg, params) -> dict:
@@ -506,6 +656,7 @@ def _latent_decode_chunk(sds, shapes, width: int) -> dict:
         "pool": cfg.n_layers * LATENT_ROWS * CELL_MAX_SEQ * cfg.cache_width,
         "wq_b": cfg.q_lora_rank * cfg.n_heads * (cfg.qk_nope_dim + cfg.qk_rope_dim),
         "wkv_b": cfg.kv_lora_rank * cfg.n_heads * (cfg.qk_nope_dim + cfg.v_head_dim),
+        "experts": cfg.n_experts * cfg.d_model * cfg.expert_width,  # a layer's
     }
     report: dict = {what: [] for what in sizes}
     entry_copies: dict = {}
@@ -536,6 +687,7 @@ def _latent_decode_chunk(sds, shapes, width: int) -> dict:
         "entry_copy_mb": {k: round(v, 1) for k, v in sorted(entry_copies.items())},
         "scores": sorted(scores),
         "routes": attention_routes.snapshot(cfg.name),
+        "experts-path": _experts_path(text),
     }
 
 
@@ -606,6 +758,7 @@ def _one_part_decode_chunk(sds, shapes, config: str) -> dict:
         "kernel": "tpu_custom_call" in text,
         "routes": attention_routes.snapshot(cfg.name),
         "state-step": _state_passes(text, cache["ssm"]["state"]),
+        "experts-path": _experts_path(text),
     }
 
 
@@ -874,6 +1027,87 @@ def test_a_decoding_rows_state_is_read_once_and_written_once(report, cell):
     assert "error" not in got, got
     got = got.get("state-step", got)
     assert got == {"rows": [], "passes": ["custom-call:in-place"] * STATE_STEPS[cell]}
+
+
+# cell's decode chunk -> (its report, the kernel's calls in its text: two an
+# expert layer, the layers unrolled or, in the latent cell, one scanned body)
+ROUTED_DECODE = {
+    "dsv2-kv384": ("latent-decode:kv384", 2),
+    "dsv2-kv2048": ("latent-decode:kv2048", 2),
+    "nem3": ("one-part-decode", 10),
+    "solar2": ("delta-decode", 8),
+}
+
+
+@pytest.mark.parametrize("cell", ROUTED_DECODE)
+def test_a_routed_decode_chunk_runs_its_experts_in_the_kernel(report, cell):
+    """The three routed cells' decode chunks (six rows x 6 / 22 / 8 choices:
+    a buffer of 48 / 144 / 48 pairs), compiled for the described chip at the
+    cells' own sizes: the experts' products are the kernel over the sorted
+    pairs (PR 46, ops/pallas/moe_pairs.py: the first pass and the second, a
+    layer), no ``ragged-dot`` is left, and nothing produces an array of the
+    size of a layer's slice of an expert stack: the kernel reads the stacks
+    where they lie. This guards the program's SHAPE; the times are the
+    chip's (PERF.md section 5)."""
+    name, calls = ROUTED_DECODE[cell]
+    got = report[name]
+    assert "error" not in got, got
+    assert got["experts-path"] == {"kernel": calls, "ragged-dot": 0}
+    assert got["experts"] == []
+
+
+@pytest.mark.parametrize("program", ["prefill-loop", "wave"])
+@pytest.mark.parametrize("cell", ROUTED_CONFIGS)
+def test_a_routed_prefill_keeps_the_grouped_product(report, cell, program):
+    """A judge prompt's chunk (512 tokens x 6 / 22 / 8 choices) and a panel
+    wave (eight rows of 256) are thousands of pairs: the switch
+    (ops/moe.py ``pairs_kernel_serves``) leaves them ``ragged_dot``."""
+    got = report[f"routed-prefill:{cell}"][program]
+    assert got["kernel"] == 0 and got["ragged-dot"] > 0
+
+
+def test_the_largest_buffer_the_switch_gives_the_kernel_compiles(report):
+    """The switch asks the shapes for the kernel's fast memory
+    (``moe_pairs.fits_fast_memory``): what it admits at the largest pairs
+    buffer compiles for the described chip, and widths that would not are
+    left to ``ragged_dot``."""
+    got = report["moe-pairs:largest"]
+    assert got == {"kernel": True, "served": True, "twice-served": False}
+
+
+def test_sharded_expert_stacks_keep_the_grouped_product(report):
+    """Under a mesh of more than one device the expert stacks are sharded
+    (w_gate / w_up ``P(None, ep, None, tp)``) and the chip's compiler
+    partitions no kernel: the switch sees the mesh (``forward`` hands it
+    down) and the chunk compiles with ``ragged_dot``, as at PR 46's parent.
+    The attention kernels are there all the same (``shard_map`` over tp)."""
+    got = report[SHARDED_ROUTED]
+    assert "error" not in got, got
+    assert got["kernel"]  # the decode attention kernel, under shard_map
+    assert got["experts-path"]["kernel"] == 0
+    assert got["experts-path"]["ragged-dot"] > 0
+
+
+PARENTS_TEXTS = [
+    *(f"{cell}:{program}" for cell in ROUTED_CONFIGS
+      for program in ("prefill-loop", "wave")),
+    *DENSE_PROGRAMS,
+    *(f"falcon-h1-34b:{program}" for program in ("prefill-loop", "wave", "decode")),
+    SHARDED_ROUTED,
+]
+
+
+@pytest.mark.parametrize("program", PARENTS_TEXTS)
+def test_a_program_the_kernel_is_not_in_lowers_to_the_parents_text(report, program):
+    """Every program of the dense, tp = 2 and hybrid configurations, and the
+    routed cells' two prefill programs, at the cells' own sizes: the text
+    each lowers to is PR 46's parent's, byte for byte (its digest, written
+    from a checkout of that commit by this file run as a script there:
+    the report's ``texts``). Re-pin only in a PR that means to change
+    these programs."""
+    with open(TEXT_PINS) as f:
+        pins = json.load(f)
+    assert report["texts"][program] == pins[f"cell:{program}"]
 
 
 DENSE_PROJECTION_HOLDS = ("entry", "layer")
