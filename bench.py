@@ -1281,7 +1281,6 @@ def _obs_overhead_phase(quant: str, preset: str = "consensus-1b") -> dict:
         from llm_consensus_tpu.obs import attrib as attrib_mod
         from llm_consensus_tpu.obs import blackbox as bb_mod
         from llm_consensus_tpu.obs import live as live_mod
-        from llm_consensus_tpu.obs import roofline as roofline_mod
 
         if live_on:
             # Worst-case live plane: fast window rotation (production
@@ -1289,21 +1288,16 @@ def _obs_overhead_phase(quant: str, preset: str = "consensus-1b") -> dict:
             # if it has one) + a full-size flight recorder ring + the
             # chip-time attribution ledger (per-token goodput bumps,
             # interval attribution, the jax compile listener — the
-            # whole ISSUE-12 plane is inside the 2% budget too) + the
-            # roofline ledger's per-dispatch booking (installed
-            # explicitly: module resolution is cached, so the OFF leg
-            # running first would otherwise pin it disabled here).
+            # whole ISSUE-12 plane is inside the 2% budget too).
             lm = live_mod.LiveMetrics(window_s=0.25)
             live_mod.install(lm)
             lm.start()
             bb_mod.install(bb_mod.FlightRecorder(capacity=4096))
             attrib_mod.install(attrib_mod.ChipTimeLedger())
-            roofline_mod.install(roofline_mod.RooflineLedger())
         else:
             live_mod.install(None)
             bb_mod.install(None)
             attrib_mod.install(None)
-            roofline_mod.install(None)
         prov = TPUProvider(
             ignore_eos=True, stream_interval=16, batch_streams=n_streams,
             quant=q,
@@ -1343,7 +1337,6 @@ def _obs_overhead_phase(quant: str, preset: str = "consensus-1b") -> dict:
             live_mod.reset()
             bb_mod.reset()
             attrib_mod.reset()
-            roofline_mod.reset()
 
     tps_off = leg(False)
     tps_on = leg(True)

@@ -822,10 +822,6 @@ def _run(
     attrib_counts0 = (
         _attrib_led.activity() if _attrib_led is not None else 0
     )
-    _roofline_led = obs_mod.roofline.ledger()
-    roofline_counts0 = (
-        _roofline_led.activity() if _roofline_led is not None else 0
-    )
 
     # Resume state (--resume): the crashed run's dir, conversation
     # history, and the panel answers its journal already completed — the
@@ -1294,7 +1290,6 @@ def _run(
             warnings=result.warnings,
             live=obs_export.live_summary(),
             attrib=obs_export.attrib_summary(),
-            roofline=obs_export.roofline_summary(),
         )
         if trace_missing:
             metrics_doc["timeline_missing_controllers"] = sorted(
@@ -1320,18 +1315,13 @@ def _run(
         attrib_grew = (
             _led is not None and _led.activity() > attrib_counts0
         )
-        _rl = obs_mod.roofline.ledger()
-        roofline_grew = (
-            _rl is not None and _rl.activity() > roofline_counts0
-        )
-        if live_doc or attrib_grew or roofline_grew:
+        if live_doc or attrib_grew:
             metrics_doc = obs_export.metrics_summary(
                 responses=result.responses,
                 failed_models=result.failed_models,
                 warnings=result.warnings,
                 live=live_doc,
                 attrib=obs_export.attrib_summary(),
-                roofline=obs_export.roofline_summary(),
             )
 
     if multictrl and mc.process_index() != 0:

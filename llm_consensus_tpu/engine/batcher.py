@@ -80,7 +80,6 @@ from llm_consensus_tpu.engine.speculative import (
     _spec_verify_batch)
 from llm_consensus_tpu.engine.tokenizer import StreamDecoder
 from llm_consensus_tpu.obs.attrib import tag as _attrib_tag
-from llm_consensus_tpu.obs import roofline as _roofline
 from llm_consensus_tpu.obs.scopes import scope
 from llm_consensus_tpu.ops.moe import pairs_kernel_serves
 from llm_consensus_tpu.ops.quant import kv_seq_axis as _seq_axis
@@ -751,23 +750,6 @@ def _compact_cache(cache, shift):
     return _kv_tree_map(
         lambda leaf: jnp.roll(leaf, -shift, axis=_seq_axis(leaf)), cache
     )
-
-
-# Roofline instrumentation (obs/roofline.py): the batcher's cache-motion
-# programs book under their ambient attribution tag ("compact" for the
-# frontier slide, "prefill" for the admission splice — the families
-# whose walls they fill).
-_compact_cache = _roofline.instrument(
-    _compact_cache, family="compact",
-    key=lambda a, k: _roofline.shape_of(jax.tree.leaves(a[0])[0]),
-)
-_splice_rows = _roofline.instrument(
-    _splice_rows, family="prefill",
-    key=lambda a, k: (
-        k.get("k", a[5] if len(a) > 5 else None),
-        k.get("width", a[6] if len(a) > 6 else None),
-    ),
-)
 
 
 class ContinuousBatcher:

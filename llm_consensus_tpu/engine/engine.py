@@ -41,7 +41,6 @@ from llm_consensus_tpu.analysis import sanitizer
 from llm_consensus_tpu.engine.tokenizer import ByteTokenizer, StreamDecoder, load_tokenizer
 from llm_consensus_tpu.models import forward, init_kv_cache, init_params
 from llm_consensus_tpu.obs.attrib import tag as _attrib_tag
-from llm_consensus_tpu.obs import roofline as _roofline
 from llm_consensus_tpu.models.config import ModelConfig
 from llm_consensus_tpu.ops.latent_attention import prefill_sweep_width
 from llm_consensus_tpu.ops.quant import kv_seq_axis, kv_tree_map, w8a8_scope
@@ -410,38 +409,8 @@ def _decode_chunk(params, cfg: ModelConfig, token, pos, cache, key,
     return (token, toks, cache, *moe)
 
 
-def _nrows(x) -> int:
-    """Batch rows of a token array, tolerant of [B] / [B, 1] shapes."""
-    shape = getattr(x, "shape", None)
-    if not shape:
-        return 1
-    n = 1
-    for d in shape:
-        n *= int(d)
-    return max(1, n)
-
-
 def _kvw(args, kwargs, idx: int):
     return kwargs.get("kv_width", args[idx] if len(args) > idx else None)
-
-
-# Roofline instrumentation (obs/roofline.py): each dispatch books its
-# (family, bucket-shape) key; the first sight of a key captures the
-# lowered cost analysis. The ambient attribution tag overrides the
-# declared family, so the draft engine's decode books "draft" and the
-# verify-window prefill books "spec_verify" with no extra plumbing.
-# ``steps`` hands the wrapper the on-device trip count XLA's cost
-# analysis counts only once (the scan/fori bodies).
-_prefill_step = _roofline.instrument(
-    _prefill_step, family="prefill",
-    key=lambda a, k: _roofline.shape_of(a[2]),
-    tokens=lambda a, k: _nrows(a[2]),
-)
-_sp_prefill_step = _roofline.instrument(
-    _sp_prefill_step, family="prefill",
-    key=lambda a, k: _roofline.shape_of(a[2]),
-    tokens=lambda a, k: _nrows(a[2]),
-)
 
 
 class _NamedPrograms:
@@ -459,19 +428,16 @@ class _NamedPrograms:
 
     The cache is per family and process-wide, not per engine: two engines
     of one model share the named program exactly as they shared the one
-    jit. Each named program is wrapped by ``_roofline.instrument`` like
-    the family's single jit was; ``lower`` / ``_cache_size`` keep the
-    jit-like surface the tests and chip_smoke.py introspect.
+    jit. ``lower`` / ``_cache_size`` keep the jit-like surface the tests
+    and chip_smoke.py introspect.
     """
 
-    def __init__(self, fn, stem: str, static: tuple, name_key: Callable,
-                 **instrument):
+    def __init__(self, fn, stem: str, static: tuple, name_key: Callable):
         self._fn = fn
         self._stem = stem
         self._static = static
         # (args, kwargs) -> (model, kv_width[, steps]): what the name says.
         self._key = name_key
-        self._instrument = instrument
         self._programs: dict = {}
         self._lock = sanitizer.make_lock(f"engine.programs.{stem}")
         self.__name__ = stem
@@ -484,7 +450,7 @@ class _NamedPrograms:
         return f"{name}__s{steps[0]}" if steps else name
 
     def program(self, *args, **kwargs):
-        """The named, instrumented jit these arguments run under."""
+        """The named jit these arguments run under."""
         key = self._key(args, kwargs)
         prog = self._programs.get(key)
         if prog is None:
@@ -503,11 +469,8 @@ class _NamedPrograms:
         named.__kwdefaults__ = fn.__kwdefaults__
         named.__qualname__ = name
         named.__doc__ = fn.__doc__
-        return _roofline.instrument(
-            jax.jit(named, static_argnames=self._static,
-                    donate_argnames=("cache",)),
-            **self._instrument,
-        )
+        return jax.jit(named, static_argnames=self._static,
+                       donate_argnames=("cache",))
 
     def __call__(self, *args, **kwargs):
         return self.program(*args, **kwargs)(*args, **kwargs)
@@ -524,18 +487,11 @@ class _NamedPrograms:
 _prefill_chunk = _NamedPrograms(
     _prefill_chunk, "prefill_chunk", ("cfg", "kv_width", "w8a8", "moe_stats"),
     lambda a, k: (a[1].name, _kvw(a, k, 6)),
-    family="prefill",
-    key=lambda a, k: (_roofline.shape_of(a[2]), _kvw(a, k, 6)),
-    tokens=lambda a, k: _nrows(a[2]),
 )
 _prefill_chunks_loop = _NamedPrograms(
     _prefill_chunks_loop, "prefill_chunks_loop",
     ("cfg", "max_chunks", "kv_width", "w8a8", "moe_stats"),
     lambda a, k: (a[1].name, _kvw(a, k, 8)),
-    family="prefill",
-    key=lambda a, k: (_roofline.shape_of(a[2]), _kvw(a, k, 8)),
-    tokens=lambda a, k: int(a[4]) * int(a[2].shape[-1]),
-    steps=lambda a, k: int(a[4]),
 )
 _decode_chunk = _NamedPrograms(
     _decode_chunk, "decode_chunk",
@@ -545,10 +501,6 @@ _decode_chunk = _NamedPrograms(
         a[1].name, _kvw(a, k, 11),
         int(k["n_steps"] if "n_steps" in k else a[6]),
     ),
-    family="decode",
-    key=lambda a, k: (_roofline.shape_of(a[2]), int(a[6]), _kvw(a, k, 11)),
-    tokens=lambda a, k: int(a[6]) * _nrows(a[2]),
-    steps=lambda a, k: int(a[6]),
 )
 
 
@@ -914,26 +866,6 @@ class Engine:
                 )
             except Exception:  # noqa: BLE001 — modeling only
                 pass
-        # Roofline cross-check baseline: the analytic per-token costs
-        # (utils/flops — the same model behind the modeled-MFU gauges)
-        # registered as the accepted range for the XLA-counted side.
-        # Context 0 and max_seq bound the attention term.
-        try:
-            from llm_consensus_tpu.utils.flops import (
-                decode_bytes_per_token, flops_per_token)
-
-            _roofline.note_modeled(
-                "decode", flops_per_token(cfg),
-                decode_bytes_per_token(cfg, 0),
-            )
-            _roofline.note_modeled(
-                "decode", flops_per_token(cfg, max_seq),
-                decode_bytes_per_token(cfg, max_seq),
-            )
-            _roofline.note_modeled("prefill", flops_per_token(cfg))
-            _roofline.note_modeled("prefill", flops_per_token(cfg, max_seq))
-        except Exception:  # noqa: BLE001 — modeling only
-            pass
         from llm_consensus_tpu.kv import pool_for
 
         # ``kv_pool=False`` opts this engine out even when LLMC_KV_POOL

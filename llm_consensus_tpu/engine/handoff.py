@@ -65,7 +65,6 @@ import jax.numpy as jnp
 
 from llm_consensus_tpu import integrity
 from llm_consensus_tpu.obs.attrib import tag as _attrib_tag
-from llm_consensus_tpu.obs import roofline as _roofline
 from llm_consensus_tpu.analysis import sanitizer
 from llm_consensus_tpu.utils import knobs
 
@@ -96,19 +95,6 @@ def _extract_row_span(pcache, row, span: int):
         return jax.lax.slice_in_dim(r, 0, span, axis=ax)
 
     return jax.tree.map(leaf, pcache)
-
-
-# Roofline instrumentation (obs/roofline.py): the staging extract books
-# under the ambient "kv_handoff" tag; the cross-mesh device_put bytes —
-# traffic the compiler never sees — land via note_transfer at the wave
-# site, so the family's bytes/s covers the actual transfer.
-_extract_row_span = _roofline.instrument(
-    _extract_row_span, family="kv_handoff",
-    key=lambda a, k: (
-        k.get("span", a[2] if len(a) > 2 else None),
-        _roofline.shape_of(jax.tree.leaves(a[0])[0]),
-    ),
-)
 
 
 class HandoffTicket:
@@ -442,9 +428,6 @@ class KVHandoff:
                     self._attrib.observe_device(
                         "kv_handoff", time.monotonic() - t_x
                     )
-                rl = _roofline.ledger()
-                if rl is not None:
-                    rl.note_transfer("kv_handoff", nbytes)
                 if self._integrity is not None:
                     # Verify the reshard moved exact bytes: digest each
                     # block span on BOTH sides of the mesh boundary. A

@@ -87,7 +87,6 @@ import jax
 import jax.numpy as jnp
 
 from llm_consensus_tpu.obs.attrib import tag as _attrib_tag
-from llm_consensus_tpu.obs import roofline as _roofline
 from llm_consensus_tpu.engine.engine import (
     Engine, GenerateResult, SamplingParams, _decode_chunk)
 from llm_consensus_tpu.engine.tokenizer import StreamDecoder
@@ -754,54 +753,6 @@ def _roll_valid(valid, shift):
     """Compaction twin of the batcher's cache roll: slide every row's
     bitmap left with the KV it describes."""
     return jnp.roll(valid, -shift, axis=1)
-
-
-# -- roofline instrumentation ------------------------------------------------
-# obs/roofline.py captures each program's lowered cost analysis once per
-# bucket shape and bumps per-dispatch counters; the ambient attrib tag at
-# the call site picks the family (verify programs run under "spec_verify",
-# proposers under "draft"). ``lower`` only traces, so donated buffers are
-# untouched by capture.
-
-def _arg(args, kwargs, name, idx):
-    return kwargs.get(name, args[idx] if len(args) > idx else None)
-
-
-_spec_verify = _roofline.instrument(
-    _spec_verify, family="spec_verify",
-    key=lambda a, k: (_roofline.shape_of(a[3]), _arg(a, k, "kv_width", 6)),
-    tokens=lambda a, k: int(a[3].shape[0]) + 1,
-)
-_spec_verify_sampled = _roofline.instrument(
-    _spec_verify_sampled, family="spec_verify",
-    key=lambda a, k: (_roofline.shape_of(a[3]), _arg(a, k, "kv_width", 9)),
-    tokens=lambda a, k: int(a[3].shape[0]) + 1,
-)
-_spec_verify_buf = _roofline.instrument(
-    _spec_verify_buf, family="spec_verify",
-    key=lambda a, k: (_roofline.shape_of(a[3]), _arg(a, k, "kv_width", 8)),
-    tokens=lambda a, k: int(a[3].shape[0]) + 1,
-)
-_spec_verify_batch = _roofline.instrument(
-    _spec_verify_batch, family="spec_verify",
-    key=lambda a, k: (_roofline.shape_of(a[3]), _arg(a, k, "k", 10),
-                      _arg(a, k, "kv_width", 11)),
-    tokens=lambda a, k: (int(a[3].shape[0])
-                         * (int(_arg(a, k, "k", 10)) + 1)),
-)
-_plain_chunk_masked = _roofline.instrument(
-    _plain_chunk_masked, family="decode",
-    key=lambda a, k: (_roofline.shape_of(a[2]),
-                      _arg(a, k, "n_steps", 9), _arg(a, k, "kv_width", 10)),
-    tokens=lambda a, k: (int(_arg(a, k, "n_steps", 9))
-                         * int(a[2].shape[0])),
-    steps=lambda a, k: int(_arg(a, k, "n_steps", 9)),
-)
-_lookup_propose = _roofline.instrument(
-    _lookup_propose, family="draft",
-    key=lambda a, k: _arg(a, k, "k", 2),
-    tokens=lambda a, k: int(_arg(a, k, "k", 2)),
-)
 
 
 # -- engine ------------------------------------------------------------------

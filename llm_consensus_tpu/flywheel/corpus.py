@@ -2,7 +2,7 @@
 
 ``data/`` holds one dir per run — but not ONLY runs: the observability
 stack parks auxiliary artifacts beside them (``blackbox/`` flight-
-recorder dumps, ``roofline-*/`` profiles, ``elastic-r*/`` replica state;
+recorder dumps, profiler windows, ``elastic-r*/`` replica state;
 new writers use ``data/_artifacts/``). The scanner therefore trusts
 exactly one signal: a ``run.json`` manifest (written by both the CLI and
 the serve scheduler before execution). No manifest → not a run → skipped,

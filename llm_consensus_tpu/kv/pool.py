@@ -43,7 +43,6 @@ import jax.numpy as jnp
 
 from llm_consensus_tpu import integrity
 from llm_consensus_tpu.obs.attrib import tag as attrib_tag
-from llm_consensus_tpu.obs import roofline as _roofline
 from llm_consensus_tpu.analysis import sanitizer
 from llm_consensus_tpu.utils import knobs
 from llm_consensus_tpu.utils.flops import cache_bytes_per_token
@@ -68,23 +67,6 @@ def _copy_blocks(dst, src, src_starts, dst_starts, k: int, bs: int):
         return d
 
     return jax.tree.map(leaf, dst, src)
-
-
-# Roofline instrumentation (obs/roofline.py): gather and publish are one
-# program with the roles swapped, so the ambient attribution tag at the
-# dispatch site ("kv_gather" / "kv_publish") picks the family; the
-# unrolled k-bucket copy is fully counted (no loop-body discount). The
-# copied tokens (k x bs) feed the cross-check denominators.
-_copy_blocks = _roofline.instrument(
-    _copy_blocks, family="kv_gather",
-    key=lambda a, k: (
-        k.get("k", a[4] if len(a) > 4 else None),
-        k.get("bs", a[5] if len(a) > 5 else None),
-    ),
-    tokens=lambda a, k: (
-        int(k.get("k", a[4])) * int(k.get("bs", a[5]))
-    ),
-)
 
 
 def _kbucket(k: int) -> int:

@@ -26,9 +26,6 @@ Sibling planes with the same resolution pattern:
   * ``obs.attrib`` — chip-time attribution: device time per program
     family, the goodput token ledger, host-gap (bubble) detection, and
     the retrace / HBM-watermark sentinels;
-  * ``obs.roofline`` — per-program static costs (XLA cost analysis at
-    lowering time) joined with the attrib walls into live achieved-
-    FLOPs/s / bytes/s and compute-vs-memory-bound verdicts;
   * ``obs.profiler`` — the on-demand bounded ``jax.profiler`` window
     behind ``POST /debugz/profile``.
 """
@@ -40,7 +37,7 @@ from typing import Optional
 
 from llm_consensus_tpu.analysis import sanitizer
 from llm_consensus_tpu.obs import (  # noqa: F401 — public API
-    attrib, blackbox, live, profiler, roofline)
+    attrib, blackbox, live, profiler)
 from llm_consensus_tpu.obs.recorder import (  # noqa: F401 — public API
     Event, Recorder, resolve_max_events)
 from llm_consensus_tpu.obs.spans import Emitter, Span  # noqa: F401
@@ -48,7 +45,7 @@ from llm_consensus_tpu.utils import knobs
 
 __all__ = [
     "Emitter", "Event", "Recorder", "Span", "attrib", "blackbox", "emitter",
-    "live", "profiler", "roofline", "recorder", "install", "reset",
+    "live", "profiler", "recorder", "install", "reset",
 ]
 
 _lock = sanitizer.make_lock("obs.registry")

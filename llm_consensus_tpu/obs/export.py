@@ -87,36 +87,9 @@ def trace_document(trace_events: list[dict]) -> dict:
     return {"traceEvents": trace_events, "displayTimeUnit": "ms"}
 
 
-def roofline_counter_events(pid: int = 0, ts_us: float = 0.0) -> list[dict]:
-    """The roofline ledger's cumulative FLOPs/bytes per family as "C"
-    (counter) trace events — Perfetto renders each name as a counter
-    track next to the span timeline, so "which family burned the FLOPs"
-    reads off the same screen as "when". Empty when the plane is off."""
-    from llm_consensus_tpu.obs import roofline as roofline_mod
-
-    led = roofline_mod.ledger()
-    if led is None:
-        return []
-    return [
-        {
-            "name": name, "ph": "C", "ts": ts_us, "pid": pid, "tid": 0,
-            "args": {"value": value},
-        }
-        for name, value in led.counter_track()
-    ]
-
-
 def local_trace(recorder: Recorder, pid: int = 0) -> dict:
-    """This process's timeline alone, as a loadable trace document
-    (plus the roofline counter tracks when that plane is live)."""
-    events = chrome_events(recorder.events(), pid=pid)
-    end_us = max(
-        (e.get("ts", 0.0) + e.get("dur", 0.0)
-         for e in events if e.get("ph") != "M"),
-        default=0.0,
-    )
-    events.extend(roofline_counter_events(pid=pid, ts_us=end_us))
-    return trace_document(events)
+    """This process's timeline alone, as a loadable trace document."""
+    return trace_document(chrome_events(recorder.events(), pid=pid))
 
 
 # Spans that bound the decode activity window: the single-stream engine's
@@ -275,19 +248,6 @@ def attrib_summary() -> Optional[dict]:
     return led.snapshot() if led is not None else None
 
 
-def roofline_summary() -> Optional[dict]:
-    """The roofline ledger's snapshot (obs/roofline: per-family static
-    costs, achieved rates, bound verdicts, coverage, cross-check), or
-    None when the plane is off or nothing dispatched — metrics.json's
-    ``roofline`` block."""
-    from llm_consensus_tpu.obs import roofline as roofline_mod
-
-    led = roofline_mod.ledger()
-    if led is None or led.activity() == 0:
-        return None
-    return led.snapshot()
-
-
 def metrics_summary(
     recorder: Optional[Recorder] = None,
     responses=None,
@@ -301,14 +261,12 @@ def metrics_summary(
     warnings: Optional[list[str]] = None,
     live: Optional[dict] = None,
     attrib: Optional[dict] = None,
-    roofline: Optional[dict] = None,
 ) -> dict:
     """The run's aggregate numbers as one JSON-serializable dict.
 
-    ``live`` / ``attrib`` / ``roofline`` carry the live-histogram
-    summary (:func:`live_summary`), chip-time attribution snapshot
-    (:func:`attrib_summary`), and roofline snapshot
-    (:func:`roofline_summary`) when the caller collected them."""
+    ``live`` / ``attrib`` carry the live-histogram summary
+    (:func:`live_summary`) and the chip-time attribution snapshot
+    (:func:`attrib_summary`) when the caller collected them."""
     out: dict = {}
     if recorder is not None:
         events = recorder.events()  # one copy, shared with the aggregate
@@ -348,8 +306,6 @@ def metrics_summary(
         out["live"] = live
     if attrib:
         out["attrib"] = attrib
-    if roofline:
-        out["roofline"] = roofline
     if fault_trace:
         out["faults"] = list(fault_trace)
     if degraded_peers:

@@ -1,9 +1,9 @@
 """On-demand deep profiling: a bounded ``jax.profiler`` trace window.
 
-The roofline plane (obs/roofline.py) answers "which family, how far from
-which roof" continuously and for free; when a family's achieved FLOPs/s
-says something is wrong, the next question — WHICH fusion, WHICH
-transfer, WHAT overlap — needs the real profiler. This module arms one
+The attribution ledger (obs/attrib.py) says which program family the
+chip's time went to; WHICH fusion, WHICH transfer, WHAT overlap, and how
+far a named program ran from the chip's roofline, need the real
+profiler. This module arms one
 ``jax.profiler.start_trace``/``stop_trace`` window on demand
 (``POST /debugz/profile``, the router fan-out, or ``--profile`` on
 one-shot CLI runs) with the blackbox plane's safety rails:

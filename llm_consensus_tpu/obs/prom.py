@@ -59,11 +59,6 @@ FAMILIES = {
     "llmc_host_gap_seconds_total": "counter",
     "llmc_compiles_total": "counter",
     "llmc_retraces_total": "counter",
-    "llmc_roofline_flops_total": "counter",
-    "llmc_roofline_bytes_total": "counter",
-    "llmc_roofline_dispatches_total": "counter",
-    "llmc_roofline_tokens_total": "counter",
-    "llmc_roofline_ridge_flops_per_byte": "gauge",
     "llmc_integrity_checks_total": "counter",
     "llmc_integrity_failures_total": "counter",
     "llmc_swap_vacate_seconds": "histogram",

@@ -868,7 +868,7 @@ class TPUProvider(Provider):
         Never from the CPU JAX falls back to when it finds no chip: that
         run would look exactly like success. And on a TPU the chip must
         be one the peaks table knows (utils/flops raises otherwise), so
-        no MFU/MBU gauge or roofline ridge is ever quietly dropped."""
+        no MFU/MBU gauge is ever quietly dropped."""
         import jax
 
         from llm_consensus_tpu.utils.backend import checked_backend

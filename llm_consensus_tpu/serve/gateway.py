@@ -230,10 +230,6 @@ class ConsensusGateway:
         # block + the labeled device-time/goodput/compile counters on
         # /metricsz come from this ledger.
         self._attrib = obs.attrib.ledger()
-        # Roofline plane (obs/roofline): per-family static costs joined
-        # with the attrib walls — the /statsz ``roofline`` block + the
-        # roofline counter families on /metricsz.
-        self._roofline = obs.roofline.ledger()
         # Deep profiler (obs/profiler): POST /debugz/profile arms one
         # bounded jax.profiler window.
         self._profiler = obs.profiler.profiler()
@@ -1093,13 +1089,6 @@ class ConsensusGateway:
 
         reg.register("attrib", attrib_block)
 
-        def roofline_block() -> Optional[dict]:
-            if self._roofline is None or self._roofline.activity() == 0:
-                return None
-            return self._roofline.snapshot()
-
-        reg.register("roofline", roofline_block)
-
         def profiler_block() -> Optional[dict]:
             if self._profiler is None:
                 return None
@@ -1236,8 +1225,6 @@ class ConsensusGateway:
             features.append("live")
         if self._attrib is not None:
             features.append("attrib")
-        if self._roofline is not None:
-            features.append("roofline")
         if self._profiler is not None:
             features.append("profile")
         return {
@@ -1273,8 +1260,6 @@ class ConsensusGateway:
         }
         if self._attrib is not None:
             families.update(self._attrib.prom_families())
-        if self._roofline is not None:
-            families.update(self._roofline.prom_families())
         if self._integrity is not None:
             families.update(self._integrity.counters.prom_families())
         return prom.render(

@@ -471,7 +471,6 @@ class Scheduler:
             warnings=out.warnings,
             live=obs_export.live_summary(self._live),
             attrib=obs_export.attrib_summary(),
-            roofline=obs_export.roofline_summary(),
         )
         obs_export.save_run_telemetry(session.run_dir, trace_doc, metrics_doc)
 

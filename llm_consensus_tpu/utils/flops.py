@@ -148,8 +148,8 @@ def _peak(table, device_kind: str):
     A device that is not a TPU (the CPU the tests ask for) has no entry
     and gets None — consumers then report no utilization. A TPU that is
     not in the table is an ERROR, not a default: a missing row would
-    otherwise drop every MFU/MBU gauge and swap the roofline ridge for a
-    guess, on exactly the machine the numbers are for."""
+    otherwise drop every MFU/MBU gauge, on exactly the machine the
+    numbers are for."""
     kind = device_kind.lower()
     for key, value in table:
         if key in kind:

@@ -300,13 +300,6 @@ _k("LLMC_BLACKBOX_DIR", "str", "", "obs",
    "Flight-recorder dump directory (default data/_artifacts/blackbox/)")
 _k("LLMC_BLACKBOX_MIN_INTERVAL_S", "float", 30.0, "obs",
    "Minimum seconds between flight-recorder dumps")
-_k("LLMC_ROOFLINE", "str", "", "obs",
-   "0 disables roofline attribution; unset follows LLMC_ATTRIB; 1 forces on")
-_k("LLMC_ROOFLINE_RIDGE", "float", 0.0, "obs",
-   "Roofline ridge point override in FLOPs/byte (0 = device peaks, or "
-   "32.0 when the device table has no entry)")
-_k("LLMC_ROOFLINE_TOL", "float", 4.0, "obs",
-   "Modeled-vs-cost-analysis crosscheck tolerance (ratio band [1/t, t])")
 _k("LLMC_PROFILE", "bool", True, "obs",
    "0 disables the on-demand deep profiler behind POST /debugz/profile")
 _k("LLMC_PROFILE_DIR", "str", "", "obs",

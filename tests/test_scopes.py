@@ -119,7 +119,7 @@ def _fresh(program):
     if isinstance(program, E._NamedPrograms):
         fn, static = program._fn, program._static
     else:
-        fn = program.__wrapped__.__wrapped__
+        fn = program.__wrapped__
         static = ("cfg", "attn_impl", "mesh", "kv_width", "w8a8", "moe_stats")
     copy = types.FunctionType(
         fn.__code__, fn.__globals__, fn.__name__, fn.__defaults__,
