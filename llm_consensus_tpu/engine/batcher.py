@@ -225,6 +225,19 @@ def kv_slots_live(pos: int, steps: int, stride: int, row_starts: Sequence[int],
     return sum(min(e - rs, window) for rs in row_starts for e in ends)
 
 
+def _window_decode(cfg, pos: int, steps: int, row_starts: Sequence[int]) -> dict:
+    """What the decode counters of a pool whose attention layers differ in
+    their window (``cfg.attn_kinds``: "W" layers beside "*") say beside the
+    rest, over one dispatch of ``steps`` from frontier ``pos``: the slots ONE
+    "W" layer sweeps for the live rows (``kv_slots_live`` under the window;
+    ``decode_kv_slots_live`` is what a full layer sweeps for such a model).
+    Nothing for any other model."""
+    if "W" not in cfg.layer_kinds:
+        return {}
+    return dict(decode_kv_slots_window_layer=kv_slots_live(
+        pos, steps, 1, row_starts, cfg.sliding_window))
+
+
 def causal_pairs(lengths: Sequence[int], base: int = 0) -> int:
     """(Query, key) pairs causality needs to prefill rows of ``lengths``
     real tokens whose first ``base`` positions are already in a cache (a
@@ -986,6 +999,10 @@ class ContinuousBatcher:
                 ssm_positions_swept=0, ssm_positions_live=0,
                 ssm_state_row_steps=0,
             )
+        # A pool whose attention layers differ in their window: what one
+        # "W" layer sweeps a dispatch (``_window_decode``).
+        if "W" in engine.cfg.layer_kinds:
+            self.stats["decode_kv_slots_window_layer"] = 0
         if engine.cfg.is_moe:
             # A routed model's programs return their routing sums
             # (ops/moe.py), which ride each fetch: (token, chosen expert)
@@ -3515,7 +3532,11 @@ class ContinuousBatcher:
                 rows_live = len(live_starts)
                 pos0 = self._pos
                 moe_decode = None
-                window = eng.cfg.sliding_window
+                # ``decode_kv_slots_live`` counts under the window of the
+                # model's "*" layers: a uniform model's one window, none
+                # where "W" layers carry it (what a full layer sweeps).
+                window = eng.cfg.attn_kinds[0][1]
+                by_window = {}
                 self._mark_dead_rows()
                 if self._spec is not None and sampling.temperature == 0.0:
                     # Speculative decode mode: the dispatch becomes a
@@ -3540,6 +3561,8 @@ class ContinuousBatcher:
                     slots_live = kv_slots_live(
                         pos0, n_steps, 1, live_starts, window
                     )
+                    by_window = _window_decode(
+                        eng.cfg, pos0, n_steps, live_starts)
                     sentinel = self._integrity is not None
                     poison = None
                     if sentinel and eng._faults is not None:
@@ -3646,6 +3669,7 @@ class ContinuousBatcher:
                         ),
                         decode_kv_slots_live=slots_live,
                         **_ssm_decode(eng.cfg, covered, self._rows_cap),
+                        **by_window,
                     )
                     # Host gap closed: the device sat idle from the
                     # drain to this dispatch while the batcher was busy

@@ -736,6 +736,12 @@ class Engine:
             # prefix snapshot (``Prefilled.reused`` stays 0), as no pooled
             # prefix sharing (engine/batcher.py).
             self.prefix_cache_enabled = False
+        elif cfg.layer_kinds:
+            self._refuse_one_part(mesh)
+            # The retained prefix snapshot and the radix arena have not been
+            # asked of a stack whose attention layers differ in their window:
+            # off and refused, as every other one-part stack's are.
+            self.prefix_cache_enabled = False
         self._prefix_max_bytes = (
             knobs.get_float("LLMC_PREFIX_CACHE_MAX_MB") * 1e6
         )
@@ -951,6 +957,21 @@ class Engine:
                 f"{name}: the radix KV arena (LLMC_KV_POOL) does not hold a "
                 "state-space model's cache: a block of slots has no state")
         refuse_ssm_mesh(self.cfg, mesh)
+
+    def _refuse_one_part(self, mesh) -> None:
+        """What a stack of one-part layers without a state (window and full
+        attention layers in one stack) does not get yet is refused by name
+        when its engine is built."""
+        from llm_consensus_tpu.kv import pool_enabled
+        from llm_consensus_tpu.models.transformer import refuse_one_part_mesh
+
+        if pool_enabled():
+            raise ValueError(
+                f"{self.cfg.name}: the radix KV arena (LLMC_KV_POOL) does not "
+                "hold the cache of a stack of one-part layers (layer_kinds "
+                f"{self.cfg.layer_kinds!r}): a block of slots is not reused "
+                "across layers of different windows yet")
+        refuse_one_part_mesh(self.cfg, mesh)
 
     @property
     def _moe_on(self) -> bool:

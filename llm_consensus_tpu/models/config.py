@@ -2,7 +2,7 @@
 
 One generic decoder-only transformer (models/transformer.py) covers every
 family the framework serves — Llama-2/3, Mistral, Gemma, Qwen2, Mixtral,
-DeepSeek-V2, Falcon-H1, Nemotron-H, Solar-Open2 — via static config switches, so each (family, shape) pair
+DeepSeek-V2, Falcon-H1, Nemotron-H, Solar-Open2, AFMoE — via static config switches, so each (family, shape) pair
 compiles to a single XLA program. Every field a family adds defaults to
 "off", so the older presets hash and compare as they did. The reference framework's "model set" is a table of
 remote API names (/root/reference/cmd/llm-consensus/main.go:49-61); here the
@@ -18,7 +18,7 @@ from typing import Optional
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                     # llama | mistral | gemma | qwen2 | mixtral | deepseek_v2 | falcon_h1 | nemotron_h | solar_open2
+    family: str                     # llama | mistral | gemma | qwen2 | mixtral | deepseek_v2 | falcon_h1 | nemotron_h | solar_open2 | afmoe
     vocab_size: int
     d_model: int
     n_layers: int
@@ -98,9 +98,17 @@ class ModelConfig:
     # [+ mixer], then an MLP). Each kind has a parameter stack and a cache of
     # its own length. A published two-part layer ``x += mixer(norm(x)); x +=
     # moe(norm(x))`` is two one-part layers: Solar-Open2's period of four is
-    # "*EKEKEKE".
+    # "*EKEKEKE". "W" is attention under a window of its own beside "*"
+    # (AFMoE: ``sliding_window`` is then the window of the "W" layers alone
+    # and "*" sees every position; both share one stack and one cache, a
+    # layer's place among the attention layers its index), "D" a dense gated
+    # MLP of ``d_ff`` (a leading dense layer's second part).
     layer_kinds: str = ""
     rotary: bool = True             # False: attention applies no rotary embedding
+    qk_norm: bool = False           # queries and keys RMS-normed over the head's
+                                    # width (one weight a projection) before rotary
+    post_norm: bool = False         # a one-part layer ends in a norm of its own:
+                                    # x + norm(part(norm(x)))
     attn_out_gate: bool = False     # attention's output times sigmoid(h W_gate)
                                     # elementwise, before wo
     # -- delta-rule layer (Kimi Delta Attention, ops/delta.py), kind "K";
@@ -116,11 +124,12 @@ class ModelConfig:
 
     def __post_init__(self):
         if self.layer_kinds:
-            odd = set(self.layer_kinds) - set("ME*K")
+            odd = set(self.layer_kinds) - set("ME*KWD")
             if odd or len(self.layer_kinds) != self.n_layers:
                 raise ValueError(
                     f"{self.name}: layer_kinds {self.layer_kinds!r} needs one of "
-                    f"'M', 'E', '*', 'K' for each of n_layers = {self.n_layers}")
+                    f"'M', 'E', '*', 'K', 'W', 'D' for each of n_layers = "
+                    f"{self.n_layers}")
             for kind, sized, what in (("M", self.has_ssm, "ssm_heads"),
                                       ("K", self.has_kda, "kda_heads")):
                 if (kind in self.layer_kinds) != sized:
@@ -131,10 +140,31 @@ class ModelConfig:
                 raise ValueError(
                     f"{self.name}: one cache holds one kind of state: 'M' "
                     "and 'K' layers in one stack are not computed")
+            if "W" in self.layer_kinds and not self.sliding_window:
+                raise ValueError(
+                    f"{self.name}: a 'W' layer attends under sliding_window, "
+                    "which is not set")
+            if "D" in self.layer_kinds and self.d_ff <= 0:
+                raise ValueError(
+                    f"{self.name}: a 'D' layer is a dense MLP of d_ff, which is "
+                    f"{self.d_ff}")
+            if self.has_state and (
+                    self.post_norm or set("WD") & set(self.layer_kinds)):
+                raise ValueError(
+                    f"{self.name}: 'W' and 'D' layers and a post-norm beside a "
+                    "layer that keeps a state ('M', 'K') are not computed")
         elif self.has_kda:
             raise ValueError(
                 f"{self.name}: a delta-rule layer is a one-part layer: "
                 "kda_heads needs layer_kinds with 'K'")
+        elif self.post_norm:
+            raise ValueError(
+                f"{self.name}: a post-norm ends a one-part layer: post_norm "
+                "needs layer_kinds")
+        if self.qk_norm and self.is_latent:
+            raise ValueError(
+                f"{self.name}: a latent (MLA) model norms its low-rank "
+                "queries and latents: qk_norm over heads is not computed")
 
     def kind_layers(self, kind: str) -> tuple[int, ...]:
         """The indices, in the whole stack, of the layers of ``kind``."""
@@ -143,7 +173,35 @@ class ModelConfig:
     @property
     def n_attn_layers(self) -> int:
         """Layers that hold keys and values in the cache."""
-        return self.layer_kinds.count("*") if self.layer_kinds else self.n_layers
+        if not self.layer_kinds:
+            return self.n_layers
+        return self.layer_kinds.count("*") + self.layer_kinds.count("W")
+
+    @property
+    def attn_kinds(self) -> tuple[tuple[str, Optional[int], bool], ...]:
+        """Each attention kind the stack has as ``(kind, window, rotary)``:
+        what ``forward`` makes a mask and a decode sweep plan for, once a
+        kind. Without a "W" layer the one kind is "*" under
+        ``sliding_window``, as every uniform model's layer is; a "W" layer
+        always applies the rotary embedding (``rotary`` is what "*" does)."""
+        if "W" not in self.layer_kinds:
+            return (("*", self.sliding_window, self.rotary),)
+        kinds = (("*", None, self.rotary),
+                 ("W", self.sliding_window, True))
+        return tuple(k for k in kinds if k[0] in self.layer_kinds)
+
+    @property
+    def n_window_layers(self) -> int:
+        """Attention layers under ``sliding_window``: the "W" layers, or
+        without one every attention layer of a model that states a window."""
+        if "W" in self.layer_kinds:
+            return self.layer_kinds.count("W")
+        return self.n_attn_layers if self.sliding_window else 0
+
+    @property
+    def n_mlp_layers(self) -> int:
+        """One-part layers that are a dense MLP."""
+        return self.layer_kinds.count("D")
 
     @property
     def n_ssm_layers(self) -> int:
@@ -364,6 +422,19 @@ MODEL_PRESETS: dict[str, ModelConfig] = {c.name: c for c in [
        router_scoring="sigmoid_bias", norm_topk=True,
        kda_heads=3, kda_head_dim=16, kda_conv=4, kda_chunk=32, kda_rank=8,
        kda_neg_eigval=True, max_seq_len=4096),
+    # AFMoE's stack (Trinity-Mini) at CI size: published layers 1-5, one
+    # leading dense layer and a whole period of four, as ten one-part layers
+    # under the sandwich norm: window attention (window 20, rotary), a dense
+    # MLP of 160, then (window, experts) (full, experts) (window, experts)
+    # (window, experts); full attention applies NO rotary embedding; 4/2
+    # heads of 24 with head norms and an output gate; 16 gated experts of 40,
+    # 3 a token by sigmoid score + bias times 2.826, one shared expert of 40.
+    _L("tiny-afmoe", "afmoe", 512, 96, 10, 4, 2, 24, 160, sliding_window=20,
+       layer_kinds="WDWE*EWEWE", rotary=False, qk_norm=True, post_norm=True,
+       attn_out_gate=True, embed_scale=True, n_experts=16,
+       experts_per_token=3, d_expert=40, n_shared_experts=1,
+       router_scoring="sigmoid_bias", routed_scale=2.826, norm_topk=True,
+       max_seq_len=4096),
     _L("tiny-llama", "llama", 512, 128, 2, 4, 2, 32, 256, max_seq_len=4096),
     _L("tiny-gemma", "gemma", 512, 128, 2, 4, 4, 32, 256, activation="gelu_tanh",
        norm_offset=1.0, embed_scale=True, tie_embeddings=True, max_seq_len=4096),

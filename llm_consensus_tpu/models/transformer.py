@@ -1,7 +1,7 @@
 """Generic decoder-only transformer in functional JAX.
 
 One implementation serves every model family (llama/mistral/gemma/qwen2/
-mixtral/deepseek_v2/falcon_h1/nemotron_h/solar_open2) via static ``ModelConfig`` switches. This replaces the reference's
+mixtral/deepseek_v2/falcon_h1/nemotron_h/solar_open2/afmoe) via static ``ModelConfig`` switches. This replaces the reference's
 "compute layer" — three HTTP clients (/root/reference/internal/provider/
 {openai,anthropic,google}.go) — with real on-device compute.
 
@@ -71,6 +71,21 @@ and names as a mixer's (a stack has one kind of state). ``*`` there passes
 its output through a learned gate before ``wo`` (``cfg.attn_out_gate``: ``o *
 sigmoid(h W_gate)``, elementwise); ``E`` is the gated expert layer of
 ops/moe.py under sigmoid scores with a correction bias.
+
+The AFMoE stack (``family="afmoe"``, Trinity-Mini) walks two more kinds. ``W``
+is attention under a window of its own beside ``*``: ``cfg.sliding_window``
+is then the window of the ``W`` layers alone, which always apply the rotary
+embedding (``cfg.rotary`` stays what ``*`` layers do: none there), and
+``forward`` makes a mask and, on the decode route, a sweep plan ONCE A KIND
+(``cfg.attn_kinds``), which ``_walk_kinds`` hands each attention layer by
+its kind. Both kinds share the stack ``layers_attn`` and the cache's ``k`` /
+``v`` leaves (same heads, same width: a layer's index is its place among the
+attention layers), every layer holding the arena's whole width. ``D`` is a
+dense gated MLP (stack ``layers_mlp``): a published leading dense layer's
+second part. Queries and keys pass an RMS norm over the head's width before
+rotary (``cfg.qk_norm``: one weight vector a projection for all heads), and a
+one-part layer ends in a norm of its own (``cfg.post_norm``: ``x +
+post_norm(part(norm(x)))``, the published sandwich).
 """
 
 from __future__ import annotations
@@ -145,7 +160,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
     with leading dense layers has two stacks, ``layers_dense`` and then
     ``layers``; a family whose every layer is one part, ``cfg.layer_kinds``,
     has a stack for each kind it has: ``layers_ssm``, ``layers_moe``,
-    ``layers_attn``, ``layers_kda``).
+    ``layers_attn``, ``layers_kda``, ``layers_mlp``).
 
     ``shardings`` (a tree of ``jax.sharding.Sharding`` shaped like the
     result: ``parallel.sharding.param_shardings``) makes every leaf
@@ -210,7 +225,17 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
         if cfg.attn_out_gate:
             out["w_ogate"] = normal(
                 next(keys), (l, d, hq * dh), proj_std, "w_ogate")
+        if cfg.qk_norm:
+            out["q_head_norm"] = norm((l, dh), "q_head_norm")
+            out["k_head_norm"] = norm((l, dh), "k_head_norm")
         return out
+
+    def dense_leaves(l: int) -> dict:
+        return {
+            "w_gate": normal(next(keys), (l, d, f), proj_std, "w_gate"),
+            "w_up": normal(next(keys), (l, d, f), proj_std, "w_up"),
+            "w_down": normal(next(keys), (l, f, d), f ** -0.5, "w_down"),
+        }
 
     def routed_leaves(l: int) -> dict:
         """The expert layer's leaves: the router over its whole width, the
@@ -273,30 +298,36 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
         if routed:
             layers.update(routed_leaves(l))
         else:
-            layers["w_gate"] = normal(next(keys), (l, d, f), proj_std, "w_gate")
-            layers["w_up"] = normal(next(keys), (l, d, f), proj_std, "w_up")
-            layers["w_down"] = normal(next(keys), (l, f, d), f ** -0.5, "w_down")
+            layers.update(dense_leaves(l))
         return layers
 
     def kind_stack(name: str, l: int, norm_name: str, leaves) -> dict:
         """``l`` one-part layers of one kind: the part's norm, named as the
-        half that reads it names it, and the part's leaves."""
+        half that reads it names it, the part's leaves, and the norm the
+        part ends in where the model has one."""
         sharding_of.update((shardings or {}).get(name, {}))
-        return {norm_name: norm((l, d), norm_name), **leaves(l)}
+        out = {norm_name: norm((l, d), norm_name), **leaves(l)}
+        if cfg.post_norm:
+            out["post_norm"] = norm((l, d), "post_norm")
+        return out
 
     if cfg.layer_kinds:
         makers = {
             "M": ("layers_ssm", "attn_norm", lambda l: _init_mixer(
                 cfg, l, keys, make, normal, norm, dtype)),
             "E": ("layers_moe", "mlp_norm", routed_leaves),
-            "*": ("layers_attn", "attn_norm", attn_leaves),
+            # both attention kinds in one stack, in the pattern's order
+            "*W": ("layers_attn", "attn_norm", attn_leaves),
             "K": ("layers_kda", "attn_norm", lambda l: _init_kda(
                 cfg, l, keys, make, normal, norm, dtype)),
+            "D": ("layers_mlp", "mlp_norm", dense_leaves),
         }
         stacks = {
-            name: kind_stack(name, cfg.layer_kinds.count(kind), norm_name, leaves)
-            for kind, (name, norm_name, leaves) in makers.items()
-            if kind in cfg.layer_kinds
+            name: kind_stack(
+                name, sum(cfg.layer_kinds.count(k) for k in kinds), norm_name,
+                leaves)
+            for kinds, (name, norm_name, leaves) in makers.items()
+            if set(kinds) & set(cfg.layer_kinds)
         }
     else:
         n_dense = cfg.n_dense_layers if cfg.is_moe else 0
@@ -426,7 +457,7 @@ def init_kv_cache(
                 f"kv cache quant {quant!r} is not computed")
         return {"kv": jnp.zeros(
             (cfg.n_layers, batch, s, 1, cfg.cache_width), dtype)}
-    shape = (cfg.n_layers, batch, s, cfg.n_kv_heads, cfg.head_dim)
+    shape = (cfg.n_attn_layers, batch, s, cfg.n_kv_heads, cfg.head_dim)
     if quant == "int8":
         # Scales are stored seq-MINOR [L, B, Hkv, S]: with seq on lanes
         # the decode kernel's scale blocks tile exactly, where a
@@ -436,7 +467,7 @@ def init_kv_cache(
         entry = lambda: {  # noqa: E731
             "q8": jnp.zeros(shape, jnp.int8),
             "s": jnp.zeros(
-                (cfg.n_layers, batch, cfg.n_kv_heads, s), dtype
+                (cfg.n_attn_layers, batch, cfg.n_kv_heads, s), dtype
             ),
         }
         return {"k": entry(), "v": entry()}
@@ -506,6 +537,8 @@ def _layer(
     ssm=None,            # state-space model: the cache's FULL per-row state
                          # stacks {"state", "conv"}, or None without a cache
     ssm_span=None,       # (lo, hi) [B] each: a row's real positions in T
+    kind: str = "*",     # which of cfg.attn_kinds this layer is: its window,
+                         # its rotary rule and its sweep's scope
 ):
     """One block. Returns ``(x, cache_k, cache_v)``; with ``moe_stats`` on a
     routed stack the expert layer's sums follow, and for a state-space model
@@ -516,6 +549,7 @@ def _layer(
     b, t, d = x.shape
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     beside = cfg.has_ssm and not cfg.layer_kinds  # a mixer beside attention
+    window, rotary = {k: (w, r) for k, w, r in cfg.attn_kinds}[kind]
 
     with scope("norm"):
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps, cfg.norm_offset)
@@ -579,7 +613,11 @@ def _layer(
                 )
 
             q, k, v = pin(q, hq), pin(k, hkv), pin(v, hkv)
-        if cfg.rotary:
+        if cfg.qk_norm:
+            with scope("attn.qk_norm"):
+                q = rms_norm(q, lp["q_head_norm"], cfg.rms_eps, cfg.norm_offset)
+                k = rms_norm(k, lp["k_head_norm"], cfg.rms_eps, cfg.norm_offset)
+        if rotary:
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
 
@@ -595,7 +633,7 @@ def _layer(
         with scope("attn.kv_write"):
             cache_k = kv_write_rows(cache_k, k, layer_idx, start_pos)
             cache_v = kv_write_rows(cache_v, v, layer_idx, start_pos)
-    with scope("attn.sweep"):
+    with scope(_sweep_scope(kind)):
         if cache_k is None:
             k_att, v_att = k, v
         elif decode_flash:
@@ -665,7 +703,7 @@ def _layer(
                 axis_name="sp",
                 head_axis=head_axis,
                 scale=dh ** -0.5,
-                sliding_window=cfg.sliding_window,
+                sliding_window=window,
                 logit_softcap=cfg.attn_logit_softcap,
             )
         elif flash_offset is not None:
@@ -675,7 +713,7 @@ def _layer(
                 flash_attention,
                 q_offset=flash_offset,
                 scale=dh ** -0.5,
-                sliding_window=cfg.sliding_window,
+                sliding_window=window,
                 logit_softcap=cfg.attn_logit_softcap,
             )
             if flash_mesh is not None:
@@ -699,7 +737,7 @@ def _layer(
                 return decode_attention(
                     q_, k_, v_, pos_, li_, rs_,
                     scale=dh ** -0.5,
-                    sliding_window=cfg.sliding_window,
+                    sliding_window=window,
                     logit_softcap=cfg.attn_logit_softcap,
                     kv_width=kv_width,
                     return_state=with_state,
@@ -782,6 +820,9 @@ def _layer(
             "btk,kd->btd", attn_out.reshape(b, t, hq * dh), lp["wo"])
         if beside:
             attn_out = attn_out * cfg.attention_out_multiplier + mixed
+        if cfg.post_norm:
+            attn_out = rms_norm(
+                attn_out, lp["post_norm"], cfg.rms_eps, cfg.norm_offset)
         x = x + attn_out
 
     if cfg.layer_kinds:
@@ -791,6 +832,12 @@ def _layer(
     out = _mlp_half(
         cfg, x, lp, routed, moe_stats, cache_k, cache_v, expert_stacks, mesh)
     return (*out, ssm) if cfg.has_ssm else out
+
+
+def _sweep_scope(kind: str) -> str:
+    """The scope an attention kind's sweep (and its plan) is traced under:
+    a name a kind, so that a device trace splits a step's time by kind."""
+    return "attn.sweep" if kind == "*" else "attn.sweep_window"
 
 
 def _mixer_layer(cfg: ModelConfig, x, lp, ssm, layer_idx, span):
@@ -854,7 +901,14 @@ def _mlp_half(cfg: ModelConfig, x, lp, routed: bool, moe_stats: bool,
     """The MLP half of a block on the post-attention residual ``x``: the
     dense gated MLP, or on a routed stack the expert layer (ops/moe.py),
     whose expert leaves are this layer's own (``lp``) or, from ``forward``'s
-    scan, the whole stacks with this layer's index."""
+    scan, the whole stacks with this layer's index. Where a one-part layer
+    ends in a norm of its own (``cfg.post_norm``) the half's output passes it
+    before the residual add."""
+    def ended(out):
+        return rms_norm(
+            out, lp["post_norm"], cfg.rms_eps, cfg.norm_offset
+        ) if cfg.post_norm else out
+
     with scope("norm"):
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps, cfg.norm_offset)
     if not routed:
@@ -862,7 +916,7 @@ def _mlp_half(cfg: ModelConfig, x, lp, routed: bool, moe_stats: bool,
             mlp_out = gated_mlp(
                 h, lp["w_gate"], lp["w_up"], lp["w_down"], cfg.activation,
                 cfg.mlp_multipliers)
-            return x + mlp_out, cache_k, cache_v
+            return x + ended(mlp_out), cache_k, cache_v
     *experts, layer = expert_stacks or (
         *(lp.get(k) for k in EXPERT_LEAVES), None)
     out = moe_block(
@@ -880,8 +934,8 @@ def _mlp_half(cfg: ModelConfig, x, lp, routed: bool, moe_stats: bool,
     )
     with scope("moe.experts"):  # the residual add rides the layer's last sum
         if moe_stats:
-            return x + out[0], cache_k, cache_v, out[1]
-        return x + out, cache_k, cache_v
+            return x + ended(out[0]), cache_k, cache_v, out[1]
+        return x + ended(out), cache_k, cache_v
 
 
 def forward(
@@ -950,6 +1004,8 @@ def forward(
         _refuse_latent(cfg, attn_impl, mesh, prefix, kv_mask)
     if cfg.has_state:
         _refuse_ssm(cfg, attn_impl, mesh, prefix, kv_mask)
+    elif cfg.layer_kinds:
+        refuse_one_part_mesh(cfg, mesh, attn_impl)
     if attn_impl == "ring":
         if cache is None or mesh is None or not (
             isinstance(start_pos, int) and start_pos == 0
@@ -974,8 +1030,9 @@ def forward(
         if cfg.sliding_window is not None:
             # Windowed attention would need the window to span the
             # prefix/suffix seam; the pool gates the feature off instead.
-            raise ValueError("shared-prefix attention does not compose "
-                             "with sliding_window")
+            raise ValueError(
+                f"{cfg.name}: shared-prefix attention does not compose "
+                "with sliding_window")
         if prefix_len is None:
             raise ValueError("prefix requires prefix_len")
     if kv_mask is not None:
@@ -993,8 +1050,9 @@ def forward(
         if cache is None or row_start is None:
             raise ValueError("kv_mask requires a cache and row_start")
         if cfg.sliding_window is not None:
-            raise ValueError("kv_mask (speculative holes) does not "
-                             "compose with sliding_window")
+            raise ValueError(
+                f"{cfg.name}: kv_mask (speculative holes) does not "
+                "compose with sliding_window")
 
     b, t = tokens.shape
     x = embed_tokens(params, cfg, tokens)
@@ -1111,11 +1169,17 @@ def forward(
             else:
                 pos_offset = jnp.broadcast_to(plen, (b,))
             positions = positions + pos_offset[:, None]
-        cos, sin = _rotary_tables(cfg, positions) if cfg.rotary else (None, None)
+        # one table for every kind that turns its heads (one theta a model)
+        cos, sin = _rotary_tables(cfg, positions) if any(
+            r for _, _, r in cfg.attn_kinds) else (None, None)
 
+    # A mask and, on the decode route, a sweep plan ONCE A KIND of attention
+    # layer (``cfg.attn_kinds``: one for every model but a stack that mixes
+    # window and full layers), each under its kind's window.
+    masks = {}
     with scope("mla.sweep" if cfg.is_latent else "attn.sweep"):
         if flash_offset is not None or decode_flash:
-            mask = None  # the kernels derive causality from pos/q_offset
+            pass  # the kernels derive causality from pos/q_offset: no mask
         elif cache is not None:
             s = _k_store(cache).shape[2]
             if kv_width is not None:
@@ -1142,16 +1206,22 @@ def forward(
                 # Keep the causal compare in the same (absolute) basis the
                 # query positions moved to.
                 kv_positions = kv_positions + pos_offset[:, None]
-            mask = make_attention_mask(positions, kv_positions, kv_valid, cfg.sliding_window)
+            for kind, window, _ in cfg.attn_kinds:
+                masks[kind] = make_attention_mask(
+                    positions, kv_positions, kv_valid, window)
         else:
-            mask = make_attention_mask(positions, positions, None, cfg.sliding_window)
+            for kind, window, _ in cfg.attn_kinds:
+                masks[kind] = make_attention_mask(
+                    positions, positions, None, window)
+    first_kind = cfg.attn_kinds[0][0]  # a uniform model's only one
+    mask = masks.get(first_kind)
 
     qkv_pin = None
     if mesh is not None and cache is not None:
         tp_sz = dict(mesh.shape).get("tp", 1)
         if tp_sz > 1 and (cfg.n_heads % tp_sz or cfg.n_kv_heads % tp_sz):
             qkv_pin = mesh
-    sweep = None
+    sweeps = {}
     if decode_flash:
         # Which kv blocks of which rows this step's attention sweeps is
         # the same in every layer: planned once here, beside the layer
@@ -1160,16 +1230,18 @@ def forward(
         from llm_consensus_tpu.ops.pallas.decode_attention import (
             decode_sweep_plan)
 
-        with scope("attn.sweep"):
-            sweep = decode_sweep_plan(
-                start,
-                jnp.zeros((b,), jnp.int32) if row_start is None else row_start,
-                width=decode_width,
-                n_kv_heads=cfg.n_kv_heads // max(shard_tp, 1),  # a shard's
-                dh=cfg.head_dim, kv_item=k_store.dtype.itemsize,
-                quantized=decode_quantized,
-                sliding_window=cfg.sliding_window,
-            )
+        for kind, window, _ in cfg.attn_kinds:
+            with scope(_sweep_scope(kind)):
+                sweeps[kind] = decode_sweep_plan(
+                    start,
+                    jnp.zeros((b,), jnp.int32) if row_start is None else row_start,
+                    width=decode_width,
+                    n_kv_heads=cfg.n_kv_heads // max(shard_tp, 1),  # a shard's
+                    dh=cfg.head_dim, kv_item=k_store.dtype.itemsize,
+                    quantized=decode_quantized,
+                    sliding_window=window,
+                )
+    sweep = sweeps.get(first_kind)
     ssm_span = None
     if cfg.has_state and (row_start is not None or row_end is not None):
         # Each row's real positions [lo, hi) inside this call's T.
@@ -1224,8 +1296,10 @@ def forward(
         cs = cache.get(STATE_KEY)
         at = start
     if cfg.layer_kinds:
+        by_kind = {
+            kind: (masks.get(kind), sweeps.get(kind)) for kind, _, _ in cfg.attn_kinds}
         x, ck, cv, stats, cs = _walk_kinds(
-            params, cfg, layer_fn, (x, ck, cv, stats, cs), (cos, sin, mask, at),
+            params, cfg, layer_fn, (x, ck, cv, stats, cs), (cos, sin, by_kind, at),
             ssm_span, moe_stats, remat and cache is None, mesh)
     li = jnp.asarray(0, jnp.int32)  # the layer, counted over both stacks
     for stack, routed in stacks:
@@ -1281,16 +1355,19 @@ def _walk_kinds(params, cfg: ModelConfig, layer_fn, carry, attn_args,
     pattern ``cfg.layer_kinds`` unrolled, each layer given its leaves out of
     its kind's stack and its index WITHIN its kind, which is its place in
     that kind's cache (keys and values for ``*``, state and tail for ``M``
-    or ``K``) and in the stacked experts (``E``). ``carry`` is ``(x, cache_k, cache_v,
+    or ``K``) and in the stacked experts (``E``). An attention layer is handed
+    the mask and the sweep plan of ITS kind (``by_kind``: ``*``, or ``W``
+    under the window; both count through one stack and one cache). ``carry`` is ``(x, cache_k, cache_v,
     stats, ssm)`` as the layer scan carries it. Unrolled, not scanned: the
     pattern has no period a scan could run over (``MEMEMEM*EME`` is three
     ``ME`` pairs and five layers that repeat nothing), eleven small bodies
     compile in the time of a few, and a static index lets each layer read
     its leaves where they lie."""
-    cos, sin, mask, at = attn_args
+    cos, sin, by_kind, at = attn_args
     moe_own, experts = _scanned(params["layers_moe"], True)
     stack_of = {"M": params.get("layers_ssm"), "E": moe_own,
-                "*": params.get("layers_attn"), "K": params.get("layers_kda")}
+                "*": params.get("layers_attn"), "K": params.get("layers_kda"),
+                "W": params.get("layers_attn"), "D": params.get("layers_mlp")}
 
     def part(kind: str, i: int, carry):
         x, ck, cv, stats, cs = carry
@@ -1299,9 +1376,13 @@ def _walk_kinds(params, cfg: ModelConfig, layer_fn, carry, attn_args,
             idx = jnp.asarray(i, jnp.int32)
         if kind in "MK":
             x, cs = _mixer_layer(cfg, x, lp, cs, idx, ssm_span)
-        elif kind == "*":
+        elif kind in "*W":
+            mask, sweep = by_kind[kind]
             x, ck, cv = layer_fn(
-                x, lp, cos, sin, mask, ck, cv, at, layer_idx=idx)
+                x, lp, cos, sin, mask, ck, cv, at, layer_idx=idx,
+                decode_sweep=sweep, kind=kind)
+        elif kind == "D":
+            x, _, _ = _mlp_half(cfg, x, lp, False, False, None, None)
         else:
             x, _, _, *more = _mlp_half(
                 cfg, x, lp, True, moe_stats, None, None, (*experts, idx), mesh)
@@ -1312,9 +1393,10 @@ def _walk_kinds(params, cfg: ModelConfig, layer_fn, carry, attn_args,
 
     seen = dict.fromkeys(stack_of, 0)
     for kind in cfg.layer_kinds:
-        fn = partial(part, kind, seen[kind])
+        counted = "*" if kind == "W" else kind  # one stack, one cache
+        fn = partial(part, kind, seen[counted])
         carry = (jax.checkpoint(fn) if remat else fn)(carry)
-        seen[kind] += 1
+        seen[counted] += 1
     return carry
 
 
@@ -1389,6 +1471,23 @@ def refuse_ssm_mesh(cfg: ModelConfig, mesh) -> None:
         raise ValueError(
             f"{cfg.name}: a state-space model runs on one chip: a mesh with "
             f"tp, ep or sp > 1 is not computed, got {dict(mesh.shape)}")
+
+
+def refuse_one_part_mesh(cfg: ModelConfig, mesh, attn_impl: str = "") -> None:
+    """A stack of one-part layers without a state (window and full attention
+    layers beside expert layers) runs on one chip, every leaf whole
+    (parallel/sharding.py): ``forward`` and the engine's constructor both
+    refuse a mesh that would split it, and the ring prefill, which scans the
+    uniform stack."""
+    if attn_impl == "ring":
+        raise ValueError(
+            f"{cfg.name}: no sequence-parallel (ring) prefill of a stack of "
+            f"one-part layers (layer_kinds {cfg.layer_kinds!r})")
+    if mesh is not None and any(
+            dict(mesh.shape).get(ax, 1) > 1 for ax in ("tp", "ep", "sp")):
+        raise ValueError(
+            f"{cfg.name}: a stack of one-part layers runs on one chip: a mesh "
+            f"with tp, ep or sp > 1 is not computed, got {dict(mesh.shape)}")
 
 
 def refuse_latent_mesh(cfg: ModelConfig, mesh) -> None:

@@ -48,6 +48,14 @@ ATTN = (
 ATTN_GATE = (
     "attn.gate",      # the gate's product, its sigmoid, the multiply
 )
+# A stack that mixes window and full attention layers (cfg.attn_kinds), with
+# an RMS norm over each query and key head (cfg.qk_norm).
+ATTN_KINDS = (
+    "attn.sweep_window",  # a "W" layer's sweep, and its kind's sweep plan:
+                          # ``attn.sweep`` under the window, by its own name
+                          # so that a trace splits device time by kind
+    "attn.qk_norm",       # the two head norms, between the split and rotary
+)
 # Latent attention, in place of ATTN.
 MLA = (
     "mla.q",       # wq_a, q_norm, wq_b, the rotary part (and its tables)
@@ -90,7 +98,7 @@ KDA = (
     "kda.state_write",  # state and tail out of and back into the full stacks
 )
 
-SCOPES = COMMON + ATTN + ATTN_GATE + MLA + MOE + SSM + KDA
+SCOPES = COMMON + ATTN + ATTN_GATE + ATTN_KINDS + MLA + MOE + SSM + KDA
 _KNOWN = frozenset(SCOPES)
 
 
@@ -103,5 +111,5 @@ def scope(part: str):
 
 
 __all__ = [
-    "ATTN", "ATTN_GATE", "COMMON", "KDA", "MLA", "MOE", "PREFIX", "SCOPES", "SSM",
+    "ATTN", "ATTN_GATE", "ATTN_KINDS", "COMMON", "KDA", "MLA", "MOE", "PREFIX", "SCOPES", "SSM",
     "scope"]

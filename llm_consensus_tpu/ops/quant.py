@@ -222,7 +222,7 @@ def quantize_params(params: dict, donate: bool = False,
     if "lm_head" in out:
         out["lm_head"] = maybe(out["lm_head"])
     for stack in ("layers_dense", "layers", "layers_ssm", "layers_moe",
-                  "layers_attn", "layers_kda"):
+                  "layers_attn", "layers_kda", "layers_mlp"):
         if stack in out:
             out[stack] = {
                 name: maybe(w) if name in QUANT_KEYS else w

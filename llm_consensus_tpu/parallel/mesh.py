@@ -91,9 +91,10 @@ def best_tp(cfg: ModelConfig, n_devices: int) -> int:
     ``n_kv_heads`` (the binding constraint under GQA), ``n_heads`` and
     ``d_ff``. Falls back toward 1, which always works. A latent (MLA)
     model is not sharded yet (its engine refuses a mesh with tp > 1): 1.
-    Nor is a state-space model (its scan is not split over heads yet): 1.
+    Nor is a state-space model (its scan is not split over heads yet), nor
+    any other stack of one-part layers (every leaf whole): 1.
     """
-    if cfg.is_latent or cfg.has_state:
+    if cfg.is_latent or cfg.has_state or cfg.layer_kinds:
         return 1
     tp = 1
     d = 1

@@ -39,6 +39,7 @@ def param_count(cfg: ModelConfig, active_only: bool = False) -> int:
         kv = 2 * d * cfg.n_kv_heads * dh
         o = cfg.n_heads * dh * d
         attn = q + kv + o + (q if cfg.attn_out_gate else 0)
+        attn += 2 * dh if cfg.qk_norm else 0  # one weight a projection
     if cfg.qkv_bias:
         attn += (cfg.n_heads + 2 * cfg.n_kv_heads) * dh
     # a mixer: in-projection, depthwise convolution with its bias, dt_bias /
@@ -72,10 +73,13 @@ def param_count(cfg: ModelConfig, active_only: bool = False) -> int:
     embed = cfg.vocab_size * d
     head = 0 if cfg.tie_embeddings else cfg.vocab_size * d
     if cfg.layer_kinds:
-        # every layer ONE part behind one norm
+        # every layer ONE part behind one norm, and where the model has one
+        # (never beside a state) before a norm of its own; "D" a dense MLP
+        ends = d + (d if cfg.post_norm else 0)
         return (
-            cfg.n_ssm_layers * (mixer + d) + cfg.n_expert_layers * (routed + d)
-            + cfg.n_attn_layers * (attn + d) + cfg.n_kda_layers * (kda + d)
+            cfg.n_ssm_layers * (mixer + d) + cfg.n_expert_layers * (routed + ends)
+            + cfg.n_attn_layers * (attn + ends) + cfg.n_kda_layers * (kda + d)
+            + cfg.n_mlp_layers * (3 * d * cfg.d_ff + ends)
             + embed + head + d)
     attn += mixer  # the mixer beside attention
     dense_mlp = 3 * d * cfg.d_ff  # gate + up + down
@@ -95,6 +99,22 @@ def cache_bytes_per_token(cfg: ModelConfig, itemsize: int = 2) -> int:
     the pools, the prefix budget and the bandwidth models read
     (``cfg.cache_width`` values a layer: K and V heads, or a latent)."""
     return cfg.n_attn_layers * cfg.cache_width * itemsize
+
+
+def live_cache_bytes(cfg: ModelConfig, context_len: int, itemsize: int = 2) -> int:
+    """Bytes of keys and values one row's decode step reads at
+    ``context_len``, by attention KIND: a layer that sees every position
+    reads the whole context, a layer under ``cfg.sliding_window`` the last
+    ``min(context, window)`` slots of it (``cfg.n_window_layers``: the "W"
+    layers of a mixed stack, or every layer of a model that states a
+    window). What the cache HOLDS a token is ``cache_bytes_per_token``: the
+    arena is uniform, every layer the pool's whole width."""
+    context = max(0, context_len)
+    windowed = cfg.n_window_layers
+    swept = (cfg.n_attn_layers - windowed) * context
+    if windowed:
+        swept += windowed * min(context, cfg.sliding_window)
+    return swept * cfg.cache_width * itemsize
 
 
 def state_bytes_per_row(cfg: ModelConfig, itemsize: int = 2) -> int:
@@ -259,12 +279,14 @@ def decode_bytes_per_token(
     """HBM bytes streamed per decode step: active weights + the KV read.
 
     ``weight_bytes``/``kv_bytes`` are the storage widths (2 = bf16,
-    1 = int8 quantized). A state-space model's recurrent state and
-    convolution tail are read and written once a step by each of the
-    step's ``rows``, whatever the context.
+    1 = int8 quantized). Each of the step's ``rows`` reads its own live
+    keys and values at ``context_len`` (``live_cache_bytes``: a live window
+    a kind of attention layer), and a state-space model's recurrent state
+    and convolution tail are read and written once a step by each row,
+    whatever the context.
     """
     weights = param_count(cfg, active_only=True)
-    kv = cache_bytes_per_token(cfg, kv_bytes) * max(0, context_len)
+    kv = rows * live_cache_bytes(cfg, context_len, kv_bytes)
     state = 2 * rows * state_bytes_per_row(cfg, kv_bytes)
     return float(weights * weight_bytes + kv + state)
 
@@ -307,10 +329,9 @@ def batched_decode_mbu(
     peak = device_peak_hbm_bw(device_kind)
     if peak is None or tokens_per_sec <= 0 or batch <= 0:
         return None
-    # weights + batch·kv per step == decode_bytes_per_token at an
-    # effective context of batch·context_len (the KV term is linear) —
-    # one bytes model serves both the single-stream and batched MBU.
+    # weights + batch·kv per step: one bytes model serves both the
+    # single-stream and the batched MBU (each row reads its own live slots).
     per_step = decode_bytes_per_token(
-        cfg, batch * context_len, weight_bytes, kv_bytes, rows=batch
+        cfg, context_len, weight_bytes, kv_bytes, rows=batch
     )
     return (tokens_per_sec / batch) * per_step / (peak * n_devices)

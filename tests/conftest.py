@@ -58,6 +58,30 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
+# JAX's file cache writes an entry in place (``Path.write_bytes``), and its
+# reader takes whatever bytes the file holds: under xdist a worker can read
+# an entry another worker is half-way through writing, and a whole run lost
+# a worker to "Fatal Python error: Segmentation fault" inside
+# compilation_cache.get_executable_and_time (four whole runs of four, PR 48,
+# each in another test; every entry read whole afterwards). Write beside the
+# entry and rename: a reader sees all of an entry or none of it.
+from jax._src import lru_cache as _lru  # noqa: E402
+
+_put_in_place = _lru.LRUCache.put
+
+
+def _put_whole(self, key: str, val: bytes) -> None:
+    if self.eviction_enabled or not key:
+        return _put_in_place(self, key, val)
+    path = self.path / f"{key}{_lru._CACHE_SUFFIX}"
+    if not path.exists():
+        beside = path.with_name(f".{path.name}.{os.getpid()}")
+        beside.write_bytes(val)
+        os.replace(beside, path)
+
+
+_lru.LRUCache.put = _put_whole
+
 
 def pytest_configure(config):
     config.addinivalue_line("markers", "slow: long-running (checkpoint/e2e) tests")

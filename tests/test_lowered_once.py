@@ -29,7 +29,8 @@ from llm_consensus_tpu.models.config import MODEL_PRESETS
 from tests.test_nemotron_h import OLDER
 from tests.test_solar_open2 import PINNED
 
-FAMILIES = {**OLDER, **{f: (name, None) for f, name in PINNED.items()}}
+FAMILIES = {**OLDER, **{f: (name, None) for f, name in PINNED.items()},
+            "afmoe": ("tiny-afmoe", None)}
 TRACE = "/jax/core/compile/jaxpr_trace_duration"
 LOWERING = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 STEMS = ("_prefill_step", "prefill_chunks_loop__", "decode_chunk__")

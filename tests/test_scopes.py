@@ -38,6 +38,8 @@ FAMILIES = {
     "routed": ("tiny-mixtral", None),
     "one-part": ("tiny-nemotron-h", None),
     "delta-rule": ("tiny-solar-open2", None),
+    # window and full attention layers in one stack, a sweep's scope a kind
+    "mixed-windows": ("tiny-afmoe", None),
 }
 PROGRAMS = ("decode_chunk", "prefill_step", "prefill_chunks_loop")
 CASES = [(f, p) for f in FAMILIES for p in PROGRAMS]
@@ -56,7 +58,7 @@ def expected(cfg, program: str) -> set:
             want.discard("moe.shared")
         if not cfg.moe_latent:
             want -= {"moe.latent_in", "moe.latent_out"}
-    if not cfg.is_moe or cfg.n_dense_layers:
+    if not cfg.is_moe or cfg.n_dense_layers or cfg.n_mlp_layers:
         want.add("mlp")
     if cfg.has_ssm:
         want |= set(scopes.SSM) - {
@@ -66,6 +68,8 @@ def expected(cfg, program: str) -> set:
             "kda.scan" if program == "decode_chunk" else "kda.step"}
     if cfg.attn_out_gate:
         want |= set(scopes.ATTN_GATE)
+    if "W" in cfg.layer_kinds:
+        want |= set(scopes.ATTN_KINDS)
     if program == "decode_chunk":
         want |= {"sample", "sentinel", "chunk.tail"}
     if program == "prefill_chunks_loop":

@@ -109,6 +109,8 @@ HYBRID_DECODE = "falcon-h1-34b:decode"
 ONE_PART_CONFIG = "benchmark/configs/nemotron3-super-ep8-trio-bf16.json"
 DELTA_CONFIG = "benchmark/configs/solar-open2-ep8-trio-bf16.json"
 ONE_PART_ROWS, ONE_PART_STEPS, ONE_PART_WIDTH = 6, 16, 384
+MIXED_WINDOW_CONFIG = "benchmark/configs/trinity-mini-pp8-trio-bf16.json"
+MIXED_WINDOW_WIDTHS = (384, 2176)  # a panel phase's bucket; a judge phase's, past the window
 SHARDED_ROUTED = "mixtral-8x7b-2-layers-tp2:decode"
 ROUTED_CONFIGS = {  # cell -> its configuration file
     "dsv2": LATENT_CONFIG, "nem3": ONE_PART_CONFIG, "solar2": DELTA_CONFIG}
@@ -239,6 +241,9 @@ def _compile_all() -> dict:
             sds, shapes, width)
     report["one-part-decode"] = _one_part_decode_chunk(sds, shapes, ONE_PART_CONFIG)
     report["delta-decode"] = _one_part_decode_chunk(sds, shapes, DELTA_CONFIG)
+    for width in MIXED_WINDOW_WIDTHS:
+        report[f"mixed-window-decode:kv{width}"] = _mixed_window_decode_chunk(
+            sds, shapes, width)
     reads: dict = {}
     report["hybrid-ssm"] = _hybrid_ssm_programs(sds, shapes, has_kernel, reads)
     for name in DENSE_PROGRAMS:
@@ -762,6 +767,44 @@ def _one_part_decode_chunk(sds, shapes, config: str) -> dict:
     }
 
 
+def _mixed_window_decode_chunk(sds, shapes, width: int) -> dict:
+    """The decode chunk of the cell whose judge mixes window and full
+    attention layers (Trinity-Mini's cut: four window layers and a full one,
+    a dense MLP, four expert layers that hold all 128 experts; six rows, 16
+    steps, the sentinel and the routing sums) at a bucket under the window
+    and at one past it, compiled: how many decode-attention kernels a step
+    holds, which form its experts take, and the route booked."""
+    import jax
+    import jax.numpy as jnp
+
+    from llm_consensus_tpu.engine.engine import _decode_chunk
+    from llm_consensus_tpu.models import init_kv_cache, init_params
+    from llm_consensus_tpu.models.transformer import attention_routes
+
+    cfg = _judge(MIXED_WINDOW_CONFIG)
+    attention_routes.reset()
+    params = shapes(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    cache = shapes(lambda: init_kv_cache(
+        cfg, ONE_PART_ROWS, CELL_MAX_SEQ, jnp.bfloat16))
+    try:
+        text = _decode_chunk.lower(
+            params, cfg, sds((ONE_PART_ROWS,)), sds(()), cache,
+            shapes(lambda: jax.random.PRNGKey(0)), n_steps=ONE_PART_STEPS,
+            temperature=0.0, top_k=None, top_p=None,
+            row_start=sds((ONE_PART_ROWS,)), kv_width=width,
+            attn_impl="flash", sentinel=True, moe_stats=True,
+        ).compile().as_text()
+    except Exception as err:  # noqa: BLE001 — what the chip would raise
+        return {"error": f"{type(err).__name__}: {str(err)[:300]}"}
+    experts = _experts_path(text)
+    return {
+        "experts-path": experts,
+        "attention-kernels": text.count("tpu_custom_call") - experts["kernel"],
+        "routes": attention_routes.snapshot(cfg.name),
+        "layers": (cfg.n_attn_layers, cfg.n_window_layers, cfg.n_expert_layers),
+    }
+
+
 def _latent_prefill_branches(sds, shapes) -> dict:
     """The widths of the float32 score blocks in each branch of each
     ``conditional`` of the compiled judge-prompt prefill, a list a
@@ -1054,6 +1097,22 @@ def test_a_routed_decode_chunk_runs_its_experts_in_the_kernel(report, cell):
     assert "error" not in got, got
     assert got["experts-path"] == {"kernel": calls, "ragged-dot": 0}
     assert got["experts"] == []
+
+
+@pytest.mark.parametrize("width", MIXED_WINDOW_WIDTHS)
+def test_a_mixed_window_decode_chunk_holds_a_kernel_a_layer(report, width):
+    """The Trinity-Mini cell's decode chunk (PR 48), compiled for the
+    described chip at the cell's own sizes, under the window's width and
+    past it: one decode-attention kernel an attention layer of either kind
+    (each under ITS kind's sweep plan, made once a step), the four expert
+    layers' products in the kernel over the sorted pairs (48 pairs: two
+    calls a layer), no ``ragged-dot``; the route booked is the kernel's."""
+    got = report[f"mixed-window-decode:kv{width}"]
+    assert "error" not in got, got
+    assert got["layers"] == [5, 4, 4]
+    assert got["attention-kernels"] == 5
+    assert got["experts-path"] == {"kernel": 8, "ragged-dot": 0}
+    assert got["routes"] == {"decode": {"pallas": 1}}
 
 
 @pytest.mark.parametrize("program", ["prefill-loop", "wave"])
