@@ -167,6 +167,7 @@ def _wave_counts(admit: dict) -> dict:
         "prefill_rows_real": admit["rows_real"],
         "prefill_rows_padded": admit["rows_padded"],
         "prefill_slot_tokens": admit["slot_tokens"],
+        "prefill_chunks": admit["chunks"],
         "prefill_kv_pairs_swept": admit["pairs_swept"],
         "prefill_kv_pairs_live": admit["pairs_live"],
     }
@@ -983,6 +984,10 @@ class ContinuousBatcher:
             "decode_kv_slots_swept": 0, "decode_kv_slots_live": 0,
             "prefill_waves": 0, "prefill_rows_real": 0,
             "prefill_rows_padded": 0, "prefill_slot_tokens": 0,
+            # Runs of the whole stack the waves' prefills made: a one-shot
+            # wave one, a prompt past ``prefill_chunk`` one a chunk of the
+            # engine's width (engine.py ``_chunk_width``).
+            "prefill_chunks": 0,
             # (Query, key) pairs the waves' prefill programs' attention
             # scored, and the pairs causality needed for their real tokens
             # (causal_pairs): their ratio is what a prefill sweeps in vain.

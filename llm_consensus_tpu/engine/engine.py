@@ -618,6 +618,7 @@ class Engine:
         stream_interval: int = 16,
         attn_impl: Optional[str] = None,
         prefill_chunk: Optional[int] = None,
+        prefill_width: Optional[int] = None,
         quant: Optional[str] = None,
         kv_quant: Optional[str] = None,
         kv_pool: bool = True,
@@ -667,6 +668,18 @@ class Engine:
         # Long-prompt prefill: past this length, prefill runs as fixed-size
         # chunks through one compiled program (see _prefill_chunk) instead
         # of one-shot per-bucket programs. 0 disables chunking.
+        # That number does two jobs: the LENGTH past which a prompt leaves
+        # the one-shot program (and a wave's rows are admitted one by one),
+        # and the WIDTH of a chunk. Given by the caller or the environment
+        # it does both, as it always has. Left to its default, the width of
+        # the ONE-ROW path's chunks (``_chunk_width``) is the model's own
+        # (utils/flops.py ``prefill_ridge_width``, once the leaves' stored
+        # type is known below): a routed model's chunk feeds each held
+        # expert a sixteenth to a fortieth of its tokens, and 512 wide
+        # streams every expert once a chunk for a dozen rows. Batched and
+        # paced waves, the prefix store and the sessions keep one number.
+        chunk_given = prefill_chunk is not None or knobs.is_set(
+            "LLMC_PREFILL_CHUNK")
         if prefill_chunk is None:
             prefill_chunk = knobs.get_int("LLMC_PREFILL_CHUNK")
         self.prefill_chunk = max(0, prefill_chunk)
@@ -707,6 +720,20 @@ class Engine:
         self.quant = resolve_mode(quant, "LLMC_QUANT", "quant", ("int8", "int4"))
         self.kv_quant = resolve_mode(kv_quant, "LLMC_KV_QUANT", "kv_quant", ("int8",))
         quant = self.quant
+        # bytes a stored weight takes
+        weight_itemsize = {"int8": 1, "int4": 0.5}.get(
+            quant, jnp.dtype(dtype).itemsize)
+        if prefill_width is None:
+            prefill_width = self.prefill_chunk
+            if self.prefill_chunk and not chunk_given:
+                from llm_consensus_tpu.utils.flops import prefill_ridge_width
+
+                device = (mesh.devices.flat[0] if mesh is not None
+                          else jax.devices()[0])
+                prefill_width = prefill_ridge_width(
+                    cfg, device.device_kind, weight_itemsize,
+                    self.prefill_chunk)
+        self.prefill_width = max(self.prefill_chunk, prefill_width)
         # Opt-in W8A8 matmuls (ops/quant._w8a8_einsum): resolved ONCE at
         # engine build and threaded into every jitted program as a STATIC
         # arg — program identity must carry it, or a cached executable
@@ -864,12 +891,9 @@ class Engine:
             try:
                 from llm_consensus_tpu.utils.flops import param_count
 
-                wb = {"int8": 1, "int4": 0.5}.get(
-                    self.quant, jnp.dtype(dtype).itemsize
-                )
                 self._attrib.update_component(
-                    f"weights:{cfg.name}", int(param_count(cfg) * wb)
-                )
+                    f"weights:{cfg.name}",
+                    int(param_count(cfg) * weight_itemsize))
             except Exception:  # noqa: BLE001 — modeling only
                 pass
         from llm_consensus_tpu.kv import pool_for
@@ -915,6 +939,8 @@ class Engine:
             # they sum to the depth only where every layer is one part.
             "attn_layers": cfg.n_attn_layers,
             "expert_layers": cfg.n_expert_layers,
+            # The widest chunk of the one-row path (on a TPU the model's own).
+            "prefill_width": self.prefill_width,
         }
         self._spans.complete(
             "engine.build", t_build_ns, "engine", model=cfg.name,
@@ -1489,7 +1515,7 @@ class Engine:
             # causal frontier reaches them — same invariant the bucketed
             # path relies on.
             last_logits, cache = self._chunked_prefill(
-                prompt_ids, n_prompt, cache, 0, chunk_len
+                prompt_ids, n_prompt, cache, 0, self._chunk_width(n_prompt)
             )
         else:
             bucket = _bucket(n_prompt, self.max_seq)
@@ -1511,6 +1537,23 @@ class Engine:
             self.last_prefill = Prefilled(
                 1, bucket, prefill_pairs_swept(cfg, 1, bucket, slots, bucket), 0)
         return last_logits, cache, reuse_len if reuse_ok else 0
+
+    def _chunk_width(self, n_prompt: int) -> int:
+        """The width of the chunks a prompt of ``n_prompt`` tokens past
+        ``prefill_chunk`` prefills in on the one-row path: the model's
+        (``prefill_width``) under the prompt's bucket and the score
+        transient's cap (utils/flops.py ``prefill_chunk_width``), and
+        narrower where a capacity that is no power of two would not hold
+        the last chunk's padding."""
+        from llm_consensus_tpu.utils.flops import prefill_chunk_width
+
+        width = prefill_chunk_width(
+            self.prefill_width, self.cfg.n_heads,
+            _bucket(n_prompt, self.max_seq), self.prefill_chunk)
+        while width > self.prefill_chunk and (
+                -(-n_prompt // width) * width > self.max_seq):
+            width //= 2
+        return width
 
     def _rows_bucket(self, n_max: int) -> int:
         """Cache capacity ``_prefill_rows`` will allocate for a wave whose

@@ -872,13 +872,57 @@ def _mixer_half(cfg: ModelConfig, h, lp, ssm, layer_idx, span):
             ssm["state"], layer_idx, 0, keepdims=False)
         tail = jax.lax.dynamic_index_in_dim(
             ssm["conv"], layer_idx, 0, keepdims=False)
-    out, state, tail = mixer(cfg, h, lp, state, tail, lo, hi, *whole)
+    if t > STATE_SEGMENT and t % STATE_SEGMENT == 0:
+        out, state, tail = _in_segments(
+            partial(mixer, cfg), h, lp, state, tail, lo, hi, state_write)
+    else:
+        out, state, tail = mixer(cfg, h, lp, state, tail, lo, hi, *whole)
     with scope(state_write):
         return out, {
             "state": state if whole else jax.lax.dynamic_update_index_in_dim(
                 ssm["state"], state.astype(ssm["state"].dtype), layer_idx, 0),
             "conv": jax.lax.dynamic_update_index_in_dim(
                 ssm["conv"], tail.astype(ssm["conv"].dtype), layer_idx, 0)}
+
+
+# Positions a state-keeping part runs over at once. From its in-projection
+# to its out-projection such a part is float32 elementwise work and a chunked
+# scan over [T, heads x head size]: at 512 positions those arrays (17 MB each
+# at 64 heads of 128) stay in the chip's fast memory, at 2,048 (67 MB) they go
+# through device memory. Read on the chip (PERF.md section 6, PR 49, ms a
+# prompt of 1.7k tokens, four chunks of 512 -> one of 2,048): Solar-Open2's
+# three delta layers ``kda.scan`` 17.0 -> 25.0, ``kda.conv`` 2.1 -> 8.5 and
+# 6.6 more in fusions the compiler names itself; Nemotron-3-Super's five
+# mixers ``ssm.scan`` + ``ssm.conv`` + ``ssm.norm`` 8.8 -> 14.5. So a chunk
+# wider than this, which a routed stack's experts want (engine.py
+# ``_chunk_width``), passes through a state-keeping part in segments, its
+# state and tail carried from each to the next as they are from chunk to
+# chunk: the same arithmetic as chunks this wide.
+STATE_SEGMENT = 512
+
+
+def _in_segments(part, h, lp, state, tail, lo, hi, state_write: str):
+    """``part(h, lp, state, tail, lo, hi)`` over ``h`` [B, T, D] in segments
+    of ``STATE_SEGMENT`` positions, one after the other; each row's real
+    span ``[lo, hi)`` (``None``: every position) is cut to each segment."""
+    b, t, d = h.shape
+    n = t // STATE_SEGMENT
+    with scope(state_write):
+        segments = jnp.moveaxis(h.reshape(b, n, STATE_SEGMENT, d), 1, 0)
+        starts = jnp.arange(n, dtype=jnp.int32) * STATE_SEGMENT
+
+    def segment(carry, xs):
+        seg, start = xs
+        with scope(state_write):
+            span = (None, None) if lo is None else tuple(
+                jnp.clip(at - start, 0, STATE_SEGMENT) for at in (lo, hi))
+        out, state, tail = part(seg, lp, *carry, *span)
+        return (state, tail), out
+
+    with scope(state_write):
+        (state, tail), out = jax.lax.scan(
+            segment, (state, tail), (segments, starts))
+        return jnp.moveaxis(out, 0, 1).reshape(b, t, d), state, tail
 
 
 def _latent_scale(cfg: ModelConfig) -> float:

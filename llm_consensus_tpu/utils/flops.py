@@ -269,6 +269,67 @@ def device_peak_hbm_bw(device_kind: str) -> Optional[float]:
     return None if gbps is None else gbps * 1e9
 
 
+def ridge_rows(device_kind: str, itemsize: float = 2) -> Optional[float]:
+    """Rows at which a product with a STORED matrix of ``itemsize`` bytes a
+    value stops being bound by the matrix's bytes: a value is read once and
+    does two operations a row, so ``peak operations / peak bytes a second x
+    itemsize / 2`` (a v5e: 240 rows of a bf16 matrix, 120 of an int8 one,
+    which is dequantized for a bf16 product). None off a TPU."""
+    flops, bw = device_peak_flops(device_kind), device_peak_hbm_bw(device_kind)
+    if flops is None or bw is None:
+        return None
+    return flops / bw * itemsize / 2
+
+
+def prefill_ridge_width(cfg: ModelConfig, device_kind: str,
+                        itemsize: float = 2, floor: int = 512) -> int:
+    """The width of a one-row prefill's chunks at which the LEAST-FED stored
+    matrix of ``cfg``'s stack reaches the device's ridge (``ridge_rows``):
+    ``floor`` doubled until it does. In a chunk of ``w`` tokens a dense
+    matrix sees ``w`` rows; a held expert's sees the pairs a token sends to
+    held experts over the experts held, ``w x experts_per_token / router
+    width`` where routing is even (the share held cancels). Under that width
+    every chunk streams every such matrix whole for fewer rows than pay for
+    it; a prompt narrower than the width can at most stream them once.
+    ``floor`` off a TPU, and for every model without a router."""
+    ridge = ridge_rows(device_kind, itemsize)
+    if ridge is None:
+        return floor
+    fed = cfg.experts_per_token / cfg.n_router if cfg.is_moe else 1.0
+    width = floor
+    while width * fed < ridge:
+        width *= 2
+    return width
+
+
+# The largest float32 score transient ``[heads, chunk, bucket]`` a chunk of a
+# one-row prefill may ask a layer for: 1 GiB, what 64 heads ask at 2,048 x
+# 2,048 and 128 heads at 1,024 x 2,048. Read on the chip (PERF.md section 6,
+# PR 49; ``memory_peak_bytes`` of a serving window of the chip's 16.9 GB, 512
+# -> under this number): Trinity-Mini's and Nemotron-3-Super's shares (32
+# heads) 2,048 wide 12.73 -> 12.74 and 11.23 -> 11.23 GB, Solar-Open2's (64)
+# 2,048 wide 12.35 -> 12.35, DeepSeek-V2's (128) 1,024 wide 13.17 -> 13.17:
+# a wave of six panel prompts sets those peaks, and the 0.57-0.95 GB the
+# wider chunk's transients add (the compiler's count for a described chip)
+# fit under them; at 2,048 DeepSeek-V2's chunk would ask 2.68 GB, in a
+# process whose check after the window ends 0.47 GB under the limit.
+PREFILL_SCORE_BYTES = 1 << 30
+
+
+def prefill_chunk_width(ridge_width: int, n_heads: int, bucket: int,
+                        floor: int = 512) -> int:
+    """The width of the chunks of ONE prompt's one-row prefill: ``floor``
+    doubled while it stays within the model's ``ridge_width``
+    (``prefill_ridge_width``), within the prompt's ``bucket`` of cache slots
+    (a wider chunk is padding) and within ``PREFILL_SCORE_BYTES`` of
+    attention scores a layer."""
+    width = floor
+    while (2 * width <= min(ridge_width, bucket)
+           and n_heads * 2 * width * bucket * 4 <= PREFILL_SCORE_BYTES):
+        width *= 2
+    return width
+
+
 def decode_bytes_per_token(
     cfg: ModelConfig,
     context_len: int = 0,

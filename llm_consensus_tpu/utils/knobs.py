@@ -62,7 +62,8 @@ def _k(name: str, kind: str, default, subsystem: str, doc: str) -> None:
 _k("LLMC_FLASH", "str", "auto", "engine",
    "1/0 force the Pallas flash-prefill kernel on/off (default: auto on TPU)")
 _k("LLMC_PREFILL_CHUNK", "int", 512, "engine",
-   "Chunked-prefill chunk length for long prompts (0 disables)")
+   "Chunked-prefill chunk length for long prompts (0 disables); set, also "
+   "every chunk's width (unset: the one-row route's is the model's own)")
 _k("LLMC_PREFILL_SCAN", "bool", True, "engine",
    "0 disables the scan-form chunked-prefill program")
 _k("LLMC_DECODE_KV_MIN", "int", 128, "engine",

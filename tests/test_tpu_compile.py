@@ -41,6 +41,11 @@ devices (Mixtral's widths, two layers) keeps ``ragged_dot``, which the
 compiler partitions; it refuses a kernel there (``Mosaic kernels cannot be
 automatically partitioned``), and interpret mode never says so.
 
+The four routed cells' judge-prompt loops are also compiled at the width
+the rule gives each judge (utils/flops.py ``prefill_chunk_width``: one chunk
+of 2,048 tokens, two of 1,024 for the 128-head latent judge), and what each
+asks for beside its operands is held under twice the score cap (PR 49).
+
 All cases compile in ONE child process (this file run as a script) and
 the tests read its report: loading libtpu and switching the persistent
 compilation cache off (an entry written for a described device cannot be
@@ -114,6 +119,11 @@ MIXED_WINDOW_WIDTHS = (384, 2176)  # a panel phase's bucket; a judge phase's, pa
 SHARDED_ROUTED = "mixtral-8x7b-2-layers-tp2:decode"
 ROUTED_CONFIGS = {  # cell -> its configuration file
     "dsv2": LATENT_CONFIG, "nem3": ONE_PART_CONFIG, "solar2": DELTA_CONFIG}
+# The four routed cells' judges: cell -> (configuration file, the width of a
+# judge prompt's chunks by the rule, utils/flops.py, in a 2,048-slot bucket).
+WIDE_PREFILL = {
+    "trin": (MIXED_WINDOW_CONFIG, 2048), "solar2": (DELTA_CONFIG, 2048),
+    "nem3": (ONE_PART_CONFIG, 2048), "dsv2": (LATENT_CONFIG, 1024)}
 TEXT_PINS = os.path.join(
     os.path.dirname(os.path.abspath(__file__)), "data", "lowered_text_pins.json")
 
@@ -253,6 +263,8 @@ def _compile_all() -> dict:
     for cell in ROUTED_CONFIGS:
         report[f"routed-prefill:{cell}"] = _routed_prefill_programs(
             sds, shapes, cell, reads)
+    for cell, (config, _) in WIDE_PREFILL.items():
+        report[f"wide-prefill:{cell}"] = _wide_prefill_loop(sds, shapes, config)
     report[SHARDED_ROUTED] = _sharded_routed_decode(topo, has_kernel, reads)
     report["moe-pairs:largest"] = _largest_pairs_kernel(sds, has_kernel)
     report["texts"] = {name[len("text:"):]: reads.pop(name)
@@ -407,6 +419,35 @@ def _routed_prefill_programs(sds, shapes, cell: str, reads: dict) -> dict:
                   for name, lowered in programs.items()})
     return {name: _experts_path(lowered.as_text())
             for name, lowered in programs.items()}
+
+
+def _wide_prefill_loop(sds, shapes, config: str) -> dict:
+    """A routed cell's judge-prompt loop at the width the rule gives the
+    judge on a v5e (utils/flops.py), compiled whole: its width, its chunks
+    and the transients the chip's compiler counts for it."""
+    import jax
+    import jax.numpy as jnp
+
+    from llm_consensus_tpu.engine.engine import _prefill_chunks_loop
+    from llm_consensus_tpu.models import init_kv_cache, init_params
+    from llm_consensus_tpu.utils import flops
+
+    cfg = _judge(config)
+    width = flops.prefill_chunk_width(
+        flops.prefill_ridge_width(cfg, "TPU v5 lite"), cfg.n_heads, LATENT_BUCKET)
+    chunks = LATENT_BUCKET // width
+    try:
+        compiled = _prefill_chunks_loop.lower(
+            shapes(lambda: init_params(cfg, jax.random.PRNGKey(0))), cfg,
+            sds((chunks, 1, width)), sds(()), sds(()), sds((1,)),
+            shapes(lambda: init_kv_cache(cfg, 1, CELL_MAX_SEQ, jnp.bfloat16)),
+            max_chunks=chunks, kv_width=LATENT_BUCKET, moe_stats=True,
+        ).compile()
+    except Exception as err:  # noqa: BLE001 — what the chip would raise
+        return {"error": f"{type(err).__name__}: {str(err)[:300]}"}
+    return {"width": width, "chunks": chunks,
+            "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+            "experts-path": _experts_path(compiled.as_text())}
 
 
 def _largest_pairs_kernel(sds, has_kernel) -> dict:
@@ -1123,6 +1164,24 @@ def test_a_routed_prefill_keeps_the_grouped_product(report, cell, program):
     (ops/moe.py ``pairs_kernel_serves``) leaves them ``ragged_dot``."""
     got = report[f"routed-prefill:{cell}"][program]
     assert got["kernel"] == 0 and got["ragged-dot"] > 0
+
+
+@pytest.mark.parametrize("cell", WIDE_PREFILL)
+def test_a_routed_judges_prompt_at_the_models_width_compiles(report, cell):
+    """PR 49: a judge prompt of a routed cell is one chunk of 2,048 tokens
+    (two of 1,024 where 128 heads' scores meet the cap), the whole loop
+    compiles for the described chip, its experts stay grouped products
+    (16-45k pairs a chunk), and what it asks beside its operands stays
+    within twice the score cap (the scores, a layer's gathered rows and the
+    experts' intermediate; 0.77-1.49 GB here against 0.17-0.93 at 512)."""
+    from llm_consensus_tpu.utils.flops import PREFILL_SCORE_BYTES
+
+    got = report[f"wide-prefill:{cell}"]
+    assert "error" not in got, got
+    assert (got["width"], got["chunks"]) == (
+        WIDE_PREFILL[cell][1], 2048 // WIDE_PREFILL[cell][1])
+    assert got["experts-path"]["kernel"] == 0 < got["experts-path"]["ragged-dot"]
+    assert got["temp_bytes"] <= 2 * PREFILL_SCORE_BYTES
 
 
 def test_the_largest_buffer_the_switch_gives_the_kernel_compiles(report):
